@@ -8,8 +8,8 @@ TPU-first:
 - ``columnar``: Arrow-layout columns/tables as sharded jax.Arrays in HBM
   (analog of cudf columns + the cudf Java handle objects).
 - ``ops``: the op surface (RowConversion, Hash, CastStrings, ZOrder, BloomFilter,
-  TimeZoneDB, RegexRewrite, joins/aggregates) as jit-able XLA programs and Pallas
-  kernels (analog of src/main/cpp/src/*.cu).
+  TimeZoneDB, RegexRewrite, joins/aggregates) as jit-able XLA programs (analog
+  of src/main/cpp/src/*.cu).
 - ``parallel``: hash-partition shuffle / exchange as ICI collectives over a
   jax.sharding.Mesh (net-new vs the reference, which defers exchange to Spark).
 - ``bridge``: native C++ handle-table + IPC bridge so a JVM-side caller round-trips

@@ -16,9 +16,6 @@ the rendered text.
 
 from __future__ import annotations
 
-import json
-import os
-import threading
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -29,38 +26,29 @@ from .plan import (Aggregate, Exchange, Filter, Join, Limit, PlanNode,
 
 # -- roofline ceiling --------------------------------------------------------
 
-_ceiling_lock = threading.Lock()
-_ceiling_cache: list = [False, None]  # [loaded?, value] — under _ceiling_lock
+#: published HBM bandwidth by ``device_kind``, GB/s.  v5e: 819 GB/s (Google
+#: Cloud documentation, "TPU v5e").  A kind that is not here has no ceiling:
+#: nothing is assumed for a device the code does not know, the CPU included.
+PUBLISHED_HBM_GBPS = {
+    "TPU v5 lite": 819.0,
+    "TPU v5e": 819.0,
+}
 
 
 def roofline_ceiling_gbps() -> Optional[float]:
     """The device-bandwidth ceiling per-node GB/s is judged against.
 
-    Resolution order: ``config.roofline_gbps`` (the SRJT_ROOFLINE_GBPS
-    override — read every call so tests can pin it via refresh()), then
-    the ``device_bandwidth_ceiling_GBps`` entry pinned in
-    BENCH_BASELINES.json at the repo root (cached after one read, behind
-    ``_ceiling_lock`` — two concurrent explain-analyze calls must not race
-    the load).  Returns None when neither exists — annotations then omit
-    ``roofline_frac`` rather than inventing a ceiling.
+    ``config.roofline_gbps`` (the SRJT_ROOFLINE_GBPS override — read every
+    call so tests can pin it via refresh()) wins; otherwise the published
+    peak for the ``device_kind`` the plan runs on.  Returns None for a kind
+    without a published peak — annotations then omit ``roofline_frac``
+    rather than inventing a ceiling.
     """
     from ..utils.config import config
     if config.roofline_gbps > 0:
         return config.roofline_gbps
-    with _ceiling_lock:
-        if not _ceiling_cache[0]:
-            _ceiling_cache[0] = True
-            root = os.path.dirname(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__))))
-            path = os.path.join(root, "BENCH_BASELINES.json")
-            try:
-                with open(path) as f:
-                    pins = json.load(f)
-                _ceiling_cache[1] = float(
-                    pins["device_bandwidth_ceiling_GBps"]["pinned_baseline"])
-            except Exception:
-                _ceiling_cache[1] = None
-        return _ceiling_cache[1]
+    import jax
+    return PUBLISHED_HBM_GBPS.get(jax.devices()[0].device_kind)
 
 
 def _describe_scan(node: Scan) -> str:

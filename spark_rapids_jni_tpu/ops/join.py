@@ -76,7 +76,7 @@ def _pair_equal(lcol: Column, rcol: Column, li, ri, null_equal: bool):
     return eq
 
 
-_TAG = jnp.int64(1) << 32  # packs (tie tag, unsort index) into ONE operand
+_TAG = np.int64(1) << 32  # packs (tie tag, unsort index) into ONE operand
 
 
 def _rank_bounds(ref, queries, ref_sorted=None) \

@@ -24,10 +24,7 @@ by the existing double-buffered prefetch pipeline:
   only <=32-bit bitcasts exist), and **dictionary gather** through the
   decoded dictionary page.
 
-Word assembly optionally runs as a Pallas VMEM kernel
-(`pallas_kernels.available()` + a Mosaic probe of this kernel shape); the
-pure-XLA shift assembly is the always-correct fallback and the CPU test
-path (``interpret=True``).
+Word assembly is XLA shifts and ors (``assemble_u32``) on every platform.
 
 Wire format: each column chunk ships as padded ``uint8`` *page planes* —
 ``comp[P+1, CB]`` (row 0 = dictionary page or zeros, rows 1..P = data
@@ -58,8 +55,7 @@ import numpy as np
 from ..columnar import Column, Table
 from ..dtypes import DType, TypeId
 
-#: floor for the per-page byte/value buckets (lane-width aligned so the
-#: Pallas word-assembly blocks always divide evenly)
+#: floor for the per-page byte/value buckets (lane-width aligned)
 MIN_BUCKET = 128
 
 
@@ -333,59 +329,13 @@ def _rle_hybrid(data, start, end, bw, n, vb: int):
 
 # -- PLAIN fixed-width gather + word assembly -------------------------------
 
-def _asm_kernel(b_ref, o_ref):
-    """u8 (blk, 512) byte block -> u32 (blk, 128) word block in VMEM."""
-    x = b_ref[:].astype(jnp.uint32).reshape(o_ref.shape[0], -1, 4)
-    o_ref[:] = (x[..., 0] | x[..., 1] << 8 | x[..., 2] << 16
-                | x[..., 3] << 24)
-
-
-def _asm_call(nblocks: int, interpret: bool):
-    from jax.experimental import pallas as pl
-    return pl.pallas_call(
-        _asm_kernel, grid=(nblocks,),
-        in_specs=[pl.BlockSpec((1, 512), lambda r: (r, 0))],
-        out_specs=pl.BlockSpec((1, 128), lambda r: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, 128), jnp.uint32),
-        interpret=interpret)
-
-
-@functools.lru_cache(maxsize=1)
-def _asm_available() -> bool:
-    """Probe whether Mosaic compiles the byte->word assembly kernel.
-
-    `pallas_kernels.available()` proves gridded pallas_call works at all;
-    this probes THIS kernel's u8 load + reshape shape, eagerly (see
-    pallas_kernels.available for why ensure_compile_time_eval)."""
-    from . import pallas_kernels
-    if not pallas_kernels.available():
-        return False
-    try:
-        with jax.ensure_compile_time_eval():
-            out = _asm_call(2, False)(jnp.zeros((2, 512), jnp.uint8))
-            np.asarray(out)
-        return True
-    except Exception:
-        return False
-
-
-def assemble_u32(b, *, interpret: bool = False, force_pallas: bool = False):
-    """``u8[..., 4]`` little-endian byte groups -> ``u32[...]``.
-
-    Pallas VMEM kernel when available (or forced for interpreter tests),
-    pure-XLA shift assembly otherwise.  The Pallas path needs the flattened
-    byte count to divide 512 — guaranteed by the pow2 buckets (>= 128
-    values x 4 bytes)."""
-    total = int(np.prod(b.shape))
-    if (force_pallas or _asm_available()) and total % 512 == 0:
-        flat = b.reshape(-1, 512)
-        out = _asm_call(flat.shape[0], interpret)(flat)
-        return out.reshape(b.shape[:-1])
+def assemble_u32(b):
+    """``u8[..., 4]`` little-endian byte groups -> ``u32[...]``."""
     x = b.astype(_U32)
     return x[..., 0] | x[..., 1] << 8 | x[..., 2] << 16 | x[..., 3] << 24
 
 
-def _plain_gather(unc, voff, nn, dtype: DType, *, interpret: bool = False):
+def _plain_gather(unc, voff, nn, dtype: DType):
     """PLAIN-encoded values: byte gather at per-slot offsets + assembly.
 
     ``unc[R, UB]`` page planes, ``voff[R]`` value-section starts, ``nn[R,V]``
@@ -405,15 +355,15 @@ def _plain_gather(unc, voff, nn, dtype: DType, *, interpret: bool = False):
     flat = jnp.clip(offs.reshape(r, -1), 0, ub - 1)
     b = jnp.take_along_axis(unc, flat, axis=1).reshape(r, -1, size)
     if size == 4:
-        w = assemble_u32(b, interpret=interpret)
+        w = assemble_u32(b)
         if dtype.id == TypeId.FLOAT32:
             return jax.lax.bitcast_convert_type(w, jnp.float32)
         return jax.lax.bitcast_convert_type(w, jnp.dtype(dtype.storage))
     # size == 8: rebuild from u32 pairs (staging's TPU-proven idiom —
     # only <= 32-bit bitcasts exist there).  FLOAT64 device storage IS the
     # int64 bit pattern (dtypes.device_storage), so this is the final form.
-    lo = assemble_u32(b[..., :4], interpret=interpret)
-    hi = assemble_u32(b[..., 4:], interpret=interpret)
+    lo = assemble_u32(b[..., :4])
+    hi = assemble_u32(b[..., 4:])
     pairs = jnp.stack([lo, hi], axis=-1)
     return jax.lax.bitcast_convert_type(pairs, jnp.int64)
 
@@ -426,8 +376,7 @@ def _le32(unc, at: int):
             | _i32(unc[:, at + 2]) << 16 | _i32(unc[:, at + 3]) << 24)
 
 
-def _decode_column(p: dict, g: ColumnGeom, rb: int, *,
-                   interpret: bool = False):
+def _decode_column(p: dict, g: ColumnGeom, rb: int):
     """One column chunk's planes -> (data[rb], validity[rb] | None)."""
     if g.encoding == "plain":
         # PLAIN never reads the dict row -- skip decompressing plane 0
@@ -459,12 +408,11 @@ def _decode_column(p: dict, g: ColumnGeom, rb: int, *,
         nnon = nv_d
 
     if g.encoding == "plain":
-        dense = _plain_gather(dunc, voff, nn, g.dtype, interpret=interpret)
+        dense = _plain_gather(dunc, voff, nn, g.dtype)
     else:  # dictionary: decode the dict page, then gather through indices
         dvals = _plain_gather(
             unc[:1], jnp.zeros((1,), _I32),
-            jnp.arange(g.db, dtype=_I32)[None, :], g.dtype,
-            interpret=interpret)[0]
+            jnp.arange(g.db, dtype=_I32)[None, :], g.dtype)[0]
         nd = p["nv"][0]
         dvals = jnp.where(jnp.arange(g.db, dtype=_I32) < nd, dvals,
                           jnp.zeros((), dvals.dtype))
@@ -494,8 +442,7 @@ def _decode_column(p: dict, g: ColumnGeom, rb: int, *,
     return data, None
 
 
-def decode_table(planes: dict, geom: ChunkGeom, *,
-                 interpret: bool = False) -> Table:
+def decode_table(planes: dict, geom: ChunkGeom) -> Table:
     """Page planes -> bucket-padded device Table (pure traced code).
 
     Mirrors the staged host chunk contract (io/staging.py padded=True):
@@ -504,8 +451,7 @@ def decode_table(planes: dict, geom: ChunkGeom, *,
     """
     cols, names = [], []
     for g in geom.columns:
-        data, validity = _decode_column(planes[g.name], g, geom.rb,
-                                        interpret=interpret)
+        data, validity = _decode_column(planes[g.name], g, geom.rb)
         storage = jnp.dtype(g.dtype.device_storage)
         if data.dtype != storage:  # e.g. unsigned storage: same-width bits
             data = jax.lax.bitcast_convert_type(data, storage)

@@ -30,16 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-try:  # moved to the jax namespace in 0.5; experimental before that
-    from jax import shard_map
-except ImportError:  # pragma: no cover - jax < 0.5
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, **kw):
-        # the replication-check kwarg was renamed check_rep -> check_vma
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_old(f, **kw)
+from jax import shard_map
 
 from ..columnar import Column, Table
 from ..ops.row_conversion import fixed_width_layout, _from_planes

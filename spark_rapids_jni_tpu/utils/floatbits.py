@@ -27,10 +27,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-_CANON_NAN = jnp.uint64(0x7FF8000000000000)
-_INF_BITS = jnp.uint64(0x7FF0000000000000)
-_MANT_MASK = jnp.uint64((1 << 52) - 1)
+# numpy scalars, not jnp: a module-level jnp scalar is a device array made at
+# import (it starts the backend) and every jitted caller closes over it
+_CANON_NAN = np.uint64(0x7FF8000000000000)
+_INF_BITS = np.uint64(0x7FF0000000000000)
+_MANT_MASK = np.uint64((1 << 52) - 1)
 _TWO52 = 2.0**52
 
 # 512 appears twice so the ladders cover the full exponent range (|e| <= 1074:

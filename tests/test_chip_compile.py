@@ -1,0 +1,222 @@
+"""AOT compiles for a DESCRIBED TPU v5e chip: the programs ``chip_smoke.py``
+runs, at their real widths, handed to the installed TPU compiler with no
+chip attached.  What the compiler refuses here it would refuse on the chip,
+at no chip time.  Nothing runs, so these say nothing about results or speed.
+
+All such compiles live in this one file: the process that describes the
+topology loads the TPU library and keeps it, so a second file (another
+xdist worker) could not.  The topology is described inside a module-scoped,
+non-autouse fixture — never at import or collection.
+
+Code that asks ``jax.default_backend()`` still sees the CPU here, so the one
+such switch on this path (``utils/floatbits.py``: native f64 bitcast on the
+CPU, arithmetic bit assembly elsewhere) is steered to its TPU branch by the
+tests, and every program is jitted fresh so no CPU-branch trace is reused.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
+    SingleDeviceSharding
+
+from spark_rapids_jni_tpu import Column, Table
+from spark_rapids_jni_tpu import dtypes as dt
+from spark_rapids_jni_tpu.utils import floatbits
+
+HBM_BYTES = 16 * 1024 ** 3       # one v5e chip
+SF1_ROWS = 2_880_404             # TPC-DS SF1 store_sales
+# Every lax.sort in a program costs the TPU compiler about a minute once the
+# operand passes a few tens of thousands of rows (measured here: 74 s for one
+# 2-operand u32 sort at 65,536 rows, 10 s for the whole groupby at 8,192), so
+# the sort-carried programs compile at a small row count and real column
+# widths; the sort-free row conversion compiles at the full table.
+CHUNK_ROWS = 4_096
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_branches(monkeypatch):
+    """Cache off around the compiles (an entry compiled for a described
+    chip cannot be read back without one) and the TPU branch of floatbits."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    monkeypatch.setattr(floatbits, "_native_f64_bitcast", lambda: False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def on(sharding, tree):
+    """Arrays / ShapeDtypeStructs of a pytree -> shapes placed by ``sharding``."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def compile_for_chip(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    ma = compiled.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    assert need < HBM_BYTES, f"{need} bytes do not fit one chip's HBM"
+    return compiled
+
+
+def sales_chunk(n):
+    """The smoke's store_sales chunk as the staged scan hands it over:
+    i64, i64, f64-as-bits, each with a validity plane."""
+    z = np.zeros(n, np.int64)
+    v = np.ones(n, np.bool_)
+    return Table([Column(dt.INT64, data=z, validity=v),
+                  Column(dt.INT64, data=z, validity=v),
+                  Column(dt.FLOAT64, data=z, validity=v)],
+                 ["ss_sold_date_sk", "ss_store_sk", "ss_ext_sales_price"])
+
+
+def test_fused_q5_chunk_segment(tmp_path, one_chip, tpu_branches):
+    """The fused chunk program of the smoke's plan — filter + semi-join
+    probe + partial groupby — found by running the real plan small on the
+    CPU, then compiled for the chip at the same shapes."""
+    import chip_smoke as cs
+    from spark_rapids_jni_tpu.engine import PlanCache
+    from spark_rapids_jni_tpu.engine import segment as seg
+
+    calls = []
+    orig = seg.CompiledSegment.__call__
+
+    def recording(self, table, nvalid=None, prepared=()):
+        calls.append((self, table, tuple(prepared)))
+        return orig(self, table, nvalid, prepared)
+
+    wh = cs.make_warehouse(str(tmp_path), 6_000, seed=1)
+    try:
+        seg.CompiledSegment.__call__ = recording
+        PlanCache().get(cs.q5_lite(wh["paths"])).execute(stats={})
+    finally:
+        seg.CompiledSegment.__call__ = orig
+    assert calls, "the plan ran no fused segment"
+    compiled, table, prepared = calls[0]
+    assert compiled.segment.agg is not None and compiled.segment.joins
+    compile_for_chip(seg._build_fn(compiled.segment, compiled),
+                     on(one_chip, table),
+                     jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+                     on(one_chip, prepared))
+
+
+def test_groupby_padded_chunk(one_chip, tpu_branches):
+    from spark_rapids_jni_tpu.ops.aggregate import groupby_padded
+
+    def step(t):
+        _, aggs, ngroups = groupby_padded(
+            t, ["ss_store_sk"], [("ss_ext_sales_price", "sum"),
+                                 ("ss_ext_sales_price", "count")])
+        return tuple(a.data for a in aggs), ngroups
+
+    compile_for_chip(step, on(one_chip, sales_chunk(CHUNK_ROWS)))
+
+
+def test_probe_join_prepared_chunk(one_chip, tpu_branches):
+    """The semi-join probe of a chunk against the year of date keys, and
+    the device half of the build (hash + sort)."""
+    from spark_rapids_jni_tpu.ops.hash import xxhash64
+    from spark_rapids_jni_tpu.ops.join import (_build_sort, prepare_build,
+                                               probe_join_prepared)
+    dates = Table([Column(dt.INT64,
+                          data=jnp.arange(2_451_545, 2_451_911))],
+                  ["d_date_sk"])
+    pb = prepare_build(dates, ["d_date_sk"])
+    keys = Table([Column(dt.INT64, data=np.zeros(CHUNK_ROWS, np.int64),
+                         validity=np.ones(CHUNK_ROWS, np.bool_))],
+                 ["ss_sold_date_sk"])
+    compile_for_chip(probe_join_prepared, on(one_chip, keys),
+                     on(one_chip, pb))
+    compile_for_chip(lambda t: _build_sort(xxhash64(t).data),
+                     on(one_chip, dates))
+
+
+def test_sort_chunk(one_chip, tpu_branches):
+    from spark_rapids_jni_tpu.ops.order import SortKey
+    from spark_rapids_jni_tpu.ops.selection import sort_table
+    compile_for_chip(
+        lambda t: sort_table(t, [SortKey(t.columns[0], ascending=True)]),
+        on(one_chip, sales_chunk(CHUNK_ROWS)))
+
+
+def test_fixed_width_rows_sf1(one_chip, tpu_branches):
+    """The reference's one op at the smoke's full table: 2,880,404 rows x
+    (i64, i64, f64) with validity, to the wire image and back."""
+    from spark_rapids_jni_tpu.ops import row_conversion as rc
+    layout = rc.fixed_width_layout([dt.INT64, dt.INT64, dt.FLOAT64])
+    datas = tuple(jax.ShapeDtypeStruct((SF1_ROWS,), jnp.int64,
+                                       sharding=one_chip) for _ in range(3))
+    masks = tuple(jax.ShapeDtypeStruct((SF1_ROWS,), jnp.bool_,
+                                       sharding=one_chip) for _ in range(3))
+    to_rows = compile_for_chip(
+        lambda d, m: rc._to_rows_wire(layout, d, m), datas, masks)
+    assert "tpu_custom_call" not in to_rows.as_text()  # XLA, no kernel
+    words = SF1_ROWS * layout.row_size // 4
+    compile_for_chip(
+        lambda w: rc._from_planes(layout, rc._from_wire(layout, w, SF1_ROWS)),
+        jax.ShapeDtypeStruct((words,), jnp.uint32, sharding=one_chip))
+
+
+def test_staged_unpack(one_chip, tpu_branches):
+    from spark_rapids_jni_tpu.io import staging
+    rows = 32_768
+    z = np.zeros(rows, np.int64)
+    v = np.ones(rows, np.bool_)
+    specs = [("a", dt.INT64, z, v), ("b", dt.INT64, z, v),
+             ("c", dt.FLOAT64, z.astype(np.float64), v)]
+    plan, total = staging._plan_for(specs)
+    compile_for_chip(lambda w: staging._unpack.__wrapped__(w, plan),
+                     jax.ShapeDtypeStruct((total,), jnp.uint32,
+                                          sharding=one_chip))
+
+
+def test_host_exchange_on_four_chips(topo, tpu_branches):
+    """The two programs of the host exchange on a Mesh of the four
+    described chips, at the smoke's partial-aggregate shape (12 stores)."""
+    from spark_rapids_jni_tpu.ops.row_conversion import fixed_width_layout
+    from spark_rapids_jni_tpu.parallel import shuffle as sh
+    from spark_rapids_jni_tpu.parallel.mesh import ROW_AXIS
+    mesh = Mesh(np.array(topo.devices), (ROW_AXIS,))
+    assert mesh.size == 4
+    rows = NamedSharding(mesh, PartitionSpec(ROW_AXIS))
+    part = Table([Column(dt.INT64, data=np.zeros(12, np.int64)),
+                  Column(dt.FLOAT64, data=np.zeros(12, np.int64)),
+                  Column(dt.INT64, data=np.zeros(12, np.int64))],
+                 ["ss_store_sk", "sales", "n"])
+    specs = sh.key_specs_for(part, ["ss_store_sk"], None)
+    datas = tuple(jax.ShapeDtypeStruct((12,), jnp.int64, sharding=rows)
+                  for _ in range(3))
+    masks = tuple(jax.ShapeDtypeStruct((12,), jnp.bool_, sharding=rows)
+                  for _ in range(3))
+    n_valid = jax.ShapeDtypeStruct(
+        (), jnp.int64, sharding=NamedSharding(mesh, PartitionSpec()))
+    sh.make_partition_counts(mesh, specs, masked=True) \
+        .lower(datas, masks, n_valid).compile()
+    shuffle = sh.make_shuffle(mesh, fixed_width_layout(part.dtypes()), specs,
+                              sh.cap_bucket(3)) \
+        .lower(datas, masks, masks[0]).compile()
+    assert "all-to-all" in shuffle.as_text()

@@ -8,6 +8,8 @@ cost strictly fewer round trips.  The server's plan cache must report a hit
 on the second submission of the same plan.
 """
 
+import os
+
 import numpy as np
 import pyarrow as pa
 import pyarrow.parquet as pq
@@ -160,3 +162,53 @@ def test_plan_execute_structured_verification_error(server, files):
     assert ei.value.node_path == "root"
     c.ping()
     c.close()
+
+
+def test_second_connection_after_release_and_close(server, files):
+    """A server must take a second client: the first runs a plan (a snappy
+    scan), a per-op read and an export, releases everything and closes;
+    only after its server thread is gone does a second connection send
+    PLAN_EXECUTE.  The server used to die with SIGSEGV here — pyarrow,
+    first loaded inside the first connection's thread, lost its allocator
+    state with that thread (bridge/server.py loads it on the main thread)."""
+    import time
+    c = BridgeClient(server)
+    handles = c.execute_plan(multi_op_plan(files))
+    th = c.read_parquet(str(files / "fact.parquet"))
+    want = c.export_host(handles[0])
+    for h in handles + [th]:
+        c.release(h)
+    assert c.live_count() == 0
+    c.close()
+    time.sleep(1.0)  # let the first connection's server thread exit
+
+    c2 = BridgeClient(server)
+    h2 = c2.execute_plan(multi_op_plan(files))
+    got = c2.export_host(h2[0])
+    for (_, a, _), (_, b, _) in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    c2.release(h2[0])
+    assert c2.metrics()["errors"] >= 0  # the server is alive and answering
+    c2.close()
+
+
+def test_children_inherit_the_platform_and_report_their_device(server,
+                                                                monkeypatch):
+    """``child_environ`` injects no platform — a child takes what it
+    inherits, or jax's own choice — and the server says over OP_METRICS
+    which device it computes on."""
+    from spark_rapids_jni_tpu.utils.config import child_environ
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    assert "JAX_PLATFORMS" not in child_environ()
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert child_environ()["JAX_PLATFORMS"] == "cpu"
+    assert "spark_rapids_jni_tpu" in "".join(
+        os.listdir(child_environ()["PYTHONPATH"].split(os.pathsep)[0]))
+
+    c = BridgeClient(server)
+    device = c.metrics()["device"]
+    c.close()
+    # the suite's servers inherit JAX_PLATFORMS=cpu from tests/conftest.py
+    assert device["platform"] == "cpu"
+    assert device["kind"] and device["count"] >= 1
+    assert "memory" in device  # allocator stats; None where unreported

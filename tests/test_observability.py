@@ -17,7 +17,7 @@ from spark_rapids_jni_tpu.utils import tracing
 
 def test_config_defaults():
     c = cfg.Config.from_env() if "SRJT_TRACE" not in os.environ else None
-    assert cfg.config.pallas in ("auto", "on", "off")
+    assert cfg.config.log_format in ("text", "json")
 
 
 def test_config_refresh_reads_env(monkeypatch):
@@ -358,7 +358,7 @@ def test_histogram_snapshot_exports_sum_count_mean(metrics_isolation):
 def test_explain_analyze_roofline_columns(metrics_warehouse, monkeypatch):
     """Per-node cost attribution: bytes_moved / GB/s / roofline_frac in
     both the structured nodes and the rendered tree, against the env-pinned
-    ceiling (SRJT_ROOFLINE_GBPS wins over BENCH_BASELINES.json)."""
+    ceiling (SRJT_ROOFLINE_GBPS wins over the published peak)."""
     from spark_rapids_jni_tpu.engine import explain_analyze
     monkeypatch.setenv("SRJT_ROOFLINE_GBPS", "100.0")
     cfg.refresh()
@@ -385,16 +385,22 @@ def test_explain_analyze_roofline_columns(metrics_warehouse, monkeypatch):
     assert total >= root["bytes_moved"]
 
 
-def test_roofline_ceiling_from_baselines_file():
-    """Without the env override the ceiling comes from the
-    device_bandwidth_ceiling_GBps pin in BENCH_BASELINES.json."""
+def test_roofline_only_for_a_published_device_kind(metrics_warehouse):
+    """Without the env override the ceiling is the published peak of the
+    device_kind that runs the plan; a kind without one (the CPU here) gets
+    no ceiling and EXPLAIN prints no roofline_frac."""
     from spark_rapids_jni_tpu.engine import explain as ex
+    from spark_rapids_jni_tpu.engine import explain_analyze
     assert "SRJT_ROOFLINE_GBPS" not in os.environ
     assert cfg.config.roofline_gbps == 0.0
-    with ex._ceiling_lock:
-        ex._ceiling_cache[0] = False  # force a re-read
-    ceiling = ex.roofline_ceiling_gbps()
-    assert ceiling == pytest.approx(562.11)
+    assert ex.PUBLISHED_HBM_GBPS["TPU v5 lite"] == 819.0
+    assert ex.roofline_ceiling_gbps() is None
+    rep = explain_analyze(_agg_plan(metrics_warehouse), fused=True)
+    assert "GB/s=" in rep.text
+    assert "roofline_frac" not in rep.text
+    assert "roofline_ceiling_GBps" not in rep.text
+    assert all(n["metrics"]["roofline_frac"] is None for n in rep.nodes
+               if n["metrics"] is not None)
 
 
 def test_memory_telemetry_in_summary_and_gauges(metrics_warehouse,
