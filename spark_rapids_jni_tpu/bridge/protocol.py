@@ -96,6 +96,11 @@ OP_QUERY_STATUS = 25   # [trace_id hex utf-8, optional] -> [json utf-8]
 #                        so a second connection can poll a running
 #                        PLAN_EXECUTE
 
+#: opcode -> lower-case name (``plan_execute``): the server's spans and
+#: timers are ``bridge.op.<name>``
+OP_NAMES = {v: k[3:].lower() for k, v in list(globals().items())
+            if k.startswith("OP_") and isinstance(v, int)}
+
 # OP_GROUPBY aggregation codes
 AGG_SUM, AGG_COUNT, AGG_MIN, AGG_MAX, AGG_MEAN = 0, 1, 2, 3, 4
 AGG_COUNT_ALL, AGG_VAR, AGG_STD, AGG_SUMSQ = 5, 6, 7, 8

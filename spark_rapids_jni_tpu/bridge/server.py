@@ -40,6 +40,7 @@ from . import protocol as P
 from . import shm as shmlib
 from ..columnar import Column, Table
 from ..dtypes import DType, TypeId
+from ..utils.tracing import op_scope
 
 _COLDESC = P.COLDESC
 _STRDESC = P.STRDESC
@@ -247,14 +248,19 @@ class BridgeServer:
             n = self._exp_counter
         return f"tpub-exp-{os.getpid()}-{n}"
 
-    def _op_export_table(self, payload: bytes) -> bytes:
+    def _op_export_table(self, payload: bytes, trace_id: str = "") -> bytes:
         (h,) = struct.unpack_from("<Q", payload)
         table = self.handles.get(h)
         if not isinstance(table, Table):
             raise TypeError(f"handle {h} is not a table")
         name = self._new_export_name()
         exp = shmlib.SegmentWriter(name)
-        descs = [_export_column_desc(exp, c) for c in table.columns]
+        from ..utils.memory import table_nbytes
+        # the device -> host fetch: where a request's last wait for the
+        # device falls (`bridge.op.export_table_s` is its timer)
+        with op_scope("bridge.export", bytes=table_nbytes(table),
+                      trace_id=trace_id):
+            descs = [_export_column_desc(exp, c) for c in table.columns]
         m = exp.finish()
         with self._exports_lock:
             self._exports[name] = m
@@ -489,15 +495,17 @@ class BridgeServer:
         from ..engine import deserialize
         from ..utils import blackbox
         with blackbox.query_scope(trace_id, label="plan_execute") as scope:
-            plan = deserialize(blob)
-            from ..utils.config import config
-            if config.verify:
-                # build-time checks up front: a bad plan (unknown column,
-                # join dtype mismatch, ...) becomes a structured error reply
-                # carrying the check code + node path (_error_body), not an
-                # executor traceback from deep inside a chunk loop
-                from ..engine import verify
-                verify(plan)
+            with op_scope("bridge.plan.decode"):  # outside `wall_s`
+                plan = deserialize(blob)
+                from ..utils.config import config
+                if config.verify:
+                    # build-time checks up front: a bad plan (unknown
+                    # column, join dtype mismatch, ...) becomes a structured
+                    # error reply carrying the check code + node path
+                    # (_error_body), not an executor traceback from deep
+                    # inside a chunk loop
+                    from ..engine import verify
+                    verify(plan)
             if self._plan_cache is None:
                 from ..engine import PlanCache
                 self._plan_cache = PlanCache()
@@ -543,8 +551,9 @@ class BridgeServer:
                                 fingerprint=fp, trace_id=scope.trace_id)
                         try:
                             compiled = self._plan_cache.get(plan)
-                            out = compiled.execute(stats=stats, cancel=tok,
-                                                   session=session)
+                            with op_scope("engine.execute", timed=True):
+                                out = compiled.execute(
+                                    stats=stats, cancel=tok, session=session)
                         finally:
                             if session is not None:
                                 session.release()
@@ -587,7 +596,7 @@ class BridgeServer:
         if opcode == P.OP_FROM_ROWS:
             return self._op_from_rows(payload)
         if opcode == P.OP_EXPORT_TABLE:
-            return self._op_export_table(payload)
+            return self._op_export_table(payload, trace_id)
         if opcode == P.OP_EXPORT_COLUMN:
             return self._op_export_column(payload)
         if opcode == P.OP_RELEASE:
@@ -819,47 +828,61 @@ class BridgeServer:
                     except OSError:
                         pass
                     return
-                try:
-                    t0 = time.perf_counter()
-                    if opcode == P.OP_PLAN_EXECUTE:
-                        # the concurrent path: plan bodies run for seconds
-                        # and the engine below is concurrency-safe, so N
-                        # sessions execute in parallel on their connection
-                        # threads — the scheduler (admission + fair-share
-                        # gates), not this lock, arbitrates between them
-                        out = self._dispatch(opcode, payload, tid)
-                    else:
-                        with self._dispatch_lock:
-                            out = self._dispatch(opcode, payload, tid)
-                    with self._metrics_lock:
-                        ops = self._metrics["ops"]
-                        ops[opcode] = ops.get(opcode, 0) + 1
-                        self._metrics["busy_s"] += time.perf_counter() - t0
-                except Exception as e:  # noqa: BLE001 — CATCH_STD analog
-                    with self._metrics_lock:
-                        self._metrics["errors"] += 1
-                    self._log.warning("op %d failed: %s: %s", opcode,
-                                      type(e).__name__, e)
-                    # post-mortem before replying: the executor's own
-                    # bundle (if any) wins via e.bundle_path; otherwise
-                    # this writes one for pre-executor failures (bad plan,
-                    # bad handle) under the client's trace
-                    from ..utils import blackbox
-                    bundle = getattr(e, "bundle_path", "") or \
-                        blackbox.post_mortem(f"bridge.op:{opcode}", exc=e,
-                                             trace_id=tid) or ""
-                    status, resp = P.STATUS_ERROR, _error_body(
-                        e, trace_id=getattr(e, "trace_id", "") or tid,
-                        bundle=bundle)
-                else:
-                    status, resp = P.STATUS_OK, out
-                try:
-                    P.send_msg(conn, status, resp, trace=trace)
-                except OSError:
-                    # client died mid-reply, or a slow client tripped the
-                    # send deadline (socket.timeout is an OSError): drop
-                    # this connection cleanly, keep serving others
-                    return
+                # header received -> reply written, on the profiler's
+                # clock and (timed) in `bridge.op.<name>_s`
+                op_name = P.OP_NAMES.get(opcode, opcode)
+                with op_scope(f"bridge.op.{op_name}", timed=True,
+                              trace_id=tid):
+                    if not self._serve_op(conn, opcode, payload, tid,
+                                          trace):
+                        return
+
+    def _serve_op(self, conn: socket.socket, opcode: int, payload: bytes,
+                  tid: str, trace) -> bool:
+        """Dispatch one request and write its reply; False when the
+        client is gone and the connection should be dropped."""
+        try:
+            t0 = time.perf_counter()
+            if opcode == P.OP_PLAN_EXECUTE:
+                # the concurrent path: plan bodies run for seconds
+                # and the engine below is concurrency-safe, so N
+                # sessions execute in parallel on their connection
+                # threads — the scheduler (admission + fair-share
+                # gates), not this lock, arbitrates between them
+                out = self._dispatch(opcode, payload, tid)
+            else:
+                with self._dispatch_lock:
+                    out = self._dispatch(opcode, payload, tid)
+            with self._metrics_lock:
+                ops = self._metrics["ops"]
+                ops[opcode] = ops.get(opcode, 0) + 1
+                self._metrics["busy_s"] += time.perf_counter() - t0
+        except Exception as e:  # noqa: BLE001 — CATCH_STD analog
+            with self._metrics_lock:
+                self._metrics["errors"] += 1
+            self._log.warning("op %d failed: %s: %s", opcode,
+                              type(e).__name__, e)
+            # post-mortem before replying: the executor's own
+            # bundle (if any) wins via e.bundle_path; otherwise
+            # this writes one for pre-executor failures (bad plan,
+            # bad handle) under the client's trace
+            from ..utils import blackbox
+            bundle = getattr(e, "bundle_path", "") or \
+                blackbox.post_mortem(f"bridge.op:{opcode}", exc=e,
+                                     trace_id=tid) or ""
+            status, resp = P.STATUS_ERROR, _error_body(
+                e, trace_id=getattr(e, "trace_id", "") or tid,
+                bundle=bundle)
+        else:
+            status, resp = P.STATUS_OK, out
+        try:
+            P.send_msg(conn, status, resp, trace=trace)
+        except OSError:
+            # client died mid-reply, or a slow client tripped the
+            # send deadline (socket.timeout is an OSError): drop
+            # this connection cleanly, keep serving others
+            return False
+        return True
 
 
 def serve(sock_path: str) -> None:

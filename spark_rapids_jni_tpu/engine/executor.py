@@ -135,10 +135,12 @@ class _ExecCtx:
     ``root``: the plan being executed — the adaptive layer needs it for
     node paths, ledger appends, and RewriteChecker runs on runtime
     rewrites (engine/adaptive.py).
+    ``stream_end``: ``perf_counter`` where the last streamed chunk loop
+    ended; ``execute`` observes ``engine.post_stream_s`` from it.
     """
 
     __slots__ = ("fuse", "prefetch", "nparents", "segments", "recovery",
-                 "root")
+                 "root", "stream_end")
 
     def __init__(self, root: PlanNode, fuse: bool, prefetch: int,
                  recovery: Optional[RecoveryPolicy] = None):
@@ -150,6 +152,7 @@ class _ExecCtx:
         self.segments: dict = {}  # id(top node) -> Segment | None
         self.recovery = recovery if recovery is not None \
             else RecoveryPolicy()
+        self.stream_end: Optional[float] = None
 
     def segment_for(self, node: PlanNode):
         if not self.fuse:
@@ -426,11 +429,13 @@ def _try_fused_stage(node: Aggregate, memo: dict, stats: dict,
         from . import adaptive
         probed, n = sg.fused_pad(inp.select(stage.sel_names()), ndev)
         probed_sharded = shard_table(probed, mesh)
-        counts = sh.partition_counts(probed_sharded, mesh,
-                                     list(stage.combine.keys),
-                                     n_valid_rows=n)
-        prepped = (probed, n, probed_sharded)  # reused by the dispatch
         metrics.host_sync(key=id(ex), label="exchange-counts-sizing")
+        with op_scope("engine.sync_wait", timed=True,
+                      label="exchange-counts-sizing"):
+            counts = sh.partition_counts(probed_sharded, mesh,
+                                         list(stage.combine.keys),
+                                         n_valid_rows=n)
+        prepped = (probed, n, probed_sharded)  # reused by the dispatch
         probe_skew = sh.device_load_stats(counts.sum(axis=0))["skew"]
         fused = probe_skew <= float(config.aqe_skew)
         adaptive.record_fused_dispatch(ctx.root, ex, probe_skew,
@@ -691,10 +696,12 @@ def _hash_exchange(node: Exchange, table: Table, ctx: _ExecCtx,
         # global max (one power-of-two bucket up), which that bound can
         # never exceed
         padded, _ = pad_to_multiple(table, ndev)
-        counts = sh.partition_counts(shard_table(padded, mesh), mesh, keys,
-                                     n_valid_rows=rows,
-                                     key_specs=key_specs)
         metrics.host_sync(key=id(node), label="exchange-counts-sizing")
+        with op_scope("engine.sync_wait", timed=True,
+                      label="exchange-counts-sizing"):
+            counts = sh.partition_counts(shard_table(padded, mesh), mesh,
+                                         keys, n_valid_rows=rows,
+                                         key_specs=key_specs)
     if aqe_split and counts is not None:
         # AQE rule 2 (engine/adaptive.py): when the measured matrix shows
         # skew over SRJT_AQE_SKEW, hot destinations' rows are re-dealt
@@ -753,36 +760,40 @@ def _hash_exchange(node: Exchange, table: Table, ctx: _ExecCtx,
     wire = 0
     buf = [[] for _ in table.columns]
     bufv = [[] for _ in table.columns]
-    for ci, (out, ok, ovf) in enumerate(outs):
-        if int(np.asarray(ovf)):
-            raise RuntimeError(
-                "hash exchange overflow despite counts-sized capacity")
-        wire += out.num_rows * layout.row_size  # every slot crosses the wire
-        keep = np.asarray(ok)
-        t_c0 = time.perf_counter()
-        for i, c in enumerate(out.columns):
-            buf[i].append(np.asarray(c.data)[keep])
-            bufv[i].append(np.ones(int(keep.sum()), bool)
-                           if c.validity is None
-                           else np.asarray(c.validity)[keep])
-        if attrib:
-            cap_c = out.num_rows // (ndev * ndev)
-            okm = keep.reshape(ndev, ndev, cap_c)
-            rows_mat += okm.sum(axis=2).T
-            wire_mat += cap_c * layout.row_size  # every slot, per pair
-            cap_rows += ndev * cap_c
-            if tl:
-                dur = time.perf_counter() - t_c0
-                chunk_dev = okm.sum(axis=(1, 2))
-                dev_cum += chunk_dev
-                for d in range(ndev):
-                    timeline.complete("engine.exchange.recv", t_c0, dur,
-                                      {"chunk": ci,
-                                       "rows": int(chunk_dev[d])}, dev=d)
-                    timeline.flow_finish("engine.exchange.chunk",
-                                         fbase + ci * ndev + d, dev=d)
-                    timeline.counter("engine.exchange.dev_rows",
-                                     int(dev_cum[d]), dev=d)
+    # the span is the fetch: ok masks and every column come to the host
+    # here, compacted as they arrive
+    with op_scope("engine.sync_wait", timed=True,
+                  label="exchange-compaction"):
+        for ci, (out, ok, ovf) in enumerate(outs):
+            if int(np.asarray(ovf)):
+                raise RuntimeError(
+                    "hash exchange overflow despite counts-sized capacity")
+            wire += out.num_rows * layout.row_size  # every slot crosses the wire
+            keep = np.asarray(ok)
+            t_c0 = time.perf_counter()
+            for i, c in enumerate(out.columns):
+                buf[i].append(np.asarray(c.data)[keep])
+                bufv[i].append(np.ones(int(keep.sum()), bool)
+                               if c.validity is None
+                               else np.asarray(c.validity)[keep])
+            if attrib:
+                cap_c = out.num_rows // (ndev * ndev)
+                okm = keep.reshape(ndev, ndev, cap_c)
+                rows_mat += okm.sum(axis=2).T
+                wire_mat += cap_c * layout.row_size  # every slot, per pair
+                cap_rows += ndev * cap_c
+                if tl:
+                    dur = time.perf_counter() - t_c0
+                    chunk_dev = okm.sum(axis=(1, 2))
+                    dev_cum += chunk_dev
+                    for d in range(ndev):
+                        timeline.complete("engine.exchange.recv", t_c0, dur,
+                                          {"chunk": ci,
+                                           "rows": int(chunk_dev[d])}, dev=d)
+                        timeline.flow_finish("engine.exchange.chunk",
+                                             fbase + ci * ndev + d, dev=d)
+                        timeline.counter("engine.exchange.dev_rows",
+                                         int(dev_cum[d]), dev=d)
     metrics.count("engine.exchange.shuffles")
     metrics.count("engine.exchange.wire_bytes", wire)
     qm = metrics.current()
@@ -953,13 +964,46 @@ def _exec_streamed(agg: Aggregate, scan: Scan, memo: dict,
       Non-unique build hashes or ineligible schemas fall back to the
       interpreted per-chunk loop, which still pipelines.
     """
-    from ..io import ParquetChunkedReader
     from ..ops.aggregate import groupby
     from ..ops.selection import concat_tables
-    from ..utils.config import config
     from . import segment as sg
 
     _precompute_independent(agg.child, scan, memo, stats, ctx)
+    with op_scope("engine.stream", timed=True):
+        reader, partials, fused, fused_compiled = _stream_chunks(
+            agg, scan, memo, stats, ctx, force_interp)
+    # what follows, to the end of ``execute``, is ``engine.post_stream``:
+    # the eager merge of the partials and every operator above it
+    ctx.stream_end = time.perf_counter()
+    stats["row_groups_pruned"] += reader.groups_pruned
+    stats["row_groups_read"] += reader.groups_read
+
+    if fused:
+        return sg.combine_partials(fused, fused_compiled)
+    if not partials:
+        # everything pruned/filtered: run the plan once on an empty chunk
+        # so the output schema still comes out right (the reader's cached
+        # footer serves the schema — no second file open/parse)
+        sub = _ChunkMemo(memo)
+        sub[id(scan)] = reader.file.empty_table(reader.columns)
+        return _groupby(_exec(agg.child, sub, stats, ctx), agg)
+
+    merged = partials[0] if len(partials) == 1 else concat_tables(partials)
+    combine = [(nm, _STREAM_COMBINE[op])
+               for nm, (_, op) in zip(agg.names, agg.aggs)]
+    return groupby(merged, list(agg.keys), combine, names=list(agg.names))
+
+
+def _stream_chunks(agg: Aggregate, scan: Scan, memo: dict, stats: dict,
+                   ctx: _ExecCtx, force_interp: bool) -> tuple:
+    """The chunk loop of ``_exec_streamed``: reader open -> last chunk
+    dispatched -> reader closed.  Returns ``(reader, partials, fused,
+    fused_compiled)``; at most one of ``partials`` (interpreted path:
+    compacted Tables) and ``fused`` (fused path: padded device partials)
+    is filled."""
+    from ..io import ParquetChunkedReader
+    from ..utils.config import config
+    from . import segment as sg
 
     cols = list(scan.columns) if scan.columns else None
     reader = ParquetChunkedReader(
@@ -994,7 +1038,10 @@ def _exec_streamed(agg: Aggregate, scan: Scan, memo: dict,
                 it = reader.iter_device()
             else:
                 it = reader.iter_staged()
-            first = next(it, None)
+            # thread start + first decode + first staging, as the
+            # consumer feels it
+            with op_scope("engine.stream.first_wait", timed=True):
+                first = next(it, None)
             veto = False
             first_preps: tuple = ()
             if first is not None:
@@ -1131,23 +1178,7 @@ def _exec_streamed(agg: Aggregate, scan: Scan, memo: dict,
                                                 stats, ctx))
     finally:
         reader.close()
-    stats["row_groups_pruned"] += reader.groups_pruned
-    stats["row_groups_read"] += reader.groups_read
-
-    if fused:
-        return sg.combine_partials(fused, fused_compiled)
-    if not partials:
-        # everything pruned/filtered: run the plan once on an empty chunk
-        # so the output schema still comes out right (the reader's cached
-        # footer serves the schema — no second file open/parse)
-        sub = _ChunkMemo(memo)
-        sub[id(scan)] = reader.file.empty_table(cols)
-        return _groupby(_exec(agg.child, sub, stats, ctx), agg)
-
-    merged = partials[0] if len(partials) == 1 else concat_tables(partials)
-    combine = [(nm, _STREAM_COMBINE[op])
-               for nm, (_, op) in zip(agg.names, agg.aggs)]
-    return groupby(merged, list(agg.keys), combine, names=list(agg.names))
+    return reader, partials, fused, fused_compiled
 
 
 def _chain_one(first, rest):
@@ -1481,4 +1512,9 @@ def execute(plan: PlanNode, stats: Optional[dict] = None,
             # query-boundary device-memory sample: with the chunk-boundary
             # samples above, summary["memory"] carries live + high-water
             metrics.mem_checkpoint()
+        if ctx.stream_end is not None:
+            # the eager tail after the stream; it crosses call frames, so
+            # it is a stamped interval and no ``with`` block
+            metrics.observe("engine.post_stream_s",
+                            time.perf_counter() - ctx.stream_end)
     return out

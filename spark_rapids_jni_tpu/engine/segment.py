@@ -46,6 +46,7 @@ import numpy as np
 from ..columnar import Column, Table
 from ..utils import metrics, timeline
 from ..utils.config import config
+from ..utils.tracing import op_scope
 from .plan import (Aggregate, Filter, Join, PlanNode, Project, expr_columns,
                    topo_nodes)
 
@@ -604,7 +605,9 @@ def run_map_segment(compiled: CompiledSegment, table: Table,
     from ..ops.selection import apply_boolean_mask
     out, live = compiled(table, nvalid)
     metrics.host_sync(label="segment-boundary-compaction")
-    return apply_boolean_mask(out, live)
+    with op_scope("engine.sync_wait", timed=True,
+                  label="segment-boundary-compaction"):
+        return apply_boolean_mask(out, live)  # fetches the survivor count
 
 
 def _compact_padded(key_dtypes, kdat, kval, out_aggs, ngroups,
@@ -612,7 +615,9 @@ def _compact_padded(key_dtypes, kdat, kval, out_aggs, ngroups,
     """groupby's padded->compact tail for fused outputs (fixed-width only,
     which runtime eligibility guarantees)."""
     metrics.host_sync(label="groupby-compaction")
-    ng = int(ngroups)  # the one host sync
+    with op_scope("engine.sync_wait", timed=True,
+                  label="groupby-compaction"):
+        ng = int(ngroups)  # the one host sync
     cols = []
     for dtype, data, valid in zip(key_dtypes, kdat, kval):
         v = np.asarray(valid)[:ng]
@@ -658,7 +663,11 @@ def combine_partials(partials: list, compiled: CompiledSegment) -> Table:
     agg = compiled.segment.agg
     nk = len(agg.keys)
     metrics.host_sync(label="combine-sizing")  # the sizing scalar fetch
-    maxng = int(jnp.max(jnp.stack([jnp.asarray(p[4]) for p in partials])))
+    # where the host waits until the device has drained every streamed
+    # segment: the first fetch after the chunk loop
+    with op_scope("engine.sync_wait", timed=True, label="combine-sizing"):
+        maxng = int(jnp.max(jnp.stack([jnp.asarray(p[4])
+                                       for p in partials])))
     cap = 64
     while cap < maxng:
         cap *= 2
@@ -1138,8 +1147,10 @@ def run_fused_stage(stage: FusedStage, table: Table, mesh,
     # device_get (not per-plane np.asarray) so the transfers overlap
     # instead of serializing eleven blocking copies.
     metrics.host_sync(label="groupby-compaction")
-    kdat, kval, adat, avalid, ngv, sent, overflow = jax.device_get(
-        (kdat, kval, adat, avalid, ngv, sent, overflow))
+    with op_scope("engine.sync_wait", timed=True,
+                  label="groupby-compaction"):
+        kdat, kval, adat, avalid, ngv, sent, overflow = jax.device_get(
+            (kdat, kval, adat, avalid, ngv, sent, overflow))
     if int(overflow):
         metrics.count("engine.fused_stage.overflow_fallbacks")
         return None

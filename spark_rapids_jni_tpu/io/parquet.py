@@ -35,6 +35,7 @@ from .. import dtypes as dt
 from ..columnar import Column, Table
 from ..utils import faults, metrics, timeline
 from ..utils.errors import retry_call
+from ..utils.tracing import op_scope
 from . import snappy
 from .thrift import decode_struct
 
@@ -1407,9 +1408,13 @@ class ParquetChunkedReader:
         """Budget-bounded host-side slices of ONE row group."""
         # transient decode failures (flaky storage) retry per row
         # group, bounded by SRJT_RETRY_MAX with backoff
-        hosts = retry_call(
-            lambda gi=gi: self._decode_group_checked(gi),
-            "parquet.chunk", cancel=self.cancel)
+        # read, decompress, decode of one row group; `bytes` from the footer
+        with op_scope("io.scan.decode", timed=True, group=gi,
+                      bytes=int(self.file.row_groups[gi].total_byte_size
+                                or 0)):
+            hosts = retry_call(
+                lambda gi=gi: self._decode_group_checked(gi),
+                "parquet.chunk", cancel=self.cancel)
         nrows = hosts[0].num_rows
         if nrows == 0:
             return
@@ -1603,39 +1608,25 @@ def _prefetched(gen, depth: int, cancel=None):
     def producer():
         with metrics.bind(qm):
             try:
-                if tl:
-                    it = iter(gen)
-                    n = 0
-                    while True:
-                        # span covers the host decode + staging pull for
-                        # chunk n; the flow tail starts inside it so the
-                        # arrow binds to the producer slice
-                        with timeline.span("io.parquet.produce_chunk",
-                                           {"chunk": n}):
-                            faults.check("parquet.prefetch")
-                            try:
-                                item = next(it)
-                            except StopIteration:
-                                break
-                            timeline.flow_start("io.parquet.chunk",
-                                                fid_base + n)
-                        if not put(item):
-                            if not stop.is_set() and cancel is not None:
-                                cancel.check()  # -> typed error via FAIL
-                            return
-                        n += 1
-                else:
-                    it = iter(gen)
-                    while True:
+                it = iter(gen)
+                n = 0
+                while True:
+                    # span covers the host decode + staging pull for
+                    # chunk n; the flow tail starts inside it so the
+                    # arrow binds to the producer slice
+                    with op_scope("io.parquet.produce_chunk", chunk=n):
                         faults.check("parquet.prefetch")
                         try:
                             item = next(it)
                         except StopIteration:
                             break
-                        if not put(item):
-                            if not stop.is_set() and cancel is not None:
-                                cancel.check()  # -> typed error via FAIL
-                            return
+                        timeline.flow_start("io.parquet.chunk",
+                                            fid_base + n)
+                    if not put(item):
+                        if not stop.is_set() and cancel is not None:
+                            cancel.check()  # -> typed error via FAIL
+                        return
+                    n += 1
                 put_ctrl(DONE)
             except BaseException as e:  # surface decode errors to consumer
                 put_ctrl((FAIL, e))
@@ -1646,7 +1637,10 @@ def _prefetched(gen, depth: int, cancel=None):
     try:
         while True:
             t0 = time.perf_counter() if timed else 0.0
-            item = q.get()
+            # `consumer_idle_s` below is this wait's timer; the span puts
+            # it on the profiler's clock
+            with op_scope("engine.stream.wait_reader", chunk=k):
+                item = q.get()
             if timed:
                 # consumer blocked waiting on host decode: the bubble the
                 # double-buffered pipeline exists to hide
@@ -1663,7 +1657,7 @@ def _prefetched(gen, depth: int, cancel=None):
                 with timeline.span("io.parquet.consume_chunk",
                                    {"chunk": k}):
                     timeline.flow_finish("io.parquet.chunk", fid_base + k)
-                k += 1
+            k += 1
             yield item
     finally:
         # early abandonment (LIMIT queries, consumer errors) must not

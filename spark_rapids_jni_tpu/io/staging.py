@@ -25,6 +25,7 @@ import numpy as np
 from .. import dtypes as dt
 from ..columnar import Column, Table
 from ..utils import faults
+from ..utils.tracing import op_scope
 
 
 def _pad4(b: bytes) -> bytes:
@@ -166,56 +167,60 @@ def stage_fixed_table(specs, padded: bool = False):
     validity.  This is the chunk-pipeline form — every same-schema chunk
     shares ONE shape class, so fused plan segments (engine/segment.py)
     compile once and mask rows ``>= n_rows`` instead of slicing."""
-    faults.check("staging.transfer")
-    blob = bytearray()
-    plan = []
-    posts = []  # (name, dtype, has_valid, n)
-    n_rows = len(specs[0][2]) if specs else 0
-    bucket = _bucket(n_rows)
+    if any(dtype.id == dt.TypeId.DECIMAL128 for _, dtype, _, _ in specs):
+        raise TypeError("DECIMAL128 staging unsupported; use the "
+                        "column-at-a-time path")
+    # pack + the one device_put + the unpack's dispatch; `bytes` is the
+    # blob's size, known from the plan before anything is packed
+    with op_scope("io.scan.stage", timed=True,
+                  bytes=_plan_for(specs)[1] * 4):
+        faults.check("staging.transfer")
+        blob = bytearray()
+        plan = []
+        posts = []  # (name, dtype, has_valid, n)
+        n_rows = len(specs[0][2]) if specs else 0
+        bucket = _bucket(n_rows)
 
-    def push(arr: np.ndarray, kind: str):
-        arr = np.ascontiguousarray(arr)
-        if len(arr) < bucket:
-            arr = np.concatenate(
-                [arr, np.zeros(bucket - len(arr), arr.dtype)])
-        off = len(blob) // 4
-        b = _pad4(arr.tobytes())
-        blob.extend(b)
-        plan.append((kind, off, len(b) // 4, bucket))
+        def push(arr: np.ndarray, kind: str):
+            arr = np.ascontiguousarray(arr)
+            if len(arr) < bucket:
+                arr = np.concatenate(
+                    [arr, np.zeros(bucket - len(arr), arr.dtype)])
+            off = len(blob) // 4
+            b = _pad4(arr.tobytes())
+            blob.extend(b)
+            plan.append((kind, off, len(b) // 4, bucket))
 
-    for name, dtype, values, validity in specs:
-        size = np.dtype(dtype.storage).itemsize if not dtype.is_decimal \
-            else dtype.itemsize
-        if dtype.id == dt.TypeId.DECIMAL128:
-            raise TypeError("DECIMAL128 staging unsupported; use the "
-                            "column-at-a-time path")
-        kind = {8: "w8", 4: "w4", 2: "w2", 1: "w1"}[size]
-        push(values, kind)
-        if validity is not None:
-            push(np.asarray(validity, np.uint8), "w1")
-        posts.append((name, dtype, validity is not None, len(values)))
+        for name, dtype, values, validity in specs:
+            size = np.dtype(dtype.storage).itemsize if not dtype.is_decimal \
+                else dtype.itemsize
+            kind = {8: "w8", 4: "w4", 2: "w2", 1: "w1"}[size]
+            push(values, kind)
+            if validity is not None:
+                push(np.asarray(validity, np.uint8), "w1")
+            posts.append((name, dtype, validity is not None, len(values)))
 
-    words = jnp.asarray(np.frombuffer(bytes(blob), np.uint32))  # ONE put
-    arrays = _unpack(words, tuple(plan))
-    with _plans_lock:
-        _ready_plans.add((tuple(plan), len(blob) // 4))
-    cols, names = [], []
-    ai = 0
-    for name, dtype, has_valid, n in posts:
-        data = arrays[ai] if padded else arrays[ai][:n]
-        ai += 1
-        storage = jnp.dtype(dtype.device_storage)
-        if data.dtype != storage:
-            if data.dtype.itemsize == storage.itemsize:
-                data = jax.lax.bitcast_convert_type(data, storage)
-            else:
-                data = data.astype(storage)
-        valid = None
-        if has_valid:
-            v = arrays[ai]
-            valid = (v if padded else v[:n]).astype(jnp.bool_)
+        words = jnp.asarray(np.frombuffer(bytes(blob), np.uint32))  # ONE put
+        arrays = _unpack(words, tuple(plan))
+        with _plans_lock:
+            _ready_plans.add((tuple(plan), len(blob) // 4))
+        cols, names = [], []
+        ai = 0
+        for name, dtype, has_valid, n in posts:
+            data = arrays[ai] if padded else arrays[ai][:n]
             ai += 1
-        cols.append(Column(dtype, data=data, validity=valid))
-        names.append(name)
-    out = Table(cols, names)
-    return (out, n_rows) if padded else out
+            storage = jnp.dtype(dtype.device_storage)
+            if data.dtype != storage:
+                if data.dtype.itemsize == storage.itemsize:
+                    data = jax.lax.bitcast_convert_type(data, storage)
+                else:
+                    data = data.astype(storage)
+            valid = None
+            if has_valid:
+                v = arrays[ai]
+                valid = (v if padded else v[:n]).astype(jnp.bool_)
+                ai += 1
+            cols.append(Column(dtype, data=data, validity=valid))
+            names.append(name)
+        out = Table(cols, names)
+        return (out, n_rows) if padded else out

@@ -39,7 +39,7 @@ from ..ops.row_conversion import (RowLayout, _build_planes,
 from .mesh import ROW_AXIS, axis_size
 from .stringplane import explode_strings, reassemble_strings
 from ..utils import faults, metrics, timeline
-from ..utils.tracing import traced
+from ..utils.tracing import op_scope, traced
 
 
 def partition_ids(key_table: Table, num_partitions: int) -> jnp.ndarray:
@@ -404,10 +404,12 @@ def shuffle_table_padded(table: Table, mesh: Mesh, keys: list,
         # The counts fetch is a DELIBERATE host sync (they must reach the
         # host to become phase 2's static capacity) — whitelisted in
         # engine/verify.SYNC_WHITELIST; the AST lint holds the label honest
-        counts_mat = partition_counts(table, mesh, list(keys), axis,
-                                      key_specs=key_specs)
-        capacity = cap_bucket(int(counts_mat.max()))
         metrics.host_sync(label="exchange-counts-sizing")
+        with op_scope("engine.sync_wait", timed=True,
+                      label="exchange-counts-sizing"):
+            counts_mat = partition_counts(table, mesh, list(keys), axis,
+                                          key_specs=key_specs)
+        capacity = cap_bucket(int(counts_mat.max()))
         if metrics.enabled():
             # the counts matrix is already on host — per-device skew
             # attribution costs nothing extra (no added syncs)

@@ -22,7 +22,9 @@ cheap dict/``perf_counter`` work — no device syncs — and with the flag off
 each returns immediately, restoring the uninstrumented fast path.  The
 pre-existing flat counters (``tracing.count``) stay on unconditionally, as
 they always were.  ``SRJT_TRACE=1`` layers Perfetto ``TraceAnnotation``s
-(``tracing.op_scope``) on top of the same span names.
+(``tracing.op_scope``) on top of the same span names, and
+``op_scope(name, timed=True)`` observes its duration here as the histogram
+``<name>_s`` (docs/OBSERVABILITY.md lists the span tree).
 
 Threading: the active query context is a thread-local; code that fans work
 out to helper threads (the chunked reader's prefetch producer) captures
@@ -386,6 +388,10 @@ def query(name: str = ""):
         summary = qm.summary()
         with _lock:
             _recent.append(summary)
+            # process-wide, so a reader can subtract every query's wall
+            # time from what encloses it (Σ `bridge.op.*_s`) however many
+            # clients ran and however few summaries `_recent` still holds
+            _hist_add(_hists, "engine.query.wall_s", qm.wall_s)
         if config.profile_dir:
             # persist one compact profile per query (utils/profile.py);
             # profile IO must never fail the query it describes

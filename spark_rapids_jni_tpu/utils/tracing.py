@@ -8,7 +8,9 @@ per-op spans.  The TPU equivalents:
   dumps and profiler traces attribute work to engine ops (compile-time
   metadata, zero runtime cost).
 - ``jax.profiler.TraceAnnotation`` — runtime spans on the host timeline,
-  enabled by ``SRJT_TRACE=1`` (visible in Perfetto via ``profile()``).
+  enabled by ``SRJT_TRACE=1`` (visible in Perfetto via ``profile()``);
+  ``op_scope(name, timed=True, **stats)`` also times the span into the
+  histogram ``<name>_s`` and tags the annotation with the query's trace id.
 - ``profile(logdir)`` — capture a full device trace
   (``jax.profiler.trace``), the Nsight-session analog.
 - ``count(name)`` / ``counters_snapshot()`` — lightweight named event
@@ -22,6 +24,7 @@ import contextlib
 import functools
 import os
 import threading
+import time
 
 import jax
 
@@ -30,17 +33,42 @@ from .config import config
 
 
 @contextlib.contextmanager
-def op_scope(name: str):
+def op_scope(name: str, timed: bool = False, **stats):
     """Named scope + (when SRJT_TRACE=1) a host profiler annotation +
     (when SRJT_TIMELINE=1) a span in the in-process event timeline —
-    one call site, three observability sinks on the same name."""
+    one call site, three observability sinks on the same name.
+
+    ``timed=True`` adds a fourth while ``SRJT_METRICS`` is on: one
+    ``perf_counter`` pair whose difference is observed as the histogram
+    ``<name>_s``, process-wide and in the query bound to this thread
+    (``metrics.bind`` carries it onto helper threads).
+
+    ``stats`` (``chunk=3``, ``label="combine-sizing"``, ``bytes=...``) go
+    to the annotation as event stats, together with ``trace_id`` of the
+    bound query: the spans of one request share it on every thread, and
+    all of them lie on the profiler's clock beside the device's ops."""
     with contextlib.ExitStack() as stack:
         stack.enter_context(jax.named_scope(name))
         if config.trace:
-            stack.enter_context(jax.profiler.TraceAnnotation(name))
+            from . import blackbox  # lazy: blackbox -> metrics -> here
+            # a caller outside any query scope (the bridge's dispatch)
+            # names the trace itself; "" there means a client without one
+            ann = {**stats, "trace_id": stats.get("trace_id")
+                   or blackbox.current_trace()}
+            if not ann["trace_id"]:
+                del ann["trace_id"]
+            stack.enter_context(jax.profiler.TraceAnnotation(name, **ann))
         if config.timeline:
-            stack.enter_context(timeline.span(name))
-        yield
+            stack.enter_context(timeline.span(name, stats))
+        if not (timed and config.metrics):
+            yield
+            return
+        from . import metrics  # lazy: metrics imports this module
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            metrics.observe(f"{name}_s", time.perf_counter() - t0)
 
 
 def traced(name: str):

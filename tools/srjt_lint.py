@@ -35,7 +35,8 @@ Six stdlib-``ast`` rules over ``spark_rapids_jni_tpu/`` + ``tools/``:
   indistinguishably.
 - **unregistered-metric** — every literal metric name recorded through
   ``metrics.count/observe/gauge_set/gauge_max/time_add`` /
-  ``tracing.count`` (and every literal ``node_set`` span label) must
+  ``tracing.count``, every ``op_scope(<name>, timed=True)`` (it observes
+  ``<name>_s``) and every literal ``node_set`` span label must
   appear in the generated catalog ``docs/METRICS.md``; f-string names
   catalog with ``<var>`` placeholders.  A name in the catalog with no
   remaining call site flags ``stale-metric``.  Regenerate with
@@ -333,6 +334,16 @@ class _FileLint(ast.NodeVisitor):
 
     def _collect_metric(self, node: ast.Call) -> None:
         fn = node.func
+        if getattr(fn, "id", getattr(fn, "attr", "")) == "op_scope" \
+                and node.args and any(
+                    kw.arg == "timed" and isinstance(kw.value, ast.Constant)
+                    and kw.value.value is True for kw in node.keywords):
+            # a timed span observes the histogram `<span>_s`
+            name = _literal_metric_name(node.args[0])
+            if name is not None:
+                self.metric_sites.append(
+                    (name + "_s", "histogram", self.relpath, node.lineno))
+            return
         if not isinstance(fn, ast.Attribute):
             return
         if fn.attr in _METRIC_FNS and isinstance(fn.value, ast.Name) \
@@ -415,7 +426,8 @@ def render_metrics_doc(catalog: dict) -> str:
         "Generated by `python tools/srjt_lint.py --write-metrics` from the",
         "literal names at `metrics.count` / `observe` / `gauge_set` /",
         "`gauge_max` / `time_add` / `tracing.count` / `node_set` call",
-        "sites; `<var>` marks an f-string interpolation (one row per",
+        "sites and from `op_scope(<name>, timed=True)` spans (histogram",
+        "`<name>_s`); `<var>` marks an f-string interpolation (one row per",
         "template, however many concrete names it expands to).  Do not",
         "edit by hand: a call site recording a name missing here fails",
         "the lint (`unregistered-metric`), and a row with no remaining",
