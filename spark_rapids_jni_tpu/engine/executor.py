@@ -973,7 +973,7 @@ def _exec_streamed(agg: Aggregate, scan: Scan, memo: dict,
         reader, partials, fused, fused_compiled = _stream_chunks(
             agg, scan, memo, stats, ctx, force_interp)
     # what follows, to the end of ``execute``, is ``engine.post_stream``:
-    # the eager merge of the partials and every operator above it
+    # the merge of the partials (one program) and every operator above it
     ctx.stream_end = time.perf_counter()
     stats["row_groups_pruned"] += reader.groups_pruned
     stats["row_groups_read"] += reader.groups_read
@@ -1513,7 +1513,7 @@ def execute(plan: PlanNode, stats: Optional[dict] = None,
             # samples above, summary["memory"] carries live + high-water
             metrics.mem_checkpoint()
         if ctx.stream_end is not None:
-            # the eager tail after the stream; it crosses call frames, so
+            # the tail after the stream; it crosses call frames, so
             # it is a stamped interval and no ``with`` block
             metrics.observe("engine.post_stream_s",
                             time.perf_counter() - ctx.stream_end)

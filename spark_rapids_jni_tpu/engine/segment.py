@@ -29,6 +29,10 @@ plans.  The TPU translation:
   shape-class is the (row-bucket, schema) signature: chunked scans pad
   rows to power-of-two buckets (io/staging.py), so every same-schema chunk
   re-enters the same compiled executable instead of retracing.
+- The merge of a streamed aggregate's padded partials is one more entry of
+  that cache (``CompiledCombine``): cut, concatenate and combine group-by
+  in ONE launch between ``combine_partials``' two host syncs, compiled
+  once per (capacity, power-of-two bucket of the partial count).
 """
 
 from __future__ import annotations
@@ -394,6 +398,9 @@ class CompiledSegment:
 
     __slots__ = ("key", "segment", "key_dtypes", "jfn", "traces", "calls")
 
+    #: prefix of this program's compile-vs-replay events (``_tick``)
+    counters = "engine.segment"
+
     def __init__(self, key: tuple, segment: Segment, key_dtypes: tuple):
         self.key = key
         self.segment = segment
@@ -403,28 +410,35 @@ class CompiledSegment:
         self.jfn = jax.jit(_build_fn(segment, self))
 
     def __call__(self, table: Table, nvalid=None, prepared=()):
-        self.calls += 1
         nv = jnp.int32(table.num_rows if nvalid is None else nvalid)
+        return self._launch(table, nv, tuple(prepared))
+
+    def _launch(self, *args):
+        self.calls += 1
         if not metrics.enabled() and not timeline.enabled():
-            return self.jfn(table, nv, tuple(prepared))
+            return self.jfn(*args)
         # compile-vs-replay tagging: ``traces`` ticks inside the traced fn,
         # so a call that bumped it paid a trace+compile; otherwise it was a
         # dispatch-only replay.  Durations are host-side dispatch time
         # (jax stays async — no sync added here).
         tr0 = self.traces
         t0 = time.perf_counter()
-        out = self.jfn(table, nv, tuple(prepared))
+        out = self.jfn(*args)
         dt = time.perf_counter() - t0
-        kind = "compile" if self.traces > tr0 else "replay"
-        timeline.complete(f"engine.segment.{kind}", t0, dt)
+        compiled = self.traces > tr0
+        timeline.complete(
+            f"{self.counters}.{'compile' if compiled else 'replay'}", t0, dt)
         if metrics.enabled():
-            if kind == "compile":
-                metrics.count("engine.segment.compile")
-                metrics.observe("engine.segment.trace_s", dt)
-            else:
-                metrics.count("engine.segment.replay")
-                metrics.observe("engine.segment.replay_dispatch_s", dt)
+            self._tick(compiled, dt)
         return out
+
+    def _tick(self, compiled: bool, dt: float) -> None:
+        if compiled:
+            metrics.count("engine.segment.compile")
+            metrics.observe("engine.segment.trace_s", dt)
+        else:
+            metrics.count("engine.segment.replay")
+            metrics.observe("engine.segment.replay_dispatch_s", dt)
 
 
 def _build_decode_fn(seg: Segment, compiled: "CompiledSegment", geom):
@@ -462,6 +476,100 @@ class CompiledDecodeSegment(CompiledSegment):
         self.calls = 0
         self.geom = geom
         self.jfn = jax.jit(_build_decode_fn(segment, self, geom))
+
+
+def _partial_class(p) -> tuple:
+    """The compile key of one padded partial — everything jax.jit would
+    retrace the merge on: slot count, key buffers, aggregate columns."""
+    kdat, _kval, out_aggs, glive = p[:4]
+    return (glive.shape[0], tuple(k.dtype.str for k in kdat),
+            tuple((c.dtype, c.data.dtype.str, c.validity is not None)
+                  for c in out_aggs))
+
+
+def _build_combine_fn(agg: Aggregate, key_dtypes: tuple, cap: int,
+                      compiled: "CompiledCombine"):
+    """The single program the merge of the streamed partials traces into.
+
+    ``fn(partials, nreal)``: ``partials`` is the bucketed tuple of
+    ``(kdat, kval, out_aggs, glive)``; entries >= ``nreal`` are filler
+    (dead rows).  Slice every partial to ``cap``, concatenate, and run the
+    combine ``groupby_padded`` under the live mask — still padded, zero
+    host syncs.
+    """
+    from .executor import _STREAM_COMBINE
+    nk = len(agg.keys)
+    knames = [f"k{i}" for i in range(nk)]
+    anames = [f"a{j}" for j in range(len(agg.aggs))]
+    combine = [(anames[j], _STREAM_COMBINE[op])
+               for j, (_, op) in enumerate(agg.aggs)]
+
+    def cut(a):
+        return a[:cap] if a.shape[0] > cap else a
+
+    def fn(partials, nreal):
+        from ..ops.aggregate import groupby_padded
+        compiled.traces += 1  # trace-time side effect, as in _build_fn
+        key_cols = [
+            Column(key_dtypes[i],
+                   data=jnp.concatenate([cut(p[0][i]) for p in partials]),
+                   validity=jnp.concatenate([cut(p[1][i])
+                                             for p in partials]))
+            for i in range(nk)]
+        agg_cols = []
+        for j in range(len(agg.aggs)):
+            datas = [cut(p[2][j].data) for p in partials]
+            valids = [None if p[2][j].validity is None
+                      else cut(p[2][j].validity) for p in partials]
+            validity = None if all(v is None for v in valids) else \
+                jnp.concatenate([jnp.ones(d.shape[0], jnp.bool_)
+                                 if v is None else v
+                                 for d, v in zip(datas, valids)])
+            agg_cols.append(Column(partials[0][2][j].dtype,
+                                   data=jnp.concatenate(datas),
+                                   validity=validity))
+        live = jnp.concatenate([cut(p[3]) & (np.int32(i) < nreal)
+                                for i, p in enumerate(partials)])
+        merged = Table(key_cols + agg_cols, knames + anames)
+        out_keys, out_aggs, ngroups = groupby_padded(
+            merged, knames, combine, row_mask=live)
+        kdat = tuple(spec[2] for spec in out_keys)
+        kval = tuple(spec[3] for spec in out_keys)
+        return kdat, kval, tuple(out_aggs), ngroups
+
+    return fn
+
+
+class CompiledCombine(CompiledSegment):
+    """The merge program of one streamed aggregate: a SEGMENT_CACHE entry
+    beside the chunk programs it merges, with counters of its own
+    (``engine.combine.*``) so the chunk program's compile/replay counts
+    stay the number of chunks."""
+
+    __slots__ = ()
+
+    counters = "engine.combine"
+
+    def __init__(self, key: tuple, segment: Segment, key_dtypes: tuple,
+                 cap: int):
+        self.key = key
+        self.segment = segment
+        self.key_dtypes = key_dtypes
+        self.traces = 0
+        self.calls = 0
+        self.jfn = jax.jit(_build_combine_fn(segment.agg, key_dtypes, cap,
+                                             self))
+
+    def __call__(self, partials: tuple, nreal: int):
+        return self._launch(partials, np.int32(nreal))
+
+    def _tick(self, compiled: bool, dt: float) -> None:
+        if compiled:
+            metrics.count("engine.combine.compile")
+            metrics.observe("engine.combine.trace_s", dt)
+        else:
+            metrics.count("engine.combine.replay")
+            metrics.observe("engine.combine.replay_dispatch_s", dt)
 
 
 def _resolve_dtype(name: str, table: Table, builds: tuple):
@@ -505,10 +613,10 @@ class SegmentCache:
         return self._maxsize if self._maxsize is not None \
             else config.segment_cache
 
-    def get(self, segment: Segment, table: Table,
-            builds: tuple = ()) -> CompiledSegment:
-        key = (segment.fingerprint(), shape_class(table),
-               tuple(shape_class(b) for b in builds))
+    def _lookup(self, key: tuple, build) -> CompiledSegment:
+        """The entry under ``key``; ``build()`` makes it on a miss, outside
+        the lock (first store wins: a racer that built in parallel counts
+        as a hit and its program is dropped)."""
         with self._lock:
             hit = self._entries.get(key)
             if hit is not None:
@@ -516,9 +624,7 @@ class SegmentCache:
                 self.hits += 1
                 metrics.count("engine.segment_cache.hit")
                 return hit
-        key_dtypes = () if segment.agg is None else tuple(
-            _resolve_dtype(k, table, builds) for k in segment.agg.keys)
-        compiled = CompiledSegment(key, segment, key_dtypes)
+        compiled = build()
         with self._lock:
             racer = self._entries.get(key)
             if racer is not None:
@@ -535,6 +641,18 @@ class SegmentCache:
                 metrics.count("engine.segment_cache.eviction")
             return compiled
 
+    def get(self, segment: Segment, table: Table,
+            builds: tuple = ()) -> CompiledSegment:
+        key = (segment.fingerprint(), shape_class(table),
+               tuple(shape_class(b) for b in builds))
+
+        def build():
+            key_dtypes = () if segment.agg is None else tuple(
+                _resolve_dtype(k, table, builds) for k in segment.agg.keys)
+            return CompiledSegment(key, segment, key_dtypes)
+
+        return self._lookup(key, build)
+
     def get_decode(self, segment: Segment, geom,
                    builds: tuple = ()) -> CompiledDecodeSegment:
         """The fused scan-decode variant of :meth:`get`: keyed by
@@ -543,33 +661,30 @@ class SegmentCache:
         whose pages quantize to the same buckets."""
         key = (segment.fingerprint(), ("device_decode", geom),
                tuple(shape_class(b) for b in builds))
-        with self._lock:
-            hit = self._entries.get(key)
-            if hit is not None:
-                self._entries.move_to_end(key)
-                self.hits += 1
-                metrics.count("engine.segment_cache.hit")
-                return hit
-        from ..ops.parquet_decode import probe_table
-        key_dtypes = () if segment.agg is None else tuple(
-            _resolve_dtype(k, probe_table(geom), builds)
-            for k in segment.agg.keys)
-        compiled = CompiledDecodeSegment(key, segment, key_dtypes, geom)
-        with self._lock:
-            racer = self._entries.get(key)
-            if racer is not None:
-                self._entries.move_to_end(key)
-                self.hits += 1
-                metrics.count("engine.segment_cache.hit")
-                return racer
-            self.misses += 1
-            metrics.count("engine.segment_cache.miss")
-            self._entries[key] = compiled
-            while len(self._entries) > self.maxsize:
-                self._entries.popitem(last=False)
-                self.evictions += 1
-                metrics.count("engine.segment_cache.eviction")
-            return compiled
+
+        def build():
+            from ..ops.parquet_decode import probe_table
+            key_dtypes = () if segment.agg is None else tuple(
+                _resolve_dtype(k, probe_table(geom), builds)
+                for k in segment.agg.keys)
+            return CompiledDecodeSegment(key, segment, key_dtypes, geom)
+
+        return self._lookup(key, build)
+
+    def get_combine(self, chunk: CompiledSegment, cap: int,
+                    partials: tuple) -> CompiledCombine:
+        """The merge program of a streamed aggregate (see
+        ``combine_partials``): keyed by the segment's fingerprint under a
+        tag of its own (the census of chunk shape classes in verify.py
+        counts it apart), the capacity every partial is cut to, the key
+        dtypes, and the class of every partial of the BUCKETED tuple — so
+        a trace is always a miss here, and ``engine.segment_cache.miss``
+        covers the merge's compiles as it covers the chunk program's."""
+        key = (chunk.segment.fingerprint() + "+combine",
+               (cap, chunk.key_dtypes,
+                tuple(_partial_class(p) for p in partials)), ())
+        return self._lookup(key, lambda: CompiledCombine(
+            key, chunk.segment, chunk.key_dtypes, cap))
 
     def __len__(self) -> int:
         with self._lock:
@@ -640,6 +755,12 @@ def run_agg_segment(compiled: CompiledSegment, table: Table,
                            ngroups, list(agg.keys) + list(agg.names))
 
 
+@jax.jit
+def _max_ngroups(ngroups: tuple):
+    """The sizing reduce of ``combine_partials``: one launch, one scalar."""
+    return jnp.max(jnp.stack(ngroups))
+
+
 def combine_partials(partials: list, compiled: CompiledSegment) -> Table:
     """Merge per-chunk padded partial aggregates into the final Table.
 
@@ -647,7 +768,9 @@ def combine_partials(partials: list, compiled: CompiledSegment) -> Table:
     off the fused agg program — still padded, never synced per chunk.
     Two host syncs total, however many chunks streamed through: one
     scalar ``max(ngroups)`` fetch to size the combine, one final
-    ``ngroups`` in the compaction tail.
+    ``ngroups`` in the compaction tail.  Between them ONE launch: the
+    cut, the concatenation and the combine ``groupby_padded`` are a single
+    compiled program (``CompiledCombine``, cached in ``SEGMENT_CACHE``).
 
     The sizing sync matters: each partial is padded to its chunk's row
     bucket (e.g. 16k slots for 12 live groups), and ``groupby_padded``
@@ -657,51 +780,27 @@ def combine_partials(partials: list, compiled: CompiledSegment) -> Table:
     capacity >= max(ngroups) preserves every live group, keeps the
     combine's shape stable across runs (jit reuse), and shrinks it by
     ~bucket/cap.
+
+    The number of partials is what pruning left, so it is bucketed too
+    (11 and 12 chunks share the 16-partial program): the tuple is filled
+    up to the next power of two with repeats of the first partial, which
+    the program masks dead (``nreal``) — dead rows sort behind every live
+    one and add to no group.
     """
-    from ..ops.aggregate import groupby_padded
-    from .executor import _STREAM_COMBINE
+    from ..ops.parquet_decode import bucket
     agg = compiled.segment.agg
-    nk = len(agg.keys)
+    nreal = len(partials)
+    filled = tuple(partials) + (partials[0],) * (bucket(nreal, 1) - nreal)
     metrics.host_sync(label="combine-sizing")  # the sizing scalar fetch
     # where the host waits until the device has drained every streamed
     # segment: the first fetch after the chunk loop
     with op_scope("engine.sync_wait", timed=True, label="combine-sizing"):
-        maxng = int(jnp.max(jnp.stack([jnp.asarray(p[4])
-                                       for p in partials])))
-    cap = 64
-    while cap < maxng:
-        cap *= 2
-
-    def cut(a):
-        return a[:cap] if a.shape[0] > cap else a
-
-    key_cols = [
-        Column(compiled.key_dtypes[i],
-               data=jnp.concatenate([cut(p[0][i]) for p in partials]),
-               validity=jnp.concatenate([cut(p[1][i]) for p in partials]))
-        for i in range(nk)]
-    agg_cols = []
-    for j in range(len(agg.aggs)):
-        datas = [cut(p[2][j].data) for p in partials]
-        valids = [None if p[2][j].validity is None
-                  else cut(p[2][j].validity) for p in partials]
-        validity = None if all(v is None for v in valids) else \
-            jnp.concatenate([jnp.ones(d.shape[0], jnp.bool_)
-                             if v is None else v
-                             for d, v in zip(datas, valids)])
-        agg_cols.append(Column(partials[0][2][j].dtype,
-                               data=jnp.concatenate(datas),
-                               validity=validity))
-    live = jnp.concatenate([cut(p[3]) for p in partials])
-    knames = [f"k{i}" for i in range(nk)]
-    anames = [f"a{j}" for j in range(len(agg.aggs))]
-    merged = Table(key_cols + agg_cols, knames + anames)
-    combine = [(anames[j], _STREAM_COMBINE[op])
-               for j, (_, op) in enumerate(agg.aggs)]
-    out_keys, out_aggs, ngroups = groupby_padded(merged, knames, combine,
-                                                 row_mask=live)
-    kdat = tuple(spec[2] for spec in out_keys)
-    kval = tuple(spec[3] for spec in out_keys)
+        maxng = int(_max_ngroups(tuple(p[4] for p in filled)))
+    cap = bucket(maxng, 64)
+    filled = tuple(p[:4] for p in filled)
+    merge = SEGMENT_CACHE.get_combine(compiled, cap, filled)
+    with op_scope("engine.combine", partials=nreal, cap=cap):
+        kdat, kval, out_aggs, ngroups = merge(filled, nreal)
     return _compact_padded(compiled.key_dtypes, kdat, kval, out_aggs,
                            ngroups, list(agg.keys) + list(agg.names))
 

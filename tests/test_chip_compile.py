@@ -94,34 +94,69 @@ def sales_chunk(n):
                  ["ss_sold_date_sk", "ss_store_sk", "ss_ext_sales_price"])
 
 
-def test_fused_q5_chunk_segment(tmp_path, one_chip, tpu_branches):
-    """The fused chunk program of the smoke's plan — filter + semi-join
-    probe + partial groupby — found by running the real plan small on the
-    CPU, then compiled for the chip at the same shapes."""
+@pytest.fixture(scope="module")
+def q5_calls(tmp_path_factory):
+    """The smoke's plan run small on the CPU, with every call of its two
+    compiled programs recorded: the fused chunk segment and the merge of
+    the streamed partials."""
     import chip_smoke as cs
     from spark_rapids_jni_tpu.engine import PlanCache
     from spark_rapids_jni_tpu.engine import segment as seg
 
-    calls = []
-    orig = seg.CompiledSegment.__call__
+    calls = {"chunk": [], "merge": []}
+    chunk, merge = seg.CompiledSegment.__call__, seg.CompiledCombine.__call__
 
-    def recording(self, table, nvalid=None, prepared=()):
-        calls.append((self, table, tuple(prepared)))
-        return orig(self, table, nvalid, prepared)
+    def chunk_call(self, table, nvalid=None, prepared=()):
+        calls["chunk"].append((self, table, tuple(prepared)))
+        return chunk(self, table, nvalid, prepared)
 
-    wh = cs.make_warehouse(str(tmp_path), 6_000, seed=1)
+    def merge_call(self, partials, nreal):
+        calls["merge"].append((self, partials))
+        return merge(self, partials, nreal)
+
+    wh = cs.make_warehouse(str(tmp_path_factory.mktemp("q5")), 6_000, seed=1)
     try:
-        seg.CompiledSegment.__call__ = recording
+        seg.CompiledSegment.__call__ = chunk_call
+        seg.CompiledCombine.__call__ = merge_call
         PlanCache().get(cs.q5_lite(wh["paths"])).execute(stats={})
     finally:
-        seg.CompiledSegment.__call__ = orig
-    assert calls, "the plan ran no fused segment"
-    compiled, table, prepared = calls[0]
+        seg.CompiledSegment.__call__ = chunk
+        seg.CompiledCombine.__call__ = merge
+    return calls
+
+
+def test_fused_q5_chunk_segment(q5_calls, one_chip, tpu_branches):
+    """The fused chunk program of the smoke's plan — filter + semi-join
+    probe + partial groupby — found by running the real plan small on the
+    CPU, then compiled for the chip at the same shapes."""
+    from spark_rapids_jni_tpu.engine import segment as seg
+    assert q5_calls["chunk"], "the plan ran no fused segment"
+    compiled, table, prepared = q5_calls["chunk"][0]
     assert compiled.segment.agg is not None and compiled.segment.joins
     compile_for_chip(seg._build_fn(compiled.segment, compiled),
                      on(one_chip, table),
                      jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
                      on(one_chip, prepared))
+
+
+def test_q5_merge_of_streamed_partials(q5_calls, one_chip, tpu_branches):
+    """The merge program of the same plan at the benchmark's shape: 11 or
+    12 chunks of an SF1 row group fill the 16-partial bucket, each partial
+    padded to the chunk's 262,144-row bucket and cut to 64 groups — three
+    ``lax.sort``s over 1,024 rows, which is what keeps its compile short."""
+    from spark_rapids_jni_tpu.engine import segment as seg
+    ((merge, partials),) = q5_calls["merge"]
+    assert len(partials) == 16
+    slots = 262_144
+    real = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((slots,), a.dtype, sharding=one_chip),
+        partials)
+    cap = merge.key[1][0]
+    assert cap == 64
+    compile_for_chip(
+        seg._build_combine_fn(merge.segment.agg, merge.key_dtypes, cap,
+                              merge),
+        real, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
 
 
 def test_groupby_padded_chunk(one_chip, tpu_branches):
