@@ -608,8 +608,9 @@ def _broadcast_exchange(node: Exchange, table: Table) -> Table:
         metrics.gauge_set("engine.exchange.replica_bytes", float(wire))
     if ndev <= 1:
         return table
-    with timeline.span("engine.exchange.broadcast",
-                       {"wire_bytes": int(wire)}):
+    # the exchange's own work, not its child's: the replicated puts
+    with op_scope("engine.exchange.broadcast", timed=True,
+                  rows=int(table.num_rows), wire_bytes=int(wire)):
         return broadcast_table(table, make_mesh(ndev))
 
 
@@ -627,6 +628,25 @@ def _hash_exchange(node: Exchange, table: Table, ctx: _ExecCtx,
     ok-mask compaction fetch at the end.
     """
     import jax
+
+    ndev = len(jax.devices())
+    if ndev <= 1:
+        return table  # placement over one device is the identity
+    # the exchange's own work, not its child's: staging, both shuffle
+    # phases, and the two engine.sync_wait spans nested inside
+    # (hash_s >= the sum of its two labelled sync_wait_s)
+    nchunks = max(1, -(-table.num_rows // chunk_rows))  # 0 rows: one pass
+    with op_scope("engine.exchange.hash", timed=True,
+                  rows=int(table.num_rows), chunks=int(nchunks)):
+        return _hash_exchange_mesh(node, table, ctx, stats, chunk_rows,
+                                   nchunks, ndev)
+
+
+def _hash_exchange_mesh(node: Exchange, table: Table, ctx: _ExecCtx,
+                        stats: Optional[dict], chunk_rows: int,
+                        nchunks: int, ndev: int) -> Table:
+    """``_hash_exchange`` over ``ndev`` > 1 devices, inside its span."""
+    import jax
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -637,9 +657,6 @@ def _hash_exchange(node: Exchange, table: Table, ctx: _ExecCtx,
     from ..parallel.mesh import (ROW_AXIS, make_mesh, pad_to_multiple,
                                  shard_table)
 
-    ndev = len(jax.devices())
-    if ndev <= 1:
-        return table  # placement over one device is the identity
     # NO empty-input early-out: a zero-row exchange runs the same
     # counts + payload passes over zero-filled shards (every helper
     # below has a sound n == 0 branch), so the runtime host-sync count
@@ -664,7 +681,6 @@ def _hash_exchange(node: Exchange, table: Table, ctx: _ExecCtx,
 
     mesh = make_mesh(ndev)
     rows = table.num_rows
-    nchunks = max(1, -(-rows // chunk_rows))  # 0 rows still run one pass
     row_spec = NamedSharding(mesh, PartitionSpec(ROW_AXIS))
     layout = fixed_width_layout(table.dtypes())
 
@@ -731,19 +747,18 @@ def _hash_exchange(node: Exchange, table: Table, ctx: _ExecCtx,
     tl = timeline.enabled()
     fbase = timeline.new_flow_base() if tl else 0
     outs = []
-    with timeline.span("engine.exchange.hash", {"chunks": int(nchunks)}):
-        for ci, item in enumerate(sh.shuffle_chunks_pipelined(
-                chunk_stream(), mesh, keys, capacity=capacity,
-                depth=max(1, ctx.prefetch), key_specs=key_specs,
-                split=split)):
-            if tl:
-                # flow arrow tails at dispatch — one flow per (chunk,
-                # dest device); heads land on the device lanes at receipt
-                for d in range(ndev):
-                    timeline.flow_start("engine.exchange.chunk",
-                                        fbase + ci * ndev + d,
-                                        {"chunk": ci})
-            outs.append(item)
+    for ci, item in enumerate(sh.shuffle_chunks_pipelined(
+            chunk_stream(), mesh, keys, capacity=capacity,
+            depth=max(1, ctx.prefetch), key_specs=key_specs,
+            split=split)):
+        if tl:
+            # flow arrow tails at dispatch — one flow per (chunk,
+            # dest device); heads land on the device lanes at receipt
+            for d in range(ndev):
+                timeline.flow_start("engine.exchange.chunk",
+                                    fbase + ci * ndev + d,
+                                    {"chunk": ci})
+        outs.append(item)
 
     # one deliberate barrier: the ok masks reach the host and the padded
     # receive slots compact to live rows (distributed.py's compact idiom)

@@ -224,6 +224,8 @@ def make_partition_counts(mesh: Mesh, key_specs: tuple,
     Returns fn(datas, masks[, n_valid]) -> int32[ndev, ndev] with row s =
     counts shard s sends to each dest.
     """
+    # the body runs on an lru_cache miss only: one build of a program
+    metrics.count("engine.exchange.program_build")
     ndev = axis_size(mesh, axis)
 
     def shard_fn(datas, masks, n_valid=None):
@@ -316,6 +318,7 @@ def make_shuffle(mesh: Mesh, layout: RowLayout, key_specs: tuple,
     merge the full exchange output (or re-combine per key afterwards) may
     ask for it.  Static (part of the compile cache key), like capacity.
     """
+    metrics.count("engine.exchange.program_build")  # a miss, as above
     ndev = axis_size(mesh, axis)
 
     def shard_fn(datas, masks, row_mask):
