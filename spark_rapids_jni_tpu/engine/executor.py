@@ -1133,7 +1133,8 @@ def _stream_chunks(agg: Aggregate, scan: Scan, memo: dict, stats: dict,
                             ctx.recovery.charge(payload.comp_bytes)
                             fused_compiled = sg.SEGMENT_CACHE.get_decode(
                                 seg, payload.geom, build_tables)
-                            with op_scope("engine.fused_segment"):
+                            with op_scope("engine.fused_segment",
+                                          **fused_compiled.span_stats()):
                                 fused.append(fused_compiled(
                                     planes, payload.nrows, preps))
                             nvalid, padded = payload.nrows, 0
@@ -1153,7 +1154,8 @@ def _stream_chunks(agg: Aggregate, scan: Scan, memo: dict, stats: dict,
                             ctx.recovery.charge(cb)
                             fused_compiled = sg.SEGMENT_CACHE.get(
                                 seg, chunk, build_tables)
-                            with op_scope("engine.fused_segment"):
+                            with op_scope("engine.fused_segment",
+                                          **fused_compiled.span_stats()):
                                 fused.append(fused_compiled(
                                     chunk, nvalid, preps))
                     else:
@@ -1163,7 +1165,8 @@ def _stream_chunks(agg: Aggregate, scan: Scan, memo: dict, stats: dict,
                         ctx.recovery.charge(cb)
                         fused_compiled = sg.SEGMENT_CACHE.get(seg, chunk,
                                                               build_tables)
-                        with op_scope("engine.fused_segment"):
+                        with op_scope("engine.fused_segment",
+                                      **fused_compiled.span_stats()):
                             fused.append(fused_compiled(chunk, nvalid,
                                                         preps))
                     if qm is not None:

@@ -20,9 +20,14 @@ plans.  The TPU translation:
 - On the streamed path a Join whose build side is scan-independent is NOT
   a breaker (``build_stream_segment``): the prepared build (hash + stable
   sort, cached in ``engine.cache.BUILD_CACHE``) enters the program as a
-  pytree input and each probe chunk masks/gathers at probe-row shape —
+  pytree input and each probe chunk masks/selects at probe-row shape —
   filter -> project -> probe-join -> partial-agg runs as one traced
-  callable per chunk with zero per-chunk host syncs.
+  callable per chunk with zero per-chunk host syncs.  How a probe row
+  finds its build row is ``ops.join.probe_method``'s choice, from the
+  build's row count: a broadcast compare of the keys for a small build
+  (no hash, sort or gather in the program), the hash merge-rank above it;
+  ``engine.probe.compare`` / ``engine.probe.rank`` count the joins that
+  took each, per chunk launch.
 - Compiled segments live in a process-wide LRU keyed by
   ``(segment fingerprint, input shape-class)`` with hit/miss/eviction
   counters in ``utils.tracing`` (``engine.segment_cache.*``).  The
@@ -315,14 +320,30 @@ def shape_class(table: Table) -> tuple:
     )
 
 
+def probe_methods(seg: Segment, builds: tuple) -> tuple:
+    """``ops.join.probe_method`` of every Join of the chain, execution
+    order, from the build Tables the chunk program is compiled for: the
+    build's row count and its key columns (the probe side's keys are
+    fixed-width by ``stream_runtime_eligible``)."""
+    from ..ops.join import probe_method
+    return tuple(
+        probe_method(b.num_rows, [b.column(k) for k in j.right_keys])
+        for j, b in zip(seg.joins(), builds))
+
+
 def _probe_join_node(nd: Join, pb, table: Table, live, needed):
     """One fused probe-join step at probe-row shape: mask ``live`` by the
-    verified match, and (inner only) gather the needed build payload
-    columns at the matched build rows.  No expansion, no host sync — the
-    prepared build guarantees <= 1 candidate per probe row."""
-    from ..ops.join import probe_join_prepared
+    verified match, and (inner only) select the needed build payload
+    columns at the matched build rows — by the probe's own method: a
+    one-hot masked reduce beside the compare probe, a gather beside the
+    merge-rank.  No expansion, no host sync — the prepared build
+    guarantees <= 1 candidate per probe row."""
+    from ..ops.join import (probe_join_prepared, probe_method,
+                            select_build_rows)
     from ..ops.selection import gather_column
     lk = Table([table.column(k) for k in nd.left_keys])
+    compare = probe_method(
+        pb.nr, list(lk.columns) + list(pb.rk.columns)) == "compare"
     ri, matched = probe_join_prepared(lk, pb, left_live=live)
     live = live & matched
     if nd.how == "semi":
@@ -338,6 +359,8 @@ def _probe_join_node(nd: Join, pb, table: Table, live, needed):
             continue
         if pb.nr == 0:  # dead rows only (live is all-False); typed zeros
             cols.append(Column(c.dtype, data=jnp.zeros((n,), c.data.dtype)))
+        elif compare:   # payload is 1-D fixed-width: runtime eligibility
+            cols.append(select_build_rows(c, ri))
         else:
             cols.append(gather_column(c, ri))
         names.append(out_nm)
@@ -394,20 +417,32 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
 
 class CompiledSegment:
     """One (segment, shape-class) entry: a jitted callable plus the trace
-    counter tests use to prove chunks reuse one executable."""
+    counter tests use to prove chunks reuse one executable.  ``probes``
+    is ``probe_methods`` of the chain's joins for this shape class: what
+    the ``engine.probe.*`` counters and the span's stat report."""
 
-    __slots__ = ("key", "segment", "key_dtypes", "jfn", "traces", "calls")
+    __slots__ = ("key", "segment", "key_dtypes", "jfn", "traces", "calls",
+                 "probes")
 
     #: prefix of this program's compile-vs-replay events (``_tick``)
     counters = "engine.segment"
 
-    def __init__(self, key: tuple, segment: Segment, key_dtypes: tuple):
+    def __init__(self, key: tuple, segment: Segment, key_dtypes: tuple,
+                 probes: tuple = ()):
         self.key = key
         self.segment = segment
         self.key_dtypes = key_dtypes
+        self.probes = probes
         self.traces = 0
         self.calls = 0
         self.jfn = jax.jit(_build_fn(segment, self))
+
+    def span_stats(self) -> dict:
+        """Stats of the ``engine.fused_segment`` span around a launch."""
+        if not self.probes:
+            return {}
+        return {"probe_compare":
+                f"{self.probes.count('compare')}/{len(self.probes)}"}
 
     def __call__(self, table: Table, nvalid=None, prepared=()):
         nv = jnp.int32(table.num_rows if nvalid is None else nvalid)
@@ -415,6 +450,11 @@ class CompiledSegment:
 
     def _launch(self, *args):
         self.calls += 1
+        compare = self.probes.count("compare")    # joins of the chain
+        if compare:
+            metrics.count("engine.probe.compare", compare)
+        if len(self.probes) > compare:
+            metrics.count("engine.probe.rank", len(self.probes) - compare)
         if not metrics.enabled() and not timeline.enabled():
             return self.jfn(*args)
         # compile-vs-replay tagging: ``traces`` ticks inside the traced fn,
@@ -468,10 +508,11 @@ class CompiledDecodeSegment(CompiledSegment):
     __slots__ = ("geom",)
 
     def __init__(self, key: tuple, segment: Segment, key_dtypes: tuple,
-                 geom):
+                 geom, probes: tuple = ()):
         self.key = key
         self.segment = segment
         self.key_dtypes = key_dtypes
+        self.probes = probes
         self.traces = 0
         self.calls = 0
         self.geom = geom
@@ -555,6 +596,7 @@ class CompiledCombine(CompiledSegment):
         self.key = key
         self.segment = segment
         self.key_dtypes = key_dtypes
+        self.probes = ()        # the merge probes nothing
         self.traces = 0
         self.calls = 0
         self.jfn = jax.jit(_build_combine_fn(segment.agg, key_dtypes, cap,
@@ -649,7 +691,8 @@ class SegmentCache:
         def build():
             key_dtypes = () if segment.agg is None else tuple(
                 _resolve_dtype(k, table, builds) for k in segment.agg.keys)
-            return CompiledSegment(key, segment, key_dtypes)
+            return CompiledSegment(key, segment, key_dtypes,
+                                   probe_methods(segment, builds))
 
         return self._lookup(key, build)
 
@@ -667,7 +710,8 @@ class SegmentCache:
             key_dtypes = () if segment.agg is None else tuple(
                 _resolve_dtype(k, probe_table(geom), builds)
                 for k in segment.agg.keys)
-            return CompiledDecodeSegment(key, segment, key_dtypes, geom)
+            return CompiledDecodeSegment(key, segment, key_dtypes, geom,
+                                         probe_methods(segment, builds))
 
         return self._lookup(key, build)
 

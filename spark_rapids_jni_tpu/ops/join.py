@@ -17,6 +17,12 @@ gather-map size.  All heavy work is device-side sort/scan/gather.
 
 Null join keys never match (SQL equi-join semantics), enforced by the
 verification pass; null-safe equality (<=>) is ``null_equal=True``.
+
+A prepared build (``PreparedBuild``, the fused chunk segment's join) is
+probed by one of two methods, chosen by ``probe_method`` from the build's
+row count (a static shape) and the key dtypes: small builds by a broadcast
+compare of the keys themselves (``_probe_compare``: no hash, no sort, no
+gather), larger ones by the hash merge-rank of steps 1-3 and 5.
 """
 
 from __future__ import annotations
@@ -218,6 +224,138 @@ def prepare_build(right: Table, on_right, right_live=None,
                          rh32, rh_sorted, r_order, right_live, unique, nr)
 
 
+#: A prepared build of at most this many rows is probed by comparing the
+#: keys themselves (``_probe_compare``); above it the hash merge-rank runs.
+#: The compare's work grows with ``nl * nr``, the rank's does not grow with
+#: ``nr``; the chip sweep that places the crossover is in PERF.md section 6
+#: (PR 31).
+PROBE_COMPARE_MAX_BUILD = 8192
+
+_NO_ROW = np.int32(np.iinfo(np.int32).max)  # "no build row": above any nr
+
+
+def _compare_ok(col: Column) -> bool:
+    """A column the compare path can read: 1-D fixed-width storage (what
+    ``engine.segment.stream_runtime_eligible`` demands of a fused join)."""
+    return not col.dtype.is_string and col.data is not None \
+        and col.data.ndim == 1
+
+
+def probe_method(nr: int, key_cols) -> str:
+    """``"compare"`` or ``"rank"``: how ``probe_join_prepared`` looks a
+    probe row up in a prepared build of ``nr`` rows.  Reads the build's row
+    count (a static shape) and the key columns' storage, nothing else: one
+    algorithm that wants a different method by size."""
+    if nr <= PROBE_COMPARE_MAX_BUILD and all(_compare_ok(c) for c in key_cols):
+        return "compare"
+    return "rank"
+
+
+def _words32(data) -> list:
+    """A 1-D fixed-width buffer as uint32 word arrays (most significant
+    first) that hold its bit pattern: the chip emulates 64-bit lanes, so
+    every per-pair compare and reduce below runs on 32-bit words.  (BOOL8
+    storage is uint8: no column buffer is ``bool``.)"""
+    size = data.dtype.itemsize
+    if size == 8:
+        u = data.astype(jnp.uint64)   # int64 -> uint64 wraps: bits kept
+        return [(u >> np.uint64(32)).astype(jnp.uint32),
+                u.astype(jnp.uint32)]
+    u = jax.lax.bitcast_convert_type(
+        data, {4: jnp.uint32, 2: jnp.uint16, 1: jnp.uint8}[size])
+    return [u.astype(jnp.uint32)]
+
+
+def _from_words32(words: list, dtype):
+    """Inverse of ``_words32`` for a buffer of ``dtype``."""
+    dtype = jnp.dtype(dtype)
+    if dtype.itemsize == 8:
+        u = (words[0].astype(jnp.uint64) << np.uint64(32)) \
+            | words[1].astype(jnp.uint64)
+        return u.astype(dtype)
+    u = words[0].astype({4: jnp.uint32, 2: jnp.uint16,
+                         1: jnp.uint8}[dtype.itemsize])
+    return jax.lax.bitcast_convert_type(u, dtype)
+
+
+def _key_words(lcol: Column, rcol: Column) -> tuple:
+    """(left words, right words) whose word-wise equality is
+    ``_pair_equal``'s value equality: normalized bits for floats (-0.0 =
+    0.0, NaN = NaN), the promoted integer value otherwise."""
+    if lcol.dtype.id == TypeId.FLOAT64:
+        ld = normalize_f64_bits(lcol.data.astype(jnp.uint64))
+        rd = normalize_f64_bits(rcol.data.astype(jnp.uint64))
+    elif lcol.dtype.id == TypeId.FLOAT32:
+        ld = normalize_f32_bits(jax.lax.bitcast_convert_type(
+            jnp.asarray(lcol.data, jnp.float32), jnp.uint32))
+        rd = normalize_f32_bits(jax.lax.bitcast_convert_type(
+            jnp.asarray(rcol.data, jnp.float32), jnp.uint32))
+    else:
+        wide = jnp.promote_types(lcol.data.dtype, rcol.data.dtype)
+        ld, rd = lcol.data.astype(wide), rcol.data.astype(wide)
+    return _words32(ld), _words32(rd)
+
+
+@traced("probe_compare")
+def _probe_compare(left_keys: Table, pb: "PreparedBuild", left_live,
+                   null_equal: bool):
+    """``probe_join_prepared`` for a small build: compare every probe key
+    with every build key and reduce, ``ri[i] = min{j : key_l[i] == key_r[j],
+    build row j live}``.  The ``[nr, nl]`` match is one fused
+    broadcast-compare-reduce — probe rows along the lanes, build rows along
+    the reduced axis — that is never materialized.  Exact by construction:
+    the keys themselves are tested, in full width, with ``_pair_equal``'s
+    null and float rules; no hash, no sort, no gather."""
+    nr = pb.nr
+    eq = None                    # [nr, nl]
+    llive, rlive = left_live, pb.right_live
+    for lc, rc in zip(left_keys.columns, pb.rk.columns):
+        lw, rw = _key_words(lc, rc)
+        e = None
+        for a, b in zip(lw, rw):
+            w = b[:, None] == a[None, :]
+            e = w if e is None else e & w
+        lv, rv = lc.validity, rc.validity
+        if null_equal:
+            if lv is not None or rv is not None:
+                lv = lc.valid_mask()[None, :]
+                rv = rc.valid_mask()[:, None]
+                e = (e & lv & rv) | ~(lv | rv)
+        else:       # a null key matches nothing: fold it into the row masks
+            if lv is not None:
+                llive = lv if llive is None else llive & lv
+            if rv is not None:
+                rlive = rv if rlive is None else rlive & rv
+        eq = e if eq is None else eq & e
+    cand = jnp.arange(nr, dtype=_I32)
+    if rlive is not None:
+        cand = jnp.where(rlive, cand, _NO_ROW)
+    ri = jnp.min(jnp.where(eq, cand[:, None], _NO_ROW), axis=0)
+    matched = ri < nr
+    if llive is not None:
+        matched = matched & llive
+    return jnp.where(matched, ri, 0), matched
+
+
+@traced("probe_compare")
+def select_build_rows(col: Column, ri) -> Column:
+    """``gather_column(col, ri)`` for a small build column without the
+    gather: each output row is the masked sum of the column's bit pattern
+    over the one-hot ``ri[i] == j`` — one term at most, so int64 extremes,
+    float64 ``-0.0`` and NaN payloads come out bit for bit.  An ``ri``
+    outside the column gives a null row, as the gather does."""
+    onehot = jnp.arange(col.data.shape[0], dtype=_I32)[:, None] \
+        == ri[None, :]
+
+    def pick(words):
+        return [jnp.sum(jnp.where(onehot, w[:, None], np.uint32(0)), axis=0,
+                        dtype=jnp.uint32) for w in words]
+
+    data = _from_words32(pick(_words32(col.data)), col.data.dtype)
+    valid = pick([col.valid_mask().astype(jnp.uint32)])[0] != 0
+    return Column(col.dtype, data=data, validity=valid)
+
+
 def probe_join_prepared(left_keys: Table, pb: PreparedBuild,
                         left_live=None, null_equal: bool = False):
     """Probe a ``PreparedBuild``: masked gather map + match mask per row.
@@ -229,15 +367,21 @@ def probe_join_prepared(left_keys: Table, pb: PreparedBuild,
     row per probe row (arbitrary where unmatched — mask before trusting
     it) and the bool match mask.  ``null_equal=True`` is null-safe
     equality (``<=>``); default SQL semantics never match null keys.
+
+    Two methods, one meaning (``probe_method``): a small build is probed by
+    ``_probe_compare``, a larger one by the hash merge-rank below.
     """
+    nl = left_keys.num_rows
+    if pb.nr == 0:
+        return jnp.zeros((nl,), _I32), jnp.zeros((nl,), jnp.bool_)
+    if probe_method(pb.nr, list(left_keys.columns)
+                    + list(pb.rk.columns)) == "compare":
+        return _probe_compare(left_keys, pb, left_live, null_equal)
     lh = xxhash64(left_keys).data
-    nl = lh.shape[0]
     if left_live is not None:
         iota = jnp.arange(nl, dtype=lh.dtype)
         lh = jnp.where(left_live, lh, iota * 2 + 1)  # odd sentinels
     lh = lh.astype(_I32)
-    if pb.nr == 0:
-        return jnp.zeros((nl,), _I32), jnp.zeros((nl,), jnp.bool_)
     lo, hi = _rank_bounds(pb.rh, lh, ref_sorted=pb.rh_sorted)
     matched = hi > lo
     ri = jnp.take(pb.r_order,

@@ -172,22 +172,54 @@ def test_groupby_padded_chunk(one_chip, tpu_branches):
 
 
 def test_probe_join_prepared_chunk(one_chip, tpu_branches):
-    """The semi-join probe of a chunk against the year of date keys, and
-    the device half of the build (hash + sort)."""
+    """The merge-rank probe of a chunk against a build one row above
+    ``PROBE_COMPARE_MAX_BUILD`` (the method a large build keeps), and the
+    device half of the build (hash + sort)."""
+    from spark_rapids_jni_tpu.ops import join as J
     from spark_rapids_jni_tpu.ops.hash import xxhash64
-    from spark_rapids_jni_tpu.ops.join import (_build_sort, prepare_build,
-                                               probe_join_prepared)
+    nr = J.PROBE_COMPARE_MAX_BUILD + 1
     dates = Table([Column(dt.INT64,
-                          data=jnp.arange(2_451_545, 2_451_911))],
+                          data=jnp.arange(2_451_545, 2_451_545 + nr))],
                   ["d_date_sk"])
-    pb = prepare_build(dates, ["d_date_sk"])
+    pb = J.prepare_build(dates, ["d_date_sk"])
+    assert J.probe_method(pb.nr, pb.rk.columns) == "rank"
     keys = Table([Column(dt.INT64, data=np.zeros(CHUNK_ROWS, np.int64),
                          validity=np.ones(CHUNK_ROWS, np.bool_))],
                  ["ss_sold_date_sk"])
-    compile_for_chip(probe_join_prepared, on(one_chip, keys),
-                     on(one_chip, pb))
-    compile_for_chip(lambda t: _build_sort(xxhash64(t).data),
+    probe = compile_for_chip(J.probe_join_prepared, on(one_chip, keys),
+                             on(one_chip, pb))
+    assert " sort(" in probe.as_text()
+    compile_for_chip(lambda t: J._build_sort(xxhash64(t).data),
                      on(one_chip, dates))
+
+
+def test_probe_compare_real_chunk(one_chip, tpu_branches):
+    """The compare probe at the benchmark's real shape — a 262,144-row
+    chunk bucket against a 512-row build, with the payload select of an
+    inner join: sort-free, so it compiles at full size in seconds; one
+    fused compare-reduce, nothing of ``nl x nr`` materialized."""
+    from spark_rapids_jni_tpu.ops import join as J
+    nl, nr = 262_144, 512
+    build = Table([Column(dt.INT64, data=jnp.arange(nr, dtype=jnp.int64)),
+                   Column(dt.INT64, data=jnp.arange(nr, dtype=jnp.int64))],
+                  ["i_item_sk", "i_brand_id"])
+    pb = J.prepare_build(build, ["i_item_sk"])
+    assert J.probe_method(pb.nr, pb.rk.columns) == "compare"
+    keys = Table([Column(dt.INT64, data=np.zeros(nl, np.int64),
+                         validity=np.ones(nl, np.bool_))], ["ss_item_sk"])
+    live = np.ones(nl, np.bool_)
+
+    def step(keys, pb, live):
+        ri, matched = J.probe_join_prepared(keys, pb, left_live=live)
+        brand = J.select_build_rows(pb.payload.column("i_brand_id"), ri)
+        return ri, matched, brand.data, brand.validity
+
+    compiled = compile_for_chip(step, on(one_chip, keys), on(one_chip, pb),
+                                on(one_chip, live))
+    text = compiled.as_text()
+    assert " sort(" not in text and " gather(" not in text
+    assert "tpu_custom_call" not in text        # XLA, no kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < nl * nr // 8
 
 
 def test_sort_chunk(one_chip, tpu_branches):

@@ -5,10 +5,12 @@ scan-independent: the build is hashed + stable-sorted ONCE per execution
 (``ops.join.prepare_build``, cached in ``engine.BUILD_CACHE``) and each
 probe chunk runs filter -> probe-join -> partial-agg as one jitted program.
 These tests pin the contracts: fused == interpreted == whole-table on every
-chunk geometry, the build cache shows exactly ``hits == chunks - 1`` on a
-cold stream, non-unique build hashes fall back (correct, just interpreted),
-and the chunked reader's prefetch thread dies when the consumer abandons
-the stream.
+chunk geometry, the probe's two methods (broadcast compare for a small
+build, hash merge-rank above ``PROBE_COMPARE_MAX_BUILD``) give one result
+and count themselves per chunk launch, the build cache shows exactly
+``hits == chunks - 1`` on a cold stream, non-unique build hashes fall back
+(correct, just interpreted), and the chunked reader's prefetch thread dies
+when the consumer abandons the stream.
 """
 
 import os
@@ -116,6 +118,39 @@ def test_streamed_join_matches_interpreter(warehouse, fname, chunk_bytes,
     whole = execute(optimize(join_agg_plan(fact, dim, how=how)),
                     fused=False)
     assert as_rows(fused) == as_rows(interp) == as_rows(whole)
+
+
+@pytest.mark.parametrize("how", ["inner", "semi"])
+def test_probe_methods_agree_and_count_per_chunk(warehouse, how,
+                                                 metrics_isolation,
+                                                 monkeypatch):
+    """The 30-row build takes the compare path; with the constant moved
+    under it the same plan takes the merge-rank: same rows, and
+    ``compare + rank == joins x chunks`` either way."""
+    from spark_rapids_jni_tpu.engine import segment as sg
+    from spark_rapids_jni_tpu.ops import join as J
+    metrics_isolation("engine.probe")
+    plan = join_agg_plan(warehouse / "fact.parquet",
+                         warehouse / "dim.parquet", 24 * 1_024, how=how)
+    results = {}
+    for method, other in (("compare", "rank"), ("rank", "compare")):
+        if method == "rank":
+            monkeypatch.setattr(J, "PROBE_COMPARE_MAX_BUILD", 29)
+        sg.SEGMENT_CACHE.clear()    # one plan shape, two programs
+        tracing.reset_counters("engine.probe")
+        stats = new_stats()
+        results[method] = as_rows(execute(optimize(plan), stats=stats,
+                                          fused=True))
+        assert stats["fused_segments"] == 1 and stats["chunks"] > 1
+        assert tracing.counter_value(f"engine.probe.{method}") \
+            == stats["chunks"]
+        assert tracing.counter_value(f"engine.probe.{other}") == 0
+    sg.SEGMENT_CACHE.clear()
+    assert results["compare"] == results["rank"]
+    whole = execute(optimize(join_agg_plan(warehouse / "fact.parquet",
+                                           warehouse / "dim.parquet",
+                                           how=how)), fused=False)
+    assert results["compare"] == as_rows(whole)
 
 
 def test_build_cache_cold_stream_hits_chunks_minus_one(warehouse,
