@@ -46,6 +46,7 @@ from .verify import (  # noqa: F401
     SchemaResolver,
     verify,
 )
+from .physical import PhysicalPlan, lower  # noqa: F401
 from .executor import execute, new_stats  # noqa: F401
 from .cache import (  # noqa: F401
     BUILD_CACHE,

@@ -440,5 +440,5 @@ def next_build_actual(warm: Optional[dict]) -> Optional[dict]:
 def _path(root: Optional[PlanNode], node: PlanNode) -> Optional[str]:
     if root is None:
         return None
-    from .verify import node_paths
+    from .plan import node_paths
     return node_paths(root).get(id(node))

@@ -29,26 +29,40 @@ from collections import OrderedDict
 from typing import Optional
 
 from ..utils import metrics
-from .executor import execute
+from .executor import execute, lowering_flags
 from .optimizer import optimize
+from .physical import PhysicalPlan, lower
 from .plan import PlanNode, Scan
 
 
 class CompiledPlan:
-    """An optimized plan plus its execution entry point."""
+    """An optimized plan, its physical plans and its execution entry
+    point."""
 
-    __slots__ = ("key", "plan", "optimized", "executions")
+    __slots__ = ("key", "plan", "optimized", "executions", "_physical")
 
     def __init__(self, key: str, plan: PlanNode, optimized: PlanNode):
         self.key = key
         self.plan = plan
         self.optimized = optimized
         self.executions = 0
+        self._physical: dict = {}  # lowering flag values -> PhysicalPlan
+
+    def physical(self) -> PhysicalPlan:
+        """The optimized plan lowered under the live flags: once per flag
+        tuple, so a repeat execution walks the plan for nothing (and a
+        test that flips ``config`` between executions sees its flags)."""
+        flags = lowering_flags()
+        key = tuple(flags.values())
+        hit = self._physical.get(key)
+        if hit is None:
+            hit = self._physical[key] = lower(self.optimized, **flags)
+        return hit
 
     def execute(self, stats: Optional[dict] = None, cancel=None,
                 session=None):
         self.executions += 1
-        return execute(self.optimized, stats=stats, cancel=cancel,
+        return execute(self.physical(), stats=stats, cancel=cancel,
                        session=session)
 
 

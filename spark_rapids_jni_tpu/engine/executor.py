@@ -20,6 +20,7 @@ from __future__ import annotations
 import time
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from ..columnar import Column, Table
@@ -27,14 +28,11 @@ from ..utils import metrics, timeline
 from ..utils.errors import CancelToken, classify
 from ..utils.memory import table_nbytes
 from ..utils.tracing import op_scope
-from .plan import (Aggregate, Exchange, Filter, Join, Limit, PlanNode,
-                   Project, Scan, Sort, TopK, node_label)
+from .physical import PhysicalPlan, Stage, lower
+from .plan import (STREAM_COMBINE, Aggregate, Exchange, Filter, Join, Limit,
+                   PlanNode, Project, Scan, Sort, TopK, depends_on,
+                   node_label)
 from .recovery import RecoveryPolicy, query_cancel_token
-
-#: aggregate ops with a (merge-op) decomposition usable for per-chunk
-#: partials; value = op that combines partial results
-_STREAM_COMBINE = {"sum": "sum", "count": "sum", "count_all": "sum",
-                   "min": "min", "max": "max"}
 
 _JOIN_FNS = None
 
@@ -54,7 +52,7 @@ def _join_fns():
 
 # -- filter expression evaluation ------------------------------------------
 
-def _eval_expr(expr, table: Table):
+def eval_expr(expr, table: Table):
     """Evaluate to ``(values, valid_or_None)``; comparisons give bool data."""
     head = expr[0]
     if head == "col":
@@ -66,10 +64,10 @@ def _eval_expr(expr, table: Table):
     if head == "lit":
         return expr[1], None
     if head == "not":
-        v, valid = _eval_expr(expr[1], table)
+        v, valid = eval_expr(expr[1], table)
         return jnp.logical_not(v), valid
-    a, avalid = _eval_expr(expr[1], table)
-    b, bvalid = _eval_expr(expr[2], table)
+    a, avalid = eval_expr(expr[1], table)
+    b, bvalid = eval_expr(expr[2], table)
     valid = avalid if bvalid is None else \
         (bvalid if avalid is None else avalid & bvalid)
     if isinstance(a, Column) or isinstance(b, Column):
@@ -105,7 +103,7 @@ def _eval_expr(expr, table: Table):
 
 def _filter_table(table: Table, predicate) -> Table:
     from ..ops.selection import apply_boolean_mask
-    vals, valid = _eval_expr(predicate, table)
+    vals, valid = eval_expr(predicate, table)
     mask = jnp.asarray(vals, jnp.bool_)
     if valid is not None:
         mask = mask & valid  # SQL semantics: NULL comparisons drop the row
@@ -124,105 +122,32 @@ def new_stats() -> dict:
 # -- execution context -----------------------------------------------------
 
 class _ExecCtx:
-    """Per-execute knobs + segment memoization.
+    """Per-execute state.
 
-    ``fuse``: run Filter/Project/Aggregate chains as fused jitted segments
-    (engine/segment.py) instead of interpreting node-by-node.
+    ``physical``: the plan being executed, lowered (engine/physical.py) —
+    every handler runs the form ``physical.stage_at(node)`` names.
     ``prefetch``: chunked-scan pipeline depth — the producer thread decodes
     and stages chunk k+1..k+prefetch while chunk k computes (0 = serial).
     ``recovery``: the query's RecoveryPolicy (retry/degradation ladder +
     cancellation token), checked at every chunk boundary.
-    ``root``: the plan being executed — the adaptive layer needs it for
-    node paths, ledger appends, and RewriteChecker runs on runtime
-    rewrites (engine/adaptive.py).
     ``stream_end``: ``perf_counter`` where the last streamed chunk loop
     ended; ``execute`` observes ``engine.post_stream_s`` from it.
     """
 
-    __slots__ = ("fuse", "prefetch", "nparents", "segments", "recovery",
-                 "root", "stream_end")
+    __slots__ = ("physical", "prefetch", "recovery", "stream_end")
 
-    def __init__(self, root: PlanNode, fuse: bool, prefetch: int,
+    def __init__(self, physical: PhysicalPlan, prefetch: int,
                  recovery: Optional[RecoveryPolicy] = None):
-        from .segment import parent_counts
-        self.root = root
-        self.fuse = fuse
+        self.physical = physical
         self.prefetch = max(0, int(prefetch))
-        self.nparents = parent_counts(root) if fuse else {}
-        self.segments: dict = {}  # id(top node) -> Segment | None
         self.recovery = recovery if recovery is not None \
             else RecoveryPolicy()
         self.stream_end: Optional[float] = None
 
-    def segment_for(self, node: PlanNode):
-        if not self.fuse:
-            return None
-        sid = id(node)
-        if sid not in self.segments:
-            from .segment import build_segment, worthwhile
-            seg = build_segment(node, self.nparents)
-            if seg is not None and not worthwhile(seg):
-                seg = None
-            self.segments[sid] = seg
-        return self.segments[sid]
-
-
-# -- streaming-aggregation eligibility -------------------------------------
-
-def _depends_on(node: PlanNode, target: PlanNode, memo: dict) -> bool:
-    if node is target:
-        return True
-    if id(node) in memo:
-        return memo[id(node)]
-    r = any(_depends_on(c, target, memo) for c in node.children())
-    memo[id(node)] = r
-    return r
-
-
-def _single_chunked_scan(root: PlanNode) -> Optional[Scan]:
-    """The single chunked parquet Scan under ``root`` reachable through
-    Filter/Project/Join nodes only (scan feeding exactly one join side) —
-    the stream axis both partial aggregation and partial top-k need."""
-    from .plan import topo_nodes
-    scans = [n for n in topo_nodes(root)
-             if isinstance(n, Scan) and n.chunk_bytes
-             and n.format == "parquet"]
-    if len(scans) != 1:
-        return None
-    scan = scans[0]
-    dep: dict = {}
-    node = root
-    while node is not scan:
-        if isinstance(node, (Filter, Project)):
-            node = node.child
-        elif isinstance(node, Join):
-            ld = _depends_on(node.left, scan, dep)
-            rd = _depends_on(node.right, scan, dep)
-            if ld and rd:
-                return None  # scan on both sides: no single stream axis
-            node = node.left if ld else node.right
-        else:
-            return None  # Sort/Limit/Aggregate between: not decomposable
-    return scan
-
-
-def _stream_scan_of(agg: Aggregate) -> Optional[Scan]:
-    """The single chunked parquet Scan this Aggregate can stream over.
-
-    Requires: every agg op decomposable, non-empty grouping keys, and a
-    ``_single_chunked_scan`` under the child.
-    """
-    if not agg.keys:
-        return None
-    if any(op not in _STREAM_COMBINE for _, op in agg.aggs):
-        return None
-    return _single_chunked_scan(agg.child)
-
 
 # -- the walk --------------------------------------------------------------
 
-def _scan_table(scan: Scan, stats: dict,
-                ctx: Optional[_ExecCtx] = None) -> Table:
+def _exec_scan(scan: Scan, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
     if scan.format == "orc":
         from ..io import read_orc
         return read_orc(scan.path, list(scan.columns)
@@ -238,7 +163,7 @@ def _scan_table(scan: Scan, stats: dict,
     reader = ParquetChunkedReader(
         scan.path, pass_read_limit=scan.chunk_bytes or (64 << 20),
         columns=cols, predicate=scan.predicate,
-        cancel=ctx.recovery.cancel if ctx is not None else None)
+        cancel=ctx.recovery.cancel)
     parts = list(reader)
     stats["row_groups_pruned"] += reader.groups_pruned
     stats["row_groups_read"] += reader.groups_read
@@ -254,13 +179,18 @@ def _groupby(table: Table, agg: Aggregate) -> Table:
                    [(c, op) for c, op in agg.aggs], names=list(agg.names))
 
 
+def _apply(nd, t: Table) -> Table:
+    """One Filter or Project node, interpreted."""
+    return _filter_table(t, nd.predicate) if isinstance(nd, Filter) \
+        else t.select(list(nd.columns))
+
+
 def _interp_chain(seg, t: Table, stats: dict) -> Table:
     """Interpreter fallback for a segment whose input schema turned out
     runtime-ineligible (string filter columns, nested buffers): exactly the
-    node-by-node semantics, just without re-entering segment_for."""
+    node-by-node semantics, just without re-entering the handlers."""
     for nd in seg.chain:
-        t = _filter_table(t, nd.predicate) if isinstance(nd, Filter) \
-            else t.select(list(nd.columns))
+        t = _apply(nd, t)
     if seg.agg is not None:
         t = _groupby(t, seg.agg)
     return t
@@ -293,25 +223,12 @@ def _exec_segment(seg, memo: dict, stats: dict, ctx: _ExecCtx,
         return sg.run_map_segment(compiled, inp)
 
 
-def _exec_scan(node: Scan, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
-    return _scan_table(node, stats, ctx)
-
-
-def _exec_filter(node: Filter, memo: dict, stats: dict,
-                 ctx: _ExecCtx) -> Table:
-    seg = ctx.segment_for(node)
-    if seg is not None:
-        return _exec_segment(seg, memo, stats, ctx, node)
-    return _filter_table(_exec(node.child, memo, stats, ctx),
-                         node.predicate)
-
-
-def _exec_project(node: Project, memo: dict, stats: dict,
-                  ctx: _ExecCtx) -> Table:
-    seg = ctx.segment_for(node)
-    if seg is not None:
-        return _exec_segment(seg, memo, stats, ctx, node)
-    return _exec(node.child, memo, stats, ctx).select(list(node.columns))
+def _exec_chain_node(node, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
+    """A Filter or a Project: the root of a ``map`` stage, or itself."""
+    st = ctx.physical.stage_at(node)
+    if st.kind == "map":
+        return _exec_segment(st.segment, memo, stats, ctx, node)
+    return _apply(node, _exec(node.child, memo, stats, ctx))
 
 
 def _exec_join(node: Join, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
@@ -328,12 +245,12 @@ def _exec_join(node: Join, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
 
 def _exec_aggregate(node: Aggregate, memo: dict, stats: dict,
                     ctx: _ExecCtx) -> Table:
-    scan = _stream_scan_of(node)
-    if scan is not None:
+    st = ctx.physical.stage_at(node)
+    if st.scan is not None:  # stream-agg / stream-agg-interp
         # scan-independent subtrees go into the shared memo BEFORE the
         # stats snapshot: a degraded re-run finds them memoized and skips
         # them, so their counts must survive the restore below
-        _precompute_independent(node.child, scan, memo, stats, ctx)
+        _precompute_independent(node.child, st.scan, memo, stats, ctx)
         snap = {k: (list(v) if isinstance(v, list) else v)
                 for k, v in stats.items()}
 
@@ -347,7 +264,7 @@ def _exec_aggregate(node: Aggregate, memo: dict, stats: dict,
                           for k, v in snap.items()})
 
         try:
-            return _exec_streamed(node, scan, memo, stats, ctx)
+            return _exec_streamed(st, memo, stats, ctx)
         except Exception as e:
             # resource exhaustion on the fused/staged stream degrades to
             # the interpreted per-chunk path — the always-correct fallback
@@ -360,54 +277,40 @@ def _exec_aggregate(node: Aggregate, memo: dict, stats: dict,
                 # session within its own budget: the pressure was a
                 # neighbor's — one same-rung retry before degrading
                 try:
-                    return _exec_streamed(node, scan, memo, stats, ctx)
+                    return _exec_streamed(st, memo, stats, ctx)
                 except Exception as e2:
                     if not ctx.recovery.can_degrade(e2):
                         raise
                     restore()
                     e = e2
             ctx.recovery.degrade("stream-interpreted", e, stats)
-            return _exec_streamed(node, scan, memo, stats, ctx,
-                                  force_interp=True)
-    from ..utils.config import config
-    if config.fuse_exchange:
-        out = _try_fused_stage(node, memo, stats, ctx)
+            return _exec_streamed(st, memo, stats, ctx, force_interp=True)
+    if st.kind == "fused-stage":
+        out = _try_fused_stage(node, st.stage, memo, stats, ctx)
         if out is not None:
             return out
-    seg = ctx.segment_for(node)
-    if seg is not None:
-        return _exec_segment(seg, memo, stats, ctx, node)
+        # the host-orchestrated form: a sandwich's combine sits directly
+        # on its Exchange (a breaker), so it is the interpreted group-by
+    elif st.kind == "agg":
+        return _exec_segment(st.segment, memo, stats, ctx, node)
     return _groupby(_exec(node.child, memo, stats, ctx), node)
 
 
-def _try_fused_stage(node: Aggregate, memo: dict, stats: dict,
+def _try_fused_stage(node: Aggregate, stage, memo: dict, stats: dict,
                      ctx: _ExecCtx) -> Optional[Table]:
-    """Whole-stage fusion (engine/segment.py ``FusedStage``): lower the
+    """Whole-stage fusion (engine/segment.py ``FusedStage``): run the
     ``partial-agg -> hash Exchange -> final-agg`` sandwich rooted at
-    ``node`` into ONE pjit/shard_map program — partial groupby, bucket
+    ``node`` as ONE pjit/shard_map program — partial groupby, bucket
     scatter, all_to_all, and combine groupby with zero host round-trips
     between the three plan nodes.  Returns the stage result, or None to
-    fall through to the host-orchestrated path (not a sandwich, shared
-    interior nodes, ineligible schema, the AQE probe routed to the
-    adaptive path, or capacity overflow — runtime re-plans, never
-    errors)."""
-    import jax
-
+    fall through to the host-orchestrated path (ineligible schema, the
+    AQE probe routed to the adaptive path, or capacity overflow —
+    runtime re-plans, never errors)."""
     from ..utils.config import config
     from . import segment as sg
 
-    # prefer the optimizer's stamped hint (planner-blessed detection);
-    # hand-built plans that never went through optimize() re-derive it
-    stage = getattr(node, "_fuse_stage", None) or sg.fused_sandwich(node)
-    if stage is None:
-        return None
     ex, partial = stage.exchange, stage.partial
-    npar = ctx.nparents if ctx.fuse else sg.parent_counts(ctx.root)
-    if npar.get(id(ex), 1) != 1 or npar.get(id(partial), 1) != 1:
-        return None  # shared interior nodes must materialize for others
-    ndev = len(jax.devices())
-    if ndev <= 1:
-        return None  # placement over one device is the identity
+    ndev = ctx.physical.ndev
     inp = _exec(partial.child, memo, stats, ctx)
     if not sg.fused_runtime_eligible(stage, inp):
         return None
@@ -438,7 +341,7 @@ def _try_fused_stage(node: Aggregate, memo: dict, stats: dict,
         prepped = (probed, n, probed_sharded)  # reused by the dispatch
         probe_skew = sh.device_load_stats(counts.sum(axis=0))["skew"]
         fused = probe_skew <= float(config.aqe_skew)
-        adaptive.record_fused_dispatch(ctx.root, ex, probe_skew,
+        adaptive.record_fused_dispatch(ctx.physical.root, ex, probe_skew,
                                        float(config.aqe_skew),
                                        "fused" if fused else "host")
         if not fused:
@@ -535,7 +438,7 @@ def _exec_exchange(node: Exchange, memo: dict, stats: dict,
     stats["exchanges"] += 1
     from ..utils import blackbox
     blackbox.record("exchange", kind=node.kind, rows=child.num_rows)
-    if node.kind == "broadcast":
+    if ctx.physical.stage_at(node).kind == "exchange-broadcast":
         return _broadcast_exchange(node, child)
     if getattr(node, "_aqe_flip", False):
         from ..utils.config import config
@@ -547,7 +450,8 @@ def _exec_exchange(node: Exchange, memo: dict, stats: dict,
             # NODE stays the same object (census, spans, and ledger paths
             # all keyed on it); only the physical op changes.
             from . import adaptive
-            if adaptive.try_broadcast_flip(node, child, ctx.root, stats):
+            if adaptive.try_broadcast_flip(node, child, ctx.physical.root,
+                                           stats):
                 return _broadcast_exchange(node, child)
     rp = ctx.recovery
     try:
@@ -584,8 +488,6 @@ def _exec_exchange(node: Exchange, memo: dict, stats: dict,
 
 
 def _broadcast_exchange(node: Exchange, table: Table) -> Table:
-    import jax
-
     from ..parallel.mesh import broadcast_table, make_mesh
     ndev = len(jax.devices())
     wire = table_nbytes(table) * max(0, ndev - 1)
@@ -627,11 +529,8 @@ def _hash_exchange(node: Exchange, table: Table, ctx: _ExecCtx,
     the whole matrix, inside ``shuffle_table_padded`` otherwise) and one
     ok-mask compaction fetch at the end.
     """
-    import jax
-
-    ndev = len(jax.devices())
-    if ndev <= 1:
-        return table  # placement over one device is the identity
+    if ctx.physical.stage_at(node).kind == "exchange-identity":
+        return table
     # the exchange's own work, not its child's: staging, both shuffle
     # phases, and the two engine.sync_wait spans nested inside
     # (hash_s >= the sum of its two labelled sync_wait_s)
@@ -639,14 +538,13 @@ def _hash_exchange(node: Exchange, table: Table, ctx: _ExecCtx,
     with op_scope("engine.exchange.hash", timed=True,
                   rows=int(table.num_rows), chunks=int(nchunks)):
         return _hash_exchange_mesh(node, table, ctx, stats, chunk_rows,
-                                   nchunks, ndev)
+                                   nchunks, ctx.physical.ndev)
 
 
 def _hash_exchange_mesh(node: Exchange, table: Table, ctx: _ExecCtx,
                         stats: Optional[dict], chunk_rows: int,
                         nchunks: int, ndev: int) -> Table:
     """``_hash_exchange`` over ``ndev`` > 1 devices, inside its span."""
-    import jax
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -725,7 +623,7 @@ def _hash_exchange_mesh(node: Exchange, table: Table, ctx: _ExecCtx,
         # post-split projection instead of the raw max
         from . import adaptive
         split, cap_need, split_entry, combine = adaptive.try_skew_split(
-            node, counts, ndev, ctx.root, stats)
+            node, counts, ndev, ctx.physical.root, stats)
     if counts is not None:
         if split is not None:
             # projected per-(src, dest) max post-split; multi-chunk pays
@@ -866,8 +764,6 @@ def _spilled_exchange(node: Exchange, table: Table, ctx: _ExecCtx) -> Table:
     (Spark HashPartitioning over original UTF-8 bytes for string keys);
     output order is pass-major — exchanges only feed order-insensitive
     consumers, so the content multiset is what matters."""
-    import jax
-
     from ..parallel import shuffle as sh
     from ..parallel.mesh import make_mesh
     from ..parallel.spill import shuffle_table_spilled
@@ -938,7 +834,7 @@ def _precompute_independent(root: PlanNode, scan: Scan, memo: dict,
     from .plan import topo_nodes
     dep: dict = {}
     for n in topo_nodes(root):
-        if n is not root and not _depends_on(n, scan, dep) \
+        if n is not root and not depends_on(n, scan, dep) \
                 and id(n) not in memo:
             _exec(n, memo, stats, ctx)
 
@@ -956,10 +852,10 @@ def _get_builds(joins: tuple, build_tables: tuple) -> tuple:
         for j, bt in zip(joins, build_tables))
 
 
-def _exec_streamed(agg: Aggregate, scan: Scan, memo: dict,
-                   stats: dict, ctx: _ExecCtx,
+def _exec_streamed(st: Stage, memo: dict, stats: dict, ctx: _ExecCtx,
                    force_interp: bool = False) -> Table:
-    """Per-chunk partial aggregation over the one chunked scan.
+    """Per-chunk partial aggregation over the one chunked scan of a
+    ``stream-agg`` / ``stream-agg-interp`` stage.
 
     Three compounding upgrades over the PR 1 interpreter loop:
 
@@ -967,13 +863,13 @@ def _exec_streamed(agg: Aggregate, scan: Scan, memo: dict,
       producer thread host-decodes and stages chunk k+1 while the device
       computes chunk k — decode/transfer overlap, the tabular-format
       study's actual ingest lever.
-    - **Fused chunk program** (``ctx.fuse``, scan feeds the segment
+    - **Fused chunk program** (``stream-agg``: the scan feeds the segment
       directly): each staged chunk arrives PADDED to a power-of-two row
       bucket, so one jitted segment (filters -> masked partial groupby)
       serves every chunk with zero per-chunk host syncs; padded partials
       accumulate on device and merge with ONE combine groupby at the end.
-    - **Fused probe joins** (``config.fuse_join``): a Join on the path
-      whose build side is scan-independent joins the segment instead of
+    - **Fused probe joins** (a Join in the stage's segment): a Join on the
+      path whose build side is scan-independent joins the segment instead of
       breaking it — the build is hashed + sorted once per execution
       (``BUILD_CACHE``) and enters the chunk program as a pytree input.
       Non-unique build hashes or ineligible schemas fall back to the
@@ -983,10 +879,12 @@ def _exec_streamed(agg: Aggregate, scan: Scan, memo: dict,
     from ..ops.selection import concat_tables
     from . import segment as sg
 
+    agg, scan = st.node, st.scan
     _precompute_independent(agg.child, scan, memo, stats, ctx)
     with op_scope("engine.stream", timed=True):
         reader, partials, fused, fused_compiled = _stream_chunks(
-            agg, scan, memo, stats, ctx, force_interp)
+            agg, scan, None if force_interp else st.segment, memo, stats,
+            ctx)
     # what follows, to the end of ``execute``, is ``engine.post_stream``:
     # the merge of the partials (one program) and every operator above it
     ctx.stream_end = time.perf_counter()
@@ -1004,15 +902,16 @@ def _exec_streamed(agg: Aggregate, scan: Scan, memo: dict,
         return _groupby(_exec(agg.child, sub, stats, ctx), agg)
 
     merged = partials[0] if len(partials) == 1 else concat_tables(partials)
-    combine = [(nm, _STREAM_COMBINE[op])
+    combine = [(nm, STREAM_COMBINE[op])
                for nm, (_, op) in zip(agg.names, agg.aggs)]
     return groupby(merged, list(agg.keys), combine, names=list(agg.names))
 
 
-def _stream_chunks(agg: Aggregate, scan: Scan, memo: dict, stats: dict,
-                   ctx: _ExecCtx, force_interp: bool) -> tuple:
+def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
+                   ctx: _ExecCtx) -> tuple:
     """The chunk loop of ``_exec_streamed``: reader open -> last chunk
-    dispatched -> reader closed.  Returns ``(reader, partials, fused,
+    dispatched -> reader closed.  ``seg``: the stage's fused chunk
+    segment, None to interpret each chunk.  Returns ``(reader, partials, fused,
     fused_compiled)``; at most one of ``partials`` (interpreted path:
     compacted Tables) and ``fused`` (fused path: padded device partials)
     is filled."""
@@ -1031,14 +930,6 @@ def _stream_chunks(agg: Aggregate, scan: Scan, memo: dict, stats: dict,
     if pqm is not None:
         # live-progress denominator from footer metadata (no page decode)
         pqm.progress_total(reader.footer_chunk_estimate())
-
-    seg = None
-    if ctx.fuse and not force_interp:
-        cand = sg.build_stream_segment(agg, scan, ctx.nparents,
-                                       fuse_join=config.fuse_join)
-        if cand is not None and cand.input is scan \
-                and sg.worthwhile(cand, streaming=True):
-            seg = cand
 
     partials: list = []          # interpreted path: compacted Tables
     fused: list = []             # fused path: padded device partials
@@ -1102,7 +993,7 @@ def _stream_chunks(agg: Aggregate, scan: Scan, memo: dict, stats: dict,
                           "link_bytes": 0, "uncompressed_bytes": 0,
                           "reasons": {}}
                     dd_entry = adaptive.record(
-                        ctx.root, {"kind": "scan:device_decode",
+                        ctx.physical.root, {"kind": "scan:device_decode",
                                    "node": node_label(scan)})
                 for item in _chain_one(first, it) \
                         if first is not None else ():
@@ -1290,8 +1181,8 @@ def _stream_partial(agg: Aggregate, scan: Scan, chunk: Table, memo: dict,
 def _exec_topk(node: TopK, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
     """ORDER BY ... LIMIT k without materializing the full table.
 
-    When the child streams over one chunked scan (``config.topk``), each
-    chunk's survivors are ranked by their order-preserving u64 key words
+    As a ``stream-topk`` stage (the child streams over one chunked scan),
+    each chunk's survivors are ranked by their order-preserving u64 key words
     (ops/order.py) plus a global arrival-index word — ties break by
     post-filter row order, which is chunk-geometry-invariant — and merged
     into a capacity-k device buffer: concat buffer-first, one lexsort, one
@@ -1300,10 +1191,9 @@ def _exec_topk(node: TopK, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
     """
     from ..ops.order import SortKey
     from ..ops.selection import slice_table, sort_table
-    from ..utils.config import config
 
-    scan = _single_chunked_scan(node.child) if config.topk else None
-    if scan is None or node.n == 0:
+    scan = ctx.physical.stage_at(node).scan
+    if scan is None:
         t = _exec(node.child, memo, stats, ctx)
         t = sort_table(t, [SortKey(t[c], ascending=a)
                            for c, a in node.keys])
@@ -1389,8 +1279,8 @@ def _exec_topk(node: TopK, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
 #: (tools/srjt_lint.py) asserts every plan._NODE_TYPES class is here
 _EXEC_DISPATCH = {
     Scan: _exec_scan,
-    Filter: _exec_filter,
-    Project: _exec_project,
+    Filter: _exec_chain_node,
+    Project: _exec_chain_node,
     Join: _exec_join,
     Aggregate: _exec_aggregate,
     Sort: _exec_sort,
@@ -1400,7 +1290,7 @@ _EXEC_DISPATCH = {
 }
 
 
-def _stamp_plan_feedback(plan: PlanNode, qm) -> None:
+def _stamp_plan_feedback(physical: PhysicalPlan, qm) -> None:
     """Post-run estimate-vs-actual join: copy the optimizer's evidence
     (``_est_rows`` per node, the root's ``_decisions`` ledger) onto the
     query's spans so summaries, EXPLAIN ANALYZE, and the profile store
@@ -1409,13 +1299,12 @@ def _stamp_plan_feedback(plan: PlanNode, qm) -> None:
     recorded; nodes without spans (fused-segment interiors) stay
     untouched — EXPLAIN falls back to the plan attribute for those."""
     from .plan import topo_nodes
-    from .verify import node_paths
-    paths = node_paths(plan)
+    plan = physical.root
     for n in topo_nodes(plan):
         rec = qm.node_spans.get(id(n))
         if rec is None:
             continue
-        fields = {"path": paths[id(n)]}
+        fields = {"path": physical.stage_at(n).path}
         est = getattr(n, "_est_rows", None)
         if est is not None:
             fields["est_rows"] = int(est)
@@ -1426,12 +1315,29 @@ def _stamp_plan_feedback(plan: PlanNode, qm) -> None:
         qm.set_decisions(dec)
 
 
-def execute(plan: PlanNode, stats: Optional[dict] = None,
+def lowering_flags(fused: Optional[bool] = None) -> dict:
+    """``physical.lower``'s keyword arguments for this process, now: the
+    live ``config`` fields (``fused`` overrides ``config.fuse``) and the
+    device count."""
+    from ..utils.config import config
+    return {"fuse": config.fuse if fused is None else bool(fused),
+            "fuse_join": config.fuse_join, "topk": config.topk,
+            "fuse_exchange": config.fuse_exchange,
+            "ndev": len(jax.devices())}
+
+
+def execute(plan: PlanNode | PhysicalPlan, stats: Optional[dict] = None,
             fused: Optional[bool] = None,
             prefetch: Optional[int] = None,
             cancel: Optional[CancelToken] = None,
             session=None) -> Table:
     """Run ``plan`` against the local io/ops layers; returns the result.
+
+    ``plan`` is lowered once (``physical.lower`` under
+    ``lowering_flags(fused)``) and every node runs the stage form the
+    physical plan names; a caller that keeps a plan across executions
+    (``cache.CompiledPlan``) passes the ``PhysicalPlan`` it kept instead
+    (``fused`` then has no say: the flags it was lowered under hold).
 
     ``stats`` (optional dict) is updated in place with execution evidence:
     ``row_groups_pruned``/``row_groups_read`` (scan pruning), ``chunks``,
@@ -1469,8 +1375,10 @@ def execute(plan: PlanNode, stats: Optional[dict] = None,
     if cancel is None:
         cancel = query_cancel_token()
     recovery = RecoveryPolicy(cancel=cancel, session=session)
-    ctx = _ExecCtx(plan,
-                   fuse=config.fuse if fused is None else bool(fused),
+    physical = plan if isinstance(plan, PhysicalPlan) \
+        else lower(plan, **lowering_flags(fused))
+    plan = physical.root
+    ctx = _ExecCtx(physical,
                    prefetch=config.prefetch if prefetch is None
                    else int(prefetch),
                    recovery=recovery)
@@ -1524,7 +1432,7 @@ def execute(plan: PlanNode, stats: Optional[dict] = None,
             oq.set_outcome("ok")
             # estimate-vs-actual + decision-ledger handoff (optimizer
             # stamped the plan; spans now hold the actuals)
-            _stamp_plan_feedback(plan, oq)
+            _stamp_plan_feedback(physical, oq)
         if qm is not None:
             qm.note_stats(stats)
             # query-boundary device-memory sample: with the chunk-boundary

@@ -24,32 +24,6 @@ from ..utils import metrics
 from .plan import (Aggregate, Exchange, Filter, Join, Limit, PlanNode,
                    Project, Scan, Sort, TopK, node_label)
 
-# -- roofline ceiling --------------------------------------------------------
-
-#: published HBM bandwidth by ``device_kind``, GB/s.  v5e: 819 GB/s (Google
-#: Cloud documentation, "TPU v5e").  A kind that is not here has no ceiling:
-#: nothing is assumed for a device the code does not know, the CPU included.
-PUBLISHED_HBM_GBPS = {
-    "TPU v5 lite": 819.0,
-    "TPU v5e": 819.0,
-}
-
-
-def roofline_ceiling_gbps() -> Optional[float]:
-    """The device-bandwidth ceiling per-node GB/s is judged against.
-
-    ``config.roofline_gbps`` (the SRJT_ROOFLINE_GBPS override — read every
-    call so tests can pin it via refresh()) wins; otherwise the published
-    peak for the ``device_kind`` the plan runs on.  Returns None for a kind
-    without a published peak — annotations then omit ``roofline_frac``
-    rather than inventing a ceiling.
-    """
-    from ..utils.config import config
-    if config.roofline_gbps > 0:
-        return config.roofline_gbps
-    import jax
-    return PUBLISHED_HBM_GBPS.get(jax.devices()[0].device_kind)
-
 
 def _describe_scan(node: Scan) -> str:
     bits = [repr(node.path)]
@@ -86,20 +60,17 @@ def _describe(node: PlanNode) -> str:
     return fn(node) if fn is not None else type(node).__name__
 
 
-def _roofline(span: dict, ceiling: Optional[float]) -> dict:
+def _throughput(span: dict) -> dict:
     """Derived per-node cost columns from a span's byte accounting:
     ``bytes_moved`` (in + out, fused-segment bytes already attributed to
-    the segment root by the executor), ``GBps`` over the node's wall
-    time, and ``roofline_frac`` against the pinned bandwidth ceiling."""
+    the segment root by the executor) and ``GBps`` over the node's host
+    wall time (the device's roofline share is the benchmark's
+    ``segment_roofline``, from the device trace)."""
     moved = int(span.get("bytes_in", 0)) + int(span.get("bytes_out", 0))
-    out = {"bytes_moved": moved, "GBps": None, "roofline_frac": None}
     wall = span.get("wall_s") or 0.0
-    if moved and wall > 0:
-        gbps = moved / wall / 1e9
-        out["GBps"] = round(gbps, 3)
-        if ceiling:
-            out["roofline_frac"] = round(gbps / ceiling, 6)
-    return out
+    return {"bytes_moved": moved,
+            "GBps": round(moved / wall / 1e9, 3)
+            if moved and wall > 0 else None}
 
 
 def _est_bits(span: Optional[dict], node: Optional[PlanNode]) -> list:
@@ -119,7 +90,7 @@ def _est_bits(span: Optional[dict], node: Optional[PlanNode]) -> list:
             f"q_error={'?' if qe is None else format(qe, '.2f')}"]
 
 
-def _annotate(span: Optional[dict], ceiling: Optional[float] = None,
+def _annotate(span: Optional[dict],
               node: Optional[PlanNode] = None) -> str:
     """The ANALYZE half: bracketed span fields for one node line."""
     if span is None:
@@ -135,24 +106,18 @@ def _annotate(span: Optional[dict], ceiling: Optional[float] = None,
         bits.append(f"padded_waste={span['padded_rows']}")
     if span["host_syncs"]:
         bits.append(f"host_syncs={span['host_syncs']}")
-    rf = _roofline(span, ceiling)
+    rf = _throughput(span)
     if rf["bytes_moved"]:
         bits.append(f"bytes_moved={rf['bytes_moved']}")
         if rf["GBps"] is not None:
             bits.append(f"GB/s={rf['GBps']:.3f}")
-        if rf["roofline_frac"] is not None:
-            bits.append(f"roofline_frac={rf['roofline_frac']:.6f}")
     wire = int(span.get("wire_bytes", 0))
     if wire:
-        # exchange cost against the same pinned ceiling: wire bytes over
-        # this node's wall time — how close the exchange ran to the roof
+        # exchange cost: wire bytes over this node's wall time
         bits.append(f"wire_bytes={wire}")
         wall = span.get("wall_s") or 0.0
         if wall > 0:
-            gbps = wire / wall / 1e9
-            bits.append(f"exch_GB/s={gbps:.3f}")
-            if ceiling:
-                bits.append(f"exch_roofline_frac={gbps / ceiling:.6f}")
+            bits.append(f"exch_GB/s={wire / wall / 1e9:.3f}")
     if span.get("decode"):
         # SRJT_DEVICE_DECODE routing verdict on a scan: which side decoded
         # the pages, what the link carried vs what the host path would
@@ -260,8 +225,7 @@ class ExplainReport:
                    if n["metrics"] is not None)
 
 
-def _render(root: PlanNode, spans: dict,
-            ceiling: Optional[float] = None) -> str:
+def _render(root: PlanNode, spans: dict) -> str:
     lines: list[str] = []
     seen: set[int] = set()
 
@@ -272,7 +236,7 @@ def _render(root: PlanNode, spans: dict,
             return
         seen.add(id(node))
         lines.append(f"{pad}{_describe(node)}  "
-                     f"{_annotate(spans.get(id(node)), ceiling, node)}")
+                     f"{_annotate(spans.get(id(node)), node)}")
         for child in node.children():
             walk(child, depth + 1)
 
@@ -340,16 +304,15 @@ def explain_analyze(plan: PlanNode, stats: Optional[dict] = None,
     spans = dict(qm.node_spans) if qm is not None else {}
     summary = qm.summary() if qm is not None else {}
 
-    ceiling = roofline_ceiling_gbps()
     from .plan import topo_nodes
     nodes = [{"label": node_label(n),
               "desc": _describe(n),
               "est_rows": getattr(n, "_est_rows", None),
               "metrics": None if id(n) not in spans else
-              {**spans[id(n)], **_roofline(spans[id(n)], ceiling)}}
+              {**spans[id(n)], **_throughput(spans[id(n)])}}
              for n in topo_nodes(opt)]
 
-    text = _render(opt, spans, ceiling)
+    text = _render(opt, spans)
     if summary:
         foot = [f"-- query {summary['name']} "
                 f"wall={summary['wall_s'] * 1e3:.2f}ms "
@@ -358,8 +321,6 @@ def explain_analyze(plan: PlanNode, stats: Optional[dict] = None,
                 f"fused_segments={stats['fused_segments']}"]
         if stats.get("exchanges"):
             foot[0] += f" exchanges={stats['exchanges']}"
-        if ceiling:
-            foot[0] += f" roofline_ceiling_GBps={ceiling}"
         mem = summary.get("memory")
         if mem:
             foot.append(
@@ -387,7 +348,7 @@ def explain_analyze(plan: PlanNode, stats: Optional[dict] = None,
             # scored against the actual rows the decision's node saw.
             # verify.decision_census(opt) counts the same structural
             # entries statically — bench/CI assert the counts match.
-            from .verify import node_paths
+            from .plan import node_paths
             actuals = {p: spans[i].get("rows_out")
                        for i, p in node_paths(opt).items() if i in spans}
             foot.append(f"-- decisions ({len(decisions)}):")

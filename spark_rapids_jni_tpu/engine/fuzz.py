@@ -31,10 +31,12 @@ benches happen to build.  Four pieces:
    ``SRJT_DIST``/``SRJT_TOPK``/``SRJT_BROADCAST_ROWS``/``SRJT_AQE``),
    asserting after every variant: ``verify()`` passes on the optimized
    plan, the stamped decision ledger equals ``verify.decision_census``
-   (for plans without hand-placed structure), the static exchange
-   census equals the executed counter, the static sync budget stays
-   inside ``SYNC_WHITELIST``, engine variants agree bit-exactly, and
-   all agree with a pandas oracle evaluated over the in-memory frames.
+   (for plans without hand-placed structure), the static sync budget
+   stays inside ``SYNC_WHITELIST``, the stage forms ``physical.lower``
+   chose are the ones that ran (``stage_census``: exchanges executed,
+   segments run and host syncs paid equal the lowered kinds' charges),
+   engine variants agree bit-exactly, and all agree with a pandas
+   oracle evaluated over the in-memory frames.
    The AQE variant plans every join as a shuffle then lets the runtime
    rules (engine/adaptive.py) flip/split mid-query — parity proves the
    rewrites content-exact, and every applied rewrite must match its
@@ -528,7 +530,7 @@ VARIANTS = (
     # whole-stage fusion: the partial/final aggregate sandwich lowers to
     # ONE jit(shard_map) program (SRJT_FUSE_EXCHANGE).  Bit-exact parity
     # vs every other variant asserts the in-program exchange is
-    # content-exact; the exchange-census check asserts the lowered
+    # content-exact; the stage-census check asserts the lowered
     # exchange still ticks stats["exchanges"]; the sync-whitelist check
     # covers the fused-stage budget entries
     {"name": "dist-fused", "fuse": True, "distribute": True,
@@ -626,6 +628,62 @@ def _check_ledger(opt, dist: bool) -> Optional[str]:
     return None
 
 
+def stage_census(physical, stats: dict, qm=None) -> Optional[str]:
+    """What ``physical.lower`` chose against what the run reports: None
+    when they agree, else the first difference.
+
+    From ``stats`` alone, exactly: the exchanges executed, whether
+    anything streamed, whether a top-k did.  With the run's QueryMetrics
+    (``qm``) also the fused segments run and the ``engine.host_sync``
+    counter against ``SYNC_CHARGES`` — less the stages a veto demoted that
+    the static side can name: a ``Stage.vetoed`` segment (schema), an
+    ``agg`` segment over an empty input (its span's ``rows_in``), a stream
+    that staged no chunk.  A ``stream-agg`` whose consumed nodes have spans
+    of their own ran interpreted; unless ``vetoed`` said so that is a
+    difference (the unique-build veto would read so too: no plan of the
+    suite or the fuzzer streams over a build with duplicate hashes).  A
+    per-chunk re-walk runs the segments under it once per chunk, so those
+    counts are then lower bounds.  Ladder steps and AQE rewrites change
+    forms mid-run: with either, only the ``stats`` checks apply."""
+    from .physical import SYNC_CHARGES
+    stages = [st for st in physical.run_stages()
+              if not (st.stage is not None and st.vetoed)]
+    kinds = [st.kind for st in stages]
+    want = {"exchanges": sum(k.startswith("exchange-") or k == "fused-stage"
+                             for k in kinds),
+            "streamed": any(st.scan is not None for st in stages),
+            "topk": "stream-topk" in kinds}
+    got = {k: stats[k] for k in want}
+    if got != want:
+        return f"stats {got} != lowered {want}"
+    if qm is None or stats.get("degradations") or config.aqe:
+        return None
+    spans = qm.node_spans
+    rewalk = False
+    ran = []
+    for st in stages:
+        if st.scan is not None:
+            walked = st.kind != "stream-agg" or st.vetoed
+            if not walked and any(id(n) in spans for n in st.nodes
+                                  if n is not st.node):
+                return f"stream-agg at {st.path} ran interpreted"
+            rewalk = rewalk or (walked and stats["chunks"] > 1)
+            if walked or not stats["chunks"]:
+                continue
+        elif st.vetoed or (st.kind == "agg"
+                           and not spans[id(st.node)]["rows_in"]):
+            continue
+        ran.append(st)
+    want = {"fused_segments": sum(st.segment is not None for st in ran),
+            "host_syncs": sum(len(SYNC_CHARGES[st.kind]) for st in ran)}
+    got = {"fused_segments": stats["fused_segments"],
+           "host_syncs": qm.counters.get("engine.host_sync", 0)}
+    if any(got[k] < want[k] for k in want) or (got != want and not rewalk):
+        return (f"ran {got} {'<' if rewalk else '!='} lowered {want} "
+                f"({sorted(set(kinds))})")
+    return None
+
+
 def run_case(plan: PlanNode, cat, variants=VARIANTS,
              optimize_fn: Optional[Callable] = None) -> None:
     """Run one plan through the full differential matrix; raises
@@ -635,9 +693,11 @@ def run_case(plan: PlanNode, cat, variants=VARIANTS,
     broken-rule-injection tests pass a sabotaged pipeline here and
     assert the harness catches it.
     """
+    from ..utils import metrics
     from . import optimizer
-    from .executor import execute, new_stats
-    from .verify import (SYNC_WHITELIST, plan_exchanges, sync_budget,
+    from .executor import execute, lowering_flags, new_stats
+    from .physical import lower
+    from .verify import (SYNC_WHITELIST, SchemaResolver, sync_budget,
                          verify)
     opt_fn = optimize_fn or optimizer.optimize
     manual = has_manual_structure(plan)
@@ -668,15 +728,16 @@ def run_case(plan: PlanNode, cat, variants=VARIANTS,
                         f"unwhitelisted sync {e['site']} at {e['path']}")
             stats = new_stats()
             try:
-                tbl = execute(opt, stats)
+                with metrics.query(f"fuzz:{name}") as qm:
+                    tbl = execute(opt, stats)
             except Exception as e:
                 raise SoundnessFailure("execute", name, repr(e)[:300])
-            static_ex = len(plan_exchanges(opt))
-            if stats["exchanges"] != static_ex:
-                raise SoundnessFailure(
-                    "exchange-census", name,
-                    f"static census {static_ex} != executed "
-                    f"{stats['exchanges']}")
+            resolver = SchemaResolver()
+            bad = stage_census(
+                lower(opt, **lowering_flags(),
+                      resolver=lambda n: verify(n, resolver)), stats, qm)
+            if bad:
+                raise SoundnessFailure("stage-census", name, bad)
             if flags.get("aqe"):
                 # runtime rewrites must leave evidence: every applied
                 # flip/split bumped its stats counter AND recorded a

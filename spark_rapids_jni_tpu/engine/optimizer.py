@@ -26,8 +26,8 @@ from __future__ import annotations
 
 from typing import Optional
 
-from .plan import (ORDER_SENSITIVE_AGGS, Aggregate, Exchange, Filter, Join,
-                   Limit, PlanNode, Project, Scan, Sort, TopK,
+from .plan import (ORDER_SENSITIVE_AGGS, STREAM_COMBINE, Aggregate, Exchange,
+                   Filter, Join, Limit, PlanNode, Project, Scan, Sort, TopK,
                    co_partitioned, expr_columns, partitioning, rebuild,
                    topo_nodes)
 
@@ -506,7 +506,6 @@ def _plan_exchanges(node: PlanNode, pmemo: dict, est: dict,
             if warmed is not None:
                 dec.append(warmed)
     elif isinstance(out, Aggregate):
-        from .executor import _STREAM_COMBINE
         p = partitioning(out.child, pmemo)
         if any(op in ORDER_SENSITIVE_AGGS for _, op in out.aggs):
             # first/last/collect_list results depend on input row ORDER,
@@ -529,13 +528,13 @@ def _plan_exchanges(node: PlanNode, pmemo: dict, est: dict,
         elif p.kind == "broadcast" or (p.kind == "hash"
                                        and set(p.keys) <= set(out.keys)):
             pass  # every group's rows already share a device
-        elif all(op in _STREAM_COMBINE for _, op in out.aggs):
+        elif all(op in STREAM_COMBINE for _, op in out.aggs):
             # partial below the exchange: per-device partials are what
             # crosses the wire, the combine above re-aggregates them.
             # Dtype-exact: count partials are INT64 and combine by sum
             # (INT64), sum/min/max combine in their own dtype.
             partial = Aggregate(out.child, out.keys, out.aggs, out.names)
-            combine = tuple((nm, _STREAM_COMBINE[op])
+            combine = tuple((nm, STREAM_COMBINE[op])
                             for nm, (_c, op) in zip(out.names, out.aggs))
             out = Aggregate(Exchange(partial, out.keys, "hash"),
                             out.keys, combine, out.names)
@@ -720,15 +719,4 @@ def optimize(plan: PlanNode,
         # structural pass would rebuild the nodes and drop them
         from . import adaptive
         adaptive.stamp_eligibility(plan)
-    if config.fuse_exchange:
-        # whole-stage fusion hint: precompute the partial/final sandwich
-        # detection (same structural test the static census uses) so the
-        # executor dispatches the planner-blessed FusedStage instead of
-        # re-deriving it per execution.  A plain-attribute stamp like the
-        # AQE ones above: fingerprints stay byte-identical.
-        from . import segment as sg
-        for n in topo_nodes(plan):
-            st = sg.fused_sandwich(n)
-            if st is not None:
-                object.__setattr__(n, "_fuse_stage", st)
     return plan

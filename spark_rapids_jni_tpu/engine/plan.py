@@ -46,6 +46,11 @@ AGG_OPS = ("sum", "min", "max", "mean", "count", "count_all", "var", "std",
 #: beneath an aggregate using these
 ORDER_SENSITIVE_AGGS = ("first", "last", "collect_list")
 
+#: aggregate ops with a (merge-op) decomposition usable for per-chunk or
+#: per-device partials; value = op that combines partial results
+STREAM_COMBINE = {"sum": "sum", "count": "sum", "count_all": "sum",
+                  "min": "min", "max": "max"}
+
 
 # -- expression helpers ----------------------------------------------------
 
@@ -464,6 +469,36 @@ def topo_nodes(root: PlanNode) -> list:
 
     visit(root)
     return out
+
+
+def depends_on(node: PlanNode, target: PlanNode, memo: dict) -> bool:
+    """Whether ``target`` is ``node`` or sits under it (``memo``: a dict
+    the caller keeps across calls for one ``target``)."""
+    if node is target:
+        return True
+    if id(node) in memo:
+        return memo[id(node)]
+    r = any(depends_on(c, target, memo) for c in node.children())
+    memo[id(node)] = r
+    return r
+
+
+def node_paths(root: PlanNode) -> dict:
+    """id(node) -> dotted path from the root (first-visit path for shared
+    nodes), matching the paths PlanVerificationError reports."""
+    paths: dict = {}
+
+    def visit(n: PlanNode, p: str) -> None:
+        if id(n) in paths:
+            return
+        paths[id(n)] = p
+        for f in ("child", "left", "right"):
+            c = getattr(n, f, None)
+            if isinstance(c, PlanNode):
+                visit(c, f"{p}.{f}")
+
+    visit(root, "root")
+    return paths
 
 
 def rebuild(node: PlanNode, **changes) -> PlanNode:

@@ -56,8 +56,8 @@ from ..columnar import Column, Table
 from ..utils import metrics, timeline
 from ..utils.config import config
 from ..utils.tracing import op_scope
-from .plan import (Aggregate, Filter, Join, PlanNode, Project, expr_columns,
-                   topo_nodes)
+from .plan import (STREAM_COMBINE, Aggregate, Filter, Join, PlanNode,
+                   Project, depends_on, expr_columns, topo_nodes)
 
 #: chain members fusable into a segment body (everything else is a
 #: breaker).  Exchange is deliberately NOT here: an exchange re-places
@@ -176,7 +176,6 @@ def build_stream_segment(agg: Aggregate, scan: PlanNode,
     """
     if not _agg_fusable(agg):
         return None
-    from .executor import _depends_on
     dep: dict = {}
     chain = []
     cur = agg.child
@@ -187,8 +186,8 @@ def build_stream_segment(agg: Aggregate, scan: PlanNode,
         elif (fuse_join and isinstance(cur, Join)
               and nparents.get(id(cur), 1) == 1
               and cur.how in _FUSABLE_JOINS
-              and _depends_on(cur.left, scan, dep)
-              and not _depends_on(cur.right, scan, dep)):
+              and depends_on(cur.left, scan, dep)
+              and not depends_on(cur.right, scan, dep)):
             chain.append(cur)
             cur = cur.left
         else:
@@ -382,13 +381,13 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
 
     def fn(table: Table, nvalid, prepared=()):
         from ..ops.aggregate import groupby_padded
-        from .executor import _eval_expr
+        from .executor import eval_expr
         compiled.traces += 1  # trace-time side effect: the no-recompile proof
         live = jnp.arange(table.num_rows, dtype=jnp.int32) < nvalid
         ji = 0
         for i, nd in enumerate(chain):
             if isinstance(nd, Filter):
-                vals, valid = _eval_expr(nd.predicate, table)
+                vals, valid = eval_expr(nd.predicate, table)
                 m = jnp.asarray(vals, jnp.bool_)
                 if valid is not None:
                     m = m & valid  # SQL semantics: NULL comparison drops
@@ -538,11 +537,10 @@ def _build_combine_fn(agg: Aggregate, key_dtypes: tuple, cap: int,
     combine ``groupby_padded`` under the live mask — still padded, zero
     host syncs.
     """
-    from .executor import _STREAM_COMBINE
     nk = len(agg.keys)
     knames = [f"k{i}" for i in range(nk)]
     anames = [f"a{j}" for j in range(len(agg.aggs))]
-    combine = [(anames[j], _STREAM_COMBINE[op])
+    combine = [(anames[j], STREAM_COMBINE[op])
                for j, (_, op) in enumerate(agg.aggs)]
 
     def cut(a):
@@ -868,11 +866,11 @@ def combine_partials(partials: list, compiled: CompiledSegment) -> Table:
 
 #: partial-side ops a fused stage supports: must both run on groupby's
 #: fast traced path (ops.aggregate._FAST_OPS) and decompose into a merge
-#: op (executor._STREAM_COMBINE keys) — the optimizer's sandwich
+#: op (plan.STREAM_COMBINE keys) — the optimizer's sandwich
 #: construction guarantees this; the detector re-checks for hand-built
 #: plans
 _FUSED_PARTIAL_OPS = frozenset({"sum", "count", "count_all", "min", "max"})
-#: merge-side ops (the _STREAM_COMBINE value set)
+#: merge-side ops (the STREAM_COMBINE value set)
 _FUSED_COMBINE_OPS = frozenset({"sum", "min", "max"})
 
 

@@ -18,20 +18,18 @@ the work BEFORE any kernel runs.  Two of the three lint passes live here
    the root output schema is an immediate failure instead of a wrong
    result, and ``bridge/server`` PLAN_EXECUTE verifies before executing.
 
-2. **Compiled-artifact linter** — ``lint_plan_artifacts`` mirrors the
-   executor's segment selection (``plan_segments``), lowers each fused
-   segment's program to a jaxpr with ``jax.make_jaxpr`` over a zero-filled
-   input table — tracing only, nothing executes — and statically asserts
-   the chunk-program contract: no host callbacks (``pure_callback`` etc.),
-   no trace-time concretization (a ``.item()``/``float()`` smuggled into a
-   traced path fails the lint, not a production run), prepared-build
-   pytree args device-resident, and the deliberate host-sync budget.
-   ``sync_budget`` is the static model of the three whitelisted sync
-   sites in engine/segment.py (the "3 deliberate host syncs" contract of
-   docs/OBSERVABILITY.md): a fused map segment pays one
-   ``segment-boundary-compaction``, a fused agg segment one
-   ``groupby-compaction``, and a streamed agg segment a ``combine-sizing``
-   plus the compaction.  ``lint_segment_cache`` flags fingerprints whose
+2. **Compiled-artifact linter** — ``lint_plan_artifacts`` takes the
+   stages the executor will run from ``physical.lower`` (the one owner of
+   that choice), lowers each fused stage's program to a jaxpr with
+   ``jax.make_jaxpr`` over a zero-filled input table — tracing only,
+   nothing executes — and statically asserts the chunk-program contract:
+   no host callbacks (``pure_callback`` etc.), no trace-time
+   concretization (a ``.item()``/``float()`` smuggled into a traced path
+   fails the lint, not a production run), prepared-build pytree args
+   device-resident, and the deliberate host-sync budget.  ``sync_budget``
+   charges every stage what ``physical.SYNC_CHARGES`` says its kind pays
+   (the "deliberate host syncs" contract of docs/OBSERVABILITY.md).
+   ``lint_segment_cache`` flags fingerprints whose
    compiled-variant count says unpadded dynamic shapes are exploding the
    (fingerprint, shape-class) SEGMENT_CACHE.
 """
@@ -43,9 +41,11 @@ from typing import Optional
 import numpy as np
 
 from ..dtypes import BOOL8, FLOAT64, INT64, LIST, DType
+from .physical import SYNC_CHARGES, lower
 from .plan import (ORDER_SENSITIVE_AGGS, Aggregate, Exchange, Filter, Join,
                    Limit, PlanNode, Project, Scan, Sort, TopK, co_partitioned,
-                   expr_columns, node_label, partitioning, topo_nodes)
+                   expr_columns, node_label, node_paths, partitioning,
+                   topo_nodes)
 
 #: the deliberate host-sync sites the engine is allowed to pay
 #: (metrics.host_sync labels; the AST lint in tools/srjt_lint.py rejects
@@ -559,7 +559,7 @@ def _nulls_filter(node: Filter, path: str, ctx: _Ctx) -> Optional[dict]:
     if child is None:
         return None
     # the executor ANDs the validity of EVERY predicate-referenced column
-    # into the keep-mask (engine/executor._eval_expr), so survivors are
+    # into the keep-mask (engine/executor.eval_expr), so survivors are
     # proven non-null in those columns regardless of the operator tree
     out = dict(child)
     for c in expr_columns(node.predicate):
@@ -698,24 +698,6 @@ class RewriteChecker:
 
 # -- pass 2: compiled-artifact lint -----------------------------------------
 
-def node_paths(root: PlanNode) -> dict:
-    """id(node) -> dotted path from the root (first-visit path for shared
-    nodes), matching the paths PlanVerificationError reports."""
-    paths: dict = {}
-
-    def visit(n: PlanNode, p: str) -> None:
-        if id(n) in paths:
-            return
-        paths[id(n)] = p
-        for f in ("child", "left", "right"):
-            c = getattr(n, f, None)
-            if isinstance(c, PlanNode):
-                visit(c, f"{p}.{f}")
-
-    visit(root, "root")
-    return paths
-
-
 def plan_exchanges(plan: PlanNode) -> list:
     """Static census of the Exchange nodes in a plan, in postorder — one
     entry ``{"path", "kind", "keys"}`` per node.  The executor bumps
@@ -831,173 +813,60 @@ def check_partitioning(plan: PlanNode) -> None:
                     f"split across devices")
 
 
-def plan_segments(plan: PlanNode, cfg=None, ndev: Optional[int] = None,
-                  resolver: Optional[SchemaResolver] = None) -> list:
-    """The fused segments the executor would form for ``plan`` — the same
-    selection logic as ``_exec``/``_exec_streamed``, run statically: each
-    entry is ``{"kind": "map"|"agg"|"stream-agg", "segment", "node",
-    "path"}``.  Interior chain nodes are consumed by their segment, so the
-    walk (parents before children) never double-roots a chain.
-
-    With ``cfg.fuse_exchange`` on a >1-device mesh, a partial/final
-    aggregate sandwich lowers to a single ``{"kind": "fused-stage",
-    "stage": FusedStage, ...}`` entry (the whole distributed stage is ONE
-    pjit program — the combine, exchange, and partial nodes are all
-    consumed by it; the walk continues below the partial's child, exactly
-    where the runtime roots its lower segments).  ``resolver`` feeds the
-    static dtype eligibility check; ``ndev`` defaults to the runtime
-    device count."""
-    from ..utils.config import config as _config
-    from . import segment as sg
-    from .executor import _stream_scan_of
-    cfg = cfg or _config
-    fuse_x = getattr(cfg, "fuse_exchange", False)
-    if fuse_x and ndev is None:
+def _lowered(plan: PlanNode, resolver: "SchemaResolver", cfg,
+             ndev: Optional[int]) -> tuple:
+    """``(physical plan, sync entries)``: ``physical.lower`` under ``cfg``
+    (default: the live config) for an ``ndev``-device mesh (default: this
+    process's) with the verifier's schema inference as its resolver, and
+    what ``sync_budget`` charges its stages."""
+    if cfg is None:
+        from ..utils.config import config as cfg
+    if ndev is None:
         import jax
         ndev = len(jax.devices())
-    fuse_x = fuse_x and (ndev or 0) > 1
-    if not cfg.fuse and not fuse_x:
-        return []
-    nparents = sg.parent_counts(plan)
-    paths = node_paths(plan)
-    out: list = []
-    consumed: set = set()
-    for node in reversed(topo_nodes(plan)):
-        if id(node) in consumed:
+    physical = lower(plan, fuse=cfg.fuse, fuse_join=cfg.fuse_join,
+                     topk=cfg.topk, fuse_exchange=cfg.fuse_exchange,
+                     ndev=ndev, resolver=lambda node: verify(node, resolver))
+    entries: list = []
+    for st in physical.run_stages():
+        sites = SYNC_CHARGES[st.kind]
+        if st.vetoed:
+            entries.append({"site": "interpreted-fallback",
+                            "path": st.path, "count": 0})
             continue
-        if fuse_x and isinstance(node, Aggregate):
-            stage = sg.fused_sandwich(node)
-            if stage is not None \
-                    and nparents.get(id(stage.exchange), 1) == 1 \
-                    and nparents.get(id(stage.partial), 1) == 1:
-                schema = (verify(stage.partial.child, resolver)
-                          if resolver is not None else None)
-                if sg.fused_static_eligible(stage, schema):
-                    for nd in (node, stage.exchange, stage.partial):
-                        consumed.add(id(nd))
-                    out.append({"kind": "fused-stage", "stage": stage,
-                                "node": node, "path": paths[id(node)]})
-                    continue
-        if not cfg.fuse:
-            continue
-        if isinstance(node, Aggregate):
-            scan = _stream_scan_of(node)
-            if scan is not None:
-                cand = sg.build_stream_segment(node, scan, nparents,
-                                               fuse_join=cfg.fuse_join)
-                if cand is not None and cand.input is scan \
-                        and sg.worthwhile(cand, streaming=True):
-                    for nd in cand.nodes():
-                        consumed.add(id(nd))
-                    out.append({"kind": "stream-agg", "segment": cand,
-                                "node": node, "path": paths[id(node)]})
-                continue  # streamed-interpreted: no fused artifact
-        if isinstance(node, (Aggregate, Filter, Project)):
-            seg = sg.build_segment(node, nparents)
-            if seg is not None and sg.worthwhile(seg):
-                for nd in seg.nodes():
-                    consumed.add(id(nd))
-                out.append({"kind": "agg" if seg.agg is not None else "map",
-                            "segment": seg, "node": node,
-                            "path": paths[id(node)]})
-    return out
-
-
-def _statically_eligible(seg, resolver: SchemaResolver) -> bool:
-    """Static shadow of runtime_eligible: a string/nested computed-on
-    column makes the executor fall back to the interpreter (segment never
-    runs, no tracked sync).  Unknown dtypes assume eligible."""
-    schema = verify(seg.input, resolver)
-    if schema is None:
-        return True
-    used = set(seg.columns_used())
-    for j in seg.joins():
-        used |= set(j.left_keys)
-    for name in used:
-        dt = schema.get(name)
-        if dt is not None and (dt.is_string or dt.is_nested):
-            return False
-    return True
+        if st.kind == "fused-stage" and cfg.aqe \
+                and getattr(st.stage.exchange, "_aqe_split", False):
+            sites = ("exchange-counts-sizing",) + sites
+        entries += [{"site": site, "path": st.path, "count": 1}
+                    for site in sites]
+    return physical, entries
 
 
 def sync_budget(plan: PlanNode, resolver: Optional[SchemaResolver] = None,
                 cfg=None, ndev: Optional[int] = None) -> list:
-    """Static model of the deliberate host syncs an optimized plan pays on
-    the fused paths — one entry per sync, ``site`` naming the whitelisted
-    call site in engine/segment.py.  Mirrors the runtime
-    ``engine.host_sync`` counter: a map segment pays one boundary
-    compaction, an agg segment one groupby compaction, a streamed agg
-    segment a combine-sizing fetch plus the compaction — however many
-    chunks stream through.
+    """Static model of the deliberate host syncs an optimized plan pays —
+    one entry per sync, ``site`` naming the whitelisted call site, ``path``
+    the stage that pays it.  Charges each stage of ``physical.lower`` what
+    ``physical.SYNC_CHARGES`` says its kind pays, however many chunks
+    stream through; equals the runtime ``engine.host_sync`` counter.
 
-    ``ndev`` is the mesh size the exchange entries assume (default: the
-    runtime ``len(jax.devices())`` at call time — pass it explicitly to
-    model a target mesh from a different host).  The budget is EXACT, not
-    an upper bound: ``_hash_exchange`` no longer early-outs on an empty
-    input (the PR 8 review discrepancy, closed — a 0-row exchange runs
-    the same two-sync shuffle over its empty planes), and the fused stage
-    pays its one boundary compaction even for empty inputs via
-    ``segment.fused_pad``'s dead-row synthesis.  A ``fused-stage`` entry
-    charges exactly one ``groupby-compaction`` for the whole sandwich
-    (partial + exchange + combine), plus one ``exchange-counts-sizing``
-    when AQE is on and the exchange carries the ``_aqe_split`` stamp (the
-    escape-hatch probe ALWAYS pays its counts fetch before picking the
-    fused or host program).  The overflow/AQE-routed host fallbacks are
-    runtime re-plans outside this static model.  One upper-bound case
-    remains: an agg SEGMENT whose input turns out empty at runtime falls
-    back to the interpreted groupby and pays no sync where this model
-    charges one — the fused stage closes exactly that gap for the
-    distributed sandwich via its dead-row synthesis.
-    """
-    from ..utils.config import config as _config
-    resolver = resolver or SchemaResolver()
-    entries: list = []
-    fused_exchanges: set = set()
-    for s in plan_segments(plan, cfg, ndev=ndev, resolver=resolver):
-        if s["kind"] == "fused-stage":
-            stage, path = s["stage"], s["path"]
-            fused_exchanges.add(id(stage.exchange))
-            aqe = getattr(cfg or _config, "aqe", False)
-            if aqe and getattr(stage.exchange, "_aqe_split", False):
-                entries.append({"site": "exchange-counts-sizing",
-                                "path": path, "count": 1})
-            entries.append({"site": "groupby-compaction", "path": path,
-                            "count": 1})
-            continue
-        seg, path = s["segment"], s["path"]
-        if not _statically_eligible(seg, resolver):
-            entries.append({"site": "interpreted-fallback", "path": path,
-                            "count": 0})
-            continue
-        if s["kind"] == "map":
-            entries.append({"site": "segment-boundary-compaction",
-                            "path": path, "count": 1})
-        elif s["kind"] == "agg":
-            entries.append({"site": "groupby-compaction", "path": path,
-                            "count": 1})
-        else:  # stream-agg
-            entries.append({"site": "combine-sizing", "path": path,
-                            "count": 1})
-            entries.append({"site": "groupby-compaction", "path": path,
-                            "count": 1})
-    # hash exchanges pay one counts-sizing fetch (phase 1 of the two-phase
-    # shuffle) and one ok-mask compaction fetch each; broadcast replication
-    # is a pure device_put and pays none.  On a 1-device mesh _exec_exchange
-    # degenerates to the identity and skips both.  An exchange lowered into
-    # a fused stage is charged by its fused-stage entry above, never here.
-    if ndev is None:
-        import jax
-        ndev = len(jax.devices())
-    if ndev > 1:
-        paths = node_paths(plan)
-        for n in topo_nodes(plan):
-            if isinstance(n, Exchange) and n.kind == "hash" \
-                    and id(n) not in fused_exchanges:
-                entries.append({"site": "exchange-counts-sizing",
-                                "path": paths[id(n)], "count": 1})
-                entries.append({"site": "exchange-compaction",
-                                "path": paths[id(n)], "count": 1})
-    return entries
+    ``ndev`` is the mesh size the stages are lowered for (default: this
+    process's — pass it to model a target mesh from a different host).
+    The budget is EXACT, not an upper bound: a 0-row hash exchange runs the
+    same two-sync shuffle over its empty planes, and the fused stage pays
+    its one boundary compaction even for empty inputs
+    (``segment.fused_pad``'s dead-row synthesis).  A ``fused-stage`` is
+    charged one ``exchange-counts-sizing`` more when AQE is on and its
+    exchange carries ``_aqe_split`` (the escape-hatch probe ALWAYS pays its
+    counts fetch before picking the fused or host program).  A stage whose
+    schema (read through ``resolver``) the executor's veto will demote is
+    one ``interpreted-fallback`` entry of count 0; a demoted sandwich is
+    followed by its exchange's and its partial's own charges.  The
+    overflow/AQE-routed host fallbacks are runtime re-plans outside this
+    model.  One upper-bound case remains: an agg SEGMENT whose input turns
+    out empty at runtime falls back to the interpreted groupby and pays no
+    sync where this model charges one."""
+    return _lowered(plan, resolver or SchemaResolver(), cfg, ndev)[1]
 
 
 def check_sync_budget(plans, cfg=None, ndev: Optional[int] = None) -> tuple:
@@ -1072,21 +941,15 @@ def device_resident(tree) -> bool:
     return all(isinstance(leaf, jax.Array) for leaf in leaves)
 
 
-def lint_segment(seg, input_table, builds: tuple = ()) -> dict:
-    """Lower one segment's program to a jaxpr WITHOUT executing it and
-    lint the artifact: trace must succeed (a ``.item()``/``float()`` on a
-    tracer fails here, statically), no forbidden host-callback primitives,
+def _lint_traced(report: dict, fn, *args, require: tuple = ()) -> dict:
+    """Lower ``fn(*args)`` to a jaxpr WITHOUT executing it and lint the
+    artifact into ``report``: the trace must succeed (a ``.item()`` /
+    ``float()`` on a tracer fails here, statically), no forbidden
+    host-callback primitives, every ``require``d primitive present,
     static output shapes."""
     import jax
-    import jax.numpy as jnp
-
-    from . import segment as sg
-    report = {"fingerprint": seg.fingerprint()[:12], "ok": True,
-              "violations": [], "primitives": 0}
-    fn = sg._build_fn(seg, _TraceProbe())
     try:
-        closed = jax.make_jaxpr(fn)(
-            input_table, jnp.int32(input_table.num_rows), tuple(builds))
+        closed = jax.make_jaxpr(fn)(*args)
     except Exception as e:  # noqa: BLE001 — any trace failure is the finding
         kind = type(e).__name__
         host = any(t in kind for t in
@@ -1099,18 +962,31 @@ def lint_segment(seg, input_table, builds: tuple = ()) -> dict:
         return report
     prims = _collect_primitives(closed.jaxpr)
     report["primitives"] = len(prims)
-    for pname in sorted(set(prims) & _FORBIDDEN_PRIMITIVES):
-        report["ok"] = False
-        report["violations"].append({"code": "forbidden-primitive",
-                                     "detail": pname})
+    bad = [{"code": "forbidden-primitive", "detail": pname}
+           for pname in sorted(set(prims) & _FORBIDDEN_PRIMITIVES)]
+    bad += [{"code": "missing-collective",
+             "detail": f"lowered without {pname} — the exchange traced away"}
+            for pname in require if pname not in prims]
     for var in closed.jaxpr.outvars:
         shape = getattr(getattr(var, "aval", None), "shape", ())
         if not all(isinstance(d, int) for d in shape):
-            report["ok"] = False
-            report["violations"].append({
-                "code": "dynamic-shape",
-                "detail": f"output aval shape {shape} is not static"})
+            bad.append({"code": "dynamic-shape",
+                        "detail": f"output aval shape {shape} is not static"})
+    report["violations"] += bad
+    report["ok"] = not bad
     return report
+
+
+def lint_segment(seg, input_table, builds: tuple = ()) -> dict:
+    """Jaxpr-lint one segment's program over ``input_table``."""
+    import jax.numpy as jnp
+
+    from . import segment as sg
+    report = {"fingerprint": seg.fingerprint()[:12], "ok": True,
+              "violations": [], "primitives": 0}
+    return _lint_traced(report, sg._build_fn(seg, _TraceProbe()),
+                        input_table, jnp.int32(input_table.num_rows),
+                        tuple(builds))
 
 
 def lint_decode_segment(seg, geom, builds: tuple = ()) -> dict:
@@ -1121,50 +997,21 @@ def lint_decode_segment(seg, geom, builds: tuple = ()) -> dict:
     must carry exactly the segment's own syncs — any forbidden callback
     or dynamic shape here means the decode path smuggled in a host
     boundary the plain segment doesn't have."""
-    import jax
     import jax.numpy as jnp
 
     from ..ops.parquet_decode import zero_planes
     from . import segment as sg
     report = {"fingerprint": seg.fingerprint()[:12], "ok": True,
               "violations": [], "primitives": 0, "decode": True}
-    fn = sg._build_decode_fn(seg, _TraceProbe(), geom)
-    try:
-        closed = jax.make_jaxpr(fn)(
-            zero_planes(geom), jnp.int32(1), tuple(builds))
-    except Exception as e:  # noqa: BLE001 — any trace failure is the finding
-        kind = type(e).__name__
-        host = any(t in kind for t in
-                   ("Concretization", "TracerArrayConversion",
-                    "TracerBoolConversion", "TracerIntegerConversion"))
-        report["ok"] = False
-        report["violations"].append({
-            "code": "host-concretization" if host else "trace-failure",
-            "detail": f"{kind}: {e}"[:400]})
-        return report
-    prims = _collect_primitives(closed.jaxpr)
-    report["primitives"] = len(prims)
-    for pname in sorted(set(prims) & _FORBIDDEN_PRIMITIVES):
-        report["ok"] = False
-        report["violations"].append({"code": "forbidden-primitive",
-                                     "detail": pname})
-    for var in closed.jaxpr.outvars:
-        shape = getattr(getattr(var, "aval", None), "shape", ())
-        if not all(isinstance(d, int) for d in shape):
-            report["ok"] = False
-            report["violations"].append({
-                "code": "dynamic-shape",
-                "detail": f"output aval shape {shape} is not static"})
-    return report
+    return _lint_traced(report, sg._build_decode_fn(seg, _TraceProbe(), geom),
+                        zero_planes(geom), jnp.int32(1), tuple(builds))
 
 
 def lint_fused_stage(stage, input_table, mesh=None, axis=None) -> dict:
-    """Lower a fused stage's whole ``jit(shard_map(...))`` program to a
-    jaxpr WITHOUT executing it and lint the artifact: trace must succeed,
-    no forbidden host-callback primitives anywhere (including inside the
-    collectives), static output shapes, and the ``all_to_all`` collective
-    must actually be present — a fused stage whose exchange traced away
-    would silently compute shard-local answers."""
+    """Jaxpr-lint a fused stage's whole ``jit(shard_map(...))`` program,
+    collectives included; the ``all_to_all`` must actually be present — a
+    fused stage whose exchange traced away would silently compute
+    shard-local answers."""
     import jax
     import jax.numpy as jnp
 
@@ -1189,41 +1036,10 @@ def lint_fused_stage(stage, input_table, mesh=None, axis=None) -> dict:
     compiled = sg.CompiledFusedStage(
         ("lint",), stage, mesh, axis, in_dtypes, key_dtypes,
         padded.num_rows // ndev)
-    datas = tuple(c.data for c in padded.columns)
-    masks = tuple(c.validity for c in padded.columns)
-    try:
-        closed = jax.make_jaxpr(compiled.jfn)(
-            datas, masks, jnp.int64(padded.num_rows))
-    except Exception as e:  # noqa: BLE001 — any trace failure is the finding
-        kind = type(e).__name__
-        host = any(t in kind for t in
-                   ("Concretization", "TracerArrayConversion",
-                    "TracerBoolConversion", "TracerIntegerConversion"))
-        report["ok"] = False
-        report["violations"].append({
-            "code": "host-concretization" if host else "trace-failure",
-            "detail": f"{kind}: {e}"[:400]})
-        return report
-    prims = _collect_primitives(closed.jaxpr)
-    report["primitives"] = len(prims)
-    for pname in sorted(set(prims) & _FORBIDDEN_PRIMITIVES):
-        report["ok"] = False
-        report["violations"].append({"code": "forbidden-primitive",
-                                     "detail": pname})
-    if "all_to_all" not in prims:
-        report["ok"] = False
-        report["violations"].append({
-            "code": "missing-collective",
-            "detail": "fused stage lowered without an all_to_all — the "
-                      "exchange traced away"})
-    for var in closed.jaxpr.outvars:
-        shape = getattr(getattr(var, "aval", None), "shape", ())
-        if not all(isinstance(d, int) for d in shape):
-            report["ok"] = False
-            report["violations"].append({
-                "code": "dynamic-shape",
-                "detail": f"output aval shape {shape} is not static"})
-    return report
+    return _lint_traced(
+        report, compiled.jfn, tuple(c.data for c in padded.columns),
+        tuple(c.validity for c in padded.columns),
+        jnp.int64(padded.num_rows), require=("all_to_all",))
 
 
 def lint_plan_artifacts(plan: PlanNode,
@@ -1238,26 +1054,30 @@ def lint_plan_artifacts(plan: PlanNode,
     resolver = resolver or SchemaResolver()
     reports: list = []
     violations: list = []
-    for s in plan_segments(plan, cfg, resolver=resolver):
-        if s["kind"] == "fused-stage":
-            stage = s["stage"]
+    physical, syncs = _lowered(plan, resolver, cfg, None)
+    for st in physical.run_stages():
+        if st.stage is not None:  # fused-stage
+            stage = st.stage
             schema = verify(stage.partial.child, resolver)
             tbl = _zero_table(schema, rows)
-            if tbl is None:
-                reports.append({"path": s["path"], "kind": s["kind"],
-                                "skipped": "input schema unknown"})
+            if tbl is None or st.vetoed:
+                reports.append({"path": st.path, "kind": st.kind,
+                                "skipped": "input schema unknown or stage "
+                                           "demoted at runtime"})
                 continue
             rep = lint_fused_stage(stage, tbl)
-            rep["path"], rep["kind"] = s["path"], s["kind"]
+            rep["path"], rep["kind"] = st.path, st.kind
             reports.append(rep)
-            violations += [{**v, "path": s["path"]}
+            violations += [{**v, "path": st.path}
                            for v in rep.get("violations", ())]
             continue
-        seg = s["segment"]
+        seg = st.segment
+        if seg is None:
+            continue  # no compiled artifact of the stage's own
         schema = verify(seg.input, resolver)
         tbl = _zero_table(schema, rows)
-        if tbl is None or not _statically_eligible(seg, resolver):
-            reports.append({"path": s["path"], "kind": s["kind"],
+        if tbl is None or st.vetoed:
+            reports.append({"path": st.path, "kind": st.kind,
                             "skipped": "input schema unknown or segment "
                                        "interpreted at runtime"})
             continue
@@ -1267,7 +1087,7 @@ def lint_plan_artifacts(plan: PlanNode,
             bts = [_zero_table(verify(j.right, resolver), rows)
                    for j in joins]
             if any(b is None for b in bts):
-                reports.append({"path": s["path"], "kind": s["kind"],
+                reports.append({"path": st.path, "kind": st.kind,
                                 "skipped": "build-side schema unknown"})
                 continue
             from ..ops.join import prepare_build
@@ -1276,15 +1096,14 @@ def lint_plan_artifacts(plan: PlanNode,
             for j, pb in zip(joins, builds):
                 if not device_resident(pb):
                     violations.append({
-                        "code": "host-resident-build", "path": s["path"],
+                        "code": "host-resident-build", "path": st.path,
                         "detail": f"prepared build for join keys "
                                   f"{list(j.right_keys)} has non-device "
                                   f"pytree leaves"})
         rep = lint_segment(seg, tbl, builds)
-        rep["path"], rep["kind"] = s["path"], s["kind"]
+        rep["path"], rep["kind"] = st.path, st.kind
         reports.append(rep)
-        violations += [{**v, "path": s["path"]} for v in rep["violations"]]
-    syncs = sync_budget(plan, resolver, cfg)
+        violations += [{**v, "path": st.path} for v in rep["violations"]]
     violations += [{"code": "unwhitelisted-host-sync", "path": e["path"],
                     "detail": e["site"]}
                    for e in syncs

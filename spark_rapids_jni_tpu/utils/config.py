@@ -42,7 +42,6 @@ environment flags read once at import:
 | ``SRJT_BLACKBOX_CAP`` | ``512`` | flight-recorder ring capacity (events; oldest dropped) |
 | ``SRJT_SLO_MS``       | *(unset)* | latency objectives: ``default_ms[,fp12=ms,...]`` per source fingerprint, evaluated from the profile store (utils/blackbox.py slo_report) |
 | ``SRJT_TRACE_ID``     | *(unset)* | inherited trace context for helper processes (bench dist subprocess); minted per client/query when empty |
-| ``SRJT_ROOFLINE_GBPS`` | ``0`` | device-bandwidth ceiling override for explain-analyze roofline fractions (0 = the published peak of the device_kind, none for an unknown kind) |
 | ``SRJT_SCHED``        | ``1``   | multi-tenant scheduler (engine/scheduler.py): SLO-aware admission + fair-share chunk interleaving on the bridge PLAN_EXECUTE path |
 | ``SRJT_MAX_SESSIONS`` | ``8``   | concurrent admitted PLAN_EXECUTE sessions; arrivals past this queue at admission |
 | ``SRJT_ADMISSION_QUEUE_S`` | ``5.0`` | max seconds a query waits in the admission queue before it is shed (AdmissionRejectedError) |
@@ -129,7 +128,6 @@ class Config:
     blackbox_cap: int = 512      # flight-recorder ring capacity (events)
     slo_ms: str = ""             # latency objectives spec (default[,fp=ms])
     trace_id: str = ""           # inherited trace context (subprocesses)
-    roofline_gbps: float = 0.0   # explain-analyze ceiling override (0=published)
     sched: bool = True           # multi-tenant scheduler (engine/scheduler)
     max_sessions: int = 8        # concurrent admitted PLAN_EXECUTE sessions
     admission_queue_s: float = 5.0  # admission-queue wait bound (seconds)
@@ -178,7 +176,6 @@ class Config:
             blackbox_cap=_int_flag("SRJT_BLACKBOX_CAP", 512, minimum=16),
             slo_ms=os.environ.get("SRJT_SLO_MS", "").strip(),
             trace_id=os.environ.get("SRJT_TRACE_ID", "").strip(),
-            roofline_gbps=_float_flag("SRJT_ROOFLINE_GBPS", 0.0),
             sched=_bool_flag("SRJT_SCHED", True),
             max_sessions=_int_flag("SRJT_MAX_SESSIONS", 8, minimum=1),
             admission_queue_s=_float_flag("SRJT_ADMISSION_QUEUE_S", 5.0),

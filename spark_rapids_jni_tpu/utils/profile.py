@@ -6,7 +6,7 @@ plan-choice feedback, and multi-tenant accounting all compare *runs*, not
 live counters.  This module is that persistence layer — ``metrics.query()``
 calls ``write(summary)`` on exit when ``SRJT_PROFILE_DIR`` is set, storing
 a compact derivative of the query summary (plan fingerprint, per-node
-wall/rows/bytes/GB/s/roofline_frac, exchange skew + straggler share, cache
+wall/rows/bytes/GB/s, exchange skew + straggler share, cache
 and host-sync counters, histogram percentiles) into a bounded on-disk ring.
 
 Layout: ``<dir>/profile-<epoch_ns>-<fp12>.json`` — zero-padded nanosecond
@@ -33,7 +33,6 @@ from __future__ import annotations
 import json
 import os
 import time
-from typing import Optional
 
 from .config import config
 
@@ -68,20 +67,11 @@ def _keep_counter(name: str) -> bool:
             or name.startswith(_COUNTER_KEEP))
 
 
-def _ceiling() -> Optional[float]:
-    try:
-        from ..engine.explain import roofline_ceiling_gbps
-        return roofline_ceiling_gbps()
-    except Exception:
-        return None
-
-
 def compact(summary: dict) -> dict:
     """Derive the compact profile document from a ``QueryMetrics.summary()``.
 
-    Pure function of the summary (plus the pinned roofline ceiling) — the
-    round-trip tests rely on every gated key surviving write -> read."""
-    ceiling = _ceiling()
+    Pure function of the summary — the round-trip tests rely on every
+    gated key surviving write -> read."""
     nodes = []
     exchanges = []
     for r in summary.get("nodes", ()):
@@ -99,9 +89,7 @@ def compact(summary: dict) -> dict:
                 "est_rows": r.get("est_rows"),
                 "q_error": r.get("q_error"),
                 "bytes_moved": moved,
-                "GBps": round(gbps, 3) if gbps is not None else None,
-                "roofline_frac": (round(gbps / ceiling, 6)
-                                  if gbps is not None and ceiling else None)}
+                "GBps": round(gbps, 3) if gbps is not None else None}
         nodes.append(node)
         if r.get("wire_bytes") or r.get("skew") is not None:
             exchanges.append({

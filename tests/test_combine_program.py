@@ -25,7 +25,7 @@ from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.engine import (Aggregate, Filter, Scan, col,
                                          execute, lit, new_stats, optimize)
 from spark_rapids_jni_tpu.engine import segment as sg
-from spark_rapids_jni_tpu.engine.executor import _STREAM_COMBINE
+from spark_rapids_jni_tpu.engine.plan import STREAM_COMBINE
 from spark_rapids_jni_tpu.ops import aggregate as agg_ops
 from spark_rapids_jni_tpu.utils import config as cfg
 from spark_rapids_jni_tpu.utils import metrics, tracing
@@ -71,7 +71,7 @@ def eager_combine(partials, compiled):
     knames = [f"k{i}" for i in range(nk)]
     anames = [f"a{j}" for j in range(len(agg.aggs))]
     merged = Table(key_cols + agg_cols, knames + anames)
-    combine = [(anames[j], _STREAM_COMBINE[op])
+    combine = [(anames[j], STREAM_COMBINE[op])
                for j, (_, op) in enumerate(agg.aggs)]
     out_keys, out_aggs, ngroups = agg_ops.groupby_padded(
         merged, knames, combine, row_mask=live)
@@ -164,7 +164,7 @@ def test_each_combine_op(agg, null_vals):
     ``count_all``, ``min``, ``max`` — over value columns with and without
     nulls: ``count``/``count_all`` partials carry no validity, the others
     do, and a group whose values are all null stays null after the merge."""
-    assert agg[1] in _STREAM_COMBINE
+    assert agg[1] in STREAM_COMBINE
     seg = segment_for(["k"], [agg])
     partials, compiled = make_partials(seg, 5, seed=3, null_vals=null_vals,
                                        ngroups=40)

@@ -422,16 +422,14 @@ class TestEngineE2E:
             partitioning(opt) is NO_PARTITIONING
 
     def test_decode_segment_lints_clean(self, tmp_path):
-        from spark_rapids_jni_tpu.engine import optimize
-        from spark_rapids_jni_tpu.engine import segment as sg
-        from spark_rapids_jni_tpu.engine.plan import (Scan as PScan,
-                                                      topo_nodes)
+        from spark_rapids_jni_tpu.engine import lower, optimize
         from spark_rapids_jni_tpu.engine.verify import lint_decode_segment
         path = self._warehouse(tmp_path)
         opt = optimize(self._plan(path), distribute=False)
-        sn = next(n for n in topo_nodes(opt) if isinstance(n, PScan))
-        seg = sg.build_stream_segment(opt, sn, sg.parent_counts(opt))
-        assert seg is not None
+        st = lower(opt, fuse=True, fuse_join=True, topk=True,
+                   fuse_exchange=False, ndev=1).stage_at(opt)
+        assert st.kind == "stream-agg"
+        seg = st.segment
         chunk, reason = pqio.plan_device_group(
             pqio.ParquetFile(path), 0, None, 1 << 30)
         assert chunk is not None, reason

@@ -310,7 +310,7 @@ def test_artifact_lint_clean_on_smoke_plans(warehouse):
 def test_artifact_lint_catches_injected_item(warehouse, monkeypatch):
     # the acceptance scenario: a synthetic .item()/float() smuggled into
     # the traced filter evaluator fails the STATIC lint, no execution
-    orig = executor._eval_expr
+    orig = executor.eval_expr
 
     def bad_eval(expr, table):
         vals, valid = orig(expr, table)
@@ -318,7 +318,7 @@ def test_artifact_lint_catches_injected_item(warehouse, monkeypatch):
             float(vals.sum())  # concretizes the tracer
         return vals, valid
 
-    monkeypatch.setattr(executor, "_eval_expr", bad_eval)
+    monkeypatch.setattr(executor, "eval_expr", bad_eval)
     rep = lint_plan_artifacts(optimize(warehouse["q5"]))
     codes = {v["code"] for v in rep["violations"]}
     assert "host-concretization" in codes
@@ -370,14 +370,14 @@ def test_ast_rules_fire_on_synthetic_sources():
         return [v["code"] for v in fl.out]
 
     traced = "spark_rapids_jni_tpu/engine/executor.py"
-    assert run("def _eval_expr(e, t):\n    return float(x.sum())\n",
+    assert run("def eval_expr(e, t):\n    return float(x.sum())\n",
                traced) == ["traced-host-op"]
-    assert run("def _eval_expr(e, t):\n    return x.item()\n",
+    assert run("def eval_expr(e, t):\n    return x.item()\n",
                traced) == ["traced-host-op"]
-    assert run("def _eval_expr(e, t):\n    return np.asarray(x)\n",
+    assert run("def eval_expr(e, t):\n    return np.asarray(x)\n",
                traced) == ["traced-host-op"]
     # literal casts and code outside traced functions are fine
-    assert run("def _eval_expr(e, t):\n    return float('nan')\n",
+    assert run("def eval_expr(e, t):\n    return float('nan')\n",
                traced) == []
     assert run("def helper(x):\n    return x.item()\n", traced) == []
     # host-sync sites need whitelisted literal labels

@@ -27,7 +27,8 @@ import pytest
 from spark_rapids_jni_tpu.engine import (Aggregate, Filter, Join, Scan,
                                          col, execute, lit, optimize)
 from spark_rapids_jni_tpu.engine.explain import explain_analyze
-from spark_rapids_jni_tpu.engine.verify import decision_census, node_paths
+from spark_rapids_jni_tpu.engine.plan import node_paths
+from spark_rapids_jni_tpu.engine.verify import decision_census
 from spark_rapids_jni_tpu.utils import config as cfg
 from spark_rapids_jni_tpu.utils import faults, metrics, profile, tracing
 

@@ -33,9 +33,9 @@ from spark_rapids_jni_tpu.engine import (
 from spark_rapids_jni_tpu.engine import segment as sg
 from spark_rapids_jni_tpu.engine.adaptive import runtime_entries
 from spark_rapids_jni_tpu.engine.fuzz import _flags
+from spark_rapids_jni_tpu.engine.physical import lower
 from spark_rapids_jni_tpu.engine.verify import (
-    SYNC_WHITELIST, lint_fused_stage, plan_exchanges, plan_segments,
-    sync_budget,
+    SYNC_WHITELIST, lint_fused_stage, plan_exchanges, sync_budget,
 )
 from spark_rapids_jni_tpu.utils import metrics, tracing
 from spark_rapids_jni_tpu.utils.config import config
@@ -144,17 +144,21 @@ def test_empty_input_budget_still_exact(warehouse):
                 assert paid >= 2  # both exchange syncs actually paid
 
 
-def test_plan_segments_reports_fused_stage(warehouse):
+def test_lowering_reports_fused_stage(warehouse):
     with _flags(fuse_exchange=True):
         opt = optimize(_sandwich(warehouse), distribute=True)
-        segs = plan_segments(opt, ndev=NDEV)
-        kinds = [s["kind"] for s in segs]
-        assert "fused-stage" in kinds
-        st = next(s["stage"] for s in segs if s["kind"] == "fused-stage")
-        assert isinstance(st, sg.FusedStage)
-        # on one device the fusion is moot and the entry disappears
-        assert "fused-stage" not in [s["kind"]
-                                     for s in plan_segments(opt, ndev=1)]
+        flags = dict(fuse=True, fuse_join=True, topk=True,
+                     fuse_exchange=True)
+        stages = lower(opt, ndev=NDEV, **flags).stages
+        assert "fused-stage" in [s.kind for s in stages]
+        st = next(s for s in stages if s.kind == "fused-stage")
+        assert isinstance(st.stage, sg.FusedStage)
+        assert st.nodes == (opt, st.stage.exchange, st.stage.partial)
+        # on one device the fusion is moot and the stage disappears: the
+        # sandwich's exchange is the identity there
+        kinds = [s.kind for s in lower(opt, ndev=1, **flags).stages]
+        assert "fused-stage" not in kinds
+        assert "exchange-identity" in kinds
 
 
 def test_compiled_once_then_replayed(warehouse):

@@ -336,7 +336,7 @@ def test_logger_null_handler_and_live_level(monkeypatch):
 
 # ---------------------------------------------------------------------------
 # PR 6: timeline-era observability — histogram export completeness, device
-# telemetry, roofline attribution, JSON logging, profile() hardening, and
+# telemetry, throughput attribution, JSON logging, profile() hardening, and
 # the bench regression gate
 
 
@@ -355,52 +355,21 @@ def test_histogram_snapshot_exports_sum_count_mean(metrics_isolation):
     assert h["buckets"]  # the [le, count] pairs are still there
 
 
-def test_explain_analyze_roofline_columns(metrics_warehouse, monkeypatch):
-    """Per-node cost attribution: bytes_moved / GB/s / roofline_frac in
-    both the structured nodes and the rendered tree, against the env-pinned
-    ceiling (SRJT_ROOFLINE_GBPS wins over the published peak)."""
+def test_explain_analyze_throughput_columns(metrics_warehouse):
+    """Per-node cost attribution: bytes_moved / GB/s in both the
+    structured nodes and the rendered tree."""
     from spark_rapids_jni_tpu.engine import explain_analyze
-    monkeypatch.setenv("SRJT_ROOFLINE_GBPS", "100.0")
-    cfg.refresh()
-    try:
-        rep = explain_analyze(_agg_plan(metrics_warehouse), fused=True)
-    finally:
-        monkeypatch.delenv("SRJT_ROOFLINE_GBPS")
-        cfg.refresh()
+    rep = explain_analyze(_agg_plan(metrics_warehouse), fused=True)
     root = rep.nodes[-1]["metrics"]
     assert root["bytes_moved"] > 0
     assert root["GBps"] is not None and root["GBps"] > 0
-    # GBps is rounded to 3 decimals but roofline_frac is computed from the
-    # unrounded rate, so compare within the rounding quantum (5e-4 / 100)
-    assert root["roofline_frac"] == pytest.approx(root["GBps"] / 100.0,
-                                                  abs=6e-6)
     assert "bytes_moved=" in rep.text
     assert "GB/s=" in rep.text
-    assert "roofline_frac=" in rep.text
-    assert "roofline_ceiling_GBps=100.0" in rep.text
     # conservation: the scan's bytes_out feed downstream bytes_in, so the
     # plan's total moved bytes must exceed the raw decoded column bytes
     total = sum(n["metrics"]["bytes_moved"] for n in rep.nodes
                 if n["metrics"] is not None)
     assert total >= root["bytes_moved"]
-
-
-def test_roofline_only_for_a_published_device_kind(metrics_warehouse):
-    """Without the env override the ceiling is the published peak of the
-    device_kind that runs the plan; a kind without one (the CPU here) gets
-    no ceiling and EXPLAIN prints no roofline_frac."""
-    from spark_rapids_jni_tpu.engine import explain as ex
-    from spark_rapids_jni_tpu.engine import explain_analyze
-    assert "SRJT_ROOFLINE_GBPS" not in os.environ
-    assert cfg.config.roofline_gbps == 0.0
-    assert ex.PUBLISHED_HBM_GBPS["TPU v5 lite"] == 819.0
-    assert ex.roofline_ceiling_gbps() is None
-    rep = explain_analyze(_agg_plan(metrics_warehouse), fused=True)
-    assert "GB/s=" in rep.text
-    assert "roofline_frac" not in rep.text
-    assert "roofline_ceiling_GBps" not in rep.text
-    assert all(n["metrics"]["roofline_frac"] is None for n in rep.nodes
-               if n["metrics"] is not None)
 
 
 def test_memory_telemetry_in_summary_and_gauges(metrics_warehouse,

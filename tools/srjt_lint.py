@@ -1,13 +1,13 @@
 #!/usr/bin/env python
 """Repo lint for the engine's static invariants (docs/ANALYSIS.md pass 3).
 
-Six stdlib-``ast`` rules over ``spark_rapids_jni_tpu/`` + ``tools/``:
+Seven stdlib-``ast`` rules over ``spark_rapids_jni_tpu/`` + ``tools/``:
 
 - **traced-host-op** — no ``.item()`` / ``float()`` / ``bool()`` / ``int()``
   / ``np.asarray`` / ``.tolist()`` / ``jax.device_get`` /
   ``.block_until_ready()`` inside the segment-traced code paths
   (``segment._build_fn`` / ``segment._probe_join_node`` /
-  ``executor._eval_expr``): any of these concretizes a tracer, turning the
+  ``executor.eval_expr``): any of these concretizes a tracer, turning the
   zero-sync fused chunk program into a per-chunk host round-trip.
 - **config-env-read** — ``os.environ`` / ``os.getenv`` only in
   ``utils/config.py``; everything else reads the ``config`` singleton so
@@ -33,6 +33,10 @@ Six stdlib-``ast`` rules over ``spark_rapids_jni_tpu/`` + ``tools/``:
   (engine/recovery.py) dispatches on the ``utils/errors`` taxonomy, and a
   bare catch swallows cancellation and resource exhaustion
   indistinguishably.
+- **executor-import** — no module imports an underscore name of
+  ``engine/executor.py``, and ``engine/physical.py`` / ``engine/verify.py``
+  import nothing from it: the choice of stage form is ``physical.lower``'s
+  alone, and the verifier budgets what it returns.
 - **unregistered-metric** — every literal metric name recorded through
   ``metrics.count/observe/gauge_set/gauge_max/time_add`` /
   ``tracing.count``, every ``op_scope(<name>, timed=True)`` (it observes
@@ -80,8 +84,11 @@ PKG = "spark_rapids_jni_tpu"
 TRACED_FUNCS = {
     f"{PKG}/engine/segment.py": {"_build_fn", "_probe_join_node",
                                  "_build_fused_fn", "_build_decode_fn"},
-    f"{PKG}/engine/executor.py": {"_eval_expr"},
+    f"{PKG}/engine/executor.py": {"eval_expr"},
 }
+
+#: modules the executor is built on: they import nothing from it
+_BELOW_EXECUTOR = (f"{PKG}/engine/physical.py", f"{PKG}/engine/verify.py")
 
 #: attribute calls that concretize a tracer / pull data to host
 #: subtrees where a bare `except:` is a lint violation — the failure-domain
@@ -394,6 +401,19 @@ class _FileLint(ast.NodeVisitor):
                 "see utils/errors taxonomy)"))
         self.generic_visit(node)
 
+    def visit_ImportFrom(self, node: ast.ImportFrom) -> None:
+        # the stage-form choice lives in engine/physical.py: a layer below
+        # the executor that reaches up for it (or for any private name of
+        # it) is a second copy of the choice waiting to drift
+        if (node.module or "").split(".")[-1] == "executor":
+            private = [a.name for a in node.names if a.name.startswith("_")]
+            below = self.relpath in _BELOW_EXECUTOR
+            if private or below:
+                self.out.append(_violation(
+                    "executor-import", self.relpath, node.lineno,
+                    f"imports {private or 'from'} engine/executor.py"
+                    + (" (a layer below it)" if below else "")))
+
 
 def _metric_catalog(sites: list) -> dict:
     """Aggregate (name, kind, file, line) sites into
@@ -663,15 +683,13 @@ def segments_pass(full: bool = False) -> list:
         # warehouse fact file and lint the fused scan+decode program
         # (verify.lint_decode_segment) — the decode prefix must splice
         # into the scan segment with ZERO added host syncs or callbacks
-        from spark_rapids_jni_tpu.engine import segment as _sg
-        from spark_rapids_jni_tpu.engine.plan import Scan as _Scan
-        from spark_rapids_jni_tpu.engine.plan import topo_nodes as _topo
+        from spark_rapids_jni_tpu.engine.physical import lower
         from spark_rapids_jni_tpu.engine.verify import lint_decode_segment
         from spark_rapids_jni_tpu.io.parquet import (ParquetFile,
                                                      plan_device_group)
         copt = plans["chunked"]
-        sn = next(n for n in _topo(copt) if isinstance(n, _Scan))
-        seg = _sg.build_stream_segment(copt, sn, _sg.parent_counts(copt))
+        seg = lower(copt, fuse=True, fuse_join=True, topk=True,
+                    fuse_exchange=False, ndev=1).stage_at(copt).segment
         chunk, reason = plan_device_group(
             ParquetFile(os.path.join(tmp, "store_sales.parquet")), 0,
             None, 1 << 30)
