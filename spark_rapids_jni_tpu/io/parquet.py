@@ -77,13 +77,16 @@ except Exception:  # pragma: no cover - pyarrow is baked into this env
     _SNAPPY_NATIVE = None
 
 
-def _decompress(page: bytes, codec: int, uncompressed_size: int) -> bytes:
+def _decompress(page, codec: int, uncompressed_size: int):
+    """One page body out of its codec: ``page`` is any bytes-like (a slice
+    of the file's memoryview), the result bytes-like of unsigned bytes with
+    ``len() == uncompressed_size`` — a view of the codec's own output
+    buffer where the codec hands one out, never a second copy of it."""
     if codec == CODEC_UNCOMPRESSED:
         return page
     if codec == CODEC_SNAPPY:
         if _SNAPPY_NATIVE is not None:
-            out = _SNAPPY_NATIVE.decompress(
-                page, decompressed_size=uncompressed_size).to_pybytes()
+            out = _codec_view(_SNAPPY_NATIVE, page, uncompressed_size)
         else:
             # literal-only pages (high-entropy / dict-encoded data) collapse
             # to slice copies; anything else hits the byte-exact decoder
@@ -99,8 +102,7 @@ def _decompress(page: bytes, codec: int, uncompressed_size: int) -> bytes:
         return out
     if codec == CODEC_ZSTD:
         import pyarrow as _pa
-        out = _pa.Codec("zstd").decompress(
-            page, decompressed_size=uncompressed_size).to_pybytes()
+        out = _codec_view(_pa.Codec("zstd"), page, uncompressed_size)
         if len(out) != uncompressed_size:
             raise ValueError("zstd page size mismatch")
         return out
@@ -109,46 +111,95 @@ def _decompress(page: bytes, codec: int, uncompressed_size: int) -> bytes:
         "(UNCOMPRESSED, SNAPPY, GZIP and ZSTD are supported)")
 
 
-def _rle_bitpacked_hybrid(buf, bit_width: int, num_values: int) -> np.ndarray:
+def _codec_view(codec, page, uncompressed_size: int) -> memoryview:
+    # the arrow buffer exports signed bytes; the decoder indexes unsigned
+    return memoryview(codec.decompress(
+        page, decompressed_size=uncompressed_size)).cast("B")
+
+
+def _unpack_groups(payload: np.ndarray, bit_width: int) -> np.ndarray:
+    """Bit-packed groups → int32: ``payload`` is uint8[groups * bit_width],
+    every ``bit_width`` bytes holding 8 values, LSB first.
+
+    Value ``j`` of a group starts at bit ``j * bit_width`` of it, the same
+    in every group, so each of the 8 is a few whole-column byte operations
+    over all groups at once: no per-value bit matrix."""
+    if bit_width in (8, 16, 32):
+        return payload.view(f"<u{bit_width // 8}").astype(np.int32)
+    groups = payload.reshape(-1, bit_width)
+    out = np.empty((len(groups), 8), np.uint32)
+    for j in range(8):
+        first, shift = divmod(j * bit_width, 8)
+        wide = np.uint32 if shift + bit_width <= 32 else np.uint64
+        acc = groups[:, first].astype(wide)
+        for k in range(1, (shift + bit_width + 7) // 8):
+            acc |= groups[:, first + k].astype(wide) << wide(8 * k)
+        if shift:
+            acc >>= wide(shift)
+        np.bitwise_and(acc, wide((1 << bit_width) - 1), out=out[:, j],
+                       casting="unsafe")
+    return out.reshape(-1).view(np.int32)
+
+
+def _rle_bitpacked_hybrid(buf, bit_width: int, num_values: int,
+                          tally=None) -> np.ndarray:
     """Decode parquet's RLE/bit-packed hybrid to int32[num_values].
 
-    Bit-packed runs unpack via np.unpackbits (LSB-first groups of 8), RLE
-    runs become np.full — both vectorized; python touches one iteration per
-    *run*, not per value.
+    The stream is decoded as a whole: one walk over the varint run headers
+    (python touches a run's header, never its values), then every bit-packed
+    payload of the stream unpacked together (`_unpack_groups`) and every
+    RLE run expanded by one ``np.repeat``; a stream that is one RLE run —
+    the definition levels of a null-free page — is one ``np.full``.
+    ``tally.runs`` (a `_DecodeTally`) grows by the runs walked.
     """
     if bit_width == 0:
         return np.zeros(num_values, np.int32)
     byte_width = (bit_width + 7) // 8
-    weights = (np.int64(1) << np.arange(bit_width, dtype=np.int64))
-    out = []
+    counts, rle_vals, packed = [], [], []   # per run; payload (start, stop)
     total = 0
     pos = 0
     n = len(buf)
     while total < num_values and pos < n:
-        header, pos = _uvarint(buf, pos)
+        header = buf[pos]
+        if header & 0x80:
+            header, pos = _uvarint(buf, pos)
+        else:
+            pos += 1
         if header & 1:  # bit-packed run: (header>>1) groups of 8 values
-            groups = header >> 1
-            nbytes = groups * bit_width
-            chunk = np.frombuffer(buf, np.uint8, min(nbytes, n - pos), pos)
-            if len(chunk) < nbytes:  # writers may truncate the last group
-                chunk = np.concatenate(
-                    [chunk, np.zeros(nbytes - len(chunk), np.uint8)])
+            nbytes = (header >> 1) * bit_width
+            packed.append((pos, pos + nbytes))
             pos += nbytes
-            bits = np.unpackbits(chunk, bitorder="little")
-            vals = bits.reshape(-1, bit_width).astype(np.int64) @ weights
-            out.append(vals.astype(np.int32))
-            total += groups * 8
+            counts.append((header >> 1) * 8)
+            rle_vals.append(None)
         else:  # RLE run
-            count = header >> 1
-            val = int.from_bytes(buf[pos:pos + byte_width], "little")
+            counts.append(header >> 1)
+            rle_vals.append(
+                int.from_bytes(buf[pos:pos + byte_width], "little"))
             pos += byte_width
-            out.append(np.full(count, val, np.int32))
-            total += count
-    if not out:
+        total += counts[-1]
+    if tally is not None:
+        tally.runs += len(counts)
+    if not counts:
         return np.zeros(num_values, np.int32)
-    res = out[0] if len(out) == 1 else np.concatenate(out)
-    if len(res) < num_values:
+    if total < num_values:
         raise ValueError("truncated RLE/bit-packed run")
+    if not packed:
+        if len(counts) == 1:  # the levels of a null-free page
+            return np.full(num_values, rle_vals[0], np.uint32).view(np.int32)
+        return np.repeat(np.array(rle_vals, np.uint32).view(np.int32),
+                         counts)[:num_values]
+    raw = np.frombuffer(buf, np.uint8)
+    pieces = [raw[a:b] for a, b in packed]
+    if packed[-1][1] > n:  # writers may truncate the last group
+        pieces.append(np.zeros(packed[-1][1] - n, np.uint8))
+    unpacked = _unpack_groups(
+        pieces[0] if len(pieces) == 1 else np.concatenate(pieces), bit_width)
+    if len(packed) == len(counts):
+        return unpacked[:num_values]
+    is_packed = np.array([v is None for v in rle_vals])
+    res = np.repeat(np.array([v or 0 for v in rle_vals],
+                             np.uint32).view(np.int32), counts)
+    res[np.repeat(is_packed, counts)] = unpacked
     return res[:num_values]
 
 
@@ -564,13 +615,16 @@ def _gather_dict(schema: ColumnSchema, dict_vals, idx: np.ndarray):
                - np.repeat(out_starts, sel_lens)
                + np.repeat(offs[idx], sel_lens))
         return chars[pos], lens[idx]
-    return dict_vals[idx]
+    # take() with pointer-sized indices: a fancy index converts int32
+    # indices on every call and costs three times as much per page
+    return dict_vals.take(idx.astype(np.intp))
 
 
 def _scatter_values(s: ColumnSchema, n: int, vals, mask):
     """Scatter the non-null value stream into ``n`` slots (nulls zeroed).
 
-    ``mask`` (bool[n] or None) marks slots that carry a real value.
+    ``mask`` (bool[n] or None) marks slots that carry a real value; None
+    says every slot does, and the value stream IS the dense array.
     Returns the (values, chars, offsets) triple of a _HostColumn.
     """
     if s.physical == PT_BYTE_ARRAY:
@@ -590,24 +644,47 @@ def _scatter_values(s: ColumnSchema, n: int, vals, mask):
                              "use a smaller row-group size")
         return None, chars, offsets.astype(np.int32)
     storage = s.dtype.storage
-    dense = np.zeros(n, storage)
     nn = np.concatenate([np.asarray(v, storage) for v in vals]) if vals \
         else np.zeros(0, storage)
     if mask is None:
-        dense[:] = nn
-    else:
-        dense[mask] = nn
+        if len(nn) != n:
+            raise ValueError(f"{s.name}: {len(nn)} values for {n} slots")
+        return nn, None, None
+    dense = np.zeros(n, storage)
+    dense[mask] = nn
     return dense, None, None
+
+
+@dataclass
+class _DecodeTally:
+    """What one or more column chunks' decode walked: the counters
+    ``io.parquet.decode.*`` and the stats of an ``io.scan.decode`` span."""
+    chunks: int = 0
+    pages: int = 0         # data pages (dictionary pages are not counted)
+    runs: int = 0          # RLE / bit-packed runs of every hybrid stream
+    dense_chunks: int = 0  # null-free chunks that skipped the masked scatter
+
+    def publish(self, into: _DecodeTally | None = None) -> None:
+        """Grow the counters by this tally, and ``into`` with them."""
+        metrics.count("io.parquet.decode.pages", self.pages)
+        metrics.count("io.parquet.decode.runs", self.runs)
+        metrics.count("io.parquet.decode.dense_chunks", self.dense_chunks)
+        if into is not None:
+            into.chunks += self.chunks
+            into.pages += self.pages
+            into.runs += self.runs
+            into.dense_chunks += self.dense_chunks
 
 
 class _ChunkDecoder:
     """Decode one column chunk's page stream into a _HostColumn."""
 
-    def __init__(self, fbuf, meta: ChunkMeta):
+    def __init__(self, fbuf, meta: ChunkMeta, tally: _DecodeTally):
         self.fbuf = fbuf
         self.meta = meta
         self.schema = meta.schema
         self.dict_vals = None
+        self.tally = tally
 
     def run(self) -> _HostColumn:
         meta = self.meta
@@ -615,28 +692,28 @@ class _ChunkDecoder:
         end = meta.start_offset + meta.total_compressed
         remaining = meta.num_values
         reps, defs, vals = [], [], []
+        # pages are sliced out of the file by view: the codec reads them
+        # where they lie
+        view = memoryview(self.fbuf)
+        self.tally.chunks += 1
         while remaining > 0 and pos < end:
             header, pos = decode_struct(self.fbuf, pos)
             ptype = header[1]
             comp = header[3]
-            page = bytes(self.fbuf[pos:pos + comp])
+            page = view[pos:pos + comp]
             pos += comp
             if ptype == PAGE_DICTIONARY:
                 data = _decompress(page, meta.codec, header[2])
                 nd = header[7][1]  # DictionaryPageHeader.num_values
                 self.dict_vals = _decode_plain(self.schema, data, nd)
-            elif ptype == PAGE_DATA:
-                r, d, v, nv = self._data_page_v1(page, header)
+            elif ptype in (PAGE_DATA, PAGE_DATA_V2):
+                r, d, v, nv = (self._data_page_v1 if ptype == PAGE_DATA
+                               else self._data_page_v2)(page, header)
                 reps.append(r)
                 defs.append(d)
                 vals.append(v)
                 remaining -= nv
-            elif ptype == PAGE_DATA_V2:
-                r, d, v, nv = self._data_page_v2(page, header)
-                reps.append(r)
-                defs.append(d)
-                vals.append(v)
-                remaining -= nv
+                self.tally.pages += 1
             elif ptype == PAGE_INDEX:
                 continue
             else:
@@ -666,7 +743,8 @@ class _ChunkDecoder:
                 raise NotImplementedError("non-RLE repetition levels")
             ln = int.from_bytes(data[0:4], "little")
             r = _rle_bitpacked_hybrid(data[4:4 + ln],
-                                      self.schema.max_rep.bit_length(), nv)
+                                      self.schema.max_rep.bit_length(), nv,
+                                      self.tally)
             pos = 4 + ln
         d = None
         md = self.schema.max_def
@@ -675,9 +753,9 @@ class _ChunkDecoder:
                 raise NotImplementedError("non-RLE definition levels")
             ln = int.from_bytes(data[pos:pos + 4], "little")
             d = _rle_bitpacked_hybrid(data[pos + 4:pos + 4 + ln],
-                                      md.bit_length(), nv)
+                                      md.bit_length(), nv, self.tally)
             pos += 4 + ln
-        nnon = nv if d is None else int((d == md).sum())
+        nnon = nv if d is None else np.count_nonzero(d == md)
         v = self._values(data[pos:], enc, nnon)
         return r, d, v, nv
 
@@ -691,17 +769,18 @@ class _ChunkDecoder:
         r = None
         if self.schema.max_rep:
             r = _rle_bitpacked_hybrid(page[0:rlen],
-                                      self.schema.max_rep.bit_length(), nv)
+                                      self.schema.max_rep.bit_length(), nv,
+                                      self.tally)
         d = None
         md = self.schema.max_def
         if md:
             d = _rle_bitpacked_hybrid(page[rlen:rlen + dlen],
-                                      md.bit_length(), nv)
+                                      md.bit_length(), nv, self.tally)
         body = page[dlen + rlen:]
         if ph.get(7, True):
             body = _decompress(body, self.meta.codec,
                                header[2] - dlen - rlen)
-        nnon = (nv - nnulls) if d is None else int((d == md).sum())
+        nnon = (nv - nnulls) if d is None else np.count_nonzero(d == md)
         v = self._values(body, enc, nnon)
         return r, d, v, nv
 
@@ -710,14 +789,14 @@ class _ChunkDecoder:
             if self.dict_vals is None:
                 raise ValueError("dictionary-encoded page before dictionary")
             bw = data[0]
-            idx = _rle_bitpacked_hybrid(data[1:], bw, nnon)
+            idx = _rle_bitpacked_hybrid(data[1:], bw, nnon, self.tally)
             return _gather_dict(self.schema, self.dict_vals, idx)
         if enc == ENC_PLAIN:
             return _decode_plain(self.schema, data, nnon)
         if enc == ENC_RLE and self.schema.physical == PT_BOOLEAN:
             ln = int.from_bytes(data[0:4], "little")
-            return _rle_bitpacked_hybrid(data[4:4 + ln], 1, nnon) \
-                .astype(np.uint8)
+            return _rle_bitpacked_hybrid(data[4:4 + ln], 1, nnon,
+                                         self.tally).astype(np.uint8)
         raise NotImplementedError(f"value encoding {enc}")
 
     def _assemble(self, defs, vals) -> _HostColumn:
@@ -734,7 +813,15 @@ class _ChunkDecoder:
                  np.ones(len(v[1]) if isinstance(v, tuple) else len(v),
                          np.bool_)
                  for d, v in zip(defs, vals)])
-        values, chars, offsets = _scatter_values(s, nrows, vals, valid)
+        # a null-free fixed-width chunk, as the levels just read show it:
+        # its value stream is the dense array, no scatter through a mask
+        # that is all true.  The validity stays an array (the staged plan
+        # and the segments' fingerprints are keyed on its presence).
+        dense = (valid is not None and s.physical != PT_BYTE_ARRAY
+                 and bool(valid.all()))
+        self.tally.dense_chunks += dense
+        values, chars, offsets = _scatter_values(
+            s, nrows, vals, None if dense else valid)
         return _HostColumn(s, values, chars, offsets, valid)
 
     def _assemble_list(self, reps, defs, vals) -> _HostColumn:
@@ -905,15 +992,20 @@ class ParquetFile:
             return list(range(len(self.schema)))
         return [self.names.index(c) for c in columns]
 
-    def _decode_group(self, gi: int, columns=None) -> list[_HostColumn]:
+    def _decode_group(self, gi: int, columns=None,
+                      tally: _DecodeTally | None = None) -> list[_HostColumn]:
+        """One row group's columns, decoded on this thread.  The
+        ``io.parquet.decode.*`` counters grow by what the decode walked,
+        and so does ``tally`` if the caller gave one (its span's stats)."""
         g = self.row_groups[gi]
         out = []
+        walked = _DecodeTally()
         for i in self._column_indices(columns):
             s = self.schema[i]
             if s.is_struct:
                 kids, svalid = [], None
                 for ck in g.chunks[i]:
-                    dec = _ChunkDecoder(self._buf, ck)
+                    dec = _ChunkDecoder(self._buf, ck, walked)
                     kids.append(dec.run())
                     if (svalid is None and s.struct_optional
                             and dec.def_stream is not None):
@@ -923,7 +1015,9 @@ class ParquetFile:
                 out.append(_HostColumn(s, None, None, None, svalid,
                                        children=kids))
             else:
-                out.append(_ChunkDecoder(self._buf, g.chunks[i]).run())
+                out.append(_ChunkDecoder(self._buf, g.chunks[i],
+                                         walked).run())
+        walked.publish(into=tally)
         return out
 
     def group_stats(self, gi: int, column: str):
@@ -1016,8 +1110,13 @@ class ParquetFile:
 
     def _decode_all_groups(self, columns=None) -> list:
         """All row groups decoded host-side; >1 group fans out on a thread
-        pool (numpy decode kernels drop the GIL — libcudf's reader decodes
-        row groups concurrently on-device for the same reason)."""
+        pool.  The pool buys little: the decode holds the GIL for most of
+        its time (python per page and per run header, numpy calls over a
+        page's few thousand values) and only the codec and the larger
+        copies drop it — 12 groups of the benchmark's fact file decoded no
+        faster on 2 or 4 threads than on one, before and after the decoder
+        went from runs to streams (PERF.md section 6, PR 33).  It stays for
+        the unstreamed reads of multi-group files, which no cell makes."""
         if self.num_row_groups > 1:
             from concurrent.futures import ThreadPoolExecutor
             with ThreadPoolExecutor(max_workers=min(
@@ -1400,21 +1499,29 @@ class ParquetChunkedReader:
                 # mark at the batch boundary
                 scope.checkpoint()
 
-    def _decode_group_checked(self, gi: int):
+    def _decode_group_checked(self, gi: int, tally: _DecodeTally):
         faults.check("parquet.chunk")
-        return self.file._decode_group(gi, self.columns)
+        return self.file._decode_group(gi, self.columns, tally)
 
     def _host_slices_group(self, gi: int):
         """Budget-bounded host-side slices of ONE row group."""
         # transient decode failures (flaky storage) retry per row
         # group, bounded by SRJT_RETRY_MAX with backoff
         # read, decompress, decode of one row group; `bytes` from the footer
+        tally = _DecodeTally()
         with op_scope("io.scan.decode", timed=True, group=gi,
                       bytes=int(self.file.row_groups[gi].total_byte_size
                                 or 0)):
             hosts = retry_call(
-                lambda gi=gi: self._decode_group_checked(gi),
+                lambda gi=gi: self._decode_group_checked(gi, tally),
                 "parquet.chunk", cancel=self.cancel)
+            # a span is given its stats when it opens and what the decode
+            # walked is known only now: it rides on an empty child span,
+            # the last thing inside the decode's
+            with op_scope("io.scan.decode.walked", group=gi,
+                          pages=tally.pages, runs=tally.runs,
+                          dense=f"{tally.dense_chunks}/{tally.chunks}"):
+                pass
         nrows = hosts[0].num_rows
         if nrows == 0:
             return
