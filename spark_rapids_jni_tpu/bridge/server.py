@@ -564,9 +564,13 @@ class BridgeServer:
             finally:
                 with self._tokens_lock:
                     self._active_tokens.pop(tok, None)
-        self._last_plan_stats = stats
-        if qm is not None:
-            self._last_plan_summary = qm.summary()
+        # N connection threads end plans at once: the pair is one plan's,
+        # written and read (`_op_metrics`) under the one lock
+        summary = qm.summary() if qm is not None else None
+        with self._metrics_lock:
+            self._last_plan_stats = stats
+            if summary is not None:
+                self._last_plan_summary = summary
         h = self.handles.put(out)
         return struct.pack("<I", 1) + struct.pack("<Q", h)
 
@@ -647,6 +651,8 @@ class BridgeServer:
             snap = {"ops": dict(self._metrics["ops"]),
                     "errors": self._metrics["errors"],
                     "busy_s": round(self._metrics["busy_s"], 6)}
+            last_stats = self._last_plan_stats
+            last_summary = self._last_plan_summary
         from ..utils.memory import runtime_memory_stats
         # None where the backend reports no allocator stats (the CPU)
         snap["device"] = {**device_info(),
@@ -656,9 +662,9 @@ class BridgeServer:
             snap["open_exports"] = len(self._exports)
         if self._plan_cache is not None:
             snap["plan_cache"] = self._plan_cache.stats()
-            snap["last_plan"] = dict(self._last_plan_stats)
-            if self._last_plan_summary:
-                snap["last_plan_summary"] = dict(self._last_plan_summary)
+            snap["last_plan"] = dict(last_stats)
+            if last_summary:
+                snap["last_plan_summary"] = dict(last_summary)
             # serving state: who is live/queued/shed, and whether repeat
             # queries are being served from the result-set cache — only
             # populated once the engine is imported (first PLAN_EXECUTE)

@@ -49,6 +49,11 @@ session within its own budget that hits RESOURCE_EXHAUSTED is feeling a
 once (``engine.sched.neighbor_pressure``) instead of force-interpreting
 an innocent tenant (engine/recovery.py).
 
+A session that really waits — queued at admission, blocked at the gate —
+is inside a span (``engine.sched.queue_wait`` / ``engine.sched.gate_wait``,
+``op_scope(..., timed=True)``): in its query's summary and, under
+``SRJT_TRACE=1``, on the profiler's clock.  One live session opens none.
+
 Docs: docs/SERVING.md.  Counters: ``engine.sched.*`` (docs/METRICS.md).
 """
 
@@ -62,6 +67,7 @@ from typing import Optional
 from ..utils import blackbox, metrics
 from ..utils.config import config
 from ..utils.errors import AdmissionRejectedError
+from ..utils.tracing import op_scope
 
 #: chunks per weight unit per round — small enough that a point query
 #: waits at most a few chunks behind a scan, large enough to amortize
@@ -209,46 +215,71 @@ class Scheduler:
             f"admission rejected ({reason}): {live}/{config.max_sessions} "
             f"sessions live after {waited_s:.2f}s queued")
 
-    def admit(self, fingerprint: str = "", source_fingerprint: str = "",
-              trace_id: str = "") -> QuerySession:
-        """Block until a session slot frees (bounded), or shed.
+    def _shed_if_burning(self, src: str, fingerprint: str, trace_id: str,
+                         t0: float):
+        """Saturated + burning fingerprint => shed now (lock held)."""
+        burn = self._burn_rate(src)
+        if burn is not None and burn >= config.admission_burn:
+            self._shed(f"slo-burn {burn:.2f}", fingerprint, trace_id,
+                       time.monotonic() - t0, len(self._live))
 
-        Saturated + burning fingerprint => immediate shed; saturated
-        otherwise => queue up to ``SRJT_ADMISSION_QUEUE_S`` then shed."""
-        t0 = time.monotonic()
+    def _enter(self, fingerprint: str, src: str, trace_id: str,
+               t0: float) -> tuple:
+        """Take a free slot (lock held): the new session and how many
+        are live with it."""
+        session = QuerySession(
+            next(self._ids), self, trace_id=trace_id,
+            fingerprint=fingerprint, source_fingerprint=src,
+            objective_ms=blackbox.slo_objective_for(src))
+        session.queued_s = time.monotonic() - t0
+        self._live[session.sid] = session
+        self.admitted += 1
+        metrics.count("engine.sched.admitted")
+        metrics.gauge_set("engine.sched.live", len(self._live))
+        return session, len(self._live)
+
+    def _queue(self, fingerprint: str, src: str, trace_id: str,
+               t0: float) -> tuple:
+        """Wait for a slot up to ``SRJT_ADMISSION_QUEUE_S``, then shed."""
         deadline = t0 + config.admission_queue_s
-        src = source_fingerprint or fingerprint
-        queued_counted = False
         with self._cv:
             while len(self._live) >= config.max_sessions:
-                burn = self._burn_rate(src)
-                if burn is not None and burn >= config.admission_burn:
-                    self._shed(f"slo-burn {burn:.2f}", fingerprint,
-                               trace_id, time.monotonic() - t0,
-                               len(self._live))
-                if not queued_counted:
-                    queued_counted = True
-                    self.queued += 1
-                    metrics.count("engine.sched.queued")
+                self._shed_if_burning(src, fingerprint, trace_id, t0)
                 remaining = deadline - time.monotonic()
                 if remaining <= 0:
                     self._shed("queue-timeout", fingerprint, trace_id,
                                time.monotonic() - t0, len(self._live))
                 self._cv.wait(min(remaining, _GATE_WAIT_S))
-            session = QuerySession(
-                next(self._ids), self, trace_id=trace_id,
-                fingerprint=fingerprint,
-                source_fingerprint=src,
-                objective_ms=blackbox.slo_objective_for(src))
-            session.queued_s = time.monotonic() - t0
-            self._live[session.sid] = session
-            self.admitted += 1
-            metrics.count("engine.sched.admitted")
-            metrics.gauge_set("engine.sched.live", len(self._live))
-            if session.queued_s > 0.001:
-                metrics.observe("engine.sched.queue_wait_s",
-                                session.queued_s)
-            return session
+            return self._enter(fingerprint, src, trace_id, t0)
+
+    def admit(self, fingerprint: str = "", source_fingerprint: str = "",
+              trace_id: str = "") -> QuerySession:
+        """Block until a session slot frees (bounded), or shed.
+
+        Saturated + burning fingerprint => immediate shed; saturated
+        otherwise => queue up to ``SRJT_ADMISSION_QUEUE_S`` then shed.
+        Only an arrival that has to queue opens the span
+        ``engine.sched.queue_wait`` (timed: ``engine.sched.queue_wait_s``);
+        the span opens and closes outside ``_cv``."""
+        t0 = time.monotonic()
+        src = source_fingerprint or fingerprint
+        with self._cv:
+            saturated = len(self._live) >= config.max_sessions
+            if saturated:
+                self._shed_if_burning(src, fingerprint, trace_id, t0)
+                self.queued += 1
+                metrics.count("engine.sched.queued")
+                live = len(self._live)
+            else:
+                session, live = self._enter(fingerprint, src, trace_id, t0)
+        if saturated:
+            with op_scope("engine.sched.queue_wait", timed=True, live=live,
+                          trace_id=trace_id):
+                session, live = self._queue(fingerprint, src, trace_id, t0)
+        # how many plans run at once, the new one included: a gauge shows
+        # the last value only, a histogram's sum / count the mean
+        metrics.observe("engine.sched.live_sessions", live)
+        return session
 
     def release(self, session: QuerySession) -> None:
         with self._cv:
@@ -266,30 +297,43 @@ class Scheduler:
             s.credits = _QUANTUM * s.weight
         self._cv.notify_all()
 
+    def _spend(self, session: QuerySession, waited_s: float) -> bool:
+        """Spend one chunk credit if the session may run now (lock held);
+        False while its round is drained and others still hold credits.
+        Starts a new round once every live session has drained, or after
+        ``_FORCE_ROUND_S`` of waiting."""
+        if session.sid not in self._live:
+            return True  # released concurrently (cancel path)
+        if session.credits <= 0 and (
+                waited_s >= _FORCE_ROUND_S
+                or all(s.credits <= 0 for s in self._live.values())):
+            self._new_round()
+        if session.credits <= 0:
+            return False
+        session.credits -= 1
+        return True
+
     def gate(self, session: QuerySession) -> None:
         """Spend one chunk credit; block while the session's round is
         drained and others still hold credits.  Bounded waits plus the
         ``_FORCE_ROUND_S`` forced replenish keep this deadlock-free even
-        when a credit holder stalls off a chunk boundary."""
+        when a credit holder stalls off a chunk boundary.
+
+        Only a session that really blocks opens the span
+        ``engine.sched.gate_wait`` (timed: ``engine.sched.gate_wait_s``),
+        and the span opens and closes outside ``_cv``."""
         with self._cv:
             if len(self._live) <= 1:
                 return  # single tenant: no contention, no bookkeeping
-            t0 = None
-            while session.credits <= 0:
-                if session.sid not in self._live:
-                    return  # released concurrently (cancel path)
-                now = time.monotonic()
-                if t0 is None:
-                    t0 = now
-                if now - t0 >= _FORCE_ROUND_S or \
-                        all(s.credits <= 0 for s in self._live.values()):
-                    self._new_round()
-                else:
+            if self._spend(session, 0.0):
+                return
+            live = len(self._live)
+        with op_scope("engine.sched.gate_wait", timed=True, sid=session.sid,
+                      live=live, trace_id=session.trace_id):
+            t0 = time.monotonic()
+            with self._cv:
+                while not self._spend(session, time.monotonic() - t0):
                     self._cv.wait(_GATE_WAIT_S)
-            session.credits -= 1
-            if t0 is not None:
-                metrics.observe("engine.sched.gate_wait_s",
-                                time.monotonic() - t0)
 
     # -- introspection ----------------------------------------------------
 
