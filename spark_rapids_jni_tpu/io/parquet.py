@@ -24,6 +24,8 @@ Supported surface (flat schemas — the Spark-SQL scan shape):
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import mmap
 import os
 from dataclasses import dataclass, field
@@ -36,7 +38,7 @@ from ..columnar import Column, Table
 from ..utils import faults, metrics, timeline
 from ..utils.errors import retry_call
 from ..utils.tracing import op_scope
-from . import snappy
+from . import decode_pool, snappy
 from .thrift import decode_struct
 
 _MAGIC = b"PAR1"
@@ -1073,11 +1075,7 @@ class ParquetFile:
         forces per-column transfers."""
         idxs = self._column_indices(columns)
         eligible = (self.num_row_groups >= 1 and
-                    all(self.schema[i].dtype is not None and
-                        self.schema[i].dtype.is_fixed_width and
-                        self.schema[i].dtype.id != dt.TypeId.DECIMAL128 and
-                        not self.schema[i].is_list and
-                        not self.schema[i].is_struct for i in idxs))
+                    all(_packs_fixed(self.schema[i]) for i in idxs))
         if staged and not eligible:
             staged = False  # explicit request, ineligible schema
         if eligible and staged is not False:
@@ -1126,6 +1124,14 @@ class ParquetFile:
                     range(self.num_row_groups)))
         return [self._decode_group(gi, columns)
                 for gi in range(self.num_row_groups)]
+
+
+def _packs_fixed(s: ColumnSchema) -> bool:
+    """A column the staged path packs (io/staging.py) and a decode pool's
+    slab carries: flat, fixed width, not DECIMAL128."""
+    return (s.dtype is not None and s.dtype.is_fixed_width
+            and s.dtype.id != dt.TypeId.DECIMAL128
+            and not s.is_list and not s.is_struct)
 
 
 def _empty_host(s: ColumnSchema) -> _HostColumn:
@@ -1401,6 +1407,33 @@ def plan_device_group(pf: ParquetFile, gi: int, columns=None,
                            unc_bytes), None
 
 
+#: a row group whose footer ``total_byte_size`` is under this is decoded
+#: where it is asked for: a worker's answer takes longer than its decode
+#: (`tools/decode_profile.py --procs`: PERF.md section 6, PR 35)
+OFFLOAD_MIN_BYTES = 128 << 10
+
+
+class _Lease:
+    """One row group's ticket in a decode pool, for as long as the stream
+    holds it: resubmitted if its worker dies, released once."""
+
+    __slots__ = ("pool", "args", "ticket")
+
+    def __init__(self, pool, path, gi, columns, nbytes):
+        self.pool = pool
+        self.args = (path, gi, columns, nbytes)
+        self.ticket = pool.submit(*self.args)
+
+    def resubmit(self) -> None:
+        self.release()
+        self.ticket = self.pool.submit(*self.args)
+
+    def release(self) -> None:
+        if self.ticket is not None:
+            self.pool.release(self.ticket)
+            self.ticket = None
+
+
 class ParquetChunkedReader:
     """Iterate a parquet file as device Tables bounded by a byte budget.
 
@@ -1437,6 +1470,12 @@ class ParquetChunkedReader:
         # never closes its iterator, which would leave the producer thread
         # parked on the bounded queue until GC; ``close()`` reaps them
         self._active: list = []
+        # what a decode pool's slab would hold of a row: None for a schema
+        # it cannot carry (`_offload`)
+        picked = [self.file.schema[i]
+                  for i in self.file._column_indices(columns)]
+        self._slab_itemsizes = [c.dtype.storage.itemsize for c in picked] \
+            if all(_packs_fixed(c) for c in picked) else None
         if self.limit <= 0:
             raise ValueError("pass_read_limit must be positive")
 
@@ -1499,50 +1538,144 @@ class ParquetChunkedReader:
                 # mark at the batch boundary
                 scope.checkpoint()
 
-    def _decode_group_checked(self, gi: int, tally: _DecodeTally):
+    def _offload(self, gi: int):
+        """Hand row group ``gi`` to the decode pool: its `_Lease`, or None
+        where the group is decoded here.  What decides is what the footer
+        shows: a group under `OFFLOAD_MIN_BYTES` decodes faster than a
+        worker answers, and a slab carries flat fixed-width columns only."""
+        g = self.file.row_groups[gi]
+        if self._slab_itemsizes is None \
+                or int(g.total_byte_size or 0) < OFFLOAD_MIN_BYTES:
+            return None
+        pool = decode_pool.shared()
+        if pool is None:
+            return None
+        lease = _Lease(pool, self.file.path, gi, self.columns,
+                       decode_pool.slab_bytes(self._slab_itemsizes,
+                                              int(g.num_rows)))
+        return lease if lease.ticket is not None else None
+
+    def _decode_group_checked(self, gi: int, tally: _DecodeTally, lease):
+        """Row group ``gi``'s host columns and the seconds a worker spent
+        on them (None: decoded here).  A worker that died is a transient
+        failure like a flaky read: the group is submitted again and the
+        caller's `retry_call` comes back for it."""
         faults.check("parquet.chunk")
-        return self.file._decode_group(gi, self.columns, tally)
+        got = None
+        if lease is not None and lease.ticket is not None:
+            try:
+                got = lease.pool.wait(lease.ticket, self.cancel)
+            except decode_pool.WorkerLost:
+                lease.resubmit()
+                raise
+        if got is None:
+            hosts = self.file._decode_group(gi, self.columns, tally)
+            metrics.count("io.scan.decode.inline")
+            return hosts, None
+        cols, walked, seconds = got
+        chunks = self.file.row_groups[gi].chunks
+        hosts = [_HostColumn(chunks[i].schema, values, None, None, validity)
+                 for i, (values, validity)
+                 in zip(self.file._column_indices(self.columns), cols)]
+        _DecodeTally(*walked).publish(into=tally)
+        metrics.count("io.scan.decode.offloaded")
+        metrics.observe("io.scan.decode.worker_s", seconds)
+        return hosts, seconds
 
-    def _host_slices_group(self, gi: int):
-        """Budget-bounded host-side slices of ONE row group."""
-        # transient decode failures (flaky storage) retry per row
-        # group, bounded by SRJT_RETRY_MAX with backoff
-        # read, decompress, decode of one row group; `bytes` from the footer
+    def _host_slices_group(self, gi: int, lease=None):
+        """Budget-bounded host-side slices of ONE row group; ``lease``: its
+        place in the decode pool, if `_host_slices` took one ahead."""
+        # transient decode failures (flaky storage, a lost worker) retry
+        # per row group, bounded by SRJT_RETRY_MAX with backoff
         tally = _DecodeTally()
-        with op_scope("io.scan.decode", timed=True, group=gi,
-                      bytes=int(self.file.row_groups[gi].total_byte_size
-                                or 0)):
-            hosts = retry_call(
-                lambda gi=gi: self._decode_group_checked(gi, tally),
-                "parquet.chunk", cancel=self.cancel)
-            # a span is given its stats when it opens and what the decode
-            # walked is known only now: it rides on an empty child span,
-            # the last thing inside the decode's
-            with op_scope("io.scan.decode.walked", group=gi,
-                          pages=tally.pages, runs=tally.runs,
-                          dense=f"{tally.dense_chunks}/{tally.chunks}"):
-                pass
-        nrows = hosts[0].num_rows
-        if nrows == 0:
-            return
-        total = sum(h.nbytes_estimate() for h in hosts)
-        metrics.count("io.parquet.bytes_decoded", int(total))
-        per_row = max(1, total // max(nrows, 1))
-        step = max(1, self.limit // per_row)
-        for a in range(0, nrows, step):
-            b = min(a + step, nrows)
-            yield [h.slice(a, b) for h in hosts]
+        try:
+            # obtaining the group, as the pipeline waits for it: read,
+            # decompress and decode here, or the wait for the worker that
+            # does (io.scan.decode.worker_s is its work then); `bytes`
+            # from the footer
+            with op_scope("io.scan.decode", timed=True, group=gi,
+                          bytes=int(self.file.row_groups[gi].total_byte_size
+                                    or 0)):
+                hosts, worker_s = retry_call(
+                    lambda: self._decode_group_checked(gi, tally, lease),
+                    "parquet.chunk", cancel=self.cancel)
+                # a span is given its stats when it opens and what the
+                # decode walked is known only now: it rides on an empty
+                # child span, the last thing inside the decode's
+                # (`worker_ms`: the worker's own time; absent: decoded here)
+                with op_scope("io.scan.decode.walked", group=gi,
+                              pages=tally.pages, runs=tally.runs,
+                              dense=f"{tally.dense_chunks}/{tally.chunks}",
+                              **({} if worker_s is None else
+                                 {"worker_ms": round(worker_s * 1e3, 3)})):
+                    pass
+            nrows = hosts[0].num_rows
+            if nrows == 0:
+                return
+            total = sum(h.nbytes_estimate() for h in hosts)
+            metrics.count("io.parquet.bytes_decoded", int(total))
+            per_row = max(1, total // max(nrows, 1))
+            step = max(1, self.limit // per_row)
+            for a in range(0, nrows, step):
+                b = min(a + step, nrows)
+                yield [h.slice(a, b) for h in hosts]
+        finally:
+            # the slices were views of the slab: whoever took them has
+            # copied them out (`_host_slices`'s contract) or is gone
+            if lease is not None:
+                lease.release()
 
-    def _host_slices(self):
-        """Budget-bounded host-side chunk slices, pre device transfer."""
+    def _host_slices(self, offload: bool = False):
+        """Budget-bounded host-side chunk slices, pre device transfer, in
+        file order.
+
+        ``offload``: hand the next `decode_pool.READ_AHEAD` + 1 non-pruned
+        row groups to the decode pool (`_offload`) and take them back in
+        order, so the results are row for row what the serial decode gives.  The
+        caller must be done with a slice — copied out of it — when it asks
+        for the next: a slab is reused.  `_staged_chunks` is (the staged
+        pack copies); `_chunks_raw` is not (`to_column` may alias host
+        memory on the CPU backend)."""
+        ahead: collections.deque = collections.deque()  # (gi, pruned, lease)
+        upcoming = self._unpruned_groups()
+        try:
+            while True:
+                while len(ahead) <= (decode_pool.READ_AHEAD if offload else 0):
+                    nxt = next(upcoming, None)
+                    if nxt is None:
+                        break
+                    gi, pruned = nxt
+                    ahead.append((gi, pruned, self._offload(gi)
+                                  if offload and gi is not None else None))
+                if not ahead:
+                    return
+                if self.cancel is not None:
+                    self.cancel.check()     # before a lease leaves `ahead`
+                gi, pruned, lease = ahead.popleft()
+                self.groups_pruned += pruned
+                if gi is None:      # the file's end, after pruned groups
+                    return
+                self.groups_read += 1
+                yield from self._host_slices_group(gi, lease)
+        finally:
+            # a cancelled or abandoned stream gives back what it holds
+            for _, _, lease in ahead:
+                if lease is not None:
+                    lease.release()
+
+    def _unpruned_groups(self):
+        """``(gi, pruned)``: each row group the predicate keeps, with the
+        number of pruned groups passed over since the last one; a last
+        ``(None, pruned)`` if the file ends on pruned groups."""
+        pruned = 0
         for gi in range(self.file.num_row_groups):
-            if self.cancel is not None:
-                self.cancel.check()
             if self._group_pruned(gi):
-                self.groups_pruned += 1
+                pruned += 1
                 continue
-            self.groups_read += 1
-            yield from self._host_slices_group(gi)
+            yield gi, pruned
+            pruned = 0
+        if pruned:
+            yield None, pruned
 
     def _chunks_raw(self):
         for sl in self._host_slices():
@@ -1560,8 +1693,11 @@ class ParquetChunkedReader:
         compile once and mask rows >= n_rows.  Ineligible schemas
         (strings, lists, structs, DECIMAL128) fall back to per-column
         transfers at natural size (n_rows == num_rows)."""
-        for sl in self._host_slices():
-            yield self._stage_one(sl)
+        # closed with this generator, not when the collector finds it: an
+        # abandoned stream's leases go back to the decode pool at once
+        with contextlib.closing(self._host_slices(offload=True)) as slices:
+            for sl in slices:
+                yield self._stage_one(sl)
 
     def _stage_one(self, sl):
         """One host slice -> (padded Table, n_rows) on the staged path."""
@@ -1737,6 +1873,11 @@ def _prefetched(gen, depth: int, cancel=None):
                 put_ctrl(DONE)
             except BaseException as e:  # surface decode errors to consumer
                 put_ctrl((FAIL, e))
+            finally:
+                # an abandoned stream's generator ends HERE, on its own
+                # thread: what it holds (decode-pool leases) goes back now
+                if hasattr(gen, "close"):
+                    gen.close()
 
     t = threading.Thread(target=producer, daemon=True)
     t.start()

@@ -10,7 +10,10 @@ to ONE bridge server on one chip, ``SRJT_MAX_SESSIONS=4``), on the CPU.
   no session left live, at most 4 live at once;
 - (c) every query's own summary holds the one-client run's counts, and the
   summaries add up to the process-wide growth: the per-query metrics
-  context does not leak between queries through the producer threads;
+  context does not leak between queries through the producer threads — nor
+  through the pool of decode worker processes (io/decode_pool.py) the four
+  producers share: every fact row group is decoded by a worker, 4 x 3 x 11
+  of them, and counted in the query that asked for it;
 - (d) a session that blocks at the gate or in the admission queue leaves a
   span (``TraceAnnotation`` under ``SRJT_TRACE=1``, the histogram
   ``<name>_s``) with its own trace id; a single session leaves none;
@@ -130,10 +133,18 @@ def served(tmp_path_factory):
                 root, seed, CONFIG["rehearsal_rows"][QUERY.FACT])
             (h,) = client.execute_plan(blob)
             client.release(h)
-            (h,) = client.execute_plan(blob)        # warm: compiles nothing
-            alone = client.export_host(h)
-            client.release(h)
-            before = client.metrics()
+            # warm: compiles nothing, and the server's decode workers, which
+            # start behind the first streamed scan, are up
+            for _ in range(200):
+                (h,) = client.execute_plan(blob)
+                alone = client.export_host(h)
+                client.release(h)
+                before = client.metrics()
+                mine = [q for q in before["queries"]
+                        if q.get("trace_id") == client.trace_id][-1]
+                if mine["counters"].get("io.scan.decode.inline") == 1:
+                    break
+                time.sleep(0.25)
             results, trace_ids = _together(sock, blob, CLIENTS, ROUNDS)
             after = client.metrics()
             out.append({
@@ -218,7 +229,8 @@ PER_QUERY = ("engine.host_sync", "engine.segment.replay",
              "engine.combine.replay", "engine.probe.compare",
              "io.parquet.chunks", "io.parquet.decode.pages",
              "io.parquet.decode.runs", "io.parquet.decode.dense_chunks",
-             "io.parquet.bytes_decoded", "engine.build_cache.hit",
+             "io.parquet.bytes_decoded", "io.scan.decode.offloaded",
+             "io.scan.decode.inline", "engine.build_cache.hit",
              "engine.segment_cache.hit", "engine.plan_cache.hit",
              "engine.sched.admitted")
 
@@ -228,13 +240,18 @@ def test_each_summary_holds_the_one_client_counts(served, i):
     run = served[i]
     alone = run["alone_query"]
     assert alone["counters"]["engine.host_sync"] == 2
+    # the 11 fact row groups by a decode worker, `date_dim`'s one here
+    assert alone["counters"]["io.scan.decode.offloaded"] == 11
+    assert alone["counters"]["io.scan.decode.inline"] == 1
+    assert _grew(run, "io.scan.decode.offloaded") == CLIENTS * ROUNDS * 11
     assert len(run["queries"]) == CLIENTS * ROUNDS
     for q in run["queries"]:
         assert q["outcome"]["status"] == "ok"
         assert q["stats"]["chunks"] == alone["stats"]["chunks"] == 11
         assert {k: q["counters"].get(k) for k in PER_QUERY} \
             == {k: alone["counters"].get(k) for k in PER_QUERY}
-        for name in ("io.scan.decode_s", "io.scan.stage_s",
+        for name in ("io.scan.decode_s", "io.scan.decode.worker_s",
+                     "io.scan.stage_s",
                      "engine.stream.chunk_latency_s", "engine.sync_wait_s",
                      "engine.stream_s", "engine.execute_s"):
             assert q["histograms"][name]["count"] \
@@ -247,8 +264,9 @@ def test_summaries_add_up_to_the_process_wide_growth(served, i):
     for name in PER_QUERY:
         assert sum(q["counters"].get(name, 0) for q in run["queries"]) \
             == _grew(run, name), name
-    for name in ("io.scan.decode_s", "io.scan.stage_s",
-                 "engine.sync_wait_s", "engine.sched.gate_wait_s"):
+    for name in ("io.scan.decode_s", "io.scan.decode.worker_s",
+                 "io.scan.stage_s", "engine.sync_wait_s",
+                 "engine.sched.gate_wait_s"):
         total, count = _hist_grew(run, name)
         mine = [q["histograms"].get(name) or {"sum": 0.0, "count": 0}
                 for q in run["queries"]]
