@@ -882,17 +882,18 @@ def _exec_streamed(st: Stage, memo: dict, stats: dict, ctx: _ExecCtx,
     agg, scan = st.node, st.scan
     _precompute_independent(agg.child, scan, memo, stats, ctx)
     with op_scope("engine.stream", timed=True):
-        reader, partials, fused, fused_compiled = _stream_chunks(
+        reader, partials, fused = _stream_chunks(
             agg, scan, None if force_interp else st.segment, memo, stats,
             ctx)
     # what follows, to the end of ``execute``, is ``engine.post_stream``:
-    # the merge of the partials (one program) and every operator above it
+    # the final merge of the partials (one program) and every operator
+    # above it
     ctx.stream_end = time.perf_counter()
     stats["row_groups_pruned"] += reader.groups_pruned
     stats["row_groups_read"] += reader.groups_read
 
     if fused:
-        return sg.combine_partials(fused, fused_compiled)
+        return fused.finish()
     if not partials:
         # everything pruned/filtered: run the plan once on an empty chunk
         # so the output schema still comes out right (the reader's cached
@@ -911,10 +912,10 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                    ctx: _ExecCtx) -> tuple:
     """The chunk loop of ``_exec_streamed``: reader open -> last chunk
     dispatched -> reader closed.  ``seg``: the stage's fused chunk
-    segment, None to interpret each chunk.  Returns ``(reader, partials, fused,
-    fused_compiled)``; at most one of ``partials`` (interpreted path:
-    compacted Tables) and ``fused`` (fused path: padded device partials)
-    is filled."""
+    segment, None to interpret each chunk.  Returns ``(reader, partials,
+    fused)``; at most one of ``partials`` (interpreted path: compacted
+    Tables) and ``fused`` (fused path: the padded device partials, folded
+    as the stream ran — ``segment.StreamedPartials``) is filled."""
     from ..io import ParquetChunkedReader
     from ..utils.config import config
     from . import segment as sg
@@ -932,8 +933,7 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
         pqm.progress_total(reader.footer_chunk_estimate())
 
     partials: list = []          # interpreted path: compacted Tables
-    fused: list = []             # fused path: padded device partials
-    fused_compiled = None
+    fused = sg.StreamedPartials()   # fused path: padded device partials
     try:
         if seg is not None:
             joins = seg.joins()
@@ -1000,6 +1000,7 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                     ctx.recovery.checkpoint()
                     stats["chunks"] += 1
                     tc0 = time.perf_counter() if qm is not None else 0.0
+                    fused.make_room()   # a long stream folds here
                     if fused:  # chunks after the first hit the cache
                         preps = _get_builds(joins, build_tables)
                     if device_mode:
@@ -1026,8 +1027,9 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                                 seg, payload.geom, build_tables)
                             with op_scope("engine.fused_segment",
                                           **fused_compiled.span_stats()):
-                                fused.append(fused_compiled(
-                                    planes, payload.nrows, preps))
+                                fused.add(fused_compiled(
+                                    planes, payload.nrows, preps),
+                                    fused_compiled)
                             nvalid, padded = payload.nrows, 0
                             cb = payload.comp_bytes
                             dd["device_chunks"] += 1
@@ -1047,8 +1049,8 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                                 seg, chunk, build_tables)
                             with op_scope("engine.fused_segment",
                                           **fused_compiled.span_stats()):
-                                fused.append(fused_compiled(
-                                    chunk, nvalid, preps))
+                                fused.add(fused_compiled(
+                                    chunk, nvalid, preps), fused_compiled)
                     else:
                         chunk, nvalid = item
                         cb = table_nbytes(chunk)
@@ -1058,8 +1060,8 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                                                               build_tables)
                         with op_scope("engine.fused_segment",
                                       **fused_compiled.span_stats()):
-                            fused.append(fused_compiled(chunk, nvalid,
-                                                        preps))
+                            fused.add(fused_compiled(chunk, nvalid, preps),
+                                      fused_compiled)
                     if qm is not None:
                         # per-chunk latency is dispatch time — the fused
                         # loop never syncs per chunk, by design
@@ -1087,7 +1089,7 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                                                 stats, ctx))
     finally:
         reader.close()
-    return reader, partials, fused, fused_compiled
+    return reader, partials, fused
 
 
 def _chain_one(first, rest):

@@ -635,7 +635,8 @@ def stage_census(physical, stats: dict, qm=None) -> Optional[str]:
     From ``stats`` alone, exactly: the exchanges executed, whether
     anything streamed, whether a top-k did.  With the run's QueryMetrics
     (``qm``) also the fused segments run and the ``engine.host_sync``
-    counter against ``SYNC_CHARGES`` — less the stages a veto demoted that
+    counter against ``SYNC_CHARGES`` (+ one sizing sync per fold of a long
+    stream, ``engine.combine.folds``) — less the stages a veto demoted that
     the static side can name: a ``Stage.vetoed`` segment (schema), an
     ``agg`` segment over an empty input (its span's ``rows_in``), a stream
     that staged no chunk.  A ``stream-agg`` whose consumed nodes have spans
@@ -675,7 +676,8 @@ def stage_census(physical, stats: dict, qm=None) -> Optional[str]:
             continue
         ran.append(st)
     want = {"fused_segments": sum(st.segment is not None for st in ran),
-            "host_syncs": sum(len(SYNC_CHARGES[st.kind]) for st in ran)}
+            "host_syncs": sum(len(SYNC_CHARGES[st.kind]) for st in ran)
+            + qm.counters.get("engine.combine.folds", 0)}
     got = {"fused_segments": stats["fused_segments"],
            "host_syncs": qm.counters.get("engine.host_sync", 0)}
     if any(got[k] < want[k] for k in want) or (got != want and not rewalk):

@@ -33,8 +33,10 @@ from .plan import (STREAM_COMBINE, Aggregate, Exchange, Filter, Join,
                    topo_nodes)
 
 #: stage kind -> the whitelisted sync sites (``verify.SYNC_WHITELIST``) one
-#: execution of the stage pays, however many chunks stream through it
-#: (``verify.sync_budget`` adds a fused stage's AQE probe: a run-time choice)
+#: execution of the stage pays (``verify.sync_budget`` adds a fused stage's
+#: AQE probe, a run-time choice; a ``stream-agg`` of more than
+#: ``segment.COMBINE_ARITY`` chunks adds one ``combine-fold-sizing`` per
+#: fold at run time, counted by ``engine.combine.folds``)
 SYNC_CHARGES = {
     "stream-agg": ("combine-sizing", "groupby-compaction"),
     "stream-agg-interp": (),

@@ -36,8 +36,10 @@ plans.  The TPU translation:
   re-enters the same compiled executable instead of retracing.
 - The merge of a streamed aggregate's padded partials is one more entry of
   that cache (``CompiledCombine``): cut, concatenate and combine group-by
-  in ONE launch between ``combine_partials``' two host syncs, compiled
-  once per (capacity, power-of-two bucket of the partial count).
+  in ONE launch, compiled once per (capacity, power-of-two bucket of the
+  partial count) — and the count never passes ``COMBINE_ARITY``: a longer
+  stream folds its partials as it runs (``StreamedPartials``), so neither
+  what the device holds nor any program grows with the stream.
 """
 
 from __future__ import annotations
@@ -518,11 +520,20 @@ class CompiledDecodeSegment(CompiledSegment):
         self.jfn = jax.jit(_build_decode_fn(segment, self, geom))
 
 
+def _partial_slots(p) -> int:
+    """Slots of one padded partial: the length of its columns."""
+    return (p[0] + tuple(c.data for c in p[2]))[0].shape[0]
+
+
 def _partial_class(p) -> tuple:
     """The compile key of one padded partial — everything jax.jit would
-    retrace the merge on: slot count, key buffers, aggregate columns."""
+    retrace the merge on: slot count, key buffers, aggregate columns.  A
+    chunk program's partial brings a mask of its live slots; a merged one
+    (a fold's output, ``StreamedPartials``) its group count in that
+    place, and is a class of its own."""
     kdat, _kval, out_aggs, glive = p[:4]
-    return (glive.shape[0], tuple(k.dtype.str for k in kdat),
+    return (glive.shape[0] if glive.ndim else ("merged", _partial_slots(p)),
+            tuple(k.dtype.str for k in kdat),
             tuple((c.dtype, c.data.dtype.str, c.validity is not None)
                   for c in out_aggs))
 
@@ -532,8 +543,9 @@ def _build_combine_fn(agg: Aggregate, key_dtypes: tuple, cap: int,
     """The single program the merge of the streamed partials traces into.
 
     ``fn(partials, nreal)``: ``partials`` is the bucketed tuple of
-    ``(kdat, kval, out_aggs, glive)``; entries >= ``nreal`` are filler
-    (dead rows).  Slice every partial to ``cap``, concatenate, and run the
+    ``(kdat, kval, out_aggs, glive)`` — ``glive`` a merged partial's group
+    count where it is a scalar; entries >= ``nreal`` are filler (dead
+    rows).  Slice every partial to ``cap``, concatenate, and run the
     combine ``groupby_padded`` under the live mask — still padded, zero
     host syncs.
     """
@@ -545,6 +557,13 @@ def _build_combine_fn(agg: Aggregate, key_dtypes: tuple, cap: int,
 
     def cut(a):
         return a[:cap] if a.shape[0] > cap else a
+
+    def live_slots(p):
+        if p[3].ndim:
+            return cut(p[3])
+        # a merged partial: its groups are packed at the front
+        return jnp.arange(min(cap, _partial_slots(p)),
+                          dtype=jnp.int32) < p[3]
 
     def fn(partials, nreal):
         from ..ops.aggregate import groupby_padded
@@ -567,7 +586,7 @@ def _build_combine_fn(agg: Aggregate, key_dtypes: tuple, cap: int,
             agg_cols.append(Column(partials[0][2][j].dtype,
                                    data=jnp.concatenate(datas),
                                    validity=validity))
-        live = jnp.concatenate([cut(p[3]) & (np.int32(i) < nreal)
+        live = jnp.concatenate([live_slots(p) & (np.int32(i) < nreal)
                                 for i, p in enumerate(partials)])
         merged = Table(key_cols + agg_cols, knames + anames)
         out_keys, out_aggs, ngroups = groupby_padded(
@@ -799,52 +818,137 @@ def run_agg_segment(compiled: CompiledSegment, table: Table,
 
 @jax.jit
 def _max_ngroups(ngroups: tuple):
-    """The sizing reduce of ``combine_partials``: one launch, one scalar."""
+    """The sizing reduce of a merge: one launch, one scalar."""
     return jnp.max(jnp.stack(ngroups))
 
 
-def combine_partials(partials: list, compiled: CompiledSegment) -> Table:
-    """Merge per-chunk padded partial aggregates into the final Table.
+#: the most partials one launch of the merge program takes: the bucket
+#: the benchmark's 11- and 12-chunk streams compile.  A power of two.
+COMBINE_ARITY = 16
 
-    ``partials``: [(kdat, kval, out_aggs, glive, ngroups), ...] straight
-    off the fused agg program — still padded, never synced per chunk.
-    Two host syncs total, however many chunks streamed through: one
-    scalar ``max(ngroups)`` fetch to size the combine, one final
-    ``ngroups`` in the compaction tail.  Between them ONE launch: the
-    cut, the concatenation and the combine ``groupby_padded`` are a single
-    compiled program (``CompiledCombine``, cached in ``SEGMENT_CACHE``).
 
-    The sizing sync matters: each partial is padded to its chunk's row
-    bucket (e.g. 16k slots for 12 live groups), and ``groupby_padded``
-    over num_chunks x bucket dead rows costs seconds.  Live groups are
-    packed at the FRONT of the padded arrays (that is what the [:ngroups]
-    compaction relies on), so slicing every partial to one power-of-two
-    capacity >= max(ngroups) preserves every live group, keeps the
-    combine's shape stable across runs (jit reuse), and shrinks it by
-    ~bucket/cap.
+def _merge_padded(partials: list, compiled: CompiledSegment, width: int,
+                  sync_label: str, **span_stats) -> tuple:
+    """Size and launch ONE merge of ``partials`` — ``[(kdat, kval,
+    out_aggs, glive, ngroups), ...]``, at most ``width`` of them — and
+    return the program's padded ``(kdat, kval, out_aggs, ngroups)``.
 
-    The number of partials is what pruning left, so it is bucketed too
-    (11 and 12 chunks share the 16-partial program): the tuple is filled
-    up to the next power of two with repeats of the first partial, which
-    the program masks dead (``nreal``) — dead rows sort behind every live
-    one and add to no group.
+    One host sync (the caller has counted it under ``sync_label``), the
+    scalar ``max(ngroups)`` fetch that sizes the merge, and it matters:
+    each chunk's partial is padded to the chunk's row bucket (e.g. 262,144
+    slots for 12 live groups), and ``groupby_padded`` over num_chunks x
+    bucket dead rows costs seconds.  Live groups are packed at the FRONT
+    of the padded arrays (that is what the [:ngroups] compaction relies
+    on), so slicing every partial to one power-of-two capacity >=
+    max(ngroups) preserves every live group, keeps the merge's shape
+    stable across runs (jit reuse), and shrinks it by ~bucket/cap.  The
+    capacity is sized from what THESE partials hold, a merged one among
+    them included, so a merge never drops a group.
+
+    The tuple is filled up to ``width`` with repeats of the newest
+    partial, which the program masks dead (``nreal``) — dead rows sort
+    behind every live one and add to no group.
     """
     from ..ops.parquet_decode import bucket
-    agg = compiled.segment.agg
     nreal = len(partials)
-    filled = tuple(partials) + (partials[0],) * (bucket(nreal, 1) - nreal)
-    metrics.host_sync(label="combine-sizing")  # the sizing scalar fetch
-    # where the host waits until the device has drained every streamed
-    # segment: the first fetch after the chunk loop
-    with op_scope("engine.sync_wait", timed=True, label="combine-sizing"):
+    filled = tuple(partials) + (partials[-1],) * (width - nreal)
+    # where the host waits until the device has drained every segment
+    # streamed so far: the first fetch after their launches
+    with op_scope("engine.sync_wait", timed=True, label=sync_label):
         maxng = int(_max_ngroups(tuple(p[4] for p in filled)))
     cap = bucket(maxng, 64)
     filled = tuple(p[:4] for p in filled)
     merge = SEGMENT_CACHE.get_combine(compiled, cap, filled)
-    with op_scope("engine.combine", partials=nreal, cap=cap):
-        kdat, kval, out_aggs, ngroups = merge(filled, nreal)
-    return _compact_padded(compiled.key_dtypes, kdat, kval, out_aggs,
-                           ngroups, list(agg.keys) + list(agg.names))
+    with op_scope("engine.combine", timed=True, partials=nreal, cap=cap,
+                  **span_stats):
+        return merge(filled, nreal)
+
+
+class StreamedPartials:
+    """The padded partial aggregates of ONE streamed aggregate, as the
+    chunk loop hands them in — straight off the fused agg program, still
+    padded, never synced per chunk.
+
+    A stream of at most ``COMBINE_ARITY`` chunks is merged once, after the
+    stream (``finish``): two host syncs, the sizing fetch and the final
+    ``ngroups`` of the compaction tail, and between them ONE launch of a
+    program whose arity is the partial count's power-of-two bucket (11 and
+    12 chunks share the 16-partial program).
+
+    A longer stream FOLDS as it runs: when ``COMBINE_ARITY`` partials are
+    pending and one more chunk is about to be launched (``make_room``),
+    they are merged into one partial — ``COMBINE_ARITY`` x cap slots, its
+    group count where a chunk's partial has its mask — which takes the
+    first place of the next merge.  So the device never holds more than
+    ``COMBINE_ARITY`` padded partials, and a stream of any length runs two
+    merge programs: 16 padded partials (the first fold: the short
+    stream's program), and 1 merged + 15 padded (every later fold and the
+    final merge, filled with dead repeats).  Each fold pays its own sizing
+    sync (``combine-fold-sizing``), which is what keeps it exact: a key
+    that first shows late in the file, or a merged partial with more
+    groups than any chunk had, raises THAT merge's capacity (another
+    program, never a dropped group).  Sums, counts, minima and maxima
+    merge associatively (``STREAM_COMBINE``), so folding changes no
+    result but the last bits of a float sum whose order matters.
+    """
+
+    __slots__ = ("pending", "compiled", "folds", "held")
+
+    def __init__(self):
+        self.pending: list = []     # a merged partial, if any, comes first
+        self.compiled = None        # the chunk program of the newest
+        self.folds = 0
+        self.held = 0               # most padded partials held at once
+
+    def __len__(self) -> int:
+        return len(self.pending)
+
+    def make_room(self) -> None:
+        """Before a chunk program is launched: fold if the pending
+        partials fill a merge."""
+        if len(self.pending) < COMBINE_ARITY:
+            return
+        self.folds += 1
+        metrics.count("engine.combine.folds")
+        metrics.host_sync(label="combine-fold-sizing")
+        kdat, kval, out_aggs, ngroups = _merge_padded(
+            self.pending, self.compiled, COMBINE_ARITY,
+            "combine-fold-sizing", fold=self.folds, final=0)
+        self.pending = [(kdat, kval, out_aggs, ngroups, ngroups)]
+
+    def add(self, partial: tuple, compiled: CompiledSegment) -> None:
+        self.pending.append(partial)
+        self.compiled = compiled
+        self.held = max(self.held, len(self.pending) - (self.folds > 0))
+
+    def finish(self) -> Table:
+        """The final merge and its compaction: the aggregate's Table."""
+        from ..ops.parquet_decode import bucket
+        metrics.observe("engine.stream.partials_held", self.held)
+        if self.folds:
+            width, stats = COMBINE_ARITY, {"fold": self.folds + 1}
+        else:
+            width, stats = bucket(len(self.pending), 1), {}
+        metrics.host_sync(label="combine-sizing")
+        kdat, kval, out_aggs, ngroups = _merge_padded(
+            self.pending, self.compiled, width, "combine-sizing",
+            final=1, **stats)
+        agg = self.compiled.segment.agg
+        return _compact_padded(self.compiled.key_dtypes, kdat, kval,
+                               out_aggs, ngroups,
+                               list(agg.keys) + list(agg.names))
+
+
+def combine_partials(partials: list, compiled: CompiledSegment) -> Table:
+    """Merge per-chunk padded partial aggregates — ``[(kdat, kval,
+    out_aggs, glive, ngroups), ...]`` off ``compiled`` — into the final
+    Table, as a stream that handed them in one by one would have
+    (``StreamedPartials``)."""
+    acc = StreamedPartials()
+    for p in partials:
+        acc.make_room()
+        acc.add(p, compiled)
+    return acc.finish()
 
 
 # -- whole-stage fusion: the exchange inside the program --------------------

@@ -52,7 +52,8 @@ from .plan import (ORDER_SENSITIVE_AGGS, Aggregate, Exchange, Filter, Join,
 #: any new metrics.host_sync call site outside this whitelist)
 SYNC_WHITELIST = (
     "segment-boundary-compaction",  # run_map_segment's survivor count
-    "combine-sizing",               # combine_partials' max(ngroups) fetch
+    "combine-sizing",               # the final merge's max(ngroups) fetch
+    "combine-fold-sizing",          # the same fetch of a mid-stream fold
     "groupby-compaction",           # _compact_padded's ngroups fetch
     "exchange-counts-sizing",       # hash exchange phase-1 counts fetch
     "exchange-compaction",          # hash exchange ok-mask fetch + compact
@@ -847,8 +848,11 @@ def sync_budget(plan: PlanNode, resolver: Optional[SchemaResolver] = None,
     """Static model of the deliberate host syncs an optimized plan pays —
     one entry per sync, ``site`` naming the whitelisted call site, ``path``
     the stage that pays it.  Charges each stage of ``physical.lower`` what
-    ``physical.SYNC_CHARGES`` says its kind pays, however many chunks
-    stream through; equals the runtime ``engine.host_sync`` counter.
+    ``physical.SYNC_CHARGES`` says its kind pays; equals the runtime
+    ``engine.host_sync`` counter less ``engine.combine.folds`` — a
+    ``stream-agg`` of more than ``segment.COMBINE_ARITY`` chunks pays one
+    ``combine-fold-sizing`` per fold, and how many chunks pruning leaves
+    is known at run time only (16 + 15 k chunks: k folds).
 
     ``ndev`` is the mesh size the stages are lowered for (default: this
     process's — pass it to model a target mesh from a different host).

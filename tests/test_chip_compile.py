@@ -159,6 +159,30 @@ def test_q5_merge_of_streamed_partials(q5_calls, one_chip, tpu_branches):
         real, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
 
 
+def test_q5_fold_of_a_long_stream(q5_calls, one_chip, tpu_branches):
+    """What a stream of more than 16 chunks adds (the benchmark's SF10
+    cell, 108 chunks): the merge of ONE merged partial — a fold's output,
+    16 x 128 slots with its group count where a chunk's partial has its
+    mask — and 15 padded partials of the chunk's bucket, cut to the 128
+    slots that 102 stores need: sorts over 2,048 rows."""
+    from spark_rapids_jni_tpu.engine import segment as seg
+    ((merge, partials),) = q5_calls["merge"]
+    cap, slots = 128, 262_144
+
+    def shaped(rows, tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            (rows,), a.dtype, sharding=one_chip), tree)
+
+    count = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    merged = shaped(seg.COMBINE_ARITY * cap, partials[0][:3]) + (count,)
+    filled = (merged,) + (shaped(slots, partials[0]),) \
+        * (seg.COMBINE_ARITY - 1)
+    assert seg._partial_class(merged)[0] == ("merged", 2_048)
+    compile_for_chip(
+        seg._build_combine_fn(merge.segment.agg, merge.key_dtypes, cap,
+                              merge), filled, count)
+
+
 def test_groupby_padded_chunk(one_chip, tpu_branches):
     from spark_rapids_jni_tpu.ops.aggregate import groupby_padded
 

@@ -36,10 +36,11 @@ ALL_AGGS = [("f", "sum"), ("i", "sum"), ("f", "count"), (None, "count_all"),
             ("i", "min"), ("f", "max")]
 
 
-def eager_combine(partials, compiled):
-    """``combine_partials`` as it was before the merge became a program:
-    the reference.  (The syncs' bookkeeping is left out; the arithmetic is
-    the parent commit's, line for line.)"""
+def eager_merge(partials, compiled):
+    """One merge as ``combine_partials`` made it before the merge became a
+    program: the reference.  (The syncs' bookkeeping is left out; the
+    arithmetic is that commit's, line for line — but for a merged
+    partial's live slots, which come from its group count.)"""
     agg = compiled.segment.agg
     nk = len(agg.keys)
     maxng = int(jnp.max(jnp.stack([jnp.asarray(p[4]) for p in partials])))
@@ -67,7 +68,10 @@ def eager_combine(partials, compiled):
         agg_cols.append(Column(partials[0][2][j].dtype,
                                data=jnp.concatenate(datas),
                                validity=validity))
-    live = jnp.concatenate([cut(p[3]) for p in partials])
+    live = jnp.concatenate([
+        cut(p[3]) if p[3].ndim
+        else jnp.arange(min(cap, p[2][0].data.shape[0])) < p[3]
+        for p in partials])
     knames = [f"k{i}" for i in range(nk)]
     anames = [f"a{j}" for j in range(len(agg.aggs))]
     merged = Table(key_cols + agg_cols, knames + anames)
@@ -77,6 +81,21 @@ def eager_combine(partials, compiled):
         merged, knames, combine, row_mask=live)
     kdat = tuple(spec[2] for spec in out_keys)
     kval = tuple(spec[3] for spec in out_keys)
+    return kdat, kval, tuple(out_aggs), ngroups
+
+
+def eager_combine(partials, compiled):
+    """The eager reference of ``combine_partials``: one merge of up to 16
+    partials; a longer list folds as ``StreamedPartials`` folds a stream
+    (16 pending and one more to come: merged into one, which goes first)."""
+    agg = compiled.segment.agg
+    pending = []
+    for p in partials:
+        if len(pending) == sg.COMBINE_ARITY:
+            kdat, kval, out_aggs, ng = eager_merge(pending, compiled)
+            pending = [(kdat, kval, out_aggs, ng, ng)]
+        pending.append(p)
+    kdat, kval, out_aggs, ngroups = eager_merge(pending, compiled)
     return sg._compact_padded(compiled.key_dtypes, kdat, kval, out_aggs,
                               ngroups, list(agg.keys) + list(agg.names))
 
@@ -145,9 +164,10 @@ def merge_entries():
 @pytest.mark.parametrize("nkeys", [1, 2])
 @pytest.mark.parametrize("count", [1, 2, 11, 12, 17])
 def test_equals_eager_merge(count, nkeys):
-    """Every bucket of the partial count (1, 2, 16, 16, 32 partials in
-    the program), chunks of three row buckets, null keys and null values,
-    every combine op at once."""
+    """Every bucket of the partial count (1, 2, 16, 16 partials in the
+    program; 17 fold: 16, then the merged one and the 17th), chunks of
+    three row buckets, null keys and null values, every combine op at
+    once."""
     seg = segment_for(["k", "k2"][:nkeys], ALL_AGGS)
     partials, compiled = make_partials(seg, count, seed=count * 10 + nkeys,
                                        null_keys=True, null_vals=True)
@@ -178,7 +198,7 @@ def test_each_combine_op(agg, null_vals):
                          ids=["first", "middle", "last", "all"])
 def test_all_dead_partial(dead):
     """A chunk the filter emptied hands in ``ngroups`` 0 and no live slot.
-    As the FIRST partial it is also what the bucket's filler repeats."""
+    As the LAST partial it is also what the bucket's filler repeats."""
     seg = segment_for(["k", "k2"], ALL_AGGS)
     partials, compiled = make_partials(seg, 5, seed=8, dead=dead,
                                        null_keys=True)
