@@ -46,9 +46,11 @@ from ..utils.config import child_environ, config, logger
 from ..utils.errors import TransientError
 
 #: row groups a stream keeps in the pool beyond the one it waits for: a
-#: worker takes about twice as long over a group as the producer takes to
-#: stage one, so two would do and the third is slack; host memory per stream
-#: is (READ_AHEAD + 1) slabs of one decoded group each
+#: worker takes 12-14 ms over a group and the stream asks for one every
+#: 5-6 ms since the staging costs 3 ms and not 6-7 (PERF.md section 6,
+#: PR 37), so three in flight just keep up and the fourth is the slack - the
+#: stream's wait for a group reads 0.8-1.9 ms where it read 0.25-1.7; host
+#: memory per stream is (READ_AHEAD + 1) slabs of one decoded group each
 READ_AHEAD = 3
 #: every buffer in a slab starts on a multiple of this
 ALIGN = 64

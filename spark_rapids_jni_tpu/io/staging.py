@@ -5,8 +5,19 @@ storage->device path off the bounce-buffer critical path).  The scan path
 packs EVERY column buffer (values and validity) into ONE contiguous uint32
 host buffer, ships it in a single ``device_put``, and slices/bitcasts each
 column back out on device in one fused XLA program: one transfer instead
-of one per buffer.  What the single transfer saves on today's machine is
-not measured.
+of one per buffer.
+
+The host pack writes each column once into a reused transfer buffer
+(``_BlobPool``).  For a chunk of three 8-byte columns, 240,034 rows padded
+to the 262,144 bucket (6.29 MB), one process alone on a v5e host
+(PERF.md section 6, PR 37): pack 0.32 ms, the put's call 0.33 ms (it
+returns before the transfer has read the buffer: put + unpack take 3.6 ms
+to finish), the unpack's dispatch 0.39 ms, a whole call 1.66 ms — 4.40 ms
+with the pack that padded by ``concatenate`` and copied through ``tobytes``,
+a ``bytearray`` and ``bytes``, and 65-68 ms against 2.3-2.5 ms with four
+threads at once, since three of those four copies held the interpreter's
+lock.  Inside the server, next to the serve thread, the same call reads
+2.9-3.6 ms (``scan_stage_ms``), 1.0 ms of it the pack.
 
 Word-level unpacking mirrors the row-conversion wire tricks
 (ops/row_conversion.py): 8-byte types rebuild from u32 pairs via the same
@@ -17,6 +28,8 @@ Word-level unpacking mirrors the row-conversion wire tricks
 from __future__ import annotations
 
 import functools
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -24,13 +37,8 @@ import numpy as np
 
 from .. import dtypes as dt
 from ..columnar import Column, Table
-from ..utils import faults
+from ..utils import faults, metrics
 from ..utils.tracing import op_scope
-
-
-def _pad4(b: bytes) -> bytes:
-    r = len(b) % 4
-    return b if r == 0 else b + b"\0" * (4 - r)
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
@@ -69,10 +77,16 @@ def _bucket(n: int) -> int:
     return b
 
 
+def _itemsize(dtype) -> int:
+    return np.dtype(dtype.storage).itemsize if not dtype.is_decimal \
+        else dtype.itemsize
+
+
 def _plan_for(specs):
-    """The (plan, total_words) ``stage_fixed_table`` will use for these
-    specs — computed WITHOUT packing, so callers can ask whether the
-    unpack program is already compiled before paying for the pack."""
+    """The (plan, total_words) ``stage_fixed_table`` uses for these specs —
+    computed WITHOUT packing: the blob's size is known before a buffer is
+    taken for it, and callers can ask whether the unpack program is
+    already compiled before paying for the pack."""
     plan = []
     off = 0
     n_rows = len(specs[0][2]) if specs else 0
@@ -86,10 +100,8 @@ def _plan_for(specs):
         off += wlen
 
     for name, dtype, values, validity in specs:
-        size = np.dtype(dtype.storage).itemsize if not dtype.is_decimal \
-            else dtype.itemsize
-        kind = {8: "w8", 4: "w4", 2: "w2", 1: "w1"}[size]
-        push(size, kind)
+        size = _itemsize(dtype)
+        push(size, {8: "w8", 4: "w4", 2: "w2", 1: "w1"}[size])
         if validity is not None:
             push(1, "w1")
     return tuple(plan), off
@@ -98,7 +110,7 @@ def _plan_for(specs):
 _ready_plans: set = set()
 _warming: set = set()
 _failed_plans: set = set()
-_plans_lock = __import__("threading").Lock()
+_plans_lock = threading.Lock()
 
 
 def plan_ready(specs) -> bool:
@@ -114,7 +126,6 @@ def warm_plan_async(specs) -> None:
     """Compile the staged unpack for these specs on a background thread so
     the NEXT scan of this (schema, row-bucket) takes the single-transfer
     path.  Idempotent; never blocks the caller."""
-    import threading
     plan, total = _plan_for(specs)
     key = (plan, total)
     with _plans_lock:
@@ -153,6 +164,99 @@ def warm_plan_async(specs) -> None:
     threading.Thread(target=work, daemon=False).start()
 
 
+def _pack_into(blob: np.ndarray, specs, plan) -> None:
+    """Write every column of ``specs`` once into its slice of ``blob`` (the
+    layout ``plan`` gives) and zero what the values do not cover: the pad
+    rows up to the bucket and a sub-word tail.  Slice assignment on a typed
+    view converts a strided or byte-swapped input as it copies, and numpy
+    drops the interpreter's lock for copies of this size."""
+    raw = blob.view(np.uint8)
+    entries = iter(plan)
+
+    def write(arr: np.ndarray, itemsize: int):
+        _, off, wlen, _ = next(entries)
+        if arr.dtype.itemsize != itemsize:
+            raise TypeError(f"staging: {arr.dtype} values for a "
+                            f"{itemsize}-byte column")
+        dst = raw[off * 4:(off + wlen) * 4]
+        used = len(arr) * itemsize
+        dst[:used].view(arr.dtype.newbyteorder("="))[:] = arr
+        dst[used:] = 0
+
+    for _, dtype, values, validity in specs:
+        write(np.asarray(values), _itemsize(dtype))
+        if validity is not None:
+            v = np.asarray(validity)
+            write(v if v.dtype.itemsize == 1 else v.astype(np.uint8), 1)
+
+
+#: most bytes the free list keeps, idle buffers and those it waits for
+#: together: 3 chunks in flight per stream (one consumed, one queued, one
+#: packed) x 4 streams x the 8 MiB chunk budget's 6.3 MB blob is 76 MB
+POOL_MAX_BYTES = 128 << 20
+
+
+class _BlobPool:
+    """Process-wide free list of host transfer buffers, keyed by size.
+
+    Process-wide and not per producer thread: every query starts a new
+    producer (a new malloc arena), and what a reused buffer saves is the
+    page faults of 6 MB of fresh memory per chunk.  ``jnp.asarray(host)``
+    may return before the transfer has read the host memory, and on the
+    CPU backend may alias it, so a buffer comes back together with the
+    ``_unpack`` outputs made from it and is handed out again only once
+    they all report ready — polled when the next buffer is asked for,
+    never waited on.  Until then the list keeps those outputs alive: at
+    most one chunk period longer than the stream does, and the last
+    chunks' until the next scan."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._idle: list = []   # buffers nobody uses, oldest first
+        self._lent: list = []   # (buffer, the device arrays made from it)
+        self._bytes = 0         # of both together
+
+    def take(self, words: int):
+        """(buffer of ``words`` uint32, reused?) — the caller's alone until
+        ``give``; one it never gives back is simply forgotten."""
+        with self._lock:
+            pending = []
+            for buf, arrays in self._lent:
+                # `is_ready` of a deleted (donated) array crashes, and a
+                # deleted array says nothing of the transfer: forget it
+                if any(a.is_deleted() for a in arrays):
+                    self._bytes -= buf.nbytes
+                elif all(a.is_ready() for a in arrays):
+                    self._idle.append(buf)
+                else:
+                    pending.append((buf, arrays))
+            self._lent = pending
+            for i in reversed(range(len(self._idle))):
+                if self._idle[i].size == words:
+                    buf = self._idle.pop(i)
+                    self._bytes -= buf.nbytes
+                    return buf, True
+        return np.empty(words, np.uint32), False
+
+    def give(self, buf: np.ndarray, arrays: tuple) -> None:
+        """Back from ``take``, with the device arrays whose readiness says
+        the device is done with it.  Over the cap the idle buffers go
+        first, oldest first; if that is not enough this one does."""
+        with self._lock:
+            while self._bytes + buf.nbytes > POOL_MAX_BYTES and self._idle:
+                self._bytes -= self._idle.pop(0).nbytes
+            if self._bytes + buf.nbytes <= POOL_MAX_BYTES:
+                self._lent.append((buf, arrays))
+                self._bytes += buf.nbytes
+
+    def held_bytes(self) -> int:
+        with self._lock:
+            return self._bytes
+
+
+_pool = _BlobPool()
+
+
 def stage_fixed_table(specs, padded: bool = False):
     """``specs``: list of (name, dtype, values_np, validity_np_or_None) for
     fixed-width dtypes only.  One host pack, ONE device transfer, one fused
@@ -170,44 +274,29 @@ def stage_fixed_table(specs, padded: bool = False):
     if any(dtype.id == dt.TypeId.DECIMAL128 for _, dtype, _, _ in specs):
         raise TypeError("DECIMAL128 staging unsupported; use the "
                         "column-at-a-time path")
-    # pack + the one device_put + the unpack's dispatch; `bytes` is the
-    # blob's size, known from the plan before anything is packed
-    with op_scope("io.scan.stage", timed=True,
-                  bytes=_plan_for(specs)[1] * 4):
+    plan, total_words = _plan_for(specs)
+    n_rows = len(specs[0][2]) if specs else 0
+    blob, reused = _pool.take(total_words)
+    if reused:
+        metrics.count("io.scan.stage.reused")
+    else:
+        metrics.count("io.scan.stage.fresh")
+    # pack + the one device_put + the unpack's dispatch
+    with op_scope("io.scan.stage", timed=True, bytes=total_words * 4,
+                  reused=int(reused)):
         faults.check("staging.transfer")
-        blob = bytearray()
-        plan = []
-        posts = []  # (name, dtype, has_valid, n)
-        n_rows = len(specs[0][2]) if specs else 0
-        bucket = _bucket(n_rows)
-
-        def push(arr: np.ndarray, kind: str):
-            arr = np.ascontiguousarray(arr)
-            if len(arr) < bucket:
-                arr = np.concatenate(
-                    [arr, np.zeros(bucket - len(arr), arr.dtype)])
-            off = len(blob) // 4
-            b = _pad4(arr.tobytes())
-            blob.extend(b)
-            plan.append((kind, off, len(b) // 4, bucket))
-
-        for name, dtype, values, validity in specs:
-            size = np.dtype(dtype.storage).itemsize if not dtype.is_decimal \
-                else dtype.itemsize
-            kind = {8: "w8", 4: "w4", 2: "w2", 1: "w1"}[size]
-            push(values, kind)
-            if validity is not None:
-                push(np.asarray(validity, np.uint8), "w1")
-            posts.append((name, dtype, validity is not None, len(values)))
-
-        words = jnp.asarray(np.frombuffer(bytes(blob), np.uint32))  # ONE put
-        arrays = _unpack(words, tuple(plan))
+        t0 = time.perf_counter()
+        _pack_into(blob, specs, plan)
+        metrics.observe("io.scan.stage.pack_s", time.perf_counter() - t0)
+        words = jnp.asarray(blob)  # ONE put
+        arrays = _unpack(words, plan)
+        _pool.give(blob, arrays)
         with _plans_lock:
-            _ready_plans.add((tuple(plan), len(blob) // 4))
+            _ready_plans.add((plan, total_words))
         cols, names = [], []
         ai = 0
-        for name, dtype, has_valid, n in posts:
-            data = arrays[ai] if padded else arrays[ai][:n]
+        for name, dtype, _, validity in specs:
+            data = arrays[ai] if padded else arrays[ai][:n_rows]
             ai += 1
             storage = jnp.dtype(dtype.device_storage)
             if data.dtype != storage:
@@ -216,9 +305,9 @@ def stage_fixed_table(specs, padded: bool = False):
                 else:
                     data = data.astype(storage)
             valid = None
-            if has_valid:
+            if validity is not None:
                 v = arrays[ai]
-                valid = (v if padded else v[:n]).astype(jnp.bool_)
+                valid = (v if padded else v[:n_rows]).astype(jnp.bool_)
                 ai += 1
             cols.append(Column(dtype, data=data, validity=valid))
             names.append(name)

@@ -251,11 +251,19 @@ def test_each_summary_holds_the_one_client_counts(served, i):
         assert {k: q["counters"].get(k) for k in PER_QUERY} \
             == {k: alone["counters"].get(k) for k in PER_QUERY}
         for name in ("io.scan.decode_s", "io.scan.decode.worker_s",
-                     "io.scan.stage_s",
+                     "io.scan.stage_s", "io.scan.stage.pack_s",
                      "engine.stream.chunk_latency_s", "engine.sync_wait_s",
                      "engine.stream_s", "engine.execute_s"):
             assert q["histograms"][name]["count"] \
                 == alone["histograms"][name]["count"], name
+        # which of the two grows depends on what the other three producers
+        # hold at that moment; their sum is the query's own staged blobs
+        assert q["counters"].get("io.scan.stage.reused", 0) \
+            + q["counters"].get("io.scan.stage.fresh", 0) \
+            == q["histograms"]["io.scan.stage_s"]["count"] >= 11
+    assert _grew(run, "io.scan.stage.reused") \
+        + _grew(run, "io.scan.stage.fresh") \
+        == _hist_grew(run, "io.scan.stage_s")[1]
 
 
 @by_seed
@@ -264,9 +272,12 @@ def test_summaries_add_up_to_the_process_wide_growth(served, i):
     for name in PER_QUERY:
         assert sum(q["counters"].get(name, 0) for q in run["queries"]) \
             == _grew(run, name), name
+    for name in ("io.scan.stage.reused", "io.scan.stage.fresh"):
+        assert sum(q["counters"].get(name, 0) for q in run["queries"]) \
+            == _grew(run, name), name
     for name in ("io.scan.decode_s", "io.scan.decode.worker_s",
-                 "io.scan.stage_s", "engine.sync_wait_s",
-                 "engine.sched.gate_wait_s"):
+                 "io.scan.stage_s", "io.scan.stage.pack_s",
+                 "engine.sync_wait_s", "engine.sched.gate_wait_s"):
         total, count = _hist_grew(run, name)
         mine = [q["histograms"].get(name) or {"sum": 0.0, "count": 0}
                 for q in run["queries"]]

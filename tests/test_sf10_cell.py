@@ -242,6 +242,12 @@ def test_counts_of_a_stream(served, seed, chunks):
         assert held["max"] == min(chunks, ARITY) <= ARITY
         assert c["engine.segment.replay"] \
             + c.get("engine.segment.compile", 0) == chunks
+        # one transfer buffer per staged blob, from the free list or new:
+        # the chunks' and the dimension scans'
+        assert c.get("io.scan.stage.reused", 0) \
+            + c.get("io.scan.stage.fresh", 0) \
+            == h["io.scan.stage_s"]["count"] \
+            == h["io.scan.stage.pack_s"]["count"] >= chunks
     warm = served[seed, chunks]["warm"]["counters"]
     assert warm["engine.combine.replay"] == folds + 1
     assert warm.get("engine.segment_cache.miss", 0) == 0

@@ -133,6 +133,10 @@ def test_one_span_per_unit_of_work(served):
     assert stats["row_groups_read"] == 5 and stats["chunks"] == 9
     assert _hist(q, "io.scan.decode_s")[1] == stats["row_groups_read"]
     assert _hist(q, "io.scan.stage_s")[1] == stats["chunks"]
+    # each staged chunk took one transfer buffer, from the list or new
+    assert _hist(q, "io.scan.stage.pack_s")[1] == stats["chunks"]
+    assert q["counters"].get("io.scan.stage.reused", 0) \
+        + q["counters"].get("io.scan.stage.fresh", 0) == stats["chunks"]
     assert _hist(q, "engine.stream_s")[1] == 1
     assert _hist(q, "engine.stream.first_wait_s")[1] == 1
     assert _hist(q, "engine.post_stream_s")[1] == 1
@@ -221,6 +225,9 @@ def test_producer_thread_spans_carry_trace_id_and_stats(served):
                for r in decodes + stages)
     assert [r["stats"]["group"] for r in decodes] == [0, 1, 2, 3, 4]
     assert all(r["stats"]["bytes"] > 0 for r in decodes + stages)
+    assert sum(r["stats"]["reused"] for r in stages) \
+        == served["query"]["counters"].get("io.scan.stage.reused", 0)
+    assert {r["stats"]["reused"] for r in stages} <= {0, 1}
     # the blob: two nullable 8-byte columns padded to the 8192-row bucket
     assert stages[0]["stats"]["bytes"] == 8192 * (8 + 1) * 2
     waits = [r["stats"]["chunk"] for r in log
