@@ -19,6 +19,7 @@ Run: ``python -m spark_rapids_jni_tpu.bridge.server --socket /tmp/tpub.sock``
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import socket
@@ -495,7 +496,9 @@ class BridgeServer:
         from ..engine import deserialize
         from ..utils import blackbox
         with blackbox.query_scope(trace_id, label="plan_execute") as scope:
-            with op_scope("bridge.plan.decode"):  # outside `wall_s`
+            # outside `wall_s`; timed since PR 38 (`bridge.plan.decode_s`)
+            with op_scope("bridge.plan.decode", timed=True,
+                          bytes=plen) as sp:
                 plan = deserialize(blob)
                 from ..utils.config import config
                 if config.verify:
@@ -505,10 +508,14 @@ class BridgeServer:
                     # (_error_body), not an executor traceback from deep
                     # inside a chunk loop
                     from ..engine import verify
-                    verify(plan)
+                    with op_scope("bridge.plan.verify", timed=True):
+                        verify(plan)
+                from ..engine.plan import topo_nodes
+                sp.stat(nodes=len(topo_nodes(plan)))
             if self._plan_cache is None:
                 from ..engine import PlanCache
                 self._plan_cache = PlanCache()
+            from ..engine.cache import RESULT_CACHE, data_version
             from ..utils import metrics
             from ..utils.config import config as _cfg
             from ..utils.errors import CancelToken
@@ -520,29 +527,45 @@ class BridgeServer:
             tok = CancelToken(_cfg.query_timeout_s or None)
             with self._tokens_lock:
                 self._active_tokens[tok] = scope.trace_id
-            fp = plan.fingerprint()
             try:
-                # plan-cache / result-cache lookups run inside the query
-                # context so their hits/misses are attributed to the query
-                # that caused them (OP_METRICS `queries`)
-                with metrics.query(f"plan:{fp[:12]}") as qm:
-                    if qm is not None:
-                        qm.trace_id = scope.trace_id
-                        # stamp the submitted-plan fingerprint so persisted
-                        # profiles key SLO burn by plan, not "(none)" — the
-                        # admission controller's shed signal depends on it
-                        qm.fingerprint = fp
-                        qm.source_fingerprint = fp
-                    out, version = None, None
-                    from ..engine.cache import RESULT_CACHE, data_version
-                    if RESULT_CACHE.enabled:
-                        # before admission on purpose: a cache hit costs no
-                        # device work, so it serves even when the scheduler
-                        # would queue or shed a real execution
-                        version = data_version(plan)
-                        out = RESULT_CACHE.get(fp, version)
-                        if out is not None:
-                            stats["served_from_cache"] = True
+                # the query context opens inside `engine.plan.prepare` and
+                # outlives it: the stack holds it to the end of the run
+                with contextlib.ExitStack() as running:
+                    # decode's end -> `engine.execute`'s start, without the
+                    # wait for admission (`engine.sched.queue_wait` is its
+                    # span): fingerprint, result-cache probe, plan cache
+                    with op_scope("engine.plan.prepare",
+                                  timed=True) as prepare:
+                        fp = plan.fingerprint()
+                        # plan-cache / result-cache lookups run inside the
+                        # query context so their hits/misses are attributed
+                        # to the query that caused them (OP_METRICS
+                        # `queries`)
+                        qm = running.enter_context(
+                            metrics.query(f"plan:{fp[:12]}"))
+                        if qm is not None:
+                            qm.trace_id = scope.trace_id
+                            # stamp the submitted-plan fingerprint so
+                            # persisted profiles key SLO burn by plan, not
+                            # "(none)" — the admission controller's shed
+                            # signal depends on it
+                            qm.fingerprint = fp
+                            qm.source_fingerprint = fp
+                        out, version = None, None
+                        if RESULT_CACHE.enabled:
+                            # before admission on purpose: a cache hit costs
+                            # no device work, so it serves even when the
+                            # scheduler would queue or shed a real execution
+                            version = data_version(plan)
+                            out = RESULT_CACHE.get(fp, version)
+                            if out is not None:
+                                stats["served_from_cache"] = True
+                        if out is None:
+                            # before admission too (PR 38): a lookup — once
+                            # per plan shape an optimization — that needs no
+                            # session.  `hit`: the shape has executed before
+                            compiled = self._plan_cache.get(plan)
+                            prepare.stat(hit=int(compiled.executions > 0))
                     if out is None:
                         session = None
                         if _cfg.sched:
@@ -550,7 +573,6 @@ class BridgeServer:
                             session = SCHEDULER.admit(
                                 fingerprint=fp, trace_id=scope.trace_id)
                         try:
-                            compiled = self._plan_cache.get(plan)
                             with op_scope("engine.execute", timed=True):
                                 out = compiled.execute(
                                     stats=stats, cancel=tok, session=session)
@@ -709,13 +731,19 @@ class BridgeServer:
         return json.dumps(snap).encode()
 
     def serve_forever(self) -> None:
-        try:
-            os.unlink(self.sock_path)
-        except FileNotFoundError:
-            pass
         srv = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-        srv.bind(self.sock_path)
+        # bound and LISTENING under another name, then renamed: whoever
+        # finds the path can connect (a connect between `bind` and `listen`
+        # is refused, and callers wait for the path to appear)
+        pending = self.sock_path + "~"
+        for stale in (pending, self.sock_path):
+            try:
+                os.unlink(stale)
+            except FileNotFoundError:
+                pass
+        srv.bind(pending)
         srv.listen(16)
+        os.rename(pending, self.sock_path)
         workers: list[threading.Thread] = []
         try:
             while not self._shutdown.is_set():
@@ -772,14 +800,29 @@ class BridgeServer:
         # can't park this worker thread in recv forever.  An idle timeout
         # between requests is not an error — loop and wait again.
         conn.settimeout(_cfg.bridge_timeout_s or None)
+        # `bridge.conn.idle`: reply written -> the next request read, on a
+        # connection that has served one — in a closed loop the client's
+        # turnaround plus the socket.  It stays open across idle timeouts,
+        # so it is entered and left by hand; a peer that leaves ends no
+        # turnaround, and the thread ends with its last wait unobserved.
+        # The wait after a metrics poll is the poller's period: no span
+        idle, last_tid = None, None
         with conn:
             while not self._shutdown.is_set():
+                if idle is None and last_tid is not None:
+                    idle = op_scope("bridge.conn.idle", timed=True,
+                                    trace_id=last_tid)
+                    idle.__enter__()
                 try:
                     opcode, payload, tid, span = P.recv_frame(conn)
                 except socket.timeout:
                     continue  # idle connection; re-check shutdown and wait
                 except ConnectionError:
                     return  # client went away; others keep running
+                if idle is not None:
+                    idle.__exit__(None, None, None)
+                idle = None
+                last_tid = tid if opcode != P.OP_METRICS else None
                 # replies mirror the request's protocol version: a traced
                 # (v2) request gets a traced reply echoing its ids, a v1
                 # request gets a byte-identical-to-before v1 reply — old

@@ -44,7 +44,6 @@ import threading
 import zlib
 from typing import Optional, Tuple
 
-from ..utils import metrics
 from ..utils.config import config
 from .plan import Aggregate, Exchange, Join, PlanNode, topo_nodes
 
@@ -244,7 +243,6 @@ def verify_rewrite(root: Optional[PlanNode], old: PlanNode, new: PlanNode,
         checker = RewriteChecker(root)
         checker.check(rule, _substitute(root, old, new, {}))
     except PlanVerificationError:
-        metrics.count("engine.aqe.verify_rejected")
         return False
     return True
 
@@ -277,7 +275,6 @@ def try_broadcast_flip(node: Exchange, table, root: Optional[PlanNode],
     entry["triggered"] = True
     record(root, entry)
     stats["aqe_flips"] = stats.get("aqe_flips", 0) + 1
-    metrics.count("engine.aqe.broadcast_flips")
     return True
 
 
@@ -344,7 +341,6 @@ def try_skew_split(node: Exchange, counts, ndev: int,
                  salt=split[1], combine=combine_ok)
     entry = record(root, entry)
     stats["aqe_splits"] = stats.get("aqe_splits", 0) + 1
-    metrics.count("engine.aqe.skew_splits")
     return split, cap_rows, entry, combine_ok
 
 

@@ -131,10 +131,13 @@ class _ExecCtx:
     ``recovery``: the query's RecoveryPolicy (retry/degradation ladder +
     cancellation token), checked at every chunk boundary.
     ``stream_end``: ``perf_counter`` where the last streamed chunk loop
-    ended; ``execute`` observes ``engine.post_stream_s`` from it.
+    ended; ``execute`` observes ``engine.post_stream_s`` from it, and
+    ``engine.post_stream.sync_wait_s`` from ``stream_sync_s``, the query's
+    ``engine.sync_wait_s`` at that moment.
     """
 
-    __slots__ = ("physical", "prefetch", "recovery", "stream_end")
+    __slots__ = ("physical", "prefetch", "recovery", "stream_end",
+                 "stream_sync_s")
 
     def __init__(self, physical: PhysicalPlan, prefetch: int,
                  recovery: Optional[RecoveryPolicy] = None):
@@ -143,6 +146,13 @@ class _ExecCtx:
         self.recovery = recovery if recovery is not None \
             else RecoveryPolicy()
         self.stream_end: Optional[float] = None
+        self.stream_sync_s = 0.0
+
+
+def _sync_wait_so_far() -> float:
+    """The bound query's ``engine.sync_wait_s`` up to now."""
+    qm = metrics.current()
+    return qm.hist_sum("engine.sync_wait_s") if qm is not None else 0.0
 
 
 # -- the walk --------------------------------------------------------------
@@ -506,8 +516,6 @@ def _broadcast_exchange(node: Exchange, table: Table) -> Table:
                     straggler_share=0.0, max_dev_rows=table.num_rows,
                     dev_rows=[table.num_rows] * ndev,
                     replica_bytes=wire)
-    if metrics.enabled():
-        metrics.gauge_set("engine.exchange.replica_bytes", float(wire))
     if ndev <= 1:
         return table
     # the exchange's own work, not its child's: the replicated puts
@@ -833,10 +841,16 @@ def _precompute_independent(root: PlanNode, scan: Scan, memo: dict,
     so per-chunk re-walks only redo scan-dependent nodes."""
     from .plan import topo_nodes
     dep: dict = {}
-    for n in topo_nodes(root):
-        if n is not root and not depends_on(n, scan, dep) \
-                and id(n) not in memo:
-            _exec(n, memo, stats, ctx)
+    # the dimension side of a streamed query — its scans (decode in place,
+    # staging), filters and builds — paid before the first fact chunk is
+    # asked for: inside `engine.execute`, before `engine.stream` opens
+    with op_scope("engine.precompute", timed=True) as sp:
+        before = stats["nodes"]
+        for n in topo_nodes(root):
+            if n is not root and not depends_on(n, scan, dep) \
+                    and id(n) not in memo:
+                _exec(n, memo, stats, ctx)
+        sp.stat(nodes=stats["nodes"] - before)
 
 
 def _get_builds(joins: tuple, build_tables: tuple) -> tuple:
@@ -880,15 +894,17 @@ def _exec_streamed(st: Stage, memo: dict, stats: dict, ctx: _ExecCtx,
     from . import segment as sg
 
     agg, scan = st.node, st.scan
-    _precompute_independent(agg.child, scan, memo, stats, ctx)
+    # the scan-independent subtrees are in ``memo`` already:
+    # ``_exec_aggregate`` precomputed them (``engine.precompute``)
     with op_scope("engine.stream", timed=True):
         reader, partials, fused = _stream_chunks(
             agg, scan, None if force_interp else st.segment, memo, stats,
             ctx)
     # what follows, to the end of ``execute``, is ``engine.post_stream``:
     # the final merge of the partials (one program) and every operator
-    # above it
+    # above it; the stream's own waits (a long stream's folds) are behind
     ctx.stream_end = time.perf_counter()
+    ctx.stream_sync_s = _sync_wait_so_far()
     stats["row_groups_pruned"] += reader.groups_pruned
     stats["row_groups_read"] += reader.groups_read
 
@@ -921,16 +937,23 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
     from . import segment as sg
 
     cols = list(scan.columns) if scan.columns else None
-    reader = ParquetChunkedReader(
-        scan.path, pass_read_limit=scan.chunk_bytes,
-        columns=cols, predicate=scan.predicate, prefetch=ctx.prefetch,
-        cancel=ctx.recovery.cancel)
-    stats["streamed"] = True
-    stats["pipelined"] = ctx.prefetch > 0
-    pqm = metrics.current()
-    if pqm is not None:
-        # live-progress denominator from footer metadata (no page decode)
-        pqm.progress_total(reader.footer_chunk_estimate())
+    # footer, row-group pruning, the progress estimate: `engine.stream`'s
+    # start -> the first chunk asked for
+    with op_scope("engine.stream.open", timed=True) as sp:
+        reader = ParquetChunkedReader(
+            scan.path, pass_read_limit=scan.chunk_bytes,
+            columns=cols, predicate=scan.predicate, prefetch=ctx.prefetch,
+            cancel=ctx.recovery.cancel)
+        stats["streamed"] = True
+        stats["pipelined"] = ctx.prefetch > 0
+        pqm = metrics.current()
+        if pqm is not None:
+            # live-progress denominator from footer metadata (no page
+            # decode)
+            pqm.progress_total(reader.footer_chunk_estimate())
+        kept = len(reader.kept_groups())
+        sp.stat(groups=reader.file.num_row_groups,
+                pruned=reader.file.num_row_groups - kept)
 
     partials: list = []          # interpreted path: compacted Tables
     fused = sg.StreamedPartials()   # fused path: padded device partials
@@ -1088,7 +1111,10 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                 partials.extend(_stream_partial(agg, scan, chunk, memo,
                                                 stats, ctx))
     finally:
-        reader.close()
+        # the last chunk is dispatched (its `wait_reader` saw the end
+        # mark): what is left is the producer's join
+        with op_scope("engine.stream.close", timed=True):
+            reader.close()
     return reader, partials, fused
 
 
@@ -1445,4 +1471,8 @@ def execute(plan: PlanNode | PhysicalPlan, stats: Optional[dict] = None,
             # it is a stamped interval and no ``with`` block
             metrics.observe("engine.post_stream_s",
                             time.perf_counter() - ctx.stream_end)
+            # ... and how much of it the host stood blocked on the device:
+            # the waits stamped after the stream's end, the folds' not
+            metrics.observe("engine.post_stream.sync_wait_s",
+                            _sync_wait_so_far() - ctx.stream_sync_s)
     return out

@@ -1252,15 +1252,14 @@ class CompiledFusedStage:
         timeline.complete(f"engine.fused_stage.{kind}", t0, dt)
         if metrics.enabled():
             metrics.count(f"engine.fused_stage.{kind}")
-            if kind == "compile":
-                metrics.observe("engine.fused_stage.trace_s", dt)
         return out
 
 
 class FusedStageCache:
     """LRU: (stage fingerprint, input shape-class, ndev, axis) ->
-    CompiledFusedStage.  Counters flow through ``utils.tracing`` as
-    ``engine.fused_stage_cache.{hit,miss,eviction}``; sized by the same
+    CompiledFusedStage.  A miss — a compile — is the counter
+    ``engine.fused_stage_cache.miss`` (``stats()`` has hits and evictions:
+    no reader asked for them as counters); sized by the same
     SRJT_SEGMENT_CACHE knob as the segment cache (both hold compiled
     executables keyed by shape-class)."""
 
@@ -1291,7 +1290,6 @@ class FusedStageCache:
             if hit is not None:
                 self._entries.move_to_end(key)
                 self.hits += 1
-                metrics.count("engine.fused_stage_cache.hit")
                 return hit
         in_dtypes = tuple(c.dtype for c in padded.columns)
         key_dtypes = tuple(padded.column(k).dtype
@@ -1304,7 +1302,6 @@ class FusedStageCache:
             if racer is not None:
                 self._entries.move_to_end(key)
                 self.hits += 1
-                metrics.count("engine.fused_stage_cache.hit")
                 return racer
             self.misses += 1
             metrics.count("engine.fused_stage_cache.miss")
@@ -1312,7 +1309,6 @@ class FusedStageCache:
             while len(self._entries) > self.maxsize:
                 self._entries.popitem(last=False)
                 self.evictions += 1
-                metrics.count("engine.fused_stage_cache.eviction")
             return compiled
 
     def __len__(self) -> int:

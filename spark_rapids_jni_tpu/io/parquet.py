@@ -1237,9 +1237,6 @@ class DevicePageChunk:
         """Transfer the planes; returns the jnp pytree decode_table eats."""
         faults.check("parquet.device_decode")
         metrics.count("io.device_decode.chunks")
-        metrics.count("io.device_decode.link_bytes", int(self.comp_bytes))
-        metrics.count("io.device_decode.uncompressed_bytes",
-                      int(self.unc_bytes))
         return {name: {k: jnp.asarray(v) for k, v in planes.items()}
                 for name, planes in self.planes.items()}
 
@@ -1470,6 +1467,7 @@ class ParquetChunkedReader:
         # never closes its iterator, which would leave the producer thread
         # parked on the bounded queue until GC; ``close()`` reaps them
         self._active: list = []
+        self._kept: list | None = None      # `kept_groups`
         # what a decode pool's slab would hold of a row: None for a schema
         # it cannot carry (`_offload`)
         picked = [self.file.schema[i]
@@ -1505,12 +1503,19 @@ class ParquetChunkedReader:
         executor publishes this as the query's live-progress
         ``chunks_total``; it is an estimate, not a promise."""
         total = 0
-        for gi in range(self.file.num_row_groups):
-            if self._group_pruned(gi):
-                continue
+        for gi in self.kept_groups():
             nbytes = int(self.file.row_groups[gi].total_byte_size or 0)
             total += max(1, -(-nbytes // self.limit))
         return total
+
+    def kept_groups(self) -> list:
+        """The row groups the predicate keeps, by footer statistics alone;
+        walked once per reader (the progress estimate and the stats of
+        ``engine.stream.open`` both ask)."""
+        if self._kept is None:
+            self._kept = [gi for gi in range(self.file.num_row_groups)
+                          if not self._group_pruned(gi)]
+        return self._kept
 
     def _group_pruned(self, gi: int) -> bool:
         if self.predicate is None:
@@ -1595,20 +1600,16 @@ class ParquetChunkedReader:
             # from the footer
             with op_scope("io.scan.decode", timed=True, group=gi,
                           bytes=int(self.file.row_groups[gi].total_byte_size
-                                    or 0)):
+                                    or 0)) as sp:
                 hosts, worker_s = retry_call(
                     lambda: self._decode_group_checked(gi, tally, lease),
                     "parquet.chunk", cancel=self.cancel)
-                # a span is given its stats when it opens and what the
-                # decode walked is known only now: it rides on an empty
-                # child span, the last thing inside the decode's
-                # (`worker_ms`: the worker's own time; absent: decoded here)
-                with op_scope("io.scan.decode.walked", group=gi,
-                              pages=tally.pages, runs=tally.runs,
-                              dense=f"{tally.dense_chunks}/{tally.chunks}",
-                              **({} if worker_s is None else
-                                 {"worker_ms": round(worker_s * 1e3, 3)})):
-                    pass
+                # what the decode walked is known only now (`worker_ms`:
+                # the worker's own time; absent: decoded here)
+                sp.stat(pages=tally.pages, runs=tally.runs,
+                        dense=f"{tally.dense_chunks}/{tally.chunks}",
+                        **({} if worker_s is None else
+                           {"worker_ms": round(worker_s * 1e3, 3)}))
             nrows = hosts[0].num_rows
             if nrows == 0:
                 return

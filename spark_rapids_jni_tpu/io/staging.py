@@ -38,6 +38,7 @@ import numpy as np
 from .. import dtypes as dt
 from ..columnar import Column, Table
 from ..utils import faults, metrics
+from ..utils.config import config
 from ..utils.tracing import op_scope
 
 
@@ -285,9 +286,17 @@ def stage_fixed_table(specs, padded: bool = False):
     with op_scope("io.scan.stage", timed=True, bytes=total_words * 4,
                   reused=int(reused)):
         faults.check("staging.transfer")
+        # pure host copying, so `pack_s`'s sum - cpu_sum is the producer
+        # standing in line for the interpreter.  Observed by hand, not a
+        # span: a millisecond-long annotation on this thread took the
+        # consumer's launches from `engine.fused_segment` in the
+        # benchmark's thread-blind naming (PERF.md section 6, PR 38)
         t0 = time.perf_counter()
+        c0 = time.thread_time() if config.trace else None  # as a timed span
         _pack_into(blob, specs, plan)
-        metrics.observe("io.scan.stage.pack_s", time.perf_counter() - t0)
+        cpu = None if c0 is None else time.thread_time() - c0
+        metrics.observe("io.scan.stage.pack_s", time.perf_counter() - t0,
+                        cpu)
         words = jnp.asarray(blob)  # ONE put
         arrays = _unpack(words, plan)
         _pool.give(blob, arrays)

@@ -30,7 +30,8 @@ Threading: the active query context is a thread-local; code that fans work
 out to helper threads (the chunked reader's prefetch producer) captures
 ``current()`` and re-enters it with ``bind(qm)`` so producer-side metrics
 still attribute to the query that spawned them.  ``QueryMetrics`` carries
-its own lock, so attribution from any bound thread is safe.
+its own lock (its histograms ride the registry's: one ``observe`` takes one
+lock for both), so attribution from any bound thread is safe.
 """
 
 from __future__ import annotations
@@ -67,7 +68,14 @@ _progress: dict[int, "QueryMetrics"] = {}
 _RECENT_LIMIT = 32
 _recent: "deque[dict]" = deque(maxlen=_RECENT_LIMIT)
 
-_tls = threading.local()
+
+class _Bound(threading.local):
+    """The query bound to a thread; a class default, because a lookup that
+    misses on a ``threading.local`` costs ten times one that hits."""
+    q = None
+
+
+_tls = _Bound()
 _qids = itertools.count(1)
 
 
@@ -81,21 +89,32 @@ def _bucket_le(value: float) -> float:
     v = float(value)
     if v <= 0.0:
         return 0.0
-    return 2.0 ** math.ceil(math.log2(v))
+    m, e = math.frexp(v)        # v == m * 2**e, 0.5 <= m < 1: no rounding
+    return math.ldexp(1.0, e - 1 if m == 0.5 else e)
 
 
-def _hist_add(hists: dict, name: str, value: float) -> None:
+def _hist_add(hists: dict, name: str, v: float, le: float,
+              cpu: float | None = None) -> None:
+    """One observation of ``v`` (a float; ``le``: its bucket).  ``cpu``: the
+    observing thread's CPU seconds over the same stretch (a timed
+    ``op_scope``), summed as ``cpu_sum`` beside ``sum``."""
     h = hists.get(name)
     if h is None:
-        h = hists[name] = {"count": 0, "sum": 0.0,
-                           "min": None, "max": None, "buckets": {}}
-    v = float(value)
+        hists[name] = h = {"count": 1, "sum": v, "min": v, "max": v,
+                           "buckets": {le: 1}}
+        if cpu is not None:
+            h["cpu_sum"] = cpu
+        return
     h["count"] += 1
     h["sum"] += v
-    h["min"] = v if h["min"] is None else min(h["min"], v)
-    h["max"] = v if h["max"] is None else max(h["max"], v)
-    le = _bucket_le(v)
-    h["buckets"][le] = h["buckets"].get(le, 0) + 1
+    if cpu is not None:
+        h["cpu_sum"] = h.get("cpu_sum", 0.0) + cpu
+    if v < h["min"]:
+        h["min"] = v
+    elif v > h["max"]:
+        h["max"] = v
+    b = h["buckets"]
+    b[le] = b.get(le, 0) + 1
 
 
 def _hist_percentiles(h: dict, qs=(0.5, 0.9, 0.99)) -> dict:
@@ -135,17 +154,23 @@ def _hist_dump(h: dict) -> dict:
     plus ``sum``/``count`` (and the derived ``mean`` and p50/p90/p99) so
     consumers of the OP_METRICS reply compute averages and tails without
     re-deriving from power-of-two bucket midpoints."""
-    return {"count": h["count"], "sum": h["sum"],
-            "mean": (h["sum"] / h["count"]) if h["count"] else None,
-            "min": h["min"], "max": h["max"],
-            **_hist_percentiles(h),
-            "buckets": sorted([le, n] for le, n in h["buckets"].items())}
+    out = {"count": h["count"], "sum": h["sum"],
+           "mean": (h["sum"] / h["count"]) if h["count"] else None,
+           "min": h["min"], "max": h["max"],
+           **_hist_percentiles(h),
+           "buckets": sorted([le, n] for le, n in h["buckets"].items())}
+    if "cpu_sum" in h:      # a timed span's histogram (tracing.op_scope)
+        out["cpu_sum"] = h["cpu_sum"]
+    return out
 
 
 def _hist_load(d: dict) -> dict:
-    return {"count": d["count"], "sum": d["sum"],
-            "min": d["min"], "max": d["max"],
-            "buckets": {float(le): n for le, n in d["buckets"]}}
+    h = {"count": d["count"], "sum": d["sum"],
+         "min": d["min"], "max": d["max"],
+         "buckets": {float(le): n for le, n in d["buckets"]}}
+    if "cpu_sum" in d:
+        h["cpu_sum"] = d["cpu_sum"]
+    return h
 
 
 def q_error(est, actual) -> float | None:
@@ -218,8 +243,15 @@ class QueryMetrics:
             self.counters[name] = self.counters.get(name, 0) + n
 
     def observe(self, name: str, value: float) -> None:
-        with self._lock:
-            _hist_add(self.hists, name, value)
+        v = float(value)
+        with _lock:     # the histograms' one lock, this query's too
+            _hist_add(self.hists, name, v, _bucket_le(v))
+
+    def hist_sum(self, name: str) -> float:
+        """What histogram ``name`` has summed up so far (0.0: nothing)."""
+        with _lock:
+            h = self.hists.get(name)
+            return h["sum"] if h is not None else 0.0
 
     def add_time(self, name: str, dt: float) -> None:
         with self._lock:
@@ -332,6 +364,8 @@ class QueryMetrics:
 
     def summary(self) -> dict:
         """JSON-ready snapshot (safe to call live or after ``finish``)."""
+        with _lock:     # `observe` writes them under this one
+            hists = {k: _hist_dump(h) for k, h in self.hists.items()}
         with self._lock:
             wall = self.wall_s if self.wall_s is not None \
                 else time.perf_counter() - self.t0
@@ -343,8 +377,7 @@ class QueryMetrics:
                    "counters": dict(self.counters),
                    "timers": {k: round(v, 6)
                               for k, v in self.timers.items()},
-                   "histograms": {k: _hist_dump(h)
-                                  for k, h in self.hists.items()},
+                   "histograms": hists,
                    "nodes": nodes}
             if self.fingerprint:
                 out["fingerprint"] = self.fingerprint
@@ -365,7 +398,7 @@ class QueryMetrics:
 
 def current() -> QueryMetrics | None:
     """The query context bound to this thread (None outside any query)."""
-    return getattr(_tls, "q", None)
+    return _tls.q
 
 
 @contextlib.contextmanager
@@ -391,7 +424,8 @@ def query(name: str = ""):
             # process-wide, so a reader can subtract every query's wall
             # time from what encloses it (Σ `bridge.op.*_s`) however many
             # clients ran and however few summaries `_recent` still holds
-            _hist_add(_hists, "engine.query.wall_s", qm.wall_s)
+            _hist_add(_hists, "engine.query.wall_s", qm.wall_s,
+                      _bucket_le(qm.wall_s))
         if config.profile_dir:
             # persist one compact profile per query (utils/profile.py);
             # profile IO must never fail the query it describes
@@ -439,15 +473,19 @@ def count(name: str, n: int = 1) -> int:
     return v
 
 
-def observe(name: str, value: float) -> None:
-    """Record ``value`` into histogram ``name`` (global + active query)."""
+def observe(name: str, value: float, cpu: float | None = None) -> None:
+    """Record ``value`` into histogram ``name`` (global + active query),
+    one lock for both; ``cpu``: the thread's CPU seconds beside a timed
+    span's wall seconds (``tracing.op_scope``)."""
     if not config.metrics:
         return
+    v = float(value)
+    le = _bucket_le(v)
+    q = _tls.q
     with _lock:
-        _hist_add(_hists, name, value)
-    q = current()
-    if q is not None:
-        q.observe(name, value)
+        _hist_add(_hists, name, v, le, cpu)
+        if q is not None:
+            _hist_add(q.hists, name, v, le, cpu)
 
 
 def time_add(name: str, dt: float) -> None:
@@ -560,10 +598,11 @@ def progress_snapshot() -> list:
         live = list(_progress.values())
     out = []
     for qm in sorted(live, key=lambda q: q.qid):
-        with qm._lock:
-            p = dict(qm.progress)
+        with _lock:
             h = qm.hists.get("engine.stream.chunk_latency_s")
             p50 = _hist_percentiles(h, (0.5,))["p50"] if h else None
+        with qm._lock:
+            p = dict(qm.progress)
             entry = {"qid": qm.qid, "name": qm.name,
                      "key": qm.trace_id or f"qid:{qm.qid}",
                      "fingerprint": qm.fingerprint,

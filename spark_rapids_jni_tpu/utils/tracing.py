@@ -10,7 +10,9 @@ per-op spans.  The TPU equivalents:
 - ``jax.profiler.TraceAnnotation`` — runtime spans on the host timeline,
   enabled by ``SRJT_TRACE=1`` (visible in Perfetto via ``profile()``);
   ``op_scope(name, timed=True, **stats)`` also times the span into the
-  histogram ``<name>_s`` and tags the annotation with the query's trace id.
+  histogram ``<name>_s`` (wall seconds; under ``SRJT_TRACE=1`` the thread's
+  CPU seconds beside them), tags the annotation with the query's trace id,
+  and hands out the open span for late stats.
 - ``profile(logdir)`` — capture a full device trace
   (``jax.profiler.trace``), the Nsight-session analog.
 - ``count(name)`` / ``counters_snapshot()`` — lightweight named event
@@ -32,43 +34,107 @@ from . import timeline
 from .config import config
 
 
-@contextlib.contextmanager
-def op_scope(name: str, timed: bool = False, **stats):
+class op_scope:
     """Named scope + (when SRJT_TRACE=1) a host profiler annotation +
     (when SRJT_TIMELINE=1) a span in the in-process event timeline —
     one call site, three observability sinks on the same name.
 
-    ``timed=True`` adds a fourth while ``SRJT_METRICS`` is on: one
-    ``perf_counter`` pair whose difference is observed as the histogram
-    ``<name>_s``, process-wide and in the query bound to this thread
-    (``metrics.bind`` carries it onto helper threads).
+    ``timed=True`` adds a fourth while ``SRJT_METRICS`` is on: the span's
+    wall seconds (``perf_counter``) go into the histogram ``<name>_s`` —
+    ``count`` and ``sum``, process-wide and in the query bound to this
+    thread (``metrics.bind`` carries it onto helper threads) — and, under
+    ``SRJT_TRACE=1``, its thread's CPU seconds (``thread_time``) with them
+    as ``cpu_sum``, in the same ONE observation.  ``sum - cpu_sum`` is the
+    time the thread was not running: the device for ``engine.sync_wait``,
+    the worker for ``io.scan.decode``, the interpreter's lock for pure
+    host work.
 
     ``stats`` (``chunk=3``, ``label="combine-sizing"``, ``bytes=...``) go
     to the annotation as event stats, together with ``trace_id`` of the
     bound query: the spans of one request share it on every thread, and
-    all of them lie on the profiler's clock beside the device's ops."""
-    with contextlib.ExitStack() as stack:
-        stack.enter_context(jax.named_scope(name))
+    all of them lie on the profiler's clock beside the device's ops.
+    ``with op_scope(...) as sp:`` hands out the open span: ``sp.stat(**kv)``
+    adds stats known only later (what a decode walked, how many groups a
+    footer pruned) to the annotation and to the timeline's span; with both
+    sinks off it does nothing.
+
+    A plain class, no generator and no ``ExitStack``: some ten of these
+    open per streamed chunk under the interpreter's lock."""
+
+    __slots__ = ("name", "_timed", "_stats", "_scope", "_live", "_ann",
+                 "_tl0", "_t0", "_c0")
+
+    def __init__(self, name: str, timed: bool = False, **stats):
+        self.name = name
+        self._timed = timed
+        self._stats = stats
+
+    def __enter__(self) -> "op_scope":
+        self._scope = scope = jax.named_scope(self.name)
+        scope.__enter__()
+        if config.trace or config.timeline \
+                or (self._timed and config.metrics):
+            self._open_sinks()
+        else:
+            self._live = False      # the common case: two attribute writes
+        return self
+
+    def _open_sinks(self) -> None:
+        self._live = True
+        self._ann = self._tl0 = self._t0 = None
         if config.trace:
             from . import blackbox  # lazy: blackbox -> metrics -> here
             # a caller outside any query scope (the bridge's dispatch)
             # names the trace itself; "" there means a client without one
-            ann = {**stats, "trace_id": stats.get("trace_id")
-                   or blackbox.current_trace()}
-            if not ann["trace_id"]:
-                del ann["trace_id"]
-            stack.enter_context(jax.profiler.TraceAnnotation(name, **ann))
+            ann = dict(self._stats)
+            trace_id = ann.pop("trace_id", "") or blackbox.current_trace()
+            if trace_id:
+                ann["trace_id"] = trace_id
+            self._ann = jax.profiler.TraceAnnotation(self.name, **ann)
+            self._ann.__enter__()
         if config.timeline:
-            stack.enter_context(timeline.span(name, stats))
-        if not (timed and config.metrics):
-            yield
+            self._tl0 = time.perf_counter()
+        if self._timed and config.metrics:
+            # the CPU stretch lies inside the wall stretch: cpu <= wall.
+            # Read only while a trace is kept: the thread's CPU clock is a
+            # system call (6 us on the chip's host, 0.3 us elsewhere)
+            self._t0 = time.perf_counter()
+            self._c0 = time.thread_time() if config.trace else None
+
+    def stat(self, **kv) -> None:
+        """Add stats to the OPEN span, in whichever sinks are on."""
+        if not self._live:
             return
-        from . import metrics  # lazy: metrics imports this module
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            metrics.observe(f"{name}_s", time.perf_counter() - t0)
+        if self._ann is not None:
+            self._ann.set_metadata(**kv)
+        if self._tl0 is not None:
+            self._stats.update(kv)
+
+    def _close_sinks(self, exc_type, exc, tb) -> None:
+        if self._t0 is not None:
+            # wall and CPU seconds in ONE observation: one call, one lock
+            cpu = None if self._c0 is None \
+                else time.thread_time() - self._c0
+            _observe(self.name + "_s", time.perf_counter() - self._t0, cpu)
+        if self._tl0 is not None:
+            timeline.complete(self.name, self._tl0,
+                              time.perf_counter() - self._tl0, self._stats)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._live:
+            self._close_sinks(exc_type, exc, tb)
+        self._scope.__exit__(exc_type, exc, tb)
+        return False
+
+
+def _observe(name: str, seconds: float, cpu: float | None) -> None:
+    """``metrics.observe``; that module imports this one, so the name is
+    bound at the first timed span."""
+    global _observe
+    from .metrics import observe as _observe
+    _observe(name, seconds, cpu)
 
 
 def traced(name: str):

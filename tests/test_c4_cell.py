@@ -285,6 +285,43 @@ def test_summaries_add_up_to_the_process_wide_growth(served, i):
         assert sum(h["sum"] for h in mine) == pytest.approx(total, rel=1e-6)
 
 
+REQUEST_SPANS = ("engine.plan.prepare_s", "engine.precompute_s",
+                 "engine.stream.open_s", "engine.stream.close_s",
+                 "engine.post_stream.sync_wait_s")
+
+
+@by_seed
+def test_each_request_keeps_its_own_path_counts(served, i):
+    """PR 38's spans under four clients: once per request in its own
+    summary, 12 process-wide, and a wait for the client per turnaround of
+    each connection."""
+    run = served[i]
+    for q in [run["alone_query"]] + run["queries"]:
+        for name in REQUEST_SPANS:
+            assert q["histograms"][name]["count"] == 1, name
+        tail_wait = q["histograms"]["engine.post_stream.sync_wait_s"]["sum"]
+        assert tail_wait == pytest.approx(
+            q["histograms"]["engine.sync_wait_s"]["sum"], abs=1e-9)
+        for name, h in q["histograms"].items():
+            if "cpu_sum" in h:
+                assert 0 <= h["cpu_sum"] <= h["sum"] + 1e-6, name
+        pre, stream, tail, whole = (q["histograms"][n]["sum"] for n in (
+            "engine.precompute_s", "engine.stream_s", "engine.post_stream_s",
+            "engine.execute_s"))
+        assert pre + stream + tail <= whole <= q["wall_s"]
+    for name in ("bridge.plan.decode_s", "bridge.plan.verify_s",
+                 "engine.plan.prepare_s", "engine.precompute_s",
+                 "bridge.op.plan_execute_s"):
+        assert _hist_grew(run, name)[1] == CLIENTS * ROUNDS, name
+    # execute, export, free, release per query; a connection's first
+    # request follows no reply, and the polls' own waits are none
+    assert _hist_grew(run, "bridge.conn.idle_s")[1] \
+        == CLIENTS * (4 * ROUNDS - 1)
+    # no trace is kept in this child: no span read the thread's CPU clock
+    assert not [name for name, h in run["after"]["histograms"].items()
+                if "cpu_sum" in h]
+
+
 # -- (d) the scheduler's waits as spans ------------------------------------------
 
 class _Annotation:
@@ -296,6 +333,9 @@ class _Annotation:
     def __init__(self, name, **stats):
         self.rec = {"name": name, "stats": stats,
                     "thread": threading.get_ident()}
+
+    def set_metadata(self, **stats):
+        self.rec["stats"].update(stats)
 
     def __enter__(self):
         self.rec["t0"] = time.perf_counter()
@@ -602,7 +642,7 @@ def test_the_cell_is_the_year_cell_sent_by_four_clients():
     # the four accepted cells instead (PERF.md section 3)
     silent = {"bridge_overhead_ms", "post_stream_launches"}
     assert new <= mine and not silent & mine
-    assert "bridge_server_ms" in mine and len(mine) == 17
+    assert "bridge_server_ms" in mine and len(mine) == 17 + 6    # PR 38's six
     for other in ("q5lite_sf1_year", "q55lite_sf1_nov1999",
                   "q5lite_sf1_14day", "q5lite_sf1_mesh4"):
         theirs = {m["name"] for m in run.Cell(other).metrics("per_layer")}

@@ -303,6 +303,9 @@ class _Annotation:
     def __init__(self, name, **stats):
         self.rec = {"name": name, "stats": stats}
 
+    def set_metadata(self, **stats):
+        self.rec["stats"].update(stats)
+
     def __enter__(self):
         return self
 

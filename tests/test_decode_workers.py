@@ -284,13 +284,19 @@ def test_small_groups_strings_and_the_unstaged_iteration_decode_here(
     assert (grew["offloaded"], grew["inline"]) == (0, 12)
 
 
-def test_walked_span_says_who_decoded(pool, warehouses, monkeypatch):
+def test_decode_span_says_who_decoded(pool, warehouses, monkeypatch):
+    """``io.scan.decode`` itself carries what its decode walked and who
+    did it (``worker_ms``: a worker), set through the open span's handle."""
     import jax
     log = []
 
     class Annotation:
         def __init__(self, name, **stats):
+            self.stats = stats
             log.append((name, stats))
+
+        def set_metadata(self, **stats):
+            self.stats.update(stats)
 
         def __enter__(self):
             return self
@@ -308,14 +314,14 @@ def test_walked_span_says_who_decoded(pool, warehouses, monkeypatch):
     finally:
         monkeypatch.undo()
         cfg.refresh()
-    walked = [s for n, s in log if n == "io.scan.decode.walked"]
+    assert not [s for n, s in log if n == "io.scan.decode.walked"]
     spans = [s for n, s in log if n == "io.scan.decode"]
-    assert len(walked) == len(spans) == 13
+    assert len(spans) == 13
     assert [s["group"] for s in spans] == list(range(12)) + [0]
-    for s in walked[:12]:
+    for s in spans[:12]:
         assert s["dense"] == "3/3" and s["pages"] >= 3
         assert 0 < s["worker_ms"] < 10_000
-    assert "worker_ms" not in walked[12] and walked[12]["dense"] == "2/2"
+    assert "worker_ms" not in spans[12] and spans[12]["dense"] == "2/2"
 
 
 # -- what goes wrong ---------------------------------------------------------------

@@ -209,6 +209,9 @@ class _Annotation:
         self.rec = {"name": name, "stats": stats,
                     "thread": threading.get_ident()}
 
+    def set_metadata(self, **stats):
+        self.rec["stats"].update(stats)
+
     def __enter__(self):
         self.rec["t0"] = time.perf_counter()
         return self

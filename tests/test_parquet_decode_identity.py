@@ -203,15 +203,20 @@ def test_streamed_fact_scan_counts_three_dense_chunks_a_group(warehouses,
 
 
 def test_decode_span_carries_what_the_decode_walked(warehouses, monkeypatch):
-    """Under ``SRJT_TRACE=1`` every ``io.scan.decode`` span ends with the
-    empty child ``io.scan.decode.walked`` whose stats say what engaged:
-    ``runs``, ``pages`` and ``dense=<n>/<chunks>`` of that row group."""
+    """Under ``SRJT_TRACE=1`` every ``io.scan.decode`` span is given, when
+    the decode ends, what engaged: ``runs``, ``pages`` and
+    ``dense=<n>/<chunks>`` of that row group — through the open span's
+    handle (``set_metadata``), not on a child span of its own."""
     import jax
     log = []
 
     class Annotation:
         def __init__(self, name, **stats):
-            self.rec = {"name": name, "stats": stats}
+            self.rec = {"name": name, "stats": stats, "late": {}}
+
+        def set_metadata(self, **stats):
+            assert "t0" in self.rec and "t1" not in self.rec    # while open
+            self.rec["late"].update(stats)
 
         def __enter__(self):
             self.rec["t0"] = time.perf_counter()
@@ -234,14 +239,14 @@ def test_decode_span_carries_what_the_decode_walked(warehouses, monkeypatch):
         cfg.refresh()
     grew = _grew(before)
     decodes = [r for r in log if r["name"] == "io.scan.decode"]
-    walked = [r for r in log if r["name"] == "io.scan.decode.walked"]
-    assert len(slices) == len(decodes) == len(walked) == 12
-    for outer, inner in zip(decodes, walked):
-        assert outer["t0"] <= inner["t0"] <= inner["t1"] <= outer["t1"]
-        assert inner["stats"]["group"] == outer["stats"]["group"]
-        assert inner["stats"]["dense"] == "3/3"
-    assert sum(r["stats"]["runs"] for r in walked) == grew["runs"]
-    assert sum(r["stats"]["pages"] for r in walked) == grew["pages"]
+    assert not [r for r in log if r["name"] == "io.scan.decode.walked"]
+    assert len(slices) == len(decodes) == 12
+    for gi, r in enumerate(decodes):
+        assert r["stats"]["group"] == gi and r["stats"]["bytes"] > 0
+        assert sorted(r["late"]) == ["dense", "pages", "runs"]
+        assert r["late"]["dense"] == "3/3"
+    assert sum(r["late"]["runs"] for r in decodes) == grew["runs"]
+    assert sum(r["late"]["pages"] for r in decodes) == grew["pages"]
 
 
 # -- page shapes ---------------------------------------------------------------------

@@ -248,6 +248,20 @@ def test_counts_of_a_stream(served, seed, chunks):
             + c.get("io.scan.stage.fresh", 0) \
             == h["io.scan.stage_s"]["count"] \
             == h["io.scan.stage.pack_s"]["count"] >= chunks
+        # PR 38: the tail's own waits, without the folds' (inside the stream)
+        tail_wait = h["engine.post_stream.sync_wait_s"]
+        assert tail_wait["count"] == 1
+        if folds:
+            assert 0 <= tail_wait["sum"] < h["engine.sync_wait_s"]["sum"]
+        else:
+            assert tail_wait["sum"] == pytest.approx(
+                h["engine.sync_wait_s"]["sum"], abs=1e-9)
+        assert tail_wait["sum"] <= h["engine.post_stream_s"]["sum"]
+        for name in ("engine.precompute_s", "engine.stream.open_s",
+                     "engine.stream.close_s", "engine.plan.prepare_s"):
+            assert h[name]["count"] == 1, name
+        assert h["engine.precompute_s"]["sum"] + h["engine.stream_s"]["sum"] \
+            + h["engine.post_stream_s"]["sum"] <= h["engine.execute_s"]["sum"]
     warm = served[seed, chunks]["warm"]["counters"]
     assert warm["engine.combine.replay"] == folds + 1
     assert warm.get("engine.segment_cache.miss", 0) == 0
@@ -473,6 +487,9 @@ class _Annotation:
     def __init__(self, name, **stats):
         self.rec = {"name": name, "stats": stats}
 
+    def set_metadata(self, **stats):
+        self.rec["stats"].update(stats)
+
     def __enter__(self):
         return self
 
@@ -535,6 +552,48 @@ def test_fold_spans(tmp_path, monkeypatch):
     assert s["counters"]["engine.combine.folds"] == 2
     assert s["histograms"]["engine.combine_s"]["count"] == 3
     assert s["histograms"]["engine.stream.partials_held"]["max"] == ARITY
+
+
+@pytest.mark.parametrize("chunks", [17, 108])
+def test_tail_waits_are_all_waits_less_the_folds(tmp_path, monkeypatch,
+                                                 chunks):
+    """`engine.post_stream.sync_wait_s` is `engine.sync_wait_s` less exactly
+    the waits observed before the stream ended — one per fold."""
+    rng = np.random.default_rng(chunks)
+    n = chunks * 128
+    path = str(tmp_path / "fact.parquet")
+    pq.write_table(pa.table({
+        "k": pa.array(rng.integers(0, 9, n).astype(np.int64)),
+        "i": pa.array(rng.integers(-100, 100, n).astype(np.int64)),
+    }), path, row_group_size=128)
+    plan = optimize(Aggregate(Scan(path, chunk_bytes=1 << 20), ["k"],
+                              [("i", "sum")], names=["s"]))
+    execute(plan, new_stats())      # compiled: the run below is a warm one
+    seen = []
+    real = metrics.observe
+
+    def observe(name, value, cpu=None):
+        seen.append((name, value))
+        real(name, value, cpu)
+
+    monkeypatch.setattr(metrics, "observe", observe)
+    monkeypatch.setattr(tracing, "_observe", observe)
+    with metrics.query("folds") as qm:
+        stats = new_stats()
+        execute(plan, stats)
+    assert stats["chunks"] == chunks
+    names = [name for name, _ in seen]
+    end = names.index("engine.stream_s")
+    in_stream = [v for name, v in seen[:end] if name == "engine.sync_wait_s"]
+    after = [v for name, v in seen[end:] if name == "engine.sync_wait_s"]
+    assert len(in_stream) == folds_of(chunks) and len(after) == 2
+    (tail_wait,) = [v for name, v in seen
+                    if name == "engine.post_stream.sync_wait_s"]
+    assert tail_wait == pytest.approx(sum(after), abs=1e-12)
+    h = qm.summary()["histograms"]
+    assert h["engine.sync_wait_s"]["sum"] - tail_wait \
+        == pytest.approx(sum(in_stream), abs=1e-12)
+    assert h["engine.post_stream.sync_wait_s"]["count"] == 1
 
 
 def test_a_short_stream_has_no_fold_stat(tmp_path, monkeypatch):
@@ -610,9 +669,13 @@ def test_new_metrics_list_the_new_cell_alone():
     assert sorted(new) == ["combine_device_ms", "combine_ms", "hbm_peak_mb",
                            "stream_partials_held"]
     assert {m["moves"] for m in new.values()} == {"fact_rows_per_s"}
+    # PR 38's six request-path readers list every cell, this one too
+    every_cell = [w["name"] for w in BENCHMARK["workloads"]]
     for m in BENCHMARK["per_layer"]:
-        if m["name"] not in new:
+        if m["name"] not in new and m.get("workloads") != every_cell:
             assert CELL not in m.get("workloads", ()), m["name"]
+    assert sum(m.get("workloads") == every_cell
+               for m in BENCHMARK["per_layer"]) == 6
     e2e = [m["name"] for m in BENCHMARK["end_to_end"]
            if "workloads" not in m or CELL in m["workloads"]]
     assert e2e == ["fact_rows_per_s", "setup_s"]

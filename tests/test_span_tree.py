@@ -9,7 +9,12 @@ What is pinned here, all on the CPU and with no profiler:
   the stream;
 - under ``SRJT_TRACE=1`` each span reaches ``jax.profiler.TraceAnnotation``
   with the client's trace id and its stats, on the producer thread too;
-- with metrics and tracing off a ``timed`` scope reads no clock;
+- with metrics and tracing off a ``timed`` scope reads no clock; a timed
+  span records the thread's CPU seconds inside its wall seconds, in one
+  observation; ``sp.stat()`` reaches whichever sink is on;
+- the request's own path (PR 38): decode, verify, prepare, precompute,
+  stream open and close each once per request, the six stretches the
+  coverage guard adds up, one ``bridge.conn.idle`` per turnaround;
 - the benchmark's readers of these spans (``benchmarks/layer_metrics``)
   and the launch-counting helper (``benchmarks/span_reduce.py``) give
   known values on known inputs and None where there is nothing to read.
@@ -47,6 +52,9 @@ class _Annotation:
     def __init__(self, name, **stats):
         self.rec = {"name": name, "stats": stats,
                     "thread": threading.get_ident()}
+
+    def set_metadata(self, **stats):
+        self.rec["stats"].update(stats)
 
     def __enter__(self):
         self.rec["t0"] = time.perf_counter()
@@ -143,6 +151,114 @@ def test_one_span_per_unit_of_work(served):
     assert _hist(q, "engine.execute_s")[1] == 1
 
 
+def test_one_span_per_stretch_of_the_request(served):
+    """PR 38: the request's own path, once each — in the query's summary
+    where the query context is open, process-wide for the two that lie
+    before it."""
+    q = served["query"]
+    for name in ("engine.plan.prepare_s", "engine.precompute_s",
+                 "engine.stream.open_s", "engine.stream.close_s",
+                 "engine.post_stream.sync_wait_s"):
+        assert _hist(q, name)[1] == 1, name
+    for name in ("bridge.plan.decode_s", "bridge.plan.verify_s",
+                 "engine.plan.prepare_s", "engine.precompute_s",
+                 "engine.stream.open_s", "engine.stream.close_s"):
+        assert _grew(served, name)[1] == 1, name
+    assert "bridge.plan.decode_s" not in q["histograms"]    # before `wall_s`
+    assert _grew(served, "bridge.plan.verify_s")[0] \
+        <= _grew(served, "bridge.plan.decode_s")[0]
+
+
+def test_stretches_of_the_execution_are_disjoint(served):
+    q = served["query"]
+    pre, stream, tail, run = (_hist(q, n)[0] for n in (
+        "engine.precompute_s", "engine.stream_s", "engine.post_stream_s",
+        "engine.execute_s"))
+    assert pre + stream + tail <= run
+    assert _hist(q, "engine.stream.open_s")[0] \
+        + _hist(q, "engine.stream.first_wait_s")[0] \
+        + _hist(q, "engine.stream.close_s")[0] <= stream
+    # the tail's waits are among the query's, and here (no fold) all of them
+    tail_wait = _hist(q, "engine.post_stream.sync_wait_s")[0]
+    assert 0 <= tail_wait <= tail
+    assert tail_wait == pytest.approx(_hist(q, "engine.sync_wait_s")[0],
+                                      abs=1e-9)
+
+
+def test_children_of_the_request_add_up(served):
+    """The coverage guard's six stretches hold >= 90 % of the request's
+    server time here (a chip run wants 95): nothing long is unnamed."""
+    request = _grew(served, "bridge.op.plan_execute_s")[0]
+    parts = sum(_grew_or_zero(served, name) for name in (
+        "bridge.plan.decode_s", "engine.plan.prepare_s",
+        "engine.sched.queue_wait_s", "engine.precompute_s",
+        "engine.stream_s", "engine.post_stream_s"))
+    assert 0.90 * request <= parts <= request
+    reader = _bench_module("layer_metrics", "request_span_coverage_pct")
+    ctx = {"snap_start": served["before"], "snap_end": served["after"]}
+    assert reader.read(ctx) == pytest.approx(parts / request * 100)
+
+
+def test_one_idle_span_per_turnaround(served):
+    """Between the two snapshots the connection served execute, export,
+    free and release, and then asked for the second snapshot: four waits
+    for the client.  The wait after a metrics poll is none."""
+    assert _grew(served, "bridge.conn.idle_s")[1] == 4
+    ops = sorted((r for r in served["log"]
+                  if r["name"].startswith("bridge.op.")),
+                 key=lambda r: r["t0"])
+    # (a loaded server may close the warm-up's last span after the log
+    # was emptied: the window starts with the first snapshot)
+    names = [r["name"][len("bridge.op."):] for r in ops]
+    ops = ops[names.index("metrics"):]
+    assert [r["name"][len("bridge.op."):] for r in ops] \
+        == ["metrics", "plan_execute", "export_table", "free_shm", "release",
+            "metrics"]
+    # (the log also holds the wait that ended with the first snapshot)
+    idles = sorted((r for r in served["log"]
+                    if r["name"] == "bridge.conn.idle"
+                    and r["t0"] >= ops[0]["t0"]), key=lambda r: r["t0"])
+    assert len(idles) == 4
+    assert all(r["stats"]["trace_id"] == served["trace_id"] for r in idles)
+    # each wait lies between one reply and the next request; none follows
+    # the first snapshot's reply
+    for done, idle, nxt in zip(ops[1:], idles, ops[2:]):
+        assert done["t1"] <= idle["t0"] <= idle["t1"] <= nxt["t0"]
+
+
+def test_cpu_seconds_lie_inside_wall_seconds(served):
+    """Under ``SRJT_TRACE=1`` every timed span's histogram holds `cpu_sum`
+    beside `sum`, process wide and in the query's summary: 0 <= cpu_sum
+    <= sum."""
+    timed = ("bridge.op.plan_execute_s", "bridge.plan.decode_s",
+             "bridge.plan.verify_s", "bridge.conn.idle_s",
+             "engine.plan.prepare_s", "engine.execute_s",
+             "engine.precompute_s", "engine.stream_s",
+             "engine.stream.open_s", "engine.stream.first_wait_s",
+             "engine.stream.close_s", "engine.sync_wait_s",
+             "io.scan.decode_s", "io.scan.stage_s", "io.scan.stage.pack_s")
+    for where in (served["after"]["histograms"],
+                  served["query"]["histograms"]):
+        for name in timed:
+            if name in where:
+                h = where[name]
+                assert 0 <= h["cpu_sum"] <= h["sum"] + 1e-6, (name, h)
+    assert all(n in served["after"]["histograms"] for n in timed)
+    # what is observed by hand carries none
+    assert "cpu_sum" not in served["after"]["histograms"][
+        "engine.post_stream_s"]
+    # the summary's share of the process-wide CPU seconds is its own
+    for name in ("io.scan.stage.pack_s", "engine.stream_s",
+                 "io.scan.decode_s"):
+        h0 = served["before"]["histograms"][name]
+        h1 = served["after"]["histograms"][name]
+        assert served["query"]["histograms"][name]["cpu_sum"] \
+            == pytest.approx(h1["cpu_sum"] - h0["cpu_sum"], rel=1e-6)
+    # a wait for the client is no work of this thread
+    idle = served["after"]["histograms"]["bridge.conn.idle_s"]
+    assert idle["cpu_sum"] < 0.5 * idle["sum"] + 1e-3
+
+
 def test_one_wait_per_host_sync(served):
     q = served["query"]
     assert q["counters"]["engine.host_sync"] == 2
@@ -183,6 +299,11 @@ def _grew(served, name):
     return h1["sum"] - h0["sum"], h1["count"] - h0["count"]
 
 
+def _grew_or_zero(served, name):
+    return _grew(served, name)[0] \
+        if name in served["after"]["histograms"] else 0.0
+
+
 def test_bridge_op_timer_encloses_the_query(served):
     seconds, count = _grew(served, "bridge.op.plan_execute_s")
     assert count == 1
@@ -198,7 +319,10 @@ SERVE_THREAD_SPANS = ("bridge.op.plan_execute", "bridge.plan.decode",
                       "engine.execute", "engine.stream",
                       "engine.stream.first_wait", "engine.stream.wait_reader",
                       "engine.sync_wait", "bridge.op.export_table",
-                      "bridge.export")
+                      "bridge.export", "bridge.conn.idle",
+                      "bridge.plan.verify", "engine.plan.prepare",
+                      "engine.precompute", "engine.stream.open",
+                      "engine.stream.close")
 
 
 @pytest.mark.parametrize("name", SERVE_THREAD_SPANS)
@@ -210,6 +334,23 @@ def test_serve_thread_span_carries_the_trace_id(served, name):
     assert {r["thread"] for r in spans} == {serve_thread}
     assert all(r["stats"].get("trace_id") == served["trace_id"]
                for r in spans)
+
+
+def test_late_stats_reach_their_open_spans(served):
+    """What is known only at a span's end is set through its handle."""
+    by_name = {}
+    for r in served["log"]:
+        by_name.setdefault(r["name"], []).append(r["stats"])
+    (decode,) = by_name["bridge.plan.decode"]
+    assert decode["bytes"] > 0 and decode["nodes"] == 2     # scan, aggregate
+    assert by_name["engine.plan.prepare"][0]["hit"] == 1    # the second run
+    assert by_name["engine.precompute"][0]["nodes"] == 0    # no dimension
+    (opened,) = by_name["engine.stream.open"]
+    assert (opened["groups"], opened["pruned"]) == (5, 0)
+    for stats in by_name["io.scan.decode"]:
+        assert stats["pages"] >= 2 and stats["runs"] >= 0
+        assert stats["dense"] == "2/2" and "worker_ms" not in stats
+    assert "io.scan.decode.walked" not in by_name
 
 
 def test_producer_thread_spans_carry_trace_id_and_stats(served):
@@ -247,7 +388,8 @@ def test_timed_scope_reads_no_clock_when_metrics_and_trace_are_off(
     cfg.refresh()
     calls = []
     monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
-        perf_counter=lambda: calls.append(1) or 0.0))
+        perf_counter=lambda: calls.append("wall") or 0.0,
+        thread_time=lambda: calls.append("cpu") or 0.0))
     try:
         with tracing.op_scope("engine.sync_wait", timed=True, label="x"):
             pass
@@ -256,7 +398,80 @@ def test_timed_scope_reads_no_clock_when_metrics_and_trace_are_off(
         cfg.refresh()
         with tracing.op_scope("engine.sync_wait", timed=True, label="x"):
             pass
-        assert len(calls) == 2
+        # no trace kept: the wall clock alone (the thread's CPU clock is a
+        # system call)
+        assert calls == ["wall", "wall"]
+        monkeypatch.setenv("SRJT_TRACE", "1")
+        cfg.refresh()
+        del calls[:]
+        with tracing.op_scope("engine.sync_wait", timed=True, label="x"):
+            pass
+        # the CPU stretch inside the wall stretch, each clock read twice
+        assert calls == ["wall", "cpu", "cpu", "wall"]
+    finally:
+        monkeypatch.undo()
+        cfg.refresh()
+
+
+def test_stat_reaches_whichever_sink_is_on(monkeypatch, metrics_isolation):
+    import jax
+
+    from spark_rapids_jni_tpu.utils import timeline
+    metrics_isolation("test.span")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    try:
+        for trace, tl in ((0, 0), (1, 0), (0, 1), (1, 1)):
+            monkeypatch.setenv("SRJT_TRACE", str(trace))
+            monkeypatch.setenv("SRJT_TIMELINE", str(tl))
+            cfg.refresh()
+            timeline.reset()
+            _Annotation.log = []
+            with tracing.op_scope("test.span.late", timed=True,
+                                  early=1) as sp:
+                sp.stat(late=2, dense="3/3")
+            anns = [r["stats"] for r in _Annotation.log]
+            assert anns == ([{"early": 1, "late": 2, "dense": "3/3"}]
+                            if trace else [])
+            events = [e for e in timeline.events_snapshot()
+                      if e["name"] == "test.span.late"]
+            assert [{k: e["args"][k] for k in ("early", "late", "dense")}
+                    for e in events] \
+                == ([{"early": 1, "late": 2, "dense": "3/3"}] if tl else [])
+        assert metrics.histograms_snapshot("test.span")[
+            "test.span.late_s"]["count"] == 4
+    finally:
+        monkeypatch.undo()
+        cfg.refresh()
+        timeline.reset()
+
+
+def test_a_timed_span_is_one_observation(monkeypatch, metrics_isolation):
+    """Wall and CPU seconds arrive together: one `metrics.observe` call
+    per timed span, none for an untimed one."""
+    metrics_isolation("test.span")
+    calls = []
+    real = metrics.observe
+    monkeypatch.setattr(tracing, "_observe",
+                        lambda *a: calls.append(a) or real(*a))
+    try:
+        with tracing.op_scope("test.span.one", timed=True):
+            sum(range(2_000))
+        with tracing.op_scope("test.span.none"):
+            pass
+        ((name, wall, cpu),) = calls
+        # no trace kept: no CPU seconds, and no `cpu_sum` in the record
+        assert name == "test.span.one_s" and wall > 0 and cpu is None
+        h = metrics.histograms_snapshot("test.span")["test.span.one_s"]
+        assert (h["count"], h["sum"]) == (1, wall) and "cpu_sum" not in h
+        monkeypatch.setenv("SRJT_TRACE", "1")
+        cfg.refresh()
+        del calls[:]
+        with tracing.op_scope("test.span.cpu", timed=True):
+            sum(range(2_000))
+        ((name, wall, cpu),) = calls
+        assert name == "test.span.cpu_s" and 0 <= cpu <= wall
+        h = metrics.histograms_snapshot("test.span")["test.span.cpu_s"]
+        assert (h["count"], h["sum"], h["cpu_sum"]) == (1, wall, cpu)
     finally:
         monkeypatch.undo()
         cfg.refresh()
@@ -384,6 +599,75 @@ def test_bridge_server_reader_takes_process_wide_growth():
     assert reader.read(_ctx([], {}, {"engine.query.wall_s": _h(1.0, 2)})) \
         is None                                              # no bridge timer
     assert reader.read(_ctx([])) is None
+
+
+# PR 38's six: process-wide growth between the window's two snapshots
+def _hc(total, count, cpu):
+    return {"sum": total, "count": count, "cpu_sum": cpu}
+
+
+REQUEST_START = {
+    "bridge.op.plan_execute_s": _hc(10.0, 20, 2.0),
+    "bridge.plan.decode_s": _hc(0.020, 20, 0.020),
+    "bridge.conn.idle_s": _hc(40.0, 79, 0.01),
+    "engine.plan.prepare_s": _hc(0.004, 20, 0.004),
+    "engine.precompute_s": _hc(0.100, 20, 0.080),
+    "engine.stream_s": _hc(5.0, 20, 1.0),
+    "engine.post_stream_s": _h(4.0, 20),
+    "engine.post_stream.sync_wait_s": _h(1.0, 20),
+    "io.scan.stage.pack_s": _hc(0.220, 220, 0.110),
+}
+REQUEST_END = {
+    "bridge.op.plan_execute_s": _hc(11.0, 30, 2.5),         # + 1.0 s
+    "bridge.plan.decode_s": _hc(0.035, 30, 0.035),          # + 15 ms
+    "bridge.conn.idle_s": _hc(40.1, 119, 0.02),             # + 100 ms
+    "engine.plan.prepare_s": _hc(0.009, 30, 0.009),         # + 5 ms
+    "engine.precompute_s": _hc(0.160, 30, 0.120),           # + 60 ms
+    "engine.stream_s": _hc(5.6, 30, 1.2),                   # + 600 ms
+    "engine.post_stream_s": _h(4.3, 30),                    # + 300 ms
+    "engine.post_stream.sync_wait_s": _h(1.08, 30),         # + 80 ms
+    "io.scan.stage.pack_s": _hc(0.330, 330, 0.150),   # + 110 ms, 40 on CPU
+}
+REQUEST_KNOWN = {
+    "plan_decode_ms": 1.5,
+    "client_turnaround_ms": 10.0,
+    "precompute_ms": 6.0,
+    "tail_host_ms": 22.0,                   # (300 - 80) / 10
+    "stage_pack_wait_ms": 70.0 / 110,       # (110 - 40) ms over 110 chunks
+    "request_span_coverage_pct": 98.0,      # 15 + 5 + 0 + 60 + 600 + 300
+}
+
+
+@pytest.mark.parametrize("name", sorted(REQUEST_KNOWN))
+def test_request_path_reader(name):
+    reader = _bench_module("layer_metrics", name)
+    ctx = _ctx([_query()] * 10, REQUEST_START, REQUEST_END)
+    assert reader.read(ctx) == pytest.approx(REQUEST_KNOWN[name])
+    # a window in which nothing grew, a program without the histograms
+    # (the parent's) and an empty snapshot: nothing, and no exception
+    assert reader.read(_ctx([_query()] * 10, REQUEST_END, REQUEST_END)) \
+        is None
+    parent = {k: {"sum": v["sum"], "count": v["count"]}
+              for k, v in REQUEST_END.items()
+              if k in ("bridge.op.plan_execute_s", "engine.stream_s",
+                       "engine.post_stream_s", "io.scan.stage.pack_s")}
+    assert reader.read(_ctx([_query()] * 10, {}, parent)) is None
+    assert reader.read(_ctx([])) is None
+
+
+def test_request_path_readers_are_in_the_benchmark():
+    import json
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cells = [w["name"] for w in bench["workloads"]]
+    mine = [m for m in bench["per_layer"] if m["name"] in REQUEST_KNOWN]
+    assert [m["name"] for m in mine] == [
+        "plan_decode_ms", "client_turnaround_ms", "precompute_ms",
+        "tail_host_ms", "stage_pack_wait_ms", "request_span_coverage_pct"]
+    assert mine == bench["per_layer"][-6:]      # appended, nothing moved
+    for m in mine:
+        assert m["workloads"] == cells and m["moves"] == "fact_rows_per_s"
+        assert m["source"] == "program_span"
 
 
 # -- (f) launches inside a derived interval ------------------------------------------
