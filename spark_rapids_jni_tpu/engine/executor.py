@@ -134,10 +134,13 @@ class _ExecCtx:
     ended; ``execute`` observes ``engine.post_stream_s`` from it, and
     ``engine.post_stream.sync_wait_s`` from ``stream_sync_s``, the query's
     ``engine.sync_wait_s`` at that moment.
+    ``tail_source``: the ``stream-agg`` Aggregate whose merged partial the
+    plan's ``tail`` stage asked for still padded; ``tail_demoted``: a veto
+    demoted that stage, and its root runs the form it would have had.
     """
 
     __slots__ = ("physical", "prefetch", "recovery", "stream_end",
-                 "stream_sync_s")
+                 "stream_sync_s", "tail_source", "tail_demoted")
 
     def __init__(self, physical: PhysicalPlan, prefetch: int,
                  recovery: Optional[RecoveryPolicy] = None):
@@ -147,6 +150,15 @@ class _ExecCtx:
             else RecoveryPolicy()
         self.stream_end: Optional[float] = None
         self.stream_sync_s = 0.0
+        self.tail_source: Optional[PlanNode] = None
+        self.tail_demoted = False
+
+    def stage_at(self, node: PlanNode) -> Stage:
+        """The form ``node`` runs in now: the physical plan's, or what a
+        demoted tail leaves its root."""
+        st = self.physical.stage_at(node)
+        return st.demoted if st.tail is not None and self.tail_demoted \
+            else st
 
 
 def _sync_wait_so_far() -> float:
@@ -235,7 +247,7 @@ def _exec_segment(seg, memo: dict, stats: dict, ctx: _ExecCtx,
 
 def _exec_chain_node(node, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
     """A Filter or a Project: the root of a ``map`` stage, or itself."""
-    st = ctx.physical.stage_at(node)
+    st = ctx.stage_at(node)
     if st.kind == "map":
         return _exec_segment(st.segment, memo, stats, ctx, node)
     return _apply(node, _exec(node.child, memo, stats, ctx))
@@ -255,7 +267,7 @@ def _exec_join(node: Join, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
 
 def _exec_aggregate(node: Aggregate, memo: dict, stats: dict,
                     ctx: _ExecCtx) -> Table:
-    st = ctx.physical.stage_at(node)
+    st = ctx.stage_at(node)
     if st.scan is not None:  # stream-agg / stream-agg-interp
         # scan-independent subtrees go into the shared memo BEFORE the
         # stats snapshot: a degraded re-run finds them memoized and skips
@@ -422,6 +434,76 @@ def _exec_limit(node: Limit, memo: dict, stats: dict,
     return slice_table(t, 0, min(node.n, t.num_rows))
 
 
+def _exec_tail(node: PlanNode, memo: dict, stats: dict,
+               ctx: _ExecCtx) -> Table:
+    """The plan's root as a ``tail`` stage (engine/segment.py ``Tail``):
+    every operator above the streamed aggregate in ONE program.
+
+    The ``stream-agg`` stage under it hands over its merged partial still
+    padded (no ``groupby-compaction``), each join's build side is padded to
+    its row bucket, and ``segment.run_tail`` launches the program and
+    compacts once (``tail-compaction``).  Per streamed query exactly one of
+    ``engine.tail.compiled`` / ``engine.tail.interp`` ticks.  A veto — the
+    static ``schema`` shadow or the inputs' own, a stream that came back a
+    Table (``stream-interpreted``, ``empty-stream``), a build row matched
+    twice (``non-unique-build``, known with the fetch) — demotes to the
+    forms the nodes have without a tail: the partial is compacted as it
+    always was and the root's own handler walks the region."""
+    from . import segment as sg
+    st = ctx.physical.stage_at(node)
+    tail = st.tail
+    src = tail.source
+    part = None
+    veto = "schema" if st.vetoed else None
+    if veto is None:
+        ctx.tail_source = src
+        part = _exec(src, memo, stats, ctx)
+        if not isinstance(part, sg.PaddedPartial):
+            part = None
+            veto = "stream-interpreted" if stats["chunks"] \
+                else "empty-stream"
+    builds: tuple = ()
+    if veto is None:
+        builds = tuple(_exec(j.right, memo, stats, ctx)
+                       for j in tail.joins())
+        if not sg.tail_runtime_eligible(tail, part, builds):
+            veto = "schema"
+    qm = metrics.current()
+    with op_scope("engine.tail", timed=True, nodes=len(tail.nodes),
+                  cap=part.num_rows if part is not None else 0) as sp:
+        if veto is None:
+            dims = sg.tail_dims(tail, builds)
+            done = sg.run_tail(sg.SEGMENT_CACHE.get_tail(tail, part, dims),
+                               part, dims)
+            if done is None:
+                veto = "non-unique-build"
+            else:
+                out, ngroups = done
+                metrics.count("engine.tail.compiled")
+                # interior nodes never pass through _exec (cf. _exec_segment)
+                stats["nodes"] += len(tail.nodes) - 1
+                if qm is not None:
+                    qm.node_set(id(src), node_label(src), rows_out=ngroups)
+                    for n in tail.nodes:
+                        qm.node_set(id(n), node_label(n), in_program=True)
+                    qm.node_set(id(node), node_label(node),
+                                tail_nodes=len(tail.nodes),
+                                tail_cap=part.num_rows)
+                    if all(c is not src for c in node.children()):
+                        qm.node_add(id(node), node_label(node),
+                                    rows_in=ngroups)
+                return out
+        metrics.count("engine.tail.interp")
+        sp.stat(veto=veto)
+        ctx.tail_demoted = True
+        if part is not None:
+            t = memo[id(src)] = part.compact()
+            if qm is not None:
+                qm.node_set(id(src), node_label(src), rows_out=t.num_rows,
+                            bytes_out=table_nbytes(t))
+        return _EXEC_DISPATCH[type(node)](node, memo, stats, ctx)
+
+
 #: per-chunk row budget for the streamed hash exchange — bounds the
 #: device-resident working set of one shuffle dispatch
 _EXCHANGE_CHUNK_ROWS = 1 << 16
@@ -448,7 +530,7 @@ def _exec_exchange(node: Exchange, memo: dict, stats: dict,
     stats["exchanges"] += 1
     from ..utils import blackbox
     blackbox.record("exchange", kind=node.kind, rows=child.num_rows)
-    if ctx.physical.stage_at(node).kind == "exchange-broadcast":
+    if ctx.stage_at(node).kind == "exchange-broadcast":
         return _broadcast_exchange(node, child)
     if getattr(node, "_aqe_flip", False):
         from ..utils.config import config
@@ -537,7 +619,7 @@ def _hash_exchange(node: Exchange, table: Table, ctx: _ExecCtx,
     the whole matrix, inside ``shuffle_table_padded`` otherwise) and one
     ok-mask compaction fetch at the end.
     """
-    if ctx.physical.stage_at(node).kind == "exchange-identity":
+    if ctx.stage_at(node).kind == "exchange-identity":
         return table
     # the exchange's own work, not its child's: staging, both shuffle
     # phases, and the two engine.sync_wait spans nested inside
@@ -811,6 +893,8 @@ def _exec(node: PlanNode, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
     if handler is None:
         raise TypeError(f"unknown plan node {type(node).__name__} "
                         f"(register it in executor._EXEC_DISPATCH)")
+    if ctx.stage_at(node).kind == "tail":
+        handler = _exec_tail    # the plan's root, whatever its type
     stats["nodes"] += 1
     qm = metrics.current()
     t0 = time.perf_counter() if qm is not None else 0.0
@@ -909,7 +993,9 @@ def _exec_streamed(st: Stage, memo: dict, stats: dict, ctx: _ExecCtx,
     stats["row_groups_read"] += reader.groups_read
 
     if fused:
-        return fused.finish()
+        # a tail above takes the merged partial as the merge left it
+        part = fused.merge()
+        return part if ctx.tail_source is agg else part.compact()
     if not partials:
         # everything pruned/filtered: run the plan once on an empty chunk
         # so the output schema still comes out right (the reader's cached
@@ -1220,7 +1306,7 @@ def _exec_topk(node: TopK, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
     from ..ops.order import SortKey
     from ..ops.selection import slice_table, sort_table
 
-    scan = ctx.physical.stage_at(node).scan
+    scan = ctx.stage_at(node).scan
     if scan is None:
         t = _exec(node.child, memo, stats, ctx)
         t = sort_table(t, [SortKey(t[c], ascending=a)

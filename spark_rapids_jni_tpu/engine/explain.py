@@ -131,9 +131,14 @@ def _annotate(span: Optional[dict],
                 bits.append(f"link_ratio={link / unc:.3f}")
     if span.get("in_program"):
         # the node ran INSIDE a fused whole-stage program (whole-stage
-        # fusion, SRJT_FUSE_EXCHANGE): its collectives paid no host
-        # round-trip of their own
+        # fusion, SRJT_FUSE_EXCHANGE: its collectives paid no host
+        # round-trip of their own) or inside the plan's tail program
         bits.append("in_program=yes")
+    if span.get("tail_nodes"):
+        # the root of a ``tail`` stage: the nodes its one program ran
+        # (this one included) and the slots of the padded partial it took
+        bits.append(f"tail_nodes={span['tail_nodes']}")
+        bits.append(f"tail_cap={span['tail_cap']}")
     if span.get("skew") is not None:
         # per-device exchange attribution (executor._hash_exchange /
         # _broadcast_exchange): destination-load balance + breakdown

@@ -635,20 +635,25 @@ def stage_census(physical, stats: dict, qm=None) -> Optional[str]:
     From ``stats`` alone, exactly: the exchanges executed, whether
     anything streamed, whether a top-k did.  With the run's QueryMetrics
     (``qm``) also the fused segments run and the ``engine.host_sync``
-    counter against ``SYNC_CHARGES`` (+ one sizing sync per fold of a long
-    stream, ``engine.combine.folds``) — less the stages a veto demoted that
+    counter against the stages' sync sites (+ one sizing sync per fold of
+    a long stream, ``engine.combine.folds``) — less the stages a veto demoted that
     the static side can name: a ``Stage.vetoed`` segment (schema), an
     ``agg`` segment over an empty input (its span's ``rows_in``), a stream
     that staged no chunk.  A ``stream-agg`` whose consumed nodes have spans
     of their own ran interpreted; unless ``vetoed`` said so that is a
     difference (the unique-build veto would read so too: no plan of the
     suite or the fuzzer streams over a build with duplicate hashes).  A
+    ``tail`` is charged with ``PhysicalPlan.sync_sites`` (its stream
+    compacts nothing): over a stream that staged no chunk it demotes, which
+    the static side can name (``PhysicalPlan.demotion``); otherwise a run
+    without ``engine.tail.compiled`` is a difference (a build matched twice
+    demotes at run time only, as the stream's unique-build veto does).  A
     per-chunk re-walk runs the segments under it once per chunk, so those
     counts are then lower bounds.  Ladder steps and AQE rewrites change
     forms mid-run: with either, only the ``stats`` checks apply."""
-    from .physical import SYNC_CHARGES
     stages = [st for st in physical.run_stages()
-              if not (st.stage is not None and st.vetoed)]
+              if not ((st.stage is not None or st.tail is not None)
+                      and st.vetoed)]
     kinds = [st.kind for st in stages]
     want = {"exchanges": sum(k.startswith("exchange-") or k == "fused-stage"
                              for k in kinds),
@@ -662,11 +667,22 @@ def stage_census(physical, stats: dict, qm=None) -> Optional[str]:
     spans = qm.node_spans
     rewalk = False
     ran = []
+    top = stages[0]
+    if top.tail is not None:
+        # the one veto of a tail the static side can name besides
+        # ``vetoed``: a stream that staged no chunk hands it a Table
+        if not stats["chunks"]:
+            stages = physical.demotion(top) + stages[1:]
+        elif not qm.counters.get("engine.tail.compiled", 0):
+            return f"tail at {top.path} ran interpreted"
     for st in stages:
         if st.scan is not None:
             walked = st.kind != "stream-agg" or st.vetoed
-            if not walked and any(id(n) in spans for n in st.nodes
-                                  if n is not st.node):
+            # (a stream that staged no chunk walks its nodes once, over an
+            # empty chunk, for the output's schema)
+            if not walked and stats["chunks"] \
+                    and any(id(n) in spans for n in st.nodes
+                            if n is not st.node):
                 return f"stream-agg at {st.path} ran interpreted"
             rewalk = rewalk or (walked and stats["chunks"] > 1)
             if walked or not stats["chunks"]:
@@ -676,7 +692,7 @@ def stage_census(physical, stats: dict, qm=None) -> Optional[str]:
             continue
         ran.append(st)
     want = {"fused_segments": sum(st.segment is not None for st in ran),
-            "host_syncs": sum(len(SYNC_CHARGES[st.kind]) for st in ran)
+            "host_syncs": sum(len(physical.sync_sites(st)) for st in ran)
             + qm.counters.get("engine.combine.folds", 0)}
     got = {"fused_segments": stats["fused_segments"],
            "host_syncs": qm.counters.get("engine.host_sync", 0)}

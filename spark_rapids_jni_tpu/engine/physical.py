@@ -18,6 +18,9 @@ each demote a stage to the interpreted form at run time
   Aggregate root, over a materialized input.
 - ``fused-stage``: partial Aggregate -> hash Exchange -> final Aggregate as
   one shard_map program.
+- ``tail``: the operators above a ``stream-agg`` stage, from the plan's root
+  down to its Aggregate, as one program over the merged partial still
+  padded, compacted once (``segment.Tail``).  One device only.
 - ``exchange-identity`` / ``exchange-broadcast`` / ``exchange-hash``.
 - ``interp``: every node no stage consumes runs node by node.
 """
@@ -36,7 +39,9 @@ from .plan import (STREAM_COMBINE, Aggregate, Exchange, Filter, Join,
 #: execution of the stage pays (``verify.sync_budget`` adds a fused stage's
 #: AQE probe, a run-time choice; a ``stream-agg`` of more than
 #: ``segment.COMBINE_ARITY`` chunks adds one ``combine-fold-sizing`` per
-#: fold at run time, counted by ``engine.combine.folds``)
+#: fold at run time, counted by ``engine.combine.folds``; one whose partial
+#: a ``tail`` takes still padded does not compact it:
+#: ``PhysicalPlan.sync_sites``)
 SYNC_CHARGES = {
     "stream-agg": ("combine-sizing", "groupby-compaction"),
     "stream-agg-interp": (),
@@ -44,6 +49,7 @@ SYNC_CHARGES = {
     "agg": ("groupby-compaction",),
     "map": ("segment-boundary-compaction",),
     "fused-stage": ("groupby-compaction",),
+    "tail": ("tail-compaction",),
     "exchange-identity": (),
     "exchange-broadcast": (),
     "exchange-hash": ("exchange-counts-sizing", "exchange-compaction"),
@@ -56,7 +62,8 @@ class Stage:
     """``kind`` run at ``node`` (its root, at ``path``), consuming ``nodes``
     (root last but in a fused stage), with the artifact the kind needs.
     ``vetoed``: footer schemas were given and say the executor's schema
-    veto will demote this stage."""
+    veto will demote this stage.  ``demoted`` (a ``tail`` only): the form
+    its root takes when a veto demotes the tail."""
 
     kind: str
     node: PlanNode
@@ -65,6 +72,8 @@ class Stage:
     segment: Optional[sg.Segment] = None
     stage: Optional[sg.FusedStage] = None
     scan: Optional[Scan] = None
+    tail: Optional[sg.Tail] = None
+    demoted: Optional["Stage"] = None
     vetoed: bool = False
 
 
@@ -87,16 +96,43 @@ class PhysicalPlan:
         the node after all."""
         return self._at[id(node)]
 
+    def demotion(self, st: Stage) -> list:
+        """What the walk runs in place of the ``tail`` stage ``st`` when a
+        veto demotes it: its nodes' own forms, parents first."""
+        out: list = []
+        consumed: set = set()
+        for n in reversed(st.nodes):
+            own = st.demoted if n is st.node else self._at[id(n)]
+            if id(n) not in consumed:
+                out.append(own)
+                consumed.update(id(m) for m in own.nodes)
+        return out
+
     def run_stages(self) -> list:
         """``stages`` as a run takes them when every static veto fires: a
         vetoed sandwich is followed by what its demotion hands back to the
-        walk, its exchange's and its partial's own stages."""
+        walk, its exchange's and its partial's own stages; a vetoed tail by
+        its nodes' own forms."""
         out: list = []
         for st in self.stages:
             out.append(st)
-            if st.stage is not None and st.vetoed:
+            if st.tail is not None and st.vetoed:
+                out += self.demotion(st)
+            elif st.stage is not None and st.vetoed:
                 out += [self._at[id(n)] for n in st.nodes[1:]]
         return out
+
+    def sync_sites(self, st: Stage) -> tuple:
+        """The whitelisted sync sites one execution of ``st`` pays:
+        ``SYNC_CHARGES`` of its kind — less ``groupby-compaction`` for the
+        ``stream-agg`` whose merged partial a tail takes still padded (the
+        tail's ``tail-compaction`` is the one fetch of both)."""
+        sites = SYNC_CHARGES[st.kind]
+        top = self.stages[0]
+        if top.tail is not None and not top.vetoed \
+                and st.node is top.tail.source:
+            sites = tuple(s for s in sites if s != "groupby-compaction")
+        return sites
 
 
 def _single_chunked_scan(root: PlanNode) -> Optional[Scan]:
@@ -141,7 +177,7 @@ def lower(plan: PlanNode, *, fuse: bool, fuse_join: bool, topk: bool,
 
     ``fuse`` / ``fuse_join`` / ``topk`` / ``fuse_exchange`` are the
     ``Config`` fields of those names (or ``execute(fused=...)``'s
-    override), ``ndev`` the mesh size.  ``resolver`` (``node -> {name:
+    override), ``ndev`` the mesh size (a ``tail`` is a one-device form).  ``resolver`` (``node -> {name:
     DType} | None``, the verifier's schema inference) changes no form: it
     lets the static shadow of the run-time schema vetoes mark
     ``Stage.vetoed``; without it the run alone decides."""
@@ -203,12 +239,26 @@ def lower(plan: PlanNode, *, fuse: bool, fuse_join: bool, topk: bool,
                           seg.nodes(), segment=seg, vetoed=vetoed(seg))
         return mk("interp")
 
+    at = {id(node): form(node) for node in topo_nodes(plan)}
+    # the operators above the streamed aggregate, as one program: from the
+    # plan's shape alone, on one device (an Exchange ends the region, and
+    # so does a second chunked scan: ``_stream_scan_of`` finds none)
+    streams = [st for st in at.values() if st.kind == "stream-agg"]
+    if ndev == 1 and len(streams) == 1:
+        src = streams[0]
+        tail = sg.build_tail(plan, src.node, src.scan, nparents)
+        if tail is not None:
+            at[id(plan)] = Stage(
+                "tail", plan, paths[id(plan)], tail.nodes, tail=tail,
+                demoted=at[id(plan)], vetoed=src.vetoed or (
+                    resolver is not None
+                    and not sg.tail_static_eligible(tail, schema)))
+
     stages: list = []
-    at: dict = {}
     consumed: set = set()
     for node in reversed(topo_nodes(plan)):
-        st = at[id(node)] = form(node)
         if id(node) not in consumed:
+            st = at[id(node)]
             stages.append(st)
             consumed.update(id(n) for n in st.nodes)
     return PhysicalPlan(plan, ndev, tuple(stages), at)

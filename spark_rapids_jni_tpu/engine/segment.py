@@ -44,6 +44,7 @@ plans.  The TPU translation:
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import threading
 import time
@@ -58,8 +59,8 @@ from ..columnar import Column, Table
 from ..utils import metrics, timeline
 from ..utils.config import config
 from ..utils.tracing import op_scope
-from .plan import (STREAM_COMBINE, Aggregate, Filter, Join, PlanNode,
-                   Project, depends_on, expr_columns, topo_nodes)
+from .plan import (STREAM_COMBINE, Aggregate, Filter, Join, Limit, PlanNode,
+                   Project, Sort, TopK, depends_on, expr_columns, topo_nodes)
 
 #: chain members fusable into a segment body (everything else is a
 #: breaker).  Exchange is deliberately NOT here: an exchange re-places
@@ -90,6 +91,23 @@ def _agg_fusable(agg: Aggregate) -> bool:
     return bool(agg.keys) and all(op in _FAST_OPS for _, op in agg.aggs)
 
 
+def _node_sig(nd: PlanNode) -> tuple:
+    """What of a node a program compiled over it depends on (its inputs
+    excluded): the unit of ``Segment`` / ``Tail`` fingerprints."""
+    if isinstance(nd, Filter):
+        return ("filter", nd.predicate)
+    if isinstance(nd, Join):
+        return ("join", tuple(nd.left_keys), tuple(nd.right_keys), nd.how)
+    if isinstance(nd, Project):
+        return ("project", tuple(nd.columns))
+    if isinstance(nd, Aggregate):
+        return ("aggregate", tuple(nd.keys), tuple(nd.aggs), tuple(nd.names))
+    if isinstance(nd, Limit):
+        return ("limit", nd.n)
+    return (type(nd).__name__.lower(), tuple(nd.keys),
+            getattr(nd, "n", None))     # Sort, TopK
+
+
 class Segment:
     """One fusable chain: ``input -> chain (bottom-up) [-> agg]``.
 
@@ -117,18 +135,7 @@ class Segment:
         """Structure-only identity (the plan-cache analog, input excluded):
         equal chains over different inputs share compiled executables."""
         if self._fp is None:
-            sig = []
-            for nd in self.chain:
-                if isinstance(nd, Filter):
-                    sig.append(("filter", nd.predicate))
-                elif isinstance(nd, Join):
-                    sig.append(("join", tuple(nd.left_keys),
-                                tuple(nd.right_keys), nd.how))
-                else:
-                    sig.append(("project", tuple(nd.columns)))
-            if self.agg is not None:
-                sig.append(("aggregate", tuple(self.agg.keys),
-                            tuple(self.agg.aggs), tuple(self.agg.names)))
+            sig = [_node_sig(nd) for nd in self.nodes()]
             self._fp = hashlib.sha256(repr(tuple(sig)).encode()).hexdigest()
         return self._fp
 
@@ -747,6 +754,19 @@ class SegmentCache:
         return self._lookup(key, lambda: CompiledCombine(
             key, chunk.segment, chunk.key_dtypes, cap))
 
+    def get_tail(self, tail: "Tail", part: "PaddedPartial",
+                 dims: tuple) -> "CompiledTail":
+        """The program of a ``tail`` stage (see ``run_tail``): keyed by the
+        region's fingerprint under a tag of its own, the class of the
+        padded partial it takes and the shape classes of its padded
+        dimension inputs — power-of-two buckets all, so another seed's
+        data compiles nothing."""
+        key = (tail.fingerprint() + "+tail",
+               (part.key_dtypes, _partial_class(part.parts)),
+               tuple(shape_class(t) for t, _ in dims))
+        return self._lookup(key, lambda: CompiledTail(
+            key, tail, part.key_dtypes))
+
     def __len__(self) -> int:
         with self._lock:
             return len(self._entries)
@@ -921,8 +941,8 @@ class StreamedPartials:
         self.compiled = compiled
         self.held = max(self.held, len(self.pending) - (self.folds > 0))
 
-    def finish(self) -> Table:
-        """The final merge and its compaction: the aggregate's Table."""
+    def merge(self) -> "PaddedPartial":
+        """The final merge, still padded: what a ``tail`` stage takes."""
         from ..ops.parquet_decode import bucket
         metrics.observe("engine.stream.partials_held", self.held)
         if self.folds:
@@ -934,9 +954,51 @@ class StreamedPartials:
             self.pending, self.compiled, width, "combine-sizing",
             final=1, **stats)
         agg = self.compiled.segment.agg
-        return _compact_padded(self.compiled.key_dtypes, kdat, kval,
-                               out_aggs, ngroups,
-                               list(agg.keys) + list(agg.names))
+        return PaddedPartial(self.compiled.key_dtypes, kdat, kval, out_aggs,
+                             ngroups, list(agg.keys) + list(agg.names))
+
+    def finish(self) -> Table:
+        """The final merge and its compaction: the aggregate's Table."""
+        return self.merge().compact()
+
+
+class PaddedPartial:
+    """A streamed aggregate's merged result as its merge program left it:
+    key buffers, their validity and the aggregate Columns at the merge's
+    slot count, the live groups packed at the front, their number a device
+    scalar nobody has fetched.  ``compact`` is the padded->compact tail
+    (one sync); a ``tail`` stage takes the partial as it is."""
+
+    __slots__ = ("key_dtypes", "kdat", "kval", "aggs", "ngroups", "names")
+
+    def __init__(self, key_dtypes, kdat, kval, aggs, ngroups, names):
+        self.key_dtypes = tuple(key_dtypes)
+        self.kdat = tuple(kdat)
+        self.kval = tuple(kval)
+        self.aggs = tuple(aggs)
+        self.ngroups = ngroups
+        self.names = list(names)
+
+    @property
+    def parts(self) -> tuple:
+        """``(kdat, kval, aggs, ngroups)``: a merged partial as the merge
+        and the tail programs take it."""
+        return self.kdat, self.kval, self.aggs, self.ngroups
+
+    @property
+    def columns(self) -> tuple:
+        """The padded columns (what ``table_nbytes`` sums)."""
+        return tuple(Column(dt, data=d, validity=v) for dt, d, v in
+                     zip(self.key_dtypes, self.kdat, self.kval)) + self.aggs
+
+    @property
+    def num_rows(self) -> int:
+        """Slots, live and dead."""
+        return _partial_slots(self.parts)
+
+    def compact(self) -> Table:
+        return _compact_padded(self.key_dtypes, self.kdat, self.kval,
+                               self.aggs, self.ngroups, self.names)
 
 
 def combine_partials(partials: list, compiled: CompiledSegment) -> Table:
@@ -949,6 +1011,358 @@ def combine_partials(partials: list, compiled: CompiledSegment) -> Table:
         acc.make_room()
         acc.add(p, compiled)
     return acc.finish()
+
+
+# -- the tail: the operators above a streamed aggregate, as one program -----
+#
+# Above a ``stream-agg`` stage a plan still has a few small operators — join
+# the store, group by manager, sort; or top-k of 150 brands — and interpreted
+# they cost some fifty eager launches and as many host round trips for
+# kernels of microseconds.  A ``Tail`` is that region, from the plan's root
+# down to the streamed Aggregate: it takes the merged partial STILL PADDED
+# (``PaddedPartial``) and runs every node over padded columns under one live
+# mask — filters AND into it, joins probe at probe-row shape, group-bys are
+# ``groupby_padded``, a sort puts dead rows last, a limit is a mask — in ONE
+# jitted program compacted once, at its end (``run_tail``: one sync).
+
+#: unary nodes a tail runs besides Join and Aggregate
+_TAIL_UNARY = (Filter, Project, Sort, Limit, TopK)
+
+
+class Tail:
+    """``source`` (the ``stream-agg`` Aggregate) ``-> nodes`` (execution
+    order, the plan's root last)."""
+
+    __slots__ = ("nodes", "source", "_fp")
+
+    def __init__(self, nodes: tuple, source: Aggregate):
+        self.nodes = nodes
+        self.source = source
+        self._fp: Optional[str] = None
+
+    def joins(self) -> tuple:
+        """Join nodes of the region, execution order."""
+        return tuple(nd for nd in self.nodes if isinstance(nd, Join))
+
+    def fingerprint(self) -> str:
+        """Structure-only identity, the inputs' names included."""
+        if self._fp is None:
+            sig = [("source", tuple(self.source.keys),
+                    tuple(self.source.names))]
+            sig += [_node_sig(nd) for nd in self.nodes]
+            self._fp = hashlib.sha256(repr(tuple(sig)).encode()).hexdigest()
+        return self._fp
+
+
+def build_tail(root: PlanNode, source: Aggregate, scan: PlanNode,
+               nparents: dict) -> Optional[Tail]:
+    """The tail rooted at the plan's ``root``, or None: grow downward
+    through Filter / Project / Sort / Limit / TopK, Aggregates of fast ops
+    and inner / semi Joins whose right side does not depend on ``scan``,
+    along the side that does, until ``source`` — the Aggregate that streams
+    over ``scan``, which stays a stage of its own.  Any other node on the
+    way (an Exchange, a cross join, a join fed from the right), or an
+    interior node with a second parent, and there is no tail."""
+    dep: dict = {}
+    chain = []
+    cur = root
+    while cur is not source:
+        if cur is not root and nparents.get(id(cur), 1) != 1:
+            return None
+        if isinstance(cur, _TAIL_UNARY):
+            below = cur.child
+        elif isinstance(cur, Aggregate) and _agg_fusable(cur):
+            below = cur.child
+        elif (isinstance(cur, Join) and cur.how in _FUSABLE_JOINS
+              and depends_on(cur.left, scan, dep)
+              and not depends_on(cur.right, scan, dep)):
+            below = cur.left
+        else:
+            return None
+        chain.append(cur)
+        cur = below
+    if not chain or nparents.get(id(cur), 1) != 1:
+        return None
+    return Tail(tuple(reversed(chain)), cur)
+
+
+def _tail_col_ok(dt) -> bool:
+    """Dtype gate of a column a tail carries (the static shadow and the run
+    share it): every column is masked, gathered and fetched as one 1-D
+    fixed-width buffer (DECIMAL128's is (n, 2) limbs)."""
+    from ..dtypes import TypeId
+    return dt.is_fixed_width and dt.id != TypeId.DECIMAL128
+
+
+def tail_static_eligible(tail: Tail, schema) -> bool:
+    """From ``schema(node) -> {name: DType} | None`` (the verifier's
+    resolved view): False when a column the tail would carry — the source's
+    output, an inner join's build side, a semi join's build keys — is not
+    ``_tail_col_ok``.  Unknown schemas assume eligible; the run decides."""
+    carried = [schema(tail.source)]
+    for j in tail.joins():
+        sch = schema(j.right)
+        if sch is not None and j.how == "semi":
+            sch = {k: sch.get(k) for k in j.right_keys}
+        carried.append(sch)
+    return all(dt is None or _tail_col_ok(dt)
+               for sch in carried if sch is not None for dt in sch.values())
+
+
+def tail_runtime_eligible(tail: Tail, part: "PaddedPartial",
+                          dims: tuple) -> bool:
+    """The actual inputs' veto (mirrors ``tail_static_eligible``)."""
+    def ok(c: Column) -> bool:
+        return _tail_col_ok(c.dtype) and c.data is not None \
+            and c.data.ndim == 1
+
+    try:
+        cols = list(part.columns)
+        for j, d in zip(tail.joins(), dims):
+            cols += [d.column(k) for k in j.right_keys] \
+                if j.how == "semi" else list(d.columns)
+    except (KeyError, ValueError):
+        return False
+    return all(ok(c) for c in cols)
+
+
+def _take(col: Column, idx) -> Column:
+    """``col`` at ``idx`` (in bounds by construction), validity kept as it
+    is: the tail tracks what the interpreted gathers would have made of it
+    apart (``_build_tail_fn``)."""
+    return Column(col.dtype, data=jnp.take(col.data, idx),
+                  validity=None if col.validity is None
+                  else jnp.take(col.validity, idx))
+
+
+def _tail_join(nd: Join, cols: list, names: list, live, right: Table,
+               rlive):
+    """One join of a tail at probe-row shape: ``(cols, names, live,
+    spill)``.  ``ops.join.probe_padded`` finds each live left row's build
+    row; a semi join only masks, an inner join selects the build side's
+    non-key columns at the matched rows (``_probe_join_node``'s two ways,
+    by the probe's method) under the interpreted join's names."""
+    from ..ops.join import probe_method, probe_padded, select_build_rows
+    left = Table(cols, names)
+    lk = Table([left.column(k) for k in nd.left_keys])
+    rk = Table([right.column(k) for k in nd.right_keys])
+    ri, matched, spill = probe_padded(lk, rk, live, rlive)
+    live = live & matched
+    compare = probe_method(
+        right.num_rows, list(lk.columns) + list(rk.columns)) == "compare"
+    if nd.how == "semi":
+        # the compare probe asks only whether a build row matches
+        return cols, names, live, (0 if compare else spill)
+    cols, names = list(cols), list(names)
+    lnames = list(names)
+    for nm, c in zip(right.names, right.columns):
+        if nm in nd.right_keys:
+            continue
+        cols.append(select_build_rows(c, ri) if compare else _take(c, ri))
+        names.append(_join_out_name(nm, lnames))
+    return cols, names, live, spill
+
+
+def _build_tail_fn(tail: Tail, compiled: "CompiledTail"):
+    """The single program a tail traces into.
+
+    ``fn(part, dims)``: ``part`` is the padded partial's ``(kdat, kval,
+    aggs, ngroups)``, ``dims`` one ``(padded Table, live rows)`` per Join
+    of the region, execution order.  Returns ``(datas, valids, nlive,
+    nulls, spill)``: the result's columns with the live rows packed at the
+    front in result order, their number, and two kinds of evidence the one
+    fetch brings along — ``nulls`` (per group key of an Aggregate: did it
+    hold a null group when it was made; the interpreted group-by keeps a
+    key's validity only then) and ``spill`` (``probe_padded``'s: > 0 and
+    the result is not the join's).
+
+    What the interpreted operators do to a column's VALIDITY is tracked
+    beside the data, so the result has the buffers theirs has: every gather
+    (filter, join, sort, limit) leaves a validity on every column, a
+    group-by leaves its keys' only where a group is null and its
+    aggregates' as the kernel made them.  ``vk`` holds, per column, None
+    (no validity), True (kept) or an index into ``nulls``.
+    """
+    src = tail.source
+
+    def fn(part, dims):
+        from ..ops.aggregate import groupby_padded
+        from ..ops.order import SortKey, encode_keys
+        from ..ops.selection import nonzero_indices
+        from .executor import eval_expr
+        compiled.traces += 1  # trace-time side effect, as in _build_fn
+        kdat, kval, aggs, ngroups = part
+        slots = kdat[0].shape[0]
+        live = jnp.arange(slots, dtype=jnp.int32) < ngroups
+        nulls: list = []
+
+        def grouped(keys, aggs, glive) -> tuple:
+            """``(cols, vk)`` of a group-by's padded output."""
+            vk = []
+            for c in keys:      # a key keeps its validity iff a group is null
+                nulls.append(jnp.any(glive & ~c.validity))
+                vk.append(len(nulls) - 1)
+            return list(keys) + list(aggs), \
+                vk + [None if c.validity is None else True for c in aggs]
+
+        cols, vk = grouped(
+            [Column(dt, data=d, validity=v) for dt, d, v in
+             zip(compiled.key_dtypes, kdat, kval)], aggs, live)
+        names = list(src.keys) + list(src.names)
+        packed = True       # live rows at the front, in result order
+        spill = jnp.int64(0)
+        ji = 0
+
+        def sort_by(keys):
+            words = [(~live).astype(jnp.uint64)] + encode_keys(
+                [SortKey(cols[names.index(c)], ascending=a)
+                 for c, a in keys])
+            return jnp.lexsort(tuple(reversed(words)))  # stable; dead last
+
+        for nd in tail.nodes:
+            if isinstance(nd, Filter):
+                vals, valid = eval_expr(nd.predicate, Table(cols, names))
+                m = jnp.asarray(vals, jnp.bool_)
+                if valid is not None:
+                    m = m & valid  # SQL semantics: NULL comparison drops
+                live, packed = live & m, False
+                vk = [True] * len(cols)
+            elif isinstance(nd, Project):
+                at = [names.index(c) for c in nd.columns]
+                cols = [cols[i] for i in at]
+                vk = [vk[i] for i in at]
+                names = list(nd.columns)
+            elif isinstance(nd, Join):
+                right, nvalid = dims[ji]
+                ji += 1
+                rlive = jnp.arange(right.num_rows, dtype=jnp.int32) < nvalid
+                cols, names, live, sp = _tail_join(nd, cols, names, live,
+                                                   right, rlive)
+                spill, packed = spill + sp, False
+                vk = [True] * len(cols)
+            elif isinstance(nd, Aggregate):
+                out_keys, out_aggs, ng = groupby_padded(
+                    Table(cols, names), list(nd.keys),
+                    [(c, op) for c, op in nd.aggs], row_mask=live)
+                live = jnp.arange(live.shape[0], dtype=jnp.int32) < ng
+                cols, vk = grouped(
+                    [Column(s[1], data=s[2], validity=s[3])
+                     for s in out_keys], out_aggs, live)
+                names = list(nd.keys) + list(nd.names)
+                packed = True
+            else:
+                if isinstance(nd, (Sort, TopK)):
+                    order = sort_by(nd.keys)
+                    cols = [_take(c, order) for c in cols]
+                    live, packed = jnp.take(live, order), True
+                if isinstance(nd, (Limit, TopK)):
+                    live = live & (jnp.cumsum(live.astype(jnp.int32))
+                                   <= np.int32(min(nd.n, 2**31 - 1)))
+                vk = [True] * len(cols)
+        if not packed:
+            order = nonzero_indices(live, count=live.shape[0])
+            cols = [_take(c, order) for c in cols]
+        # static at trace: what the host makes the result Table of
+        compiled.out_names = tuple(names)
+        compiled.out_dtypes = tuple(c.dtype for c in cols)
+        compiled.out_vk = tuple(vk)
+        return (tuple(c.data for c in cols),
+                tuple(None if k is None else c.valid_mask()
+                      for c, k in zip(cols, vk)),
+                jnp.sum(live, dtype=jnp.int32), tuple(nulls), spill)
+
+    return fn
+
+
+class CompiledTail(CompiledSegment):
+    """The program of one tail: a SEGMENT_CACHE entry keyed by the region's
+    fingerprint and its inputs' shape classes.  A compile ticks
+    ``engine.segment.compile`` like a chunk program's (the counter a
+    measured window holds at 0); a replay has a counter of its own, so the
+    chunk program's replays stay the number of chunks."""
+
+    __slots__ = ("tail", "out_names", "out_dtypes", "out_vk")
+
+    counters = "engine.tail"
+
+    def __init__(self, key: tuple, tail: Tail, key_dtypes: tuple):
+        self.key = key
+        self.segment = None
+        self.tail = tail
+        self.key_dtypes = key_dtypes
+        self.probes = ()        # counted by the chunk programs only
+        self.traces = 0
+        self.calls = 0
+        self.out_names = self.out_dtypes = self.out_vk = None
+        self.jfn = jax.jit(_build_tail_fn(tail, self))
+
+    def __call__(self, part: tuple, dims: tuple):
+        return self._launch(part, dims)
+
+    def _tick(self, compiled: bool, dt: float) -> None:
+        if compiled:
+            metrics.count("engine.segment.compile")
+            metrics.observe("engine.tail.trace_s", dt)
+        else:
+            metrics.count("engine.tail.replay")
+
+
+#: the fewest slots a tail's dimension input is padded to
+TAIL_DIM_FLOOR = 8
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _pad_rows(table: Table, slots: int) -> Table:
+    """``table`` zero-filled up to ``slots`` rows: one launch."""
+    def pad(a):
+        return None if a is None else \
+            jnp.pad(a, ((0, slots - a.shape[0]),) + ((0, 0),) * (a.ndim - 1))
+
+    return Table([Column(c.dtype, data=pad(c.data),
+                         validity=pad(c.validity)) for c in table.columns],
+                 table.names)
+
+
+def tail_dims(tail: Tail, tables: tuple) -> tuple:
+    """Each Join's build Table as the program takes it: ``(the columns it
+    carries, padded to their power-of-two row bucket; live rows)`` — so
+    another seed's dimension of another size runs the same program."""
+    from ..ops.parquet_decode import bucket
+    dims = []
+    for j, t in zip(tail.joins(), tables):
+        if j.how == "semi":
+            t = t.select(list(j.right_keys))
+        slots = bucket(t.num_rows, TAIL_DIM_FLOOR)
+        dims.append((t if slots == t.num_rows else _pad_rows(t, slots),
+                     np.int32(t.num_rows)))
+    return tuple(dims)
+
+
+def run_tail(compiled: CompiledTail, part: PaddedPartial,
+             dims: tuple) -> Optional[tuple]:
+    """Launch the tail's program and compact its result: ``(Table, source
+    groups)``, or None where a join found a second candidate for some row
+    (``probe_padded``'s spill: the caller demotes).
+
+    ONE deliberate host sync, ``tail-compaction``: the live row count, the
+    result's columns and the program's evidence come in one batched fetch,
+    and the result is made of the fetched buffers as they are — the rows
+    are few, and a result's next stop is the wire."""
+    datas, valids, nlive, nulls, spill = compiled(part.parts, dims)
+    metrics.host_sync(label="tail-compaction")
+    with op_scope("engine.sync_wait", timed=True, label="tail-compaction"):
+        datas, valids, nlive, nulls, spill, ngroups = jax.device_get(
+            (datas, valids, nlive, nulls, spill, part.ngroups))
+    if int(spill):
+        return None
+    n = int(nlive)
+    cols = []
+    for dt, d, v, k in zip(compiled.out_dtypes, datas, valids,
+                           compiled.out_vk):
+        keep = k is True or (k is not None and bool(nulls[k]))
+        cols.append(Column(dt, data=d[:n],
+                           validity=v[:n] if keep else None))
+    return Table(cols, list(compiled.out_names)), int(ngroups)
 
 
 # -- whole-stage fusion: the exchange inside the program --------------------

@@ -41,7 +41,7 @@ from typing import Optional
 import numpy as np
 
 from ..dtypes import BOOL8, FLOAT64, INT64, LIST, DType
-from .physical import SYNC_CHARGES, lower
+from .physical import lower
 from .plan import (ORDER_SENSITIVE_AGGS, Aggregate, Exchange, Filter, Join,
                    Limit, PlanNode, Project, Scan, Sort, TopK, co_partitioned,
                    expr_columns, node_label, node_paths, partitioning,
@@ -55,6 +55,7 @@ SYNC_WHITELIST = (
     "combine-sizing",               # the final merge's max(ngroups) fetch
     "combine-fold-sizing",          # the same fetch of a mid-stream fold
     "groupby-compaction",           # _compact_padded's ngroups fetch
+    "tail-compaction",              # run_tail's row count + result fetch
     "exchange-counts-sizing",       # hash exchange phase-1 counts fetch
     "exchange-compaction",          # hash exchange ok-mask fetch + compact
 )
@@ -830,7 +831,7 @@ def _lowered(plan: PlanNode, resolver: "SchemaResolver", cfg,
                      ndev=ndev, resolver=lambda node: verify(node, resolver))
     entries: list = []
     for st in physical.run_stages():
-        sites = SYNC_CHARGES[st.kind]
+        sites = physical.sync_sites(st)
         if st.vetoed:
             entries.append({"site": "interpreted-fallback",
                             "path": st.path, "count": 0})
@@ -1046,6 +1047,35 @@ def lint_fused_stage(stage, input_table, mesh=None, axis=None) -> dict:
         jnp.int64(padded.num_rows), require=("all_to_all",))
 
 
+def lint_tail(tail, source_schema: dict, builds: tuple,
+              rows: int = 8) -> dict:
+    """Jaxpr-lint a ``tail`` stage's program (``segment._build_tail_fn``)
+    over a zero-filled padded partial of ``source_schema`` (the streamed
+    Aggregate's output: keys, then aggregates) and zero-filled build
+    Tables: every operator above the stream in one artifact, no host
+    callback, no concretization, static output shapes."""
+    import types
+
+    import jax.numpy as jnp
+
+    from ..columnar import Column
+    from . import segment as sg
+    nk = len(tail.source.keys)
+    dts = list(source_schema.values())
+    ones = jnp.ones((rows,), jnp.bool_)
+    part = (tuple(jnp.zeros((rows,), dt.device_storage) for dt in dts[:nk]),
+            (ones,) * nk,
+            tuple(Column(dt, data=jnp.zeros((rows,), dt.device_storage),
+                         validity=ones) for dt in dts[nk:]),
+            jnp.int32(0))
+    probe = types.SimpleNamespace(traces=0, key_dtypes=tuple(dts[:nk]))
+    report = {"fingerprint": tail.fingerprint()[:12], "ok": True,
+              "violations": [], "primitives": 0,
+              "nodes": [node_label(n) for n in tail.nodes], "capacity": rows}
+    return _lint_traced(report, sg._build_tail_fn(tail, probe), part,
+                        sg.tail_dims(tail, builds))
+
+
 def lint_plan_artifacts(plan: PlanNode,
                         resolver: Optional[SchemaResolver] = None,
                         rows: int = 8, cfg=None) -> dict:
@@ -1074,6 +1104,22 @@ def lint_plan_artifacts(plan: PlanNode,
             reports.append(rep)
             violations += [{**v, "path": st.path}
                            for v in rep.get("violations", ())]
+            continue
+        if st.tail is not None:
+            tail = st.tail
+            schema = verify(tail.source, resolver)
+            bts = [_zero_table(verify(j.right, resolver), rows)
+                   for j in tail.joins()]
+            if st.vetoed or schema is None or any(b is None for b in bts):
+                reports.append({"path": st.path, "kind": st.kind,
+                                "skipped": "input schema unknown or tail "
+                                           "demoted at runtime"})
+                continue
+            rep = lint_tail(tail, schema, tuple(bts), rows)
+            rep["path"], rep["kind"] = st.path, st.kind
+            reports.append(rep)
+            violations += [{**v, "path": st.path}
+                           for v in rep["violations"]]
             continue
         seg = st.segment
         if seg is None:

@@ -68,6 +68,27 @@ def _unpack(words: jnp.ndarray, plan: tuple):
     return tuple(outs)
 
 
+def _as_storage(a, storage: str):
+    """An unpacked array in its column's device storage (``"bool"``: a
+    validity plane): a bitcast where the width is the same."""
+    storage = jnp.dtype(storage)
+    if a.dtype == storage:
+        return a
+    if storage != jnp.bool_ and a.dtype.itemsize == storage.itemsize:
+        return jax.lax.bitcast_convert_type(a, storage)
+    return a.astype(storage)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _trim(arrays: tuple, n_rows: int, storages: tuple) -> tuple:
+    """``_unpack``'s arrays cut back from the row bucket to the true row
+    count, each in its storage: ONE launch for an unpadded read (a
+    dimension table, once per query), apart from ``_unpack`` so that the
+    unpack stays one program per (schema, row bucket)."""
+    return tuple(_as_storage(a[:n_rows], st)
+                 for a, st in zip(arrays, storages))
+
+
 def _bucket(n: int) -> int:
     """Next power of two >= n: the staged unpack compiles once per
     (schema, row bucket), not once per exact file size — scanning many
@@ -265,7 +286,8 @@ def stage_fixed_table(specs, padded: bool = False):
 
     Rows are padded host-side to a power-of-two bucket so the jitted
     unpack's shapes (and hence its compile) are shared across file sizes;
-    outputs are sliced back to the true row count on device.
+    outputs are cut back to the true row count on device, in one launch
+    (``_trim``).
 
     ``padded=True`` keeps the bucket-padded form and returns
     ``(Table, n_rows)`` instead: pad rows carry zeroed values and False
@@ -302,21 +324,23 @@ def stage_fixed_table(specs, padded: bool = False):
         _pool.give(blob, arrays)
         with _plans_lock:
             _ready_plans.add((plan, total_words))
+        # per unpacked array, the storage it is handed on in
+        storages = []
+        for _, dtype, _, validity in specs:
+            storages.append(jnp.dtype(dtype.device_storage).name)
+            if validity is not None:
+                storages.append("bool")
+        if padded:
+            arrays = [_as_storage(a, st) for a, st in zip(arrays, storages)]
+        else:   # one launch for the table, not three eager ones a column
+            arrays = _trim(arrays, n_rows, tuple(storages))
         cols, names = [], []
         ai = 0
         for name, dtype, _, validity in specs:
-            data = arrays[ai] if padded else arrays[ai][:n_rows]
+            data, valid = arrays[ai], None
             ai += 1
-            storage = jnp.dtype(dtype.device_storage)
-            if data.dtype != storage:
-                if data.dtype.itemsize == storage.itemsize:
-                    data = jax.lax.bitcast_convert_type(data, storage)
-                else:
-                    data = data.astype(storage)
-            valid = None
             if validity is not None:
-                v = arrays[ai]
-                valid = (v if padded else v[:n_rows]).astype(jnp.bool_)
+                valid = arrays[ai]
                 ai += 1
             cols.append(Column(dtype, data=data, validity=valid))
             names.append(name)

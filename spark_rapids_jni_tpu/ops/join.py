@@ -296,20 +296,16 @@ def _key_words(lcol: Column, rcol: Column) -> tuple:
     return _words32(ld), _words32(rd)
 
 
-@traced("probe_compare")
-def _probe_compare(left_keys: Table, pb: "PreparedBuild", left_live,
-                   null_equal: bool):
-    """``probe_join_prepared`` for a small build: compare every probe key
-    with every build key and reduce, ``ri[i] = min{j : key_l[i] == key_r[j],
-    build row j live}``.  The ``[nr, nl]`` match is one fused
-    broadcast-compare-reduce — probe rows along the lanes, build rows along
-    the reduced axis — that is never materialized.  Exact by construction:
-    the keys themselves are tested, in full width, with ``_pair_equal``'s
-    null and float rules; no hash, no sort, no gather."""
-    nr = pb.nr
+def _compare_matrix(left_keys: Table, rk: Table, left_live, right_live,
+                    null_equal: bool):
+    """``(eq, llive, rlive)``: the ``[nr, nl]`` key equality of every build
+    row with every probe row — ``_pair_equal``'s null and float rules, in
+    full width — and the row masks with the null keys folded in (a null key
+    matches nothing unless ``null_equal``).  A broadcast compare the
+    reduces below fuse with: it is never materialized."""
     eq = None                    # [nr, nl]
-    llive, rlive = left_live, pb.right_live
-    for lc, rc in zip(left_keys.columns, pb.rk.columns):
+    llive, rlive = left_live, right_live
+    for lc, rc in zip(left_keys.columns, rk.columns):
         lw, rw = _key_words(lc, rc)
         e = None
         for a, b in zip(lw, rw):
@@ -327,6 +323,22 @@ def _probe_compare(left_keys: Table, pb: "PreparedBuild", left_live,
             if rv is not None:
                 rlive = rv if rlive is None else rlive & rv
         eq = e if eq is None else eq & e
+    return eq, llive, rlive
+
+
+@traced("probe_compare")
+def _probe_compare(left_keys: Table, pb: "PreparedBuild", left_live,
+                   null_equal: bool):
+    """``probe_join_prepared`` for a small build: compare every probe key
+    with every build key and reduce, ``ri[i] = min{j : key_l[i] == key_r[j],
+    build row j live}``.  The ``[nr, nl]`` match is one fused
+    broadcast-compare-reduce — probe rows along the lanes, build rows along
+    the reduced axis — that is never materialized.  Exact by construction:
+    the keys themselves are tested, in full width, with ``_pair_equal``'s
+    null and float rules; no hash, no sort, no gather."""
+    nr = pb.nr
+    eq, llive, rlive = _compare_matrix(left_keys, pb.rk, left_live,
+                                       pb.right_live, null_equal)
     cand = jnp.arange(nr, dtype=_I32)
     if rlive is not None:
         cand = jnp.where(rlive, cand, _NO_ROW)
@@ -335,6 +347,45 @@ def _probe_compare(left_keys: Table, pb: "PreparedBuild", left_live,
     if llive is not None:
         matched = matched & llive
     return jnp.where(matched, ri, 0), matched
+
+
+def probe_padded(left_keys: Table, right_keys: Table, left_live,
+                 right_live):
+    """Probe a padded build that nobody prepared, at probe-row shape and
+    inside one program: ``(ri, matched, spill)`` — the int32 build row of
+    every live probe row that has one, the match mask, and how many
+    candidates that shape could not hold (``spill`` > 0: some probe row has
+    more than one, so an inner join's output would outgrow the probe side
+    and the caller must take another path).  Both sides are padded Tables of
+    key columns under live masks; a null key matches nothing.
+
+    ``probe_method``'s two methods, chosen by the build's slot count: the
+    broadcast compare counts the live build rows that equal each probe key;
+    above ``PROBE_COMPARE_MAX_BUILD`` it is ``inner_join_padded`` at a
+    capacity of one pair per probe row, whose overflow is the spill (a
+    second candidate by the 32-bit hash counts too: it is an upper bound)."""
+    nl, nr = left_keys.num_rows, right_keys.num_rows
+    if probe_method(nr, list(left_keys.columns)
+                    + list(right_keys.columns)) == "compare":
+        with jax.named_scope("probe_compare"):
+            eq, llive, rlive = _compare_matrix(left_keys, right_keys,
+                                               left_live, right_live, False)
+            cand = jnp.arange(nr, dtype=_I32)
+            if rlive is not None:
+                eq = eq & rlive[:, None]
+            hits = jnp.sum(eq, axis=0, dtype=_I32)
+            ri = jnp.min(jnp.where(eq, cand[:, None], _NO_ROW), axis=0)
+            matched = hits > 0
+            if llive is not None:
+                matched = matched & llive
+            spill = jnp.sum(jnp.where(matched, hits - 1, 0), dtype=jnp.int64)
+            return jnp.where(matched, ri, 0), matched, spill
+    names = [f"k{i}" for i in range(left_keys.num_columns)]
+    _li, ri, eq, _npairs, overflow = inner_join_padded(
+        Table(left_keys.columns, names), Table(right_keys.columns, names),
+        names, names, capacity=nl, left_live=left_live,
+        right_live=right_live, pack=False)
+    return ri, eq, overflow.astype(jnp.int64)
 
 
 @traced("probe_compare")

@@ -183,6 +183,47 @@ def test_q5_fold_of_a_long_stream(q5_calls, one_chip, tpu_branches):
                               merge), filled, count)
 
 
+@pytest.mark.parametrize("config_name,kinds", (
+    ("nds_q5lite_sf1", {"Join", "Aggregate", "Sort"}),
+    ("nds_q55lite_sf1", {"TopK"})))
+def test_tail_programs_of_the_benchmark(tmp_path, one_chip, tpu_branches,
+                                        config_name, kinds):
+    """The ``tail`` stage of q5-lite (join the store, group by manager,
+    sort) and of q55-lite (top-k of the brands), found by running the
+    benchmark's plan at its rehearsal rows (``tests/test_tail_program.py``'s
+    warehouse, 11 chunks) on the CPU as one device, then compiled for the chip at the shapes it ran at — which ARE the cell's:
+    the padded partial's slots come from the groups (16 x 64 for 11 or 12
+    chunks of 12 stores or 150 brands), not from the fact's rows."""
+    from spark_rapids_jni_tpu.engine import execute, lower, optimize
+    from spark_rapids_jni_tpu.engine import segment as seg
+    from spark_rapids_jni_tpu.engine.executor import lowering_flags
+    import test_tail_program as tp      # tests/ is on the path (rootdir)
+    calls = []
+    launch = seg.CompiledTail.__call__
+
+    def tail_call(self, part, dims):
+        calls.append((self, part, dims))
+        return launch(self, part, dims)
+
+    query, _, paths = tp._warehouse(str(tmp_path), config_name, 7, 11)
+    opt = optimize(query.plan(paths, tp.OPEN[config_name], 64 << 20))
+    physical = lower(opt, **{**lowering_flags(), "ndev": 1})
+    try:
+        seg.CompiledTail.__call__ = tail_call
+        execute(physical)
+    finally:
+        seg.CompiledTail.__call__ = launch
+    ((compiled, part, dims),) = calls
+    assert {type(n).__name__ for n in compiled.tail.nodes} == kinds
+    assert part[0][0].shape == (1_024,)
+    program = compile_for_chip(
+        seg._build_tail_fn(compiled.tail, compiled), on(one_chip, part),
+        tuple((on(one_chip, t), jax.ShapeDtypeStruct((), jnp.int32,
+                                                     sharding=one_chip))
+              for t, _ in dims))
+    assert "tpu_custom_call" not in program.as_text()    # XLA, no kernel
+
+
 def test_groupby_padded_chunk(one_chip, tpu_branches):
     from spark_rapids_jni_tpu.ops.aggregate import groupby_padded
 

@@ -298,6 +298,26 @@ def test_q5_sync_budget_detail(warehouse):
         [("combine-sizing", 1), ("groupby-compaction", 1)]
 
 
+def test_sync_budget_of_a_plan_with_a_tail_is_two(warehouse):
+    """Lowered for one device, a Sort above the chunked stream's Aggregate
+    is a ``tail`` stage: the stream pays its sizing, the tail the ONE
+    compaction of both — and the run pays exactly those; for this
+    process's mesh the same plan is charged what it was."""
+    chunked = optimize(Sort(warehouse["chunked"], (("ss_store_sk", True),)))
+    assert sorted((e["site"], e["count"], e["path"])
+                  for e in sync_budget(chunked, ndev=1)) == \
+        [("combine-sizing", 1, "root.child"), ("tail-compaction", 1, "root")]
+    assert sorted(e["site"] for e in sync_budget(chunked) if e["count"]) == \
+        ["combine-sizing", "groupby-compaction"]
+    entries, bad = check_sync_budget([chunked], ndev=1)
+    assert bad == [] and "tail-compaction" in SYNC_WHITELIST
+    from spark_rapids_jni_tpu.engine import lower
+    physical = lower(chunked, **{**executor.lowering_flags(), "ndev": 1})
+    with metrics.query("verify-tail-crosscheck") as qm:
+        executor.execute(physical)
+    assert qm.summary()["counters"]["engine.host_sync"] == 2
+
+
 def test_artifact_lint_clean_on_smoke_plans(warehouse):
     for name, p in warehouse.items():
         rep = lint_plan_artifacts(optimize(p))

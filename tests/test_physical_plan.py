@@ -211,6 +211,69 @@ def test_lowered_census_equals_what_ran(plans, family, fuse):
     assert qm.counters.get("engine.host_sync", 0) == len(charged)
 
 
+#: what each family lowers to for ONE device (the suite's process has
+#: eight): the benchmark's two queries root a ``tail``, the others keep
+#: their forms
+ONE_DEVICE = {
+    "q5lite": ("tail", ("join", "aggregate", "sort")),
+    "q55lite": ("tail", ("topk",)),
+    "topk-chunked": ("stream-topk", None),
+    "string-agg": ("agg", None),
+    "string-agg-chunked": ("stream-agg", None),
+    "shared-interior": ("interp", None),
+}
+
+
+@pytest.mark.parametrize("family", sorted(ONE_DEVICE))
+def test_one_device_census_equals_what_ran(plans, family):
+    """The seam again, lowered for one device: a ``tail`` above the
+    benchmark's streamed aggregates, and the run pays the two syncs the
+    budget charges."""
+    from spark_rapids_jni_tpu.engine.plan import node_label
+    opt = _optimized(plans, family)
+    kind, consumed = ONE_DEVICE[family]
+    with _flags(fuse=True):
+        resolver = SchemaResolver()
+        physical = lower(opt, **{**lowering_flags(), "ndev": 1},
+                         resolver=lambda n: verify(n, resolver))
+        budget = sync_budget(opt, cfg=config, ndev=1)
+        top = physical.stages[0]
+        assert top.kind == kind and top.node is opt
+        if consumed is not None:
+            assert tuple(node_label(n) for n in top.nodes) == consumed
+            assert top.demoted.node is opt and top.demoted.kind == "interp"
+            assert physical.stage_at(top.tail.source).kind == "stream-agg"
+        seq0 = max((e["seq"] for e in blackbox.tail()), default=0)
+        stats = new_stats()
+        with metrics.query("physical-plan") as qm:
+            out = execute(physical, stats)
+        labels = sorted(e["label"] for e in blackbox.tail()
+                        if e["ev"] == "host_sync" and e["seq"] > seq0)
+        assert stage_census(physical, stats, qm) is None
+        assert labels == sorted(e["site"] for e in budget if e["count"])
+        if kind == "tail":
+            assert labels == ["combine-sizing", "tail-compaction"]
+            assert qm.counters["engine.tail.compiled"] == 1
+        # every node in exactly one stage, parents first, here too
+        owner = collections.Counter(id(n) for st in physical.stages
+                                    for n in st.nodes)
+        assert owner == collections.Counter(id(n) for n in topo_nodes(opt))
+        want = execute(opt, fused=False)
+    assert out.names == want.names
+    for x, y in zip(out.columns, want.columns):
+        assert x.to_pylist() == y.to_pylist()
+
+
+def test_the_census_catches_a_tail_that_did_not_run(plans):
+    opt = _optimized(plans, "q5lite")
+    with _flags(fuse=True):
+        physical = lower(opt, **{**lowering_flags(), "ndev": 1})
+        stats = new_stats()
+        with metrics.query("physical-plan") as qm:
+            execute(opt, stats)     # eight devices: today's forms ran
+    assert stage_census(physical, stats, qm) is not None
+
+
 def test_the_census_catches_a_form_that_did_not_run(plans):
     opt = _optimized(plans, "q5lite")
     with _flags(fuse=True):

@@ -105,6 +105,14 @@ def served(tmp_path_factory):
         cols = client.export_host(h)
         client.release(h)
         after = client.metrics()
+        # the server closes a request's span after it wrote the reply: on a
+        # loaded host the second poll's may still be open when the reply is
+        # read here
+        deadline = time.monotonic() + 5
+        while sum(r["name"] == "bridge.op.metrics"
+                  for r in list(_Annotation.log)) < 2 \
+                and time.monotonic() < deadline:
+            time.sleep(0.005)
         out = {"before": before, "after": after, "cols": cols,
                "trace_id": client.trace_id, "log": list(_Annotation.log),
                "query": [q for q in after["queries"]
@@ -289,6 +297,60 @@ def test_every_sync_opens_after_the_stream_closed(served):
     assert all(stream["t1"] <= r["t0"] and r["t1"] <= run["t1"]
                for r in syncs)
     assert run["t0"] <= stream["t0"]
+
+
+def test_the_tail_span_lies_after_the_stream_inside_the_execution(
+        tmp_path, monkeypatch):
+    """``engine.tail`` (one per streamed query whose plan has a ``tail``
+    stage: lowered for one device) opens after ``engine.stream`` closed
+    and closes inside ``engine.execute``, with its stats; the tail's one
+    wait nests in it, and the stretch after the stream holds both."""
+    import jax
+
+    from spark_rapids_jni_tpu.engine import Sort, lower
+    from spark_rapids_jni_tpu.engine.executor import lowering_flags
+    path = str(tmp_path / "fact.parquet")
+    pq.write_table(pa.table({
+        "k": pa.array((np.arange(ROWS) % 13).astype(np.int64)),
+        "v": pa.array(np.arange(ROWS, dtype=np.int64)),
+    }), path, row_group_size=GROUP_ROWS)
+    plan = optimize(Sort(Aggregate(Scan(path, chunk_bytes=CHUNK_BYTES),
+                                   ["k"], [("v", "sum")], names=["s"]),
+                         (("s", False),)))
+    physical = lower(plan, **{**lowering_flags(), "ndev": 1})
+    assert physical.stages[0].kind == "tail"
+    execute(physical)                       # compiles
+    monkeypatch.setenv("SRJT_TRACE", "1")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    cfg.refresh()
+    try:
+        _Annotation.log = []
+        with metrics.query("span-tree-tail") as qm:
+            with tracing.op_scope("engine.execute", timed=True):
+                out = execute(physical)
+        log = list(_Annotation.log)
+    finally:
+        monkeypatch.undo()
+        cfg.refresh()
+    assert out.column("s").to_pylist() == sorted(
+        out.column("s").to_pylist(), reverse=True) and out.num_rows == 13
+    (run,) = [r for r in log if r["name"] == "engine.execute"]
+    (stream,) = [r for r in log if r["name"] == "engine.stream"]
+    (tail,) = [r for r in log if r["name"] == "engine.tail"]
+    assert run["t0"] <= stream["t0"] <= stream["t1"] <= tail["t0"] \
+        <= tail["t1"] <= run["t1"]
+    assert tail["stats"]["nodes"] == 1 and tail["stats"]["cap"] >= 13
+    assert "veto" not in tail["stats"]
+    waits = {r["stats"]["label"]: r for r in log
+             if r["name"] == "engine.sync_wait"}
+    assert sorted(waits) == ["combine-sizing", "tail-compaction"]
+    assert tail["t0"] <= waits["tail-compaction"]["t0"] \
+        <= waits["tail-compaction"]["t1"] <= tail["t1"]
+    assert waits["combine-sizing"]["t1"] <= tail["t0"]
+    h = qm.summary()["histograms"]
+    assert h["engine.tail_s"]["count"] == 1
+    assert h["engine.tail_s"]["sum"] <= h["engine.post_stream_s"]["sum"]
+    assert qm.counters["engine.tail.compiled"] == 1
 
 
 # -- (b) the bridge's own timer ----------------------------------------------------

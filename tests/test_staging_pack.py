@@ -105,6 +105,39 @@ def test_every_width_in_one_blob():
     assert packed_blob(specs) == reference_blob(specs)
 
 
+def test_an_unpadded_read_is_the_padded_one_cut_in_one_launch(monkeypatch):
+    """``stage_fixed_table(specs)`` cuts the unpacked arrays back to the
+    true row count in ONE program (``_trim``) — every column in its device
+    storage, a validity as bool — where it sliced column by column."""
+    n = 1500
+    rng = np.random.default_rng(6)
+    specs = [
+        ("a", dt.INT64, values_of(np.int64, n), rng.random(n) > 0.5),
+        ("b", dt.FLOAT64, rng.standard_normal(n), None),
+        ("c", dt.INT16, values_of(np.int16, n), rng.random(n) > 0.5),
+        ("d", dt.FLOAT32, rng.random(n).astype(np.float32), None),
+    ]
+    launches = []
+    trim = staging._trim
+    monkeypatch.setattr(staging, "_trim", lambda *a: (
+        launches.append(a[1:]), trim(*a))[1])
+    cut = staging.stage_fixed_table(specs)
+    assert len(launches) == 1 and launches[0][0] == n
+    padded, rows = staging.stage_fixed_table(specs, padded=True)
+    assert len(launches) == 1 and rows == n
+    assert cut.names == padded.names and cut.num_rows == n
+    for c, p, (_, dtype, values, validity) in zip(
+            cut.columns, padded.columns, specs):
+        assert c.data.dtype == p.data.dtype == np.dtype(dtype.device_storage)
+        assert np.array_equal(np.asarray(c.data), np.asarray(p.data)[:n])
+        assert np.asarray(c.data).tobytes() == np.asarray(values).tobytes()
+        assert (c.validity is None) == (validity is None)
+        if validity is not None:
+            assert c.validity.dtype == p.validity.dtype == np.bool_
+            assert np.array_equal(np.asarray(c.validity), validity)
+            assert not np.asarray(p.validity)[n:].any()
+
+
 def test_values_of_another_width_are_refused():
     with pytest.raises(TypeError, match="4-byte column"):
         packed_blob([("c", dt.INT32, np.arange(10, dtype=np.int64), None)])
