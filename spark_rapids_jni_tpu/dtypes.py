@@ -16,7 +16,7 @@ decimal64 scale -8).
 from __future__ import annotations
 
 import enum
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import jax.numpy as jnp
 import numpy as np
@@ -100,10 +100,14 @@ class DType:
 
     Matches the int pair the reference marshals per column across JNI
     (RowConversion.java:113-118 flattens schema to parallel typeId/scale arrays).
+    ``precision`` is a decimal's SQL precision where it is known (a Parquet
+    footer's, or Spark's rule for a computed column; 0 = unknown): metadata
+    only, outside equality and hashing — the storage is the type-id's.
     """
 
     id: TypeId
     scale: int = 0
+    precision: int = field(default=0, compare=False, repr=False)
 
     def __post_init__(self):
         if self.scale != 0 and not self.is_decimal:
@@ -209,12 +213,12 @@ TIMESTAMP_MICROSECONDS = DType(TypeId.TIMESTAMP_MICROSECONDS)
 TIMESTAMP_NANOSECONDS = DType(TypeId.TIMESTAMP_NANOSECONDS)
 
 
-def decimal32(scale: int) -> DType:
-    return DType(TypeId.DECIMAL32, scale)
+def decimal32(scale: int, precision: int = 0) -> DType:
+    return DType(TypeId.DECIMAL32, scale, precision)
 
 
-def decimal64(scale: int) -> DType:
-    return DType(TypeId.DECIMAL64, scale)
+def decimal64(scale: int, precision: int = 0) -> DType:
+    return DType(TypeId.DECIMAL64, scale, precision)
 
 
 def decimal128(scale: int) -> DType:

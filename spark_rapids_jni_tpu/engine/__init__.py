@@ -38,6 +38,8 @@ from .plan import (  # noqa: F401
     expr_columns,
     from_dict,
     lit,
+    lit_date,
+    lit_decimal,
     node_label,
 )
 from .optimizer import optimize, output_names  # noqa: F401

@@ -50,64 +50,39 @@ def _join_fns():
     return _JOIN_FNS
 
 
-# -- filter expression evaluation ------------------------------------------
+# -- expression evaluation (engine/expr.py) ---------------------------------
 
-def eval_expr(expr, table: Table):
-    """Evaluate to ``(values, valid_or_None)``; comparisons give bool data."""
-    head = expr[0]
-    if head == "col":
-        c = table.column(expr[1])
-        if c.dtype.is_string:
-            return c, c.validity  # compared via ops.strings.equal below
-        vals = c.float_values() if c.dtype.is_floating else c.data
-        return vals, c.validity
-    if head == "lit":
-        return expr[1], None
-    if head == "not":
-        v, valid = eval_expr(expr[1], table)
-        return jnp.logical_not(v), valid
-    a, avalid = eval_expr(expr[1], table)
-    b, bvalid = eval_expr(expr[2], table)
-    valid = avalid if bvalid is None else \
-        (bvalid if avalid is None else avalid & bvalid)
-    if isinstance(a, Column) or isinstance(b, Column):
-        # STRING operand: chars/offsets need the dedicated equality kernel;
-        # found by the plan-space fuzzer — ("!=", col(<str>), lit(<str>))
-        # previously compared the raw chars buffer against the literal
-        if head not in ("==", "!="):
-            raise ValueError(
-                f"string comparison {head!r} unsupported (only ==/!=; "
-                f"verify() rejects ordering comparisons over strings)")
-        from ..ops import strings as _strings
-        scol, other = (a, b) if isinstance(a, Column) else (b, a)
-        eq = jnp.asarray(_strings.equal(scol, other).data, jnp.bool_)
-        return (eq if head == "==" else jnp.logical_not(eq)), valid
-    if head == ">=":
-        return a >= b, valid
-    if head == "<=":
-        return a <= b, valid
-    if head == ">":
-        return a > b, valid
-    if head == "<":
-        return a < b, valid
-    if head == "==":
-        return a == b, valid
-    if head == "!=":
-        return a != b, valid
-    if head == "&":
-        return jnp.logical_and(a, b), valid
-    if head == "|":
-        return jnp.logical_or(a, b), valid
-    raise ValueError(f"unknown expression op {head!r}")
+def _eager_exprs(*exprs) -> None:
+    """Count the expression nodes the interpreter evaluates."""
+    from .expr import count_nodes
+    n = sum(count_nodes(e) for e in exprs)
+    if n:
+        metrics.count("engine.expr.eager", n)
 
 
 def _filter_table(table: Table, predicate) -> Table:
     from ..ops.selection import apply_boolean_mask
-    vals, valid = eval_expr(predicate, table)
+    from .expr import any_flag, evaluate, raise_if_overflow
+    _eager_exprs(predicate)
+    ovf: list = []
+    vals, valid, _ = evaluate(predicate, table, ovf)
+    raise_if_overflow(any_flag(ovf))
     mask = jnp.asarray(vals, jnp.bool_)
     if valid is not None:
         mask = mask & valid  # SQL semantics: NULL comparisons drop the row
     return apply_boolean_mask(table, mask)
+
+
+def _project_table(table: Table, node: Project) -> Table:
+    """``Project`` interpreted: a select, or the computed columns too."""
+    if not node.computed:
+        return table.select(list(node.columns))
+    from .expr import any_flag, project, raise_if_overflow
+    _eager_exprs(*(e for _, e in node.computed))
+    ovf: list = []
+    out = project(table, node.items, ovf)
+    raise_if_overflow(any_flag(ovf))
+    return out
 
 
 # -- execution stats -------------------------------------------------------
@@ -195,16 +170,23 @@ def _exec_scan(scan: Scan, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
     return parts[0] if len(parts) == 1 else concat_tables(parts)
 
 
-def _groupby(table: Table, agg: Aggregate) -> Table:
+def _groupby(table: Table, agg: Aggregate, aggs=None) -> Table:
+    """``agg`` interpreted (``aggs``: other ``(column, op)`` pairs under its
+    names, a merge's), a decimal sum checked for overflow first."""
     from ..ops.aggregate import groupby
-    return groupby(table, list(agg.keys),
-                   [(c, op) for c, op in agg.aggs], names=list(agg.names))
+    from .expr import any_flag, decimal_sums, raise_if_overflow, sum_check
+    aggs = list(agg.aggs) if aggs is None else list(aggs)
+    ovf: list = []
+    for c in decimal_sums(aggs, table):
+        sum_check(table.column(c), True, ovf)
+    raise_if_overflow(any_flag(ovf))
+    return groupby(table, list(agg.keys), aggs, names=list(agg.names))
 
 
 def _apply(nd, t: Table) -> Table:
     """One Filter or Project node, interpreted."""
     return _filter_table(t, nd.predicate) if isinstance(nd, Filter) \
-        else t.select(list(nd.columns))
+        else _project_table(t, nd)
 
 
 def _interp_chain(seg, t: Table, stats: dict) -> Table:
@@ -267,6 +249,8 @@ def _exec_join(node: Join, memo: dict, stats: dict, ctx: _ExecCtx) -> Table:
 
 def _exec_aggregate(node: Aggregate, memo: dict, stats: dict,
                     ctx: _ExecCtx) -> Table:
+    if not node.keys:
+        metrics.count("engine.agg.keyless")   # one row, however it runs
     st = ctx.stage_at(node)
     if st.scan is not None:  # stream-agg / stream-agg-interp
         # scan-independent subtrees go into the shared memo BEFORE the
@@ -973,9 +957,7 @@ def _exec_streamed(st: Stage, memo: dict, stats: dict, ctx: _ExecCtx,
       Non-unique build hashes or ineligible schemas fall back to the
       interpreted per-chunk loop, which still pipelines.
     """
-    from ..ops.aggregate import groupby
     from ..ops.selection import concat_tables
-    from . import segment as sg
 
     agg, scan = st.node, st.scan
     # the scan-independent subtrees are in ``memo`` already:
@@ -1005,9 +987,8 @@ def _exec_streamed(st: Stage, memo: dict, stats: dict, ctx: _ExecCtx,
         return _groupby(_exec(agg.child, sub, stats, ctx), agg)
 
     merged = partials[0] if len(partials) == 1 else concat_tables(partials)
-    combine = [(nm, STREAM_COMBINE[op])
-               for nm, (_, op) in zip(agg.names, agg.aggs)]
-    return groupby(merged, list(agg.keys), combine, names=list(agg.names))
+    return _groupby(merged, agg, [(nm, STREAM_COMBINE[op])
+                                  for nm, (_, op) in zip(agg.names, agg.aggs)])
 
 
 def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,

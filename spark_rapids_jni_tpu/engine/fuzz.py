@@ -11,17 +11,19 @@ benches happen to build.  Four pieces:
    cardinality, a string key, quarter-valued float64 measures (every
    value is ``n/4``, so sums/mins/maxes stay exactly representable and
    executor parity can be asserted bit-for-bit regardless of reduction
-   order), plus dimension tables keyed by each family.  The dataframes
-   are kept in memory as the oracle's base relations.
+   order), a DECIMAL(9,2) measure (its frame holds int64 units), plus
+   dimension tables keyed by each family.  The dataframes are kept in
+   memory as the oracle's base relations.
 
 2. **Plan generator** — ``gen_plan`` synthesizes a random valid plan
    over all 9 ``plan._NODE_TYPES``: scans with column subsets,
-   filters over a random operator tree, projects, joins in every key
-   family (int/string) and how (inner/left/semi/anti/cross),
-   aggregates (including order-sensitive ``first``/``last`` over
-   order-deterministic chains), sorts/top-k with a unique tiebreak
-   suffix (so LIMIT cutoffs are deterministic across executors), and
-   occasionally a hand-placed hash Exchange in the two
+   filters over a random operator tree, projects (with computed ``*``,
+   ``+``, ``-`` columns over the decimal and integer columns), joins in
+   every key family (int/string) and how (inner/left/semi/anti/cross),
+   aggregates (with or without group keys; order-sensitive
+   ``first``/``last`` over order-deterministic chains), sorts/top-k with
+   a unique tiebreak suffix (so LIMIT cutoffs are deterministic across
+   executors), and occasionally a hand-placed hash Exchange in the two
    partitioning-sound positions (under an Aggregate on a subset of its
    group keys, or under a Sort).
 
@@ -58,13 +60,15 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+from decimal import Decimal
 from typing import Callable, Optional
 
 import numpy as np
 
 from ..utils.config import config
 from .plan import (Aggregate, Exchange, Filter, Join, Limit, PlanNode,
-                   Project, Scan, Sort, TopK, col, lit, rebuild, topo_nodes)
+                   Project, Scan, Sort, TopK, col, expr_columns, lit,
+                   lit_decimal, rebuild, topo_nodes)
 
 #: string pool for the string key family (small cardinality, fixed order)
 _STRINGS = ("ash", "birch", "cedar", "dome", "elm", "fir")
@@ -76,6 +80,10 @@ _LOW_CARD = ("k1", "k2", "sk", "dgrp", "skey")
 #: results are not bit-comparable across reduction orders / executors)
 _AGG_OPS = ("sum", "count", "count_all", "min", "max", "mean")
 _ORDER_OPS = ("first", "last")
+
+#: decimal columns of the warehouse and their scales (frames hold units);
+#: a generated column of scale s has the kind ``dec<s>``
+_DECIMALS = {"d": 2}
 
 #: ledger kinds that leave structure behind (mirror verify.decision_census)
 _STRUCTURAL_KINDS = frozenset(
@@ -123,12 +131,20 @@ def gen_warehouse(root, rng) -> dict:
         "skey": np.array(_STRINGS, dtype=object),
         "sv": _quarters(rng, len(_STRINGS)),
     })
+    # drawn last, so the other columns are what they were before it
+    fact["d"] = rng.integers(-5000, 5000, n).astype(np.int64)
     cat = {}
     for name, df in (("fact", fact), ("dimfull", dimfull),
                      ("dimpart", dimpart), ("dimstr", dimstr)):
         path = str(root / f"{name}.parquet")
-        pq.write_table(pa.Table.from_pandas(df, preserve_index=False), path,
-                       row_group_size=max(8, len(df) // 4))
+        table = pa.Table.from_pandas(df, preserve_index=False)
+        for c, scale in _DECIMALS.items():
+            if c in df:     # the file holds the decimal, the frame its units
+                table = table.set_column(
+                    table.schema.get_field_index(c), c,
+                    pa.array([Decimal(int(u)).scaleb(-scale) for u in df[c]],
+                             pa.decimal128(9, scale)))
+        pq.write_table(table, path, row_group_size=max(8, len(df) // 4))
         cat[name] = {"path": path, "df": df}
     return cat
 
@@ -146,7 +162,7 @@ class _Rel:
 
     def __init__(self, node, kinds, unique, ordered):
         self.node = node
-        self.kinds = kinds      # {name: "i64"|"i32"|"f64"|"str"}
+        self.kinds = kinds      # {name: "i64"|"i32"|"f64"|"str"|"dec<s>"}
         self.unique = unique    # tuple of column names, or None
         self.ordered = ordered  # bool
 
@@ -157,6 +173,7 @@ _DOMAINS = {
     "k1": (0, 8), "k2": (0, 5), "w": (-50, 50), "v": (-100.0, 100.0),
     "rid": (0, 160), "dk1": (0, 8), "dgrp": (0, 3), "dk2": (0, 5),
     "du": (0, 100), "dv": (-100.0, 100.0), "sv": (-100.0, 100.0),
+    "d": (-50, 50),
 }
 
 
@@ -165,6 +182,10 @@ def _gen_lit(rng, c: str, kind: str):
         return str(_STRINGS[int(rng.integers(0, len(_STRINGS)))])
     lo, hi = _DOMAINS.get(c, (0, 100))
     span = hi - lo
+    if kind.startswith("dec") and rng.random() < 0.5:
+        # an exact decimal literal, at two places
+        v = int(rng.integers((lo - span // 8) * 100, (hi + span // 8) * 100))
+        return lit_decimal(f"{v / 100:.2f}")
     if kind == "f64":
         return float(int(rng.integers((lo - span // 8) * 4,
                                       (hi + span // 8) * 4 + 1)) / 4.0)
@@ -188,7 +209,8 @@ def _gen_pred(rng, kinds: dict, depth: int = 0) -> tuple:
         cmp = ("==", "!=")[int(rng.integers(0, 2))]
     else:
         cmp = (">=", "<=", ">", "<", "==", "!=")[int(rng.integers(0, 6))]
-    return (cmp, col(c), lit(_gen_lit(rng, c, kind)))
+    v = _gen_lit(rng, c, kind)
+    return (cmp, col(c), v if isinstance(v, tuple) else lit(v))
 
 
 #: join specs: key column on the current relation -> (dim table, dim key,
@@ -218,9 +240,53 @@ def _stage_project(rng, rel: _Rel, cat) -> _Rel:
     cols = [c for c in rel.kinds if c in keep]  # preserve order
     if not cols:
         return rel
+    kinds = {c: rel.kinds[c] for c in cols}
+    nums = [c for c in rel.kinds
+            if rel.kinds[c] in ("i64", "i32") or rel.kinds[c][:3] == "dec"]
+    if nums and rng.random() < 0.5:
+        # computed columns: arithmetic over the decimal and int columns
+        for _ in range(int(rng.integers(1, 3))):
+            expr = _gen_arith(rng, nums)
+            name = f"e{len(kinds)}"
+            while name in kinds or name in rel.kinds:
+                name += "x"
+            cols.append((name, expr))
+            scale = _arith_scale(expr, rel.kinds)
+            kinds[name] = f"dec{scale}" if scale is not None else "i64"
     rel.node = Project(rel.node, tuple(cols))
-    rel.kinds = {c: rel.kinds[c] for c in cols}
+    rel.kinds = kinds
     return rel
+
+
+def _gen_arith(rng, nums: list, depth: int = 0) -> tuple:
+    """``a op b`` over the integer-valued columns and small literals."""
+    def operand():
+        r = rng.random()
+        if depth < 1 and r < 0.2:
+            return _gen_arith(rng, nums, depth + 1)
+        if r < 0.75:
+            return col(nums[int(rng.integers(0, len(nums)))])
+        if r < 0.9:
+            return lit(int(rng.integers(-9, 10)))
+        return lit_decimal(f"{int(rng.integers(-999, 1000)) / 100:.2f}")
+    return (("*", "+", "-")[int(rng.integers(0, 3))], operand(), operand())
+
+
+def _arith_scale(expr, kinds: dict):
+    """The decimal scale of an arithmetic result (None: an integer)."""
+    head = expr[0]
+    if head == "col":
+        k = kinds[expr[1]]
+        return int(k[3:]) if k.startswith("dec") else None
+    if head == "lit":
+        return None
+    if head == "lit_decimal":
+        return expr[3]
+    a, b = _arith_scale(expr[1], kinds), _arith_scale(expr[2], kinds)
+    if a is None and b is None:
+        return None
+    a, b = a or 0, b or 0
+    return a + b if head == "*" else max(a, b)
 
 
 def _stage_join(rng, rel: _Rel, cat) -> _Rel:
@@ -257,10 +323,10 @@ def _stage_cross(rng, rel: _Rel, cat) -> _Rel:
 
 def _stage_aggregate(rng, rel: _Rel, cat) -> _Rel:
     keycand = [c for c in rel.kinds if c in _LOW_CARD]
-    if not keycand:
-        return rel
-    nk = int(rng.integers(1, min(2, len(keycand)) + 1))
-    keys = sorted(rng.choice(keycand, size=nk, replace=False).tolist())
+    # no group key at all: one row (Spark's ungrouped aggregate)
+    nk = int(rng.integers(0, min(2, len(keycand)) + 1))
+    keys = sorted(rng.choice(keycand, size=nk, replace=False).tolist()) \
+        if nk else []
     numeric = [c for c in rel.kinds
                if rel.kinds[c] != "str" and c not in keys]
     ops = list(_AGG_OPS)
@@ -285,8 +351,8 @@ def _stage_aggregate(rng, rel: _Rel, cat) -> _Rel:
         elif op == "mean":
             kinds[nm] = "f64"
         elif op == "sum":
-            kinds[nm] = "f64" if rel.kinds.get(aggs[-1][0]) == "f64" \
-                else "i64"
+            k = rel.kinds.get(aggs[-1][0])
+            kinds[nm] = k if k == "f64" or k.startswith("dec") else "i64"
         else:
             kinds[nm] = rel.kinds.get(aggs[-1][0], "i64")
     if not aggs:
@@ -294,7 +360,7 @@ def _stage_aggregate(rng, rel: _Rel, cat) -> _Rel:
         kinds["a0"] = "i64"
     child = rel.node
     manual = False
-    if not has_order and rng.random() < 0.18:
+    if keys and not has_order and rng.random() < 0.18:
         # partitioning-sound hand-placed shuffle: hash keys must be a
         # subset of the group keys (verify.check_partitioning)
         nx = int(rng.integers(1, len(keys) + 1))
@@ -349,7 +415,7 @@ def gen_plan(rng, cat) -> PlanNode:
     """One random valid plan over the catalog (all 9 node types
     reachable).  Same rng state -> same plan, always."""
     kinds = {"k1": "i64", "k2": "i64", "sk": "str", "v": "f64",
-             "w": "i32", "rid": "i64"}
+             "w": "i32", "rid": "i64", "d": "dec2"}
     scan_cols = None
     if rng.random() < 0.3:
         drop = ("v", "w")[int(rng.integers(0, 2))]
@@ -382,20 +448,37 @@ _PD_CMP = {">=": "__ge__", "<=": "__le__", ">": "__gt__", "<": "__lt__",
            "==": "__eq__", "!=": "__ne__"}
 
 
-def _eval_pd(expr, df):
+def _eval_pd(expr, df, scales: dict):
+    """``(values, scale)``: a decimal's values are int64 units of
+    ``10**-scale``, anything else has the scale None.  Comparisons and
+    ``+``/``-`` bring both sides to the larger scale, ``*`` adds them —
+    Spark's rules, on units."""
     head = expr[0]
     if head == "col":
-        return df[expr[1]]
+        return df[expr[1]], scales.get(expr[1])
     if head == "lit":
-        return expr[1]
+        return expr[1], None
+    if head == "lit_decimal":
+        return expr[1], expr[3]
     if head == "not":
-        return ~_eval_pd(expr[1], df)
-    a, b = _eval_pd(expr[1], df), _eval_pd(expr[2], df)
+        return ~_eval_pd(expr[1], df, scales)[0], None
+    (a, sa), (b, sb) = (_eval_pd(e, df, scales) for e in expr[1:])
     if head == "&":
-        return a & b
+        return a & b, None
     if head == "|":
-        return a | b
-    return getattr(a, _PD_CMP[head])(b)
+        return a | b, None
+    if head == "*":
+        return a * b, None if sa is None and sb is None \
+            else (sa or 0) + (sb or 0)
+    s = None
+    if sa is not None or sb is not None:    # both to the larger scale
+        s = max(sa or 0, sb or 0)
+        a, b = a * 10 ** (s - (sa or 0)), b * 10 ** (s - (sb or 0))
+    if head == "+":
+        return a + b, s
+    if head == "-":
+        return a - b, s
+    return getattr(a, _PD_CMP[head])(b), None
 
 
 def _oracle_scan(node: Scan, env):
@@ -407,12 +490,23 @@ def _oracle_scan(node: Scan, env):
 
 def _oracle_filter(node: Filter, env):
     df = _oracle(node.child, env)
-    mask = _eval_pd(node.predicate, df)
-    return df[np.asarray(mask, dtype=bool)]
+    mask, _ = _eval_pd(node.predicate, df, env["scales"])
+    mask = np.asarray(mask, dtype=bool)
+    for c in expr_columns(node.predicate):  # a NULL operand drops the row
+        mask = mask & df[c].notna().to_numpy()
+    return df[mask]
 
 
 def _oracle_project(node: Project, env):
-    return _oracle(node.child, env)[list(node.columns)]
+    df = _oracle(node.child, env)
+    out = {}
+    for name, e in node.items:
+        v, scale = _eval_pd(e, df, env["scales"])
+        if e[0] != "col":
+            env["scales"][name] = scale
+        out[name] = v if not np.isscalar(v) else np.full(len(df), v)
+    import pandas as pd
+    return pd.DataFrame(out, index=df.index)
 
 
 def _oracle_join(node: Join, env):
@@ -443,11 +537,35 @@ _PD_AGG = {"sum": "sum", "min": "min", "max": "max", "mean": "mean",
 def _oracle_aggregate(node: Aggregate, env):
     import pandas as pd
     df = _oracle(node.child, env)
+    scales = env["scales"]
+    for (cname, op), outname in zip(node.aggs, node.names):
+        if op in ("sum", "min", "max", "first", "last"):
+            scales[outname] = scales.get(cname)
+    if not node.keys:
+        # one row, an empty input's too: sum/min/max/mean NULL, counts 0
+        row = {}
+        for (cname, op), outname in zip(node.aggs, node.names):
+            if op == "count_all":
+                row[outname] = len(df)
+            elif op == "count":
+                row[outname] = int(df[cname].notna().sum())
+            elif not len(df):
+                row[outname] = None
+            elif op == "mean" and scales.get(cname):
+                row[outname] = df[cname].sum() / len(df) \
+                    / 10 ** scales[cname]
+            else:
+                row[outname] = df[cname].agg(_PD_AGG[op])
+        return pd.DataFrame({k: pd.Series([v], dtype=object if v is None
+                                          else None)
+                             for k, v in row.items()})
     g = df.groupby(list(node.keys), sort=False, dropna=False)
     pieces = {}
     for (cname, op), outname in zip(node.aggs, node.names):
         if op == "count_all":
             pieces[outname] = g.size()
+        elif op == "mean" and scales.get(cname):
+            pieces[outname] = g[cname].mean() / 10 ** scales[cname]
         else:
             pieces[outname] = g[cname].agg(_PD_AGG[op])
     out = pd.DataFrame(pieces).reset_index()
@@ -503,6 +621,7 @@ def oracle(plan: PlanNode, cat) -> "object":
     """Reference result of the UNOPTIMIZED plan over the in-memory
     frames, as a pandas DataFrame."""
     env = {e["path"]: e["df"] for e in cat.values()}
+    env["scales"] = dict(_DECIMALS)     # name -> decimal scale, as it grows
     return _oracle(plan, env).reset_index(drop=True)
 
 
@@ -585,6 +704,11 @@ def _as_frame(table):
             cols[n] = np.array(c.to_pylist(), dtype=object)
         else:
             cols[n] = np.asarray(c.to_numpy())
+            valid = c.validity_numpy()
+            if not valid.all():     # a NULL (an ungrouped sum of nothing)
+                cols[n] = np.array([v if ok else None for v, ok
+                                    in zip(cols[n].tolist(), valid)],
+                                   dtype=object)
     return pd.DataFrame(cols)
 
 
@@ -693,7 +817,10 @@ def stage_census(physical, stats: dict, qm=None) -> Optional[str]:
         ran.append(st)
     want = {"fused_segments": sum(st.segment is not None for st in ran),
             "host_syncs": sum(len(physical.sync_sites(st)) for st in ran)
-            + qm.counters.get("engine.combine.folds", 0)}
+            # a keyed stream's folds are sized; a keyless one's are not
+            + (qm.counters.get("engine.combine.folds", 0)
+               if any(st.kind == "stream-agg" and st.node.keys
+                      for st in ran) else 0)}
     got = {"fused_segments": stats["fused_segments"],
            "host_syncs": qm.counters.get("engine.host_sync", 0)}
     if any(got[k] < want[k] for k in want) or (got != want and not rewalk):

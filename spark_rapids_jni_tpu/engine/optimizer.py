@@ -2,8 +2,9 @@
 
 A structural rule first: ``Limit(Sort(x), n)`` fuses into ``TopK`` so the
 executor can stream ORDER BY ... LIMIT as a per-chunk partial top-k instead
-of materializing the full sorted table.  Then three rules, applied in a
-fixed order chosen so each enables the next:
+of materializing the full sorted table; then an integer literal compared
+with a DECIMAL column becomes a ``lit_decimal`` at the column's scale.  Then
+three rules, applied in a fixed order chosen so each enables the next:
 
 1. **Filter split + pushdown below joins** — conjunctions split into single
    filters; a filter whose columns all come from one join input moves below
@@ -28,8 +29,8 @@ from typing import Optional
 
 from .plan import (ORDER_SENSITIVE_AGGS, STREAM_COMBINE, Aggregate, Exchange,
                    Filter, Join, Limit, PlanNode, Project, Scan, Sort, TopK,
-                   co_partitioned, expr_columns, partitioning, rebuild,
-                   topo_nodes)
+                   co_partitioned, decimal_literal, expr_columns,
+                   partitioning, rebuild, topo_nodes)
 
 #: comparisons a scan predicate hint can absorb (col vs literal)
 _RANGE_OPS = {">=", "<=", ">", "<", "=="}
@@ -65,7 +66,7 @@ def output_names(node: PlanNode, schema: Optional[_Schema] = None,
     if isinstance(node, Scan):
         out = schema.scan_names(node)
     elif isinstance(node, Project):
-        out = list(node.columns)
+        out = list(node.names)
     elif isinstance(node, (Filter, Sort, Limit, TopK)):
         out = output_names(node.child, schema, memo)
     elif isinstance(node, Aggregate):
@@ -161,6 +162,51 @@ def _rename_expr(expr, mapping):
     if expr[0] == "lit":
         return expr
     return (expr[0],) + tuple(_rename_expr(e, mapping) for e in expr[1:])
+
+
+# -- rule 0b: literals typed by the column they meet -----------------------
+
+def _type_literal(pred, schema: dict):
+    """``pred`` with each plain integer literal compared with a DECIMAL
+    column brought to the column's scale as a ``lit_decimal`` (24 against a
+    decimal(15,2) is 2400 units): the comparison the executor makes, made
+    visible — and kept from the scan pruning hint, which reads a plain
+    literal as the column's raw value."""
+    if not isinstance(pred, tuple) or pred[0] in ("col", "lit") \
+            or pred[0].startswith("lit_"):
+        return pred
+    if pred[0] in _RANGE_OPS | {"!="} and len(pred) == 3:
+        a, b = pred[1], pred[2]
+        for c, v in ((a, b), (b, a)):
+            dt = schema.get(c[1]) if c[0] == "col" else None
+            if dt is not None and dt.is_decimal and v[0] == "lit" \
+                    and type(v[1]) is int:
+                typed = decimal_literal(v[1] * 10 ** -dt.scale, -dt.scale)
+                return (pred[0],) + tuple(typed if e is v else e
+                                          for e in (a, b))
+        return pred
+    return (pred[0],) + tuple(_type_literal(e, schema) for e in pred[1:])
+
+
+def _type_literals(node: PlanNode, view, memo: dict) -> PlanNode:
+    if id(node) in memo:
+        return memo[id(node)]
+    kids = {f: _type_literals(getattr(node, f), view, memo)
+            for f in ("child", "left", "right") if hasattr(node, f)}
+    out = rebuild(node, **{k: v for k, v in kids.items()
+                           if v is not getattr(node, k)})
+    if isinstance(out, Filter):
+        from .verify import PlanVerificationError
+        try:
+            schema = view(node.child)
+        except PlanVerificationError:
+            schema = None       # SRJT_VERIFY=0 and a plan it would refuse
+        if schema is not None:
+            pred = _type_literal(out.predicate, schema)
+            if pred != out.predicate:
+                out = Filter(out.child, pred)
+    memo[id(node)] = out
+    return out
 
 
 # -- rule 0: ORDER BY ... LIMIT -> TopK ------------------------------------
@@ -295,7 +341,8 @@ def _collect_required(node: PlanNode, needed, schema: _Schema, req: dict):
     if isinstance(node, Scan):
         return
     if isinstance(node, Project):
-        _collect_required(node.child, set(node.columns), schema, req)
+        _collect_required(node.child, set().union(
+            *(expr_columns(e) for _, e in node.items)), schema, req)
     elif isinstance(node, Filter):
         sub = None if needed is None else needed | expr_columns(node.predicate)
         _collect_required(node.child, sub, schema, req)
@@ -679,6 +726,11 @@ def optimize(plan: PlanNode,
     plan = _fuse_topk(plan, {}, decisions)
     if checker is not None:
         checker.check("fuse_topk", plan)
+    from .verify import SchemaResolver, schema_view
+    plan = _type_literals(plan, schema_view(
+        checker.resolver if checker is not None else SchemaResolver()), {})
+    if checker is not None:
+        checker.check("type_literals", plan)
     plan = _push_filters(plan, schema, {})
     if checker is not None:
         checker.check("push_filters", plan)

@@ -124,10 +124,14 @@ class PhysicalPlan:
 
     def sync_sites(self, st: Stage) -> tuple:
         """The whitelisted sync sites one execution of ``st`` pays:
-        ``SYNC_CHARGES`` of its kind — less ``groupby-compaction`` for the
-        ``stream-agg`` whose merged partial a tail takes still padded (the
-        tail's ``tail-compaction`` is the one fetch of both)."""
+        ``SYNC_CHARGES`` of its kind — less ``combine-sizing`` for a
+        ``stream-agg`` with no group keys, and less ``groupby-compaction``
+        for the ``stream-agg`` whose merged partial a tail takes still
+        padded (the tail's ``tail-compaction`` is the one fetch of both)."""
         sites = SYNC_CHARGES[st.kind]
+        if st.kind == "stream-agg" and not st.node.keys:
+            # one-slot partials: no merge is sized, the result's fetch is all
+            sites = tuple(s for s in sites if s != "combine-sizing")
         top = self.stages[0]
         if top.tail is not None and not top.vetoed \
                 and st.node is top.tail.source:
@@ -163,9 +167,9 @@ def _single_chunked_scan(root: PlanNode) -> Optional[Scan]:
 
 def _stream_scan_of(agg: Aggregate) -> Optional[Scan]:
     """The single chunked parquet Scan this Aggregate can stream over:
-    every agg op decomposable, non-empty grouping keys, and a
-    ``_single_chunked_scan`` under the child."""
-    if not agg.keys or any(op not in STREAM_COMBINE for _, op in agg.aggs):
+    every agg op decomposable and a ``_single_chunked_scan`` under the
+    child (an aggregate with no keys streams one-row partials)."""
+    if any(op not in STREAM_COMBINE for _, op in agg.aggs):
         return None
     return _single_chunked_scan(agg.child)
 

@@ -12,10 +12,13 @@ Design notes:
 
 - Nodes are frozen dataclasses with *identity* hashing (``eq=False``): the
   same object appearing twice in a DAG is one node, executed once.
-- Filter predicates are a tiny expression language of nested tuples —
-  ``("col", name)``, ``("lit", value)``, and ``(op, a, b)`` for the
-  comparison/boolean ops in ``_EXPR_OPS`` — chosen because tuples serialize
-  to JSON losslessly and compare structurally.
+- Filter predicates and computed ``Project`` outputs are a tiny expression
+  language of nested tuples — ``("col", name)``, ``("lit", value)``, the
+  typed literals ``("lit_decimal", unscaled, precision, scale)`` and
+  ``("lit_date", days)``, and ``(op, a, b)`` for the comparison/boolean ops
+  in ``_EXPR_OPS`` and the arithmetic ops in ``ARITH_OPS`` — chosen because
+  tuples serialize to JSON losslessly and compare structurally
+  (``engine/expr.py`` types and evaluates them).
 - ``serialize()`` emits canonical JSON (topological node list, integer ids,
   sorted keys) so ``fingerprint()`` — the plan-cache key — is stable across
   processes for structurally identical plans.
@@ -30,8 +33,11 @@ from typing import Optional, Tuple
 
 PLAN_VERSION = 1
 
-#: comparison / boolean operators permitted in filter expressions
-_EXPR_OPS = {">=", "<=", ">", "<", "==", "!=", "&", "|"}
+#: arithmetic operators (Spark's decimal typing: engine/expr.py)
+ARITH_OPS = ("*", "+", "-")
+
+#: comparison / boolean / arithmetic operators permitted in expressions
+_EXPR_OPS = {">=", "<=", ">", "<", "==", "!=", "&", "|", *ARITH_OPS}
 
 JOIN_HOWS = ("inner", "left", "right", "full", "semi", "anti", "cross")
 
@@ -64,6 +70,31 @@ def lit(value) -> tuple:
     return ("lit", value)
 
 
+def lit_decimal(text: str) -> tuple:
+    """Exact decimal literal from its text (``"0.05"``): the unscaled
+    integer, its precision and scale, as Spark types a decimal literal."""
+    from decimal import Decimal
+    sign, digits, exp = Decimal(str(text)).as_tuple()
+    if not isinstance(exp, int):
+        raise ValueError(f"not a finite decimal: {text!r}")
+    unscaled = int("".join(map(str, digits)) or "0") * 10 ** max(exp, 0)
+    return decimal_literal(-unscaled if sign else unscaled, max(-exp, 0))
+
+
+def decimal_literal(unscaled: int, scale: int) -> tuple:
+    """``lit_decimal`` of ``unscaled`` units of ``10**-scale``: precision
+    its digits, at least the scale (Spark's ``DecimalType.fromDecimal``)."""
+    return ("lit_decimal", unscaled, max(len(str(abs(unscaled))), scale, 1),
+            scale)
+
+
+def lit_date(iso: str) -> tuple:
+    """DATE literal from its ISO text: days since the epoch."""
+    import datetime
+    days = (datetime.date.fromisoformat(iso) - datetime.date(1970, 1, 1)).days
+    return ("lit_date", days)
+
+
 def expr_columns(expr) -> set:
     """All column names referenced by an expression."""
     if not isinstance(expr, tuple):
@@ -88,6 +119,13 @@ def _validate_expr(expr) -> None:
     elif head == "lit":
         if len(expr) != 2:
             raise ValueError(f"malformed literal: {expr!r}")
+    elif head == "lit_decimal":
+        if len(expr) != 4 or not all(type(v) is int for v in expr[1:]) \
+                or not 0 <= expr[3] <= expr[2] <= 38:
+            raise ValueError(f"malformed decimal literal: {expr!r}")
+    elif head == "lit_date":
+        if len(expr) != 2 or type(expr[1]) is not int:
+            raise ValueError(f"malformed date literal: {expr!r}")
     elif head == "not":
         if len(expr) != 2:
             raise ValueError(f"malformed not: {expr!r}")
@@ -234,15 +272,46 @@ class Filter(PlanNode):
 
 @dataclass(frozen=True, eq=False)
 class Project(PlanNode):
-    """Restrict (and reorder) output columns."""
+    """Restrict, reorder and compute output columns: each entry of
+    ``columns`` is a child column's name or a ``(name, expr)`` pair, a
+    column computed from the child's by an expression."""
     child: PlanNode
-    columns: Tuple[str, ...]
+    columns: Tuple
 
     def __post_init__(self):
-        object.__setattr__(self, "columns", tuple(self.columns))
+        cols = []
+        for c in self.columns:
+            if isinstance(c, str):
+                cols.append(c)
+                continue
+            name, expr = c
+            expr = _expr_from_json(list(expr))
+            _validate_expr(expr)
+            cols.append((str(name), expr))
+        object.__setattr__(self, "columns", tuple(cols))
+        if len(set(self.names)) != len(cols):
+            raise ValueError(f"duplicate project output names {self.names}")
+
+    @property
+    def names(self) -> tuple:
+        """The output column names, in order."""
+        return tuple(c if isinstance(c, str) else c[0] for c in self.columns)
+
+    @property
+    def items(self) -> tuple:
+        """``(name, expr)`` per output; a plain name is ``col(name)``."""
+        return tuple((c, ("col", c)) if isinstance(c, str) else c
+                     for c in self.columns)
+
+    @property
+    def computed(self) -> tuple:
+        """The ``(name, expr)`` outputs that are not plain names."""
+        return tuple(c for c in self.columns if not isinstance(c, str))
 
     def _node_dict(self, child_ids):
-        return {"child": child_ids[0], "columns": list(self.columns)}
+        return {"child": child_ids[0], "columns": [
+            c if isinstance(c, str) else [c[0], _expr_to_json(c[1])]
+            for c in self.columns]}
 
     @classmethod
     def _from_dict(cls, d, built):
@@ -558,7 +627,9 @@ def partitioning(node: PlanNode, _memo: Optional[dict] = None) -> Partitioning:
         p = partitioning(node.child, memo)
     elif isinstance(node, Project):
         p = partitioning(node.child, memo)
-        if p.kind == "hash" and not set(p.keys) <= set(node.columns):
+        # a computed output may reuse a key's name: only plain ones carry it
+        kept = {c for c in node.columns if isinstance(c, str)}
+        if p.kind == "hash" and not set(p.keys) <= kept:
             p = NO_PARTITIONING
     elif isinstance(node, Aggregate):
         p = partitioning(node.child, memo)

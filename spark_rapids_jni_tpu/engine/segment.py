@@ -87,8 +87,10 @@ def parent_counts(root: PlanNode) -> dict:
 
 
 def _agg_fusable(agg: Aggregate) -> bool:
+    """Every op on groupby's traced path (an aggregate with no keys is a
+    masked reduction there: ``ops.aggregate._keyless_padded``)."""
     from ..ops.aggregate import _FAST_OPS
-    return bool(agg.keys) and all(op in _FAST_OPS for _, op in agg.aggs)
+    return all(op in _FAST_OPS for _, op in agg.aggs)
 
 
 def _node_sig(nd: PlanNode) -> tuple:
@@ -139,11 +141,25 @@ class Segment:
             self._fp = hashlib.sha256(repr(tuple(sig)).encode()).hexdigest()
         return self._fp
 
+    def exprs(self) -> int:
+        """Expression nodes the program computes (``expr.count_nodes``):
+        its filters' predicates and its Projects' computed columns."""
+        from .expr import count_nodes
+        return sum(count_nodes(nd.predicate) if isinstance(nd, Filter)
+                   else sum(count_nodes(e) for _, e in nd.computed)
+                   if isinstance(nd, Project) else 0 for nd in self.chain)
+
     def columns_used(self) -> set:
+        """Columns the program computes on: filtered on, computed from
+        (a Project's expressions) or aggregated — a plain name a Project
+        passes through is not among them."""
         cols = set()
         for nd in self.chain:
             if isinstance(nd, Filter):
                 cols |= expr_columns(nd.predicate)
+            elif isinstance(nd, Project):
+                for _, e in nd.computed:
+                    cols |= expr_columns(e)
         if self.agg is not None:
             cols |= set(self.agg.keys)
             cols |= {c for c, _ in self.agg.aggs if c is not None}
@@ -219,17 +235,9 @@ def worthwhile(seg: Segment, streaming: bool = False) -> bool:
 def runtime_eligible(seg: Segment, table: Table) -> bool:
     """Static fusability said yes; the actual input schema gets the veto:
     computed-on columns must be 1-D fixed-width (strings may pass THROUGH
-    a segment untouched, but can't be filtered on or aggregated)."""
-    if seg.agg is not None and table.num_rows == 0:
-        return False  # empty-input agg: let groupby's host path handle it
-    try:
-        for name in seg.columns_used():
-            c = table.column(name)
-            if c.dtype.is_string or c.data is None or c.data.ndim != 1:
-                return False
-    except (KeyError, ValueError):
-        return False
-    return True
+    a segment untouched, but can't be filtered on, computed from or
+    aggregated)."""
+    return stream_runtime_eligible(seg, table, ())
 
 
 def _needed_after(seg: Segment, pos: int) -> frozenset:
@@ -243,7 +251,8 @@ def _needed_after(seg: Segment, pos: int) -> frozenset:
         elif isinstance(nd, Join):
             need |= set(nd.left_keys)
         else:
-            need |= set(nd.columns)
+            for _, e in nd.items:
+                need |= expr_columns(e)
     if seg.agg is not None:
         need |= set(seg.agg.keys)
         need |= {c for c, _ in seg.agg.aggs if c is not None}
@@ -258,17 +267,17 @@ def _join_out_name(name: str, left_names) -> str:
 
 def stream_runtime_eligible(seg: Segment, table: Table,
                             builds: tuple) -> bool:
-    """``runtime_eligible`` for join-bearing stream segments: walks the
-    chain tracking the available name -> Column mapping (chunk columns,
-    then gathered build payloads), vetoing strings / non-1-D buffers in
-    any computed-on or gathered position."""
-    if not seg.joins():
-        return runtime_eligible(seg, table)
+    """The actual input schema's veto of a segment: walks the chain
+    tracking the available name -> Column mapping (chunk columns, computed
+    columns, then gathered build payloads), vetoing strings / non-1-D
+    buffers in any computed-on or gathered position.  A string reaching an
+    arithmetic node demotes the segment: the interpreter then meets it."""
     if seg.agg is not None and table.num_rows == 0:
-        return False
+        return False  # empty-input agg: let groupby's host path handle it
 
-    def ok(c: Column) -> bool:
-        return not (c.dtype.is_string or c.data is None or c.data.ndim != 1)
+    def ok(c) -> bool:     # None: a computed column, fixed-width by making
+        return c is None or not (c.dtype.is_string or c.data is None
+                                 or c.data.ndim != 1)
 
     try:
         avail = {nm: table.column(nm) for nm in (table.names or [])}
@@ -279,7 +288,11 @@ def stream_runtime_eligible(seg: Segment, table: Table,
                     if not ok(avail[name]):
                         return False
             elif isinstance(nd, Project):
-                avail = {nm: avail[nm] for nm in nd.columns}
+                for _, e in nd.computed:
+                    if not all(ok(avail[c]) for c in expr_columns(e)):
+                        return False
+                avail = {nm: None if e[0] != "col" else avail[e[1]]
+                         for nm, e in nd.items}
             else:  # Join
                 b = builds[ji]
                 ji += 1
@@ -380,23 +393,27 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
 
     ``fn(table, nvalid, prepared)``: rows >= nvalid are padding (chunk
     buckets); ``prepared`` carries one ``PreparedBuild`` pytree per Join
-    in the chain (execution order).  Map segments return (table, live);
-    agg segments return padded partial aggregates + group-live mask — all
-    device-resident, zero host syncs.
+    in the chain (execution order).  Map segments return (table, live,
+    ovf); agg segments return padded partial aggregates + group-live mask
+    + ovf — all device-resident, zero host syncs.  ``ovf`` is the program's
+    overflow flag (``engine/expr.py``: an arithmetic node or a decimal sum
+    outgrew int64's checked bound), None where it checks nothing.
     """
+    from .expr import decimal_sums
     chain, agg = seg.chain, seg.agg
     needed = {i: _needed_after(seg, i + 1)
               for i, nd in enumerate(chain) if isinstance(nd, Join)}
 
     def fn(table: Table, nvalid, prepared=()):
         from ..ops.aggregate import groupby_padded
-        from .executor import eval_expr
+        from .expr import any_flag, evaluate, project, sum_check
         compiled.traces += 1  # trace-time side effect: the no-recompile proof
         live = jnp.arange(table.num_rows, dtype=jnp.int32) < nvalid
+        ovf: list = []
         ji = 0
         for i, nd in enumerate(chain):
             if isinstance(nd, Filter):
-                vals, valid = eval_expr(nd.predicate, table)
+                vals, valid, _ = evaluate(nd.predicate, table, ovf)
                 m = jnp.asarray(vals, jnp.bool_)
                 if valid is not None:
                     m = m & valid  # SQL semantics: NULL comparison drops
@@ -405,10 +422,14 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
                 table, live = _probe_join_node(nd, prepared[ji], table,
                                                live, needed[i])
                 ji += 1
+            elif nd.computed:
+                table = project(table, nd.items, ovf)
             else:
                 table = table.select(list(nd.columns))
         if agg is None:
-            return table, live
+            return table, live, any_flag(ovf)
+        for c in decimal_sums(agg.aggs, table):
+            sum_check(table.column(c), live, ovf)
         out_keys, out_aggs, ngroups = groupby_padded(
             table, list(agg.keys), [(c, op) for c, op in agg.aggs],
             row_mask=live)
@@ -418,7 +439,7 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
         # buffers cross the jit boundary
         kdat = tuple(spec[2] for spec in out_keys)
         kval = tuple(spec[3] for spec in out_keys)
-        return kdat, kval, tuple(out_aggs), glive, ngroups
+        return kdat, kval, tuple(out_aggs), glive, ngroups, any_flag(ovf)
 
     return fn
 
@@ -430,7 +451,7 @@ class CompiledSegment:
     the ``engine.probe.*`` counters and the span's stat report."""
 
     __slots__ = ("key", "segment", "key_dtypes", "jfn", "traces", "calls",
-                 "probes")
+                 "probes", "exprs")
 
     #: prefix of this program's compile-vs-replay events (``_tick``)
     counters = "engine.segment"
@@ -441,16 +462,19 @@ class CompiledSegment:
         self.segment = segment
         self.key_dtypes = key_dtypes
         self.probes = probes
+        self.exprs = segment.exprs()
         self.traces = 0
         self.calls = 0
         self.jfn = jax.jit(_build_fn(segment, self))
 
     def span_stats(self) -> dict:
-        """Stats of the ``engine.fused_segment`` span around a launch."""
-        if not self.probes:
-            return {}
-        return {"probe_compare":
-                f"{self.probes.count('compare')}/{len(self.probes)}"}
+        """Stats of the ``engine.fused_segment`` span around a launch: the
+        expression nodes compiled into it, the joins' probe methods."""
+        out = {"exprs": self.exprs}
+        if self.probes:
+            out["probe_compare"] = \
+                f"{self.probes.count('compare')}/{len(self.probes)}"
+        return out
 
     def __call__(self, table: Table, nvalid=None, prepared=()):
         nv = jnp.int32(table.num_rows if nvalid is None else nvalid)
@@ -458,6 +482,8 @@ class CompiledSegment:
 
     def _launch(self, *args):
         self.calls += 1
+        if self.exprs:
+            metrics.count("engine.expr.fused", self.exprs)
         compare = self.probes.count("compare")    # joins of the chain
         if compare:
             metrics.count("engine.probe.compare", compare)
@@ -521,6 +547,7 @@ class CompiledDecodeSegment(CompiledSegment):
         self.segment = segment
         self.key_dtypes = key_dtypes
         self.probes = probes
+        self.exprs = segment.exprs()
         self.traces = 0
         self.calls = 0
         self.geom = geom
@@ -550,12 +577,15 @@ def _build_combine_fn(agg: Aggregate, key_dtypes: tuple, cap: int,
     """The single program the merge of the streamed partials traces into.
 
     ``fn(partials, nreal)``: ``partials`` is the bucketed tuple of
-    ``(kdat, kval, out_aggs, glive)`` — ``glive`` a merged partial's group
-    count where it is a scalar; entries >= ``nreal`` are filler (dead
-    rows).  Slice every partial to ``cap``, concatenate, and run the
-    combine ``groupby_padded`` under the live mask — still padded, zero
-    host syncs.
+    ``(kdat, kval, out_aggs, glive, ovf)`` — ``glive`` a merged partial's
+    group count where it is a scalar, ``ovf`` its overflow flag or None;
+    entries >= ``nreal`` are filler (dead rows).  Slice every partial to
+    ``cap``, concatenate, and run the combine ``groupby_padded`` under the
+    live mask — still padded, zero host syncs.  The merge's own flag ORs
+    the live partials' with its decimal sums' check (None where neither
+    exists).
     """
+    from .expr import any_flag, sum_check
     nk = len(agg.keys)
     knames = [f"k{i}" for i in range(nk)]
     anames = [f"a{j}" for j in range(len(agg.aggs))]
@@ -596,11 +626,16 @@ def _build_combine_fn(agg: Aggregate, key_dtypes: tuple, cap: int,
         live = jnp.concatenate([live_slots(p) & (np.int32(i) < nreal)
                                 for i, p in enumerate(partials)])
         merged = Table(key_cols + agg_cols, knames + anames)
+        ovf = [p[4] & (np.int32(i) < nreal) for i, p in enumerate(partials)
+               if p[4] is not None]
+        for (nm, op), col in zip(combine, agg_cols):
+            if op == "sum" and col.dtype.is_decimal:
+                sum_check(col, live, ovf)
         out_keys, out_aggs, ngroups = groupby_padded(
             merged, knames, combine, row_mask=live)
         kdat = tuple(spec[2] for spec in out_keys)
         kval = tuple(spec[3] for spec in out_keys)
-        return kdat, kval, tuple(out_aggs), ngroups
+        return kdat, kval, tuple(out_aggs), ngroups, any_flag(ovf)
 
     return fn
 
@@ -621,6 +656,7 @@ class CompiledCombine(CompiledSegment):
         self.segment = segment
         self.key_dtypes = key_dtypes
         self.probes = ()        # the merge probes nothing
+        self.exprs = 0
         self.traces = 0
         self.calls = 0
         self.jfn = jax.jit(_build_combine_fn(segment.agg, key_dtypes, cap,
@@ -799,21 +835,39 @@ def run_map_segment(compiled: CompiledSegment, table: Table,
     """Fused chain then ONE compaction at the breaker boundary (the only
     host sync the whole chain pays, vs one per interpreted Filter)."""
     from ..ops.selection import apply_boolean_mask
-    out, live = compiled(table, nvalid)
+    from .expr import raise_if_overflow
+    out, live, ovf = compiled(table, nvalid)
     metrics.host_sync(label="segment-boundary-compaction")
     with op_scope("engine.sync_wait", timed=True,
                   label="segment-boundary-compaction"):
+        raise_if_overflow(ovf)  # rides the fetch: a program with arithmetic
         return apply_boolean_mask(out, live)  # fetches the survivor count
 
 
 def _compact_padded(key_dtypes, kdat, kval, out_aggs, ngroups,
-                    names) -> Table:
+                    names, ovf=None) -> Table:
     """groupby's padded->compact tail for fused outputs (fixed-width only,
-    which runtime eligibility guarantees)."""
+    which runtime eligibility guarantees).  The overflow flag ``ovf``
+    rides the group count's fetch; an aggregate with no keys (one group,
+    known) fetches its columns and the flag in that one fetch instead."""
+    from .expr import raise_if_overflow
     metrics.host_sync(label="groupby-compaction")
+    if not key_dtypes:
+        with op_scope("engine.sync_wait", timed=True,
+                      label="groupby-compaction"):
+            datas, valids, ovf = jax.device_get(
+                (tuple(c.data for c in out_aggs),
+                 tuple(c.validity for c in out_aggs), ovf))
+        raise_if_overflow(ovf)
+        return Table([Column(c.dtype, data=jnp.asarray(d[:1]),
+                             validity=None if v is None
+                             else jnp.asarray(v[:1]))
+                      for c, d, v in zip(out_aggs, datas, valids)], names)
     with op_scope("engine.sync_wait", timed=True,
                   label="groupby-compaction"):
-        ng = int(ngroups)  # the one host sync
+        ng, ovf = jax.device_get((ngroups, ovf))  # the one host sync
+    raise_if_overflow(ovf)
+    ng = int(ng)
     cols = []
     for dtype, data, valid in zip(key_dtypes, kdat, kval):
         v = np.asarray(valid)[:ng]
@@ -831,9 +885,9 @@ def run_agg_segment(compiled: CompiledSegment, table: Table,
                     nvalid=None) -> Table:
     """Fused chain + aggregate, compacted to the final group rows."""
     agg = compiled.segment.agg
-    kdat, kval, out_aggs, _glive, ngroups = compiled(table, nvalid)
+    kdat, kval, out_aggs, _glive, ngroups, ovf = compiled(table, nvalid)
     return _compact_padded(compiled.key_dtypes, kdat, kval, out_aggs,
-                           ngroups, list(agg.keys) + list(agg.names))
+                           ngroups, list(agg.keys) + list(agg.names), ovf)
 
 
 @jax.jit
@@ -848,10 +902,14 @@ COMBINE_ARITY = 16
 
 
 def _merge_padded(partials: list, compiled: CompiledSegment, width: int,
-                  sync_label: str, **span_stats) -> tuple:
+                  sync_label: Optional[str], **span_stats) -> tuple:
     """Size and launch ONE merge of ``partials`` — ``[(kdat, kval,
-    out_aggs, glive, ngroups), ...]``, at most ``width`` of them — and
-    return the program's padded ``(kdat, kval, out_aggs, ngroups)``.
+    out_aggs, glive, ngroups, ovf), ...]``, at most ``width`` of them —
+    and return the program's padded ``(kdat, kval, out_aggs, ngroups,
+    ovf)``.
+
+    A keyless aggregate's partials hold one slot each, known on the host:
+    ``sync_label`` None, no sizing fetch, capacity 1.
 
     One host sync (the caller has counted it under ``sync_label``), the
     scalar ``max(ngroups)`` fetch that sizes the merge, and it matters:
@@ -872,12 +930,15 @@ def _merge_padded(partials: list, compiled: CompiledSegment, width: int,
     from ..ops.parquet_decode import bucket
     nreal = len(partials)
     filled = tuple(partials) + (partials[-1],) * (width - nreal)
-    # where the host waits until the device has drained every segment
-    # streamed so far: the first fetch after their launches
-    with op_scope("engine.sync_wait", timed=True, label=sync_label):
-        maxng = int(_max_ngroups(tuple(p[4] for p in filled)))
-    cap = bucket(maxng, 64)
-    filled = tuple(p[:4] for p in filled)
+    if sync_label is None:
+        cap = 1
+    else:
+        # where the host waits until the device has drained every segment
+        # streamed so far: the first fetch after their launches
+        with op_scope("engine.sync_wait", timed=True, label=sync_label):
+            maxng = int(_max_ngroups(tuple(p[4] for p in filled)))
+        cap = bucket(maxng, 64)
+    filled = tuple(p[:4] + (p[5],) for p in filled)
     merge = SEGMENT_CACHE.get_combine(compiled, cap, filled)
     with op_scope("engine.combine", timed=True, partials=nreal, cap=cap,
                   **span_stats):
@@ -910,6 +971,11 @@ class StreamedPartials:
     program, never a dropped group).  Sums, counts, minima and maxima
     merge associatively (``STREAM_COMBINE``), so folding changes no
     result but the last bits of a float sum whose order matters.
+
+    An aggregate with no group keys has one-slot partials: neither a fold
+    nor the final merge fetches a size, and the result's one fetch
+    (``compact``) is the stream's only host sync, however long it is.
+    The overflow flags of the partials ride every merge into that fetch.
     """
 
     __slots__ = ("pending", "compiled", "folds", "held")
@@ -930,11 +996,14 @@ class StreamedPartials:
             return
         self.folds += 1
         metrics.count("engine.combine.folds")
-        metrics.host_sync(label="combine-fold-sizing")
-        kdat, kval, out_aggs, ngroups = _merge_padded(
-            self.pending, self.compiled, COMBINE_ARITY,
-            "combine-fold-sizing", fold=self.folds, final=0)
-        self.pending = [(kdat, kval, out_aggs, ngroups, ngroups)]
+        label = None
+        if self.compiled.segment.agg.keys:
+            label = "combine-fold-sizing"
+            metrics.host_sync(label="combine-fold-sizing")
+        kdat, kval, out_aggs, ngroups, ovf = _merge_padded(
+            self.pending, self.compiled, COMBINE_ARITY, label,
+            fold=self.folds, final=0)
+        self.pending = [(kdat, kval, out_aggs, ngroups, ngroups, ovf)]
 
     def add(self, partial: tuple, compiled: CompiledSegment) -> None:
         self.pending.append(partial)
@@ -949,13 +1018,15 @@ class StreamedPartials:
             width, stats = COMBINE_ARITY, {"fold": self.folds + 1}
         else:
             width, stats = bucket(len(self.pending), 1), {}
-        metrics.host_sync(label="combine-sizing")
-        kdat, kval, out_aggs, ngroups = _merge_padded(
-            self.pending, self.compiled, width, "combine-sizing",
-            final=1, **stats)
         agg = self.compiled.segment.agg
+        label = None
+        if agg.keys:
+            label = "combine-sizing"
+            metrics.host_sync(label="combine-sizing")
+        kdat, kval, out_aggs, ngroups, ovf = _merge_padded(
+            self.pending, self.compiled, width, label, final=1, **stats)
         return PaddedPartial(self.compiled.key_dtypes, kdat, kval, out_aggs,
-                             ngroups, list(agg.keys) + list(agg.names))
+                             ngroups, list(agg.keys) + list(agg.names), ovf)
 
     def finish(self) -> Table:
         """The final merge and its compaction: the aggregate's Table."""
@@ -969,15 +1040,18 @@ class PaddedPartial:
     scalar nobody has fetched.  ``compact`` is the padded->compact tail
     (one sync); a ``tail`` stage takes the partial as it is."""
 
-    __slots__ = ("key_dtypes", "kdat", "kval", "aggs", "ngroups", "names")
+    __slots__ = ("key_dtypes", "kdat", "kval", "aggs", "ngroups", "names",
+                 "ovf")
 
-    def __init__(self, key_dtypes, kdat, kval, aggs, ngroups, names):
+    def __init__(self, key_dtypes, kdat, kval, aggs, ngroups, names,
+                 ovf=None):
         self.key_dtypes = tuple(key_dtypes)
         self.kdat = tuple(kdat)
         self.kval = tuple(kval)
         self.aggs = tuple(aggs)
         self.ngroups = ngroups
         self.names = list(names)
+        self.ovf = ovf          # the overflow flag the merge left, or None
 
     @property
     def parts(self) -> tuple:
@@ -998,7 +1072,7 @@ class PaddedPartial:
 
     def compact(self) -> Table:
         return _compact_padded(self.key_dtypes, self.kdat, self.kval,
-                               self.aggs, self.ngroups, self.names)
+                               self.aggs, self.ngroups, self.names, self.ovf)
 
 
 def combine_partials(partials: list, compiled: CompiledSegment) -> Table:
@@ -1063,12 +1137,18 @@ def build_tail(root: PlanNode, source: Aggregate, scan: PlanNode,
     over ``scan``, which stays a stage of its own.  Any other node on the
     way (an Exchange, a cross join, a join fed from the right), or an
     interior node with a second parent, and there is no tail."""
+    from .expr import is_arith
+    if not source.keys:
+        return None     # one row: nothing above it is worth a program
     dep: dict = {}
     chain = []
     cur = root
     while cur is not source:
         if cur is not root and nparents.get(id(cur), 1) != 1:
             return None
+        if (isinstance(cur, Project) and cur.computed) or \
+                (isinstance(cur, Filter) and is_arith(cur.predicate)):
+            return None     # a tail moves columns; it computes none
         if isinstance(cur, _TAIL_UNARY):
             below = cur.child
         elif isinstance(cur, Aggregate) and _agg_fusable(cur):
@@ -1189,7 +1269,7 @@ def _build_tail_fn(tail: Tail, compiled: "CompiledTail"):
         from ..ops.aggregate import groupby_padded
         from ..ops.order import SortKey, encode_keys
         from ..ops.selection import nonzero_indices
-        from .executor import eval_expr
+        from .expr import evaluate
         compiled.traces += 1  # trace-time side effect, as in _build_fn
         kdat, kval, aggs, ngroups = part
         slots = kdat[0].shape[0]
@@ -1221,7 +1301,9 @@ def _build_tail_fn(tail: Tail, compiled: "CompiledTail"):
 
         for nd in tail.nodes:
             if isinstance(nd, Filter):
-                vals, valid = eval_expr(nd.predicate, Table(cols, names))
+                # no arithmetic here (``build_tail``): nothing to check
+                vals, valid, _ = evaluate(nd.predicate, Table(cols, names),
+                                          [])
                 m = jnp.asarray(vals, jnp.bool_)
                 if valid is not None:
                     m = m & valid  # SQL semantics: NULL comparison drops
@@ -1291,6 +1373,9 @@ class CompiledTail(CompiledSegment):
         self.tail = tail
         self.key_dtypes = key_dtypes
         self.probes = ()        # counted by the chunk programs only
+        from .expr import count_nodes
+        self.exprs = sum(count_nodes(nd.predicate) for nd in tail.nodes
+                         if isinstance(nd, Filter))
         self.traces = 0
         self.calls = 0
         self.out_names = self.out_dtypes = self.out_vk = None
@@ -1348,11 +1433,13 @@ def run_tail(compiled: CompiledTail, part: PaddedPartial,
     result's columns and the program's evidence come in one batched fetch,
     and the result is made of the fetched buffers as they are — the rows
     are few, and a result's next stop is the wire."""
+    from .expr import raise_if_overflow
     datas, valids, nlive, nulls, spill = compiled(part.parts, dims)
     metrics.host_sync(label="tail-compaction")
     with op_scope("engine.sync_wait", timed=True, label="tail-compaction"):
-        datas, valids, nlive, nulls, spill, ngroups = jax.device_get(
-            (datas, valids, nlive, nulls, spill, part.ngroups))
+        datas, valids, nlive, nulls, spill, ngroups, ovf = jax.device_get(
+            (datas, valids, nlive, nulls, spill, part.ngroups, part.ovf))
+    raise_if_overflow(ovf)
     if int(spill):
         return None
     n = int(nlive)

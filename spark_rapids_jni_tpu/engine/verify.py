@@ -42,10 +42,10 @@ import numpy as np
 
 from ..dtypes import BOOL8, FLOAT64, INT64, LIST, DType
 from .physical import lower
-from .plan import (ORDER_SENSITIVE_AGGS, Aggregate, Exchange, Filter, Join,
-                   Limit, PlanNode, Project, Scan, Sort, TopK, co_partitioned,
-                   expr_columns, node_label, node_paths, partitioning,
-                   topo_nodes)
+from .plan import (ARITH_OPS, ORDER_SENSITIVE_AGGS, Aggregate, Exchange,
+                   Filter, Join, Limit, PlanNode, Project, Scan, Sort, TopK,
+                   co_partitioned, expr_columns, node_label, node_paths,
+                   partitioning, topo_nodes)
 
 #: the deliberate host-sync sites the engine is allowed to pay
 #: (metrics.host_sync labels; the AST lint in tools/srjt_lint.py rejects
@@ -88,7 +88,8 @@ class PlanVerificationError(ValueError):
     Structured so the bridge can ship it as a machine-parseable error
     reply: ``code`` names the check (``unknown-column``,
     ``join-key-dtype-mismatch``, ``invalid-cast``, ``overflow-unsafe-cast``,
-    ``aggregate-over-string``, ``order-sensitive-exchange``,
+    ``aggregate-over-string``, ``arithmetic-over-string``,
+    ``date-decimal-mix``, ``invalid-arithmetic``, ``order-sensitive-exchange``,
     ``rewrite-schema-change``, ``rewrite-nullability-change``,
     ``unknown-node``), ``node_path`` locates the offending node from the
     root (``root.child.left`` ...).
@@ -235,7 +236,10 @@ def _agg_out_dtype(op: str, dt: Optional[DType]) -> Optional[DType]:
             return FLOAT64
         if dt.is_integral:
             return INT64
-        return dt  # decimal sums keep their scale
+        if dt.is_decimal:   # Spark: decimal(min(38, p+10), s)
+            from .expr import sum_type
+            return sum_type(dt)
+        return dt
     return dt  # min/max/first/last
 
 
@@ -256,11 +260,34 @@ def _expr_dtype(expr, schema: dict, path: str,
         return schema[expr[1]]
     if head == "lit":
         return _lit_dtype(expr[1])
+    if head in ("lit_decimal", "lit_date"):
+        from .expr import literal
+        value, dt = literal(expr)
+        if head == "lit_decimal" and not _fits_int64(value):
+            raise PlanVerificationError(
+                "overflow-unsafe-cast", path,
+                f"{node_label(node)}: decimal literal {expr!r} does not fit "
+                "the engine's int64 decimal storage")
+        return dt
     if head == "not":
         _expr_dtype(expr[1], schema, path, node)
         return BOOL8
     a = _expr_dtype(expr[1], schema, path, node)
     b = _expr_dtype(expr[2], schema, path, node)
+    from .expr import ExprTypeError, arith_dtype, compare_check
+    # a plain literal is typed by its value (Spark's rule), not as INT64
+    (ta, va), (tb, vb) = ((None, e[1]) if e[0] == "lit" else (t, None)
+                          for e, t in ((expr[1], a), (expr[2], b)))
+    try:
+        if head in ARITH_OPS:
+            if (ta is None and va is None) or (tb is None and vb is None):
+                return None     # schema unknown: the run decides
+            return arith_dtype(head, ta, tb, va, vb)
+        if head not in ("&", "|"):
+            compare_check(head, ta, tb, va, vb)
+    except ExprTypeError as e:
+        raise PlanVerificationError(e.code, path,
+                                    f"{node_label(node)}: {e}") from None
     if head in ("&", "|"):
         for side in (a, b):
             if side is not None and (side.is_string or side.is_nested):
@@ -298,7 +325,14 @@ def _check_lit_overflow(head, col_dt: Optional[DType], value, path: str,
     wrong instead of merely slow (``overflow-unsafe-cast``)."""
     if col_dt is None or isinstance(value, bool):
         return
-    if col_dt.is_integral and isinstance(value, int):
+    if col_dt.is_decimal and isinstance(value, int):
+        # the literal is brought to the column's scale: units must fit
+        if not _fits_int64(value * 10 ** -col_dt.scale):
+            raise PlanVerificationError(
+                "overflow-unsafe-cast", path,
+                f"{node_label(node)}: literal {value} at the {col_dt!r} "
+                f"column's scale overflows int64 in comparison {head!r}")
+    elif col_dt.is_integral and isinstance(value, int):
         info = np.iinfo(col_dt.storage)
         if not (int(info.min) <= value <= int(info.max)):
             raise PlanVerificationError(
@@ -320,6 +354,10 @@ def _check_lit_overflow(head, col_dt: Optional[DType], value, path: str,
                 f"{node_label(node)}: integer literal {value} is not exactly "
                 f"representable as {col_dt!r} (past 2^53) in comparison "
                 f"{head!r}")
+
+
+def _fits_int64(v: int) -> bool:
+    return -2 ** 63 <= v < 2 ** 63
 
 
 # -- per-node infer_schema rules (the verifier dispatch table) --------------
@@ -380,13 +418,16 @@ def _infer_project(node: Project, path: str, ctx: _Ctx) -> Optional[dict]:
     child = _infer(node.child, path + ".child", ctx)
     if child is None:
         return None
-    missing = [c for c in node.columns if c not in child]
+    missing = [c for c in node.columns if isinstance(c, str)
+               and c not in child]
     if missing:
         raise PlanVerificationError(
             "unknown-column", path,
             f"project selects unknown column(s) {missing} "
             f"(child has: {sorted(child)})")
-    return {c: child[c] for c in node.columns}
+    return {name: child[e[1]] if e[0] == "col"
+            else _expr_dtype(e, child, path, node)
+            for name, e in node.items}
 
 
 def _infer_join(node: Join, path: str, ctx: _Ctx) -> Optional[dict]:
@@ -545,6 +586,13 @@ def verify(plan: PlanNode,
     return _infer(plan, "root", _Ctx(resolver or SchemaResolver()))
 
 
+def schema_view(resolver: Optional[SchemaResolver] = None):
+    """``node -> {name: DType} | None`` over one plan's nodes, inference
+    shared between calls (``verify`` infers from scratch each time)."""
+    ctx = _Ctx(resolver or SchemaResolver())
+    return lambda node: _infer(node, "root", ctx)
+
+
 # -- nullability abstract interpretation ------------------------------------
 
 def _nulls_scan(node: Scan, path: str, ctx: _Ctx) -> Optional[dict]:
@@ -561,7 +609,7 @@ def _nulls_filter(node: Filter, path: str, ctx: _Ctx) -> Optional[dict]:
     if child is None:
         return None
     # the executor ANDs the validity of EVERY predicate-referenced column
-    # into the keep-mask (engine/executor.eval_expr), so survivors are
+    # into the keep-mask (engine/expr.py::evaluate), so survivors are
     # proven non-null in those columns regardless of the operator tree
     out = dict(child)
     for c in expr_columns(node.predicate):
@@ -574,7 +622,11 @@ def _nulls_project(node: Project, path: str, ctx: _Ctx) -> Optional[dict]:
     child = _nulls(node.child, path + ".child", ctx)
     if child is None:
         return None
-    return {c: child[c] for c in node.columns if c in child}
+    # a computed column is null where any column it reads is
+    return {name: NULL_NEVER if all(child.get(c) == NULL_NEVER
+                                    for c in expr_columns(e)) else NULL_MAYBE
+            for name, e in node.items
+            if e[0] != "col" or e[1] in child}
 
 
 def _nulls_join(node: Join, path: str, ctx: _Ctx) -> Optional[dict]:
@@ -609,6 +661,8 @@ def _nulls_aggregate(node: Aggregate, path: str, ctx: _Ctx) -> Optional[dict]:
             out[outname] = NULL_NEVER  # counts and lists always materialize
         elif cname is None:
             out[outname] = NULL_NEVER
+        elif not node.keys:
+            out[outname] = NULL_MAYBE  # one row, an empty input's: NULL
         else:
             out[outname] = child.get(cname, NULL_MAYBE)
     return out
@@ -850,10 +904,11 @@ def sync_budget(plan: PlanNode, resolver: Optional[SchemaResolver] = None,
     one entry per sync, ``site`` naming the whitelisted call site, ``path``
     the stage that pays it.  Charges each stage of ``physical.lower`` what
     ``physical.SYNC_CHARGES`` says its kind pays; equals the runtime
-    ``engine.host_sync`` counter less ``engine.combine.folds`` — a
+    ``engine.host_sync`` counter less ``engine.combine.folds`` — a keyed
     ``stream-agg`` of more than ``segment.COMBINE_ARITY`` chunks pays one
     ``combine-fold-sizing`` per fold, and how many chunks pruning leaves
-    is known at run time only (16 + 15 k chunks: k folds).
+    is known at run time only (16 + 15 k chunks: k folds); a keyless one
+    folds without a fetch.
 
     ``ndev`` is the mesh size the stages are lowered for (default: this
     process's — pass it to model a target mesh from a different host).

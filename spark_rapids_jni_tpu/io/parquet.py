@@ -312,8 +312,9 @@ def _interpret_schema_element(elem: dict) -> ColumnSchema | None:
         # parquet scale counts digits right of the point; engine scale is the
         # power-of-ten exponent of the stored integer (cudf convention)
         ours = -scale
-        return (dt.decimal32(ours) if phys == PT_INT32 and precision <= 9
-                else dt.decimal64(ours))
+        return (dt.decimal32(ours, precision)
+                if phys == PT_INT32 and precision <= 9
+                else dt.decimal64(ours, precision))
 
     if phys == PT_BOOLEAN:
         out = dt.BOOL8

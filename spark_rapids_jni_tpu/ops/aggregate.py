@@ -388,6 +388,90 @@ def _fast_groupby_padded(key_cols, agg_specs, row_mask):
     return out_keys, out_aggs, ngroups
 
 
+def _keyless_padded(agg_specs, row_mask, n: int):
+    """An aggregate with no group keys: ONE group, a masked reduction over
+    the rows — no sort, no segment ids.  Every output column has one row;
+    ``ngroups`` is 1 (Spark returns one row for an ungrouped aggregate, an
+    empty input's included: ``sum`` NULL there, ``count`` 0)."""
+    live = jnp.ones((n,), jnp.bool_) if row_mask is None else row_mask
+    out = []
+    for col, op in agg_specs:
+        if op == "count_all":
+            out.append(Column(INT64, data=jnp.sum(live, dtype=jnp.int64)[None]))
+            continue
+        valid = live & col.valid_mask()
+        count = jnp.sum(valid, dtype=jnp.int64)
+        has = (count > 0)[None]
+        if op == "count":
+            out.append(Column(INT64, data=count[None]))
+        elif op in ("sum", "mean"):
+            vals, out_dtype, is_float = _sum_dtype_and_vals(col, col.data,
+                                                            valid)
+            s = jnp.sum(jnp.where(valid, vals, jnp.zeros((), vals.dtype)))
+            if op == "mean":
+                m = s.astype(jnp.float64) / jnp.maximum(count, 1).astype(
+                    jnp.float64)
+                if col.dtype.is_decimal:
+                    m = m * (10.0 ** col.dtype.scale)
+                out.append(Column.fixed(FLOAT64, m[None], validity=has))
+            elif is_float:
+                out.append(Column.fixed(FLOAT64, s[None], validity=has))
+            else:
+                out.append(Column(out_dtype, data=s[None], validity=has))
+        elif op in ("var", "std", "sumsq", "fsum"):
+            vf = jnp.where(valid, _float64_vals(col, col.data), 0.0)
+            if op in ("var", "std"):
+                # two passes: moments about the mean (no cancellation)
+                mean = jnp.sum(vf) / jnp.maximum(count, 1).astype(
+                    jnp.float64)
+                vf = jnp.where(valid, vf - mean, 0.0)
+            s, q = jnp.sum(vf), jnp.sum(vf * vf)
+            if op in ("sumsq", "fsum"):
+                out.append(Column.fixed(FLOAT64, (q if op == "sumsq" else s)
+                                        [None], validity=has))
+                continue
+            nf = count.astype(jnp.float64)
+            var = jnp.maximum(q / jnp.maximum(nf - 1.0, 1.0), 0.0)
+            out.append(Column.fixed(
+                FLOAT64, (jnp.sqrt(var) if op == "std" else var)[None],
+                validity=(count > 1)[None]))
+        elif op in ("min", "max"):
+            if col.dtype.id in (TypeId.FLOAT32, TypeId.FLOAT64):
+                enc = _order._fixed_to_u64(col)
+                ident = jnp.uint64(2**64 - 1) if op == "min" \
+                    else jnp.uint64(0)
+                enc = jnp.where(valid, enc, ident)
+                red = jnp.min(enc, initial=ident) if op == "min" \
+                    else jnp.max(enc, initial=ident)
+                data = _order.decode_minmax_bits(red[None], col.dtype)
+            else:
+                info = jnp.iinfo(col.data.dtype)
+                ident = jnp.asarray(info.max if op == "min" else info.min,
+                                    col.data.dtype)
+                m = jnp.where(valid, col.data, ident)
+                red = (jnp.min(m, initial=ident) if op == "min"
+                       else jnp.max(m, initial=ident))
+                data = red[None]
+            out.append(Column(col.dtype, data=data, validity=has))
+        elif op in ("first", "last"):
+            # Spark first/last (ignoreNulls=False): the value at the first /
+            # last live row
+            idx = jnp.arange(n, dtype=jnp.int32)
+            pos = jnp.min(jnp.where(live, idx, n), initial=n) \
+                if op == "first" else jnp.max(jnp.where(live, idx, -1),
+                                              initial=-1)
+            any_row = (pos >= 0) & (pos < n)
+            at = jnp.clip(pos, 0, max(n - 1, 0))
+            data = col.data[at][None] if n else \
+                jnp.zeros((1,) + col.data.shape[1:], col.data.dtype)
+            ok = col.valid_mask()[at] & any_row if n else jnp.bool_(False)
+            out.append(Column(col.dtype, data=data, validity=ok[None]))
+        else:
+            raise ValueError(f"aggregation {op!r} over no group keys is "
+                             "not supported")
+    return [], out, jnp.int32(1)
+
+
 def _seg_ids(keys: list[SortKey], row_mask=None):
     """Sort+segment the rows; masked-out rows sort last as dead groups.
 
@@ -553,6 +637,10 @@ def groupby_padded(table: Table, key_names: list, aggs: list[tuple],
             (None if op == "count_all" else table.column(col_ref))
         resolved.append((col, op))
     agg_inputs = [c for c, _ in resolved if c is not None]
+    if not key_cols:
+        rows = row_mask.shape[0] if row_mask is not None else \
+            table.num_rows
+        return _keyless_padded(resolved, row_mask, rows)
     if key_cols and key_cols[0].data is not None \
             and key_cols[0].data.shape[0] > 0 \
             and _fast_eligible(key_cols, agg_inputs) \

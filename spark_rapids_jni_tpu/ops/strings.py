@@ -124,7 +124,7 @@ def equal(col: Column, other) -> Column:
     """Elementwise ``==`` against a python string or another STRING column.
 
     The kernel the interpreted Filter path lowers ``==``/``!=`` predicates
-    over STRING columns onto (executor.eval_expr) — raw ``col.data`` is a
+    over STRING columns onto (engine/expr.py::evaluate) — raw ``col.data`` is a
     chars buffer, so the generic jnp comparison is meaningless for strings.
     """
     mat, lengths = to_padded_bytes(col)

@@ -77,6 +77,18 @@ class BridgeTimeoutError(TransientError, TimeoutError):
     """A bridge socket op exceeded its deadline (SRJT_BRIDGE_TIMEOUT_S)."""
 
 
+class DecimalOverflowError(EngineError, ArithmeticError):
+    """A decimal (or integral) value outgrew the engine's checked int64
+    bound (engine/expr.py): the query fails, nothing wrapped is returned.
+    ``fatal``: the same data overflows the same way on a retry."""
+
+    code = "decimal-overflow"
+
+    def __init__(self, msg: str = ""):
+        super().__init__(msg if msg.startswith(self.code)
+                         else f"{self.code}: {msg}")
+
+
 #: substrings that mark a runtime allocation failure (jax raises
 #: XlaRuntimeError with a RESOURCE_EXHAUSTED status; host numpy raises
 #: MemoryError directly)
@@ -121,6 +133,7 @@ _WIRE_TYPES = {
     "QueryCancelledError": QueryCancelledError,
     "QueryTimeoutError": QueryTimeoutError,
     "BridgeTimeoutError": BridgeTimeoutError,
+    "DecimalOverflowError": DecimalOverflowError,
 }
 
 _KIND_FALLBACK = {

@@ -174,7 +174,8 @@ def test_q5_fold_of_a_long_stream(q5_calls, one_chip, tpu_branches):
             (rows,), a.dtype, sharding=one_chip), tree)
 
     count = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    merged = shaped(seg.COMBINE_ARITY * cap, partials[0][:3]) + (count,)
+    # q5 checks no overflow: a merged partial's flag is None, as a chunk's
+    merged = shaped(seg.COMBINE_ARITY * cap, partials[0][:3]) + (count, None)
     filled = (merged,) + (shaped(slots, partials[0]),) \
         * (seg.COMBINE_ARITY - 1)
     assert seg._partial_class(merged)[0] == ("merged", 2_048)
@@ -352,3 +353,67 @@ def test_host_exchange_on_four_chips(topo, tpu_branches):
                               sh.cap_bucket(3)) \
         .lower(datas, masks, masks[0]).compile()
     assert "all-to-all" in shuffle.as_text()
+
+
+def test_q6_chunk_program_and_keyless_merge(tmp_path, one_chip,
+                                            tpu_branches):
+    """TPC-H Q6's chunk program (PR 40: five typed comparisons, a decimal
+    multiply with its overflow check, a keyless masked sum) at the cell's
+    real chunk — 262,144 rows, the bucket of 250,051 — and the merge of 16
+    one-slot partials: sort-free, so both compile in seconds at full size."""
+    import importlib.util
+    import json
+
+    from spark_rapids_jni_tpu.engine import execute, optimize
+    from spark_rapids_jni_tpu.engine import segment as seg
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def load(rel, name):
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(root, "benchmarks", rel))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    q6 = load("queries/tpch_q6.py", "chipc_q6")
+    run = load("run.py", "chipc_run")
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "tpch_q6_sf1.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "benchmarks", "traffic",
+                           "q6_1994.json")) as f:
+        params = json.load(f)["params"]
+    frames = q6.tables(5, {"lineitem": 4_800})
+    paths = run.write_tables(frames, cfg, str(tmp_path))
+    calls = {"chunk": [], "merge": []}
+    chunk, merge = seg.CompiledSegment.__call__, seg.CompiledCombine.__call__
+
+    def chunk_call(self, table, nvalid=None, prepared=()):
+        calls["chunk"].append((self, table))
+        return chunk(self, table, nvalid, prepared)
+
+    def merge_call(self, partials, nreal):
+        calls["merge"].append((self, partials))
+        return merge(self, partials, nreal)
+
+    try:
+        seg.CompiledSegment.__call__ = chunk_call
+        seg.CompiledCombine.__call__ = merge_call
+        execute(optimize(q6.plan(paths, params, cfg["storage"]
+                                 ["chunk_bytes"])))
+    finally:
+        seg.CompiledSegment.__call__ = chunk
+        seg.CompiledCombine.__call__ = merge
+    compiled, table = calls["chunk"][0]
+    assert compiled.segment.agg.keys == () and compiled.exprs == 10
+    rows = 262_144
+    real = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (rows,) + a.shape[1:], a.dtype, sharding=one_chip), table)
+    count = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compile_for_chip(seg._build_fn(compiled.segment, compiled), real, count,
+                     ())
+    m, partials = calls["merge"][0]
+    assert len(partials) == seg.COMBINE_ARITY and m.key[1][0] == 1
+    compile_for_chip(
+        seg._build_combine_fn(m.segment.agg, m.key_dtypes, 1, m),
+        on(one_chip, partials), count)

@@ -330,15 +330,16 @@ def test_artifact_lint_clean_on_smoke_plans(warehouse):
 def test_artifact_lint_catches_injected_item(warehouse, monkeypatch):
     # the acceptance scenario: a synthetic .item()/float() smuggled into
     # the traced filter evaluator fails the STATIC lint, no execution
-    orig = executor.eval_expr
+    from spark_rapids_jni_tpu.engine import expr as expr_mod
+    orig = expr_mod.evaluate
 
-    def bad_eval(expr, table):
-        vals, valid = orig(expr, table)
+    def bad_eval(expr, table, ovf):
+        vals, valid, dt = orig(expr, table, ovf)
         if hasattr(vals, "sum"):
             float(vals.sum())  # concretizes the tracer
-        return vals, valid
+        return vals, valid, dt
 
-    monkeypatch.setattr(executor, "eval_expr", bad_eval)
+    monkeypatch.setattr(expr_mod, "evaluate", bad_eval)
     rep = lint_plan_artifacts(optimize(warehouse["q5"]))
     codes = {v["code"] for v in rep["violations"]}
     assert "host-concretization" in codes
@@ -389,15 +390,15 @@ def test_ast_rules_fire_on_synthetic_sources():
         fl.visit(ast.parse(src))
         return [v["code"] for v in fl.out]
 
-    traced = "spark_rapids_jni_tpu/engine/executor.py"
-    assert run("def eval_expr(e, t):\n    return float(x.sum())\n",
+    traced = "spark_rapids_jni_tpu/engine/expr.py"
+    assert run("def evaluate(e, t, o):\n    return float(x.sum())\n",
                traced) == ["traced-host-op"]
-    assert run("def eval_expr(e, t):\n    return x.item()\n",
+    assert run("def evaluate(e, t, o):\n    return x.item()\n",
                traced) == ["traced-host-op"]
-    assert run("def eval_expr(e, t):\n    return np.asarray(x)\n",
+    assert run("def evaluate(e, t, o):\n    return np.asarray(x)\n",
                traced) == ["traced-host-op"]
     # literal casts and code outside traced functions are fine
-    assert run("def eval_expr(e, t):\n    return float('nan')\n",
+    assert run("def evaluate(e, t, o):\n    return float('nan')\n",
                traced) == []
     assert run("def helper(x):\n    return x.item()\n", traced) == []
     # host-sync sites need whitelisted literal labels

@@ -380,7 +380,8 @@ def test_rehearsal_every_join_of_every_chunk_took_the_compare_path(rehearsal):
     compiled = calls[0][0]
     assert compiled.probes == ("compare",) * rehearsal["joins"]
     assert compiled.span_stats() == {
-        "probe_compare": f"{rehearsal['joins']}/{rehearsal['joins']}"}
+        "probe_compare": f"{rehearsal['joins']}/{rehearsal['joins']}",
+        "exprs": compiled.segment.exprs()}     # PR 40's stat beside it
     # the forced merge-rank run counts the other way: compare + rank is
     # joins x chunks either way
     _, rstats, rgrew, _, _ = rehearsal["rank"]

@@ -726,10 +726,20 @@ def test_request_path_readers_are_in_the_benchmark():
     assert [m["name"] for m in mine] == [
         "plan_decode_ms", "client_turnaround_ms", "precompute_ms",
         "tail_host_ms", "stage_pack_wait_ms", "request_span_coverage_pct"]
-    assert mine == bench["per_layer"][-6:]      # appended, nothing moved
+    at = bench["per_layer"].index(mine[0])
+    assert mine == bench["per_layer"][at:at + 6]    # appended together
+    # each lists the six cells it was accepted with (PR 38): a later cell
+    # is not appended to an accepted entry, which would edit the benchmark
     for m in mine:
-        assert m["workloads"] == cells and m["moves"] == "fact_rows_per_s"
+        assert m["workloads"] == ACCEPTED_WITH \
+            and m["moves"] == "fact_rows_per_s"
         assert m["source"] == "program_span"
+    assert set(ACCEPTED_WITH) <= set(cells)
+
+
+#: the cells PR 38's request-path readers were accepted with
+ACCEPTED_WITH = ["q5lite_sf1_year", "q55lite_sf1_nov1999", "q5lite_sf1_14day",
+                 "q5lite_sf1_mesh4", "q5lite_sf1_c4", "q5lite_sf10_year"]
 
 
 # -- (f) launches inside a derived interval ------------------------------------------

@@ -7,8 +7,9 @@ Seven stdlib-``ast`` rules over ``spark_rapids_jni_tpu/`` + ``tools/``:
   / ``np.asarray`` / ``.tolist()`` / ``jax.device_get`` /
   ``.block_until_ready()`` inside the segment-traced code paths
   (``segment._build_fn`` / ``segment._probe_join_node`` /
-  ``executor.eval_expr``): any of these concretizes a tracer, turning the
-  zero-sync fused chunk program into a per-chunk host round-trip.
+  ``expr.evaluate`` and its helpers): any of these concretizes a tracer,
+  turning the zero-sync fused chunk program into a per-chunk host
+  round-trip.
 - **config-env-read** — ``os.environ`` / ``os.getenv`` only in
   ``utils/config.py``; everything else reads the ``config`` singleton so
   ``refresh()`` stays the one switchboard.  Env *writes*
@@ -84,7 +85,10 @@ PKG = "spark_rapids_jni_tpu"
 TRACED_FUNCS = {
     f"{PKG}/engine/segment.py": {"_build_fn", "_probe_join_node",
                                  "_build_fused_fn", "_build_decode_fn"},
-    f"{PKG}/engine/executor.py": {"eval_expr"},
+    f"{PKG}/engine/expr.py": {"evaluate", "_arith", "_align", "_rescale",
+                              "_as_float", "_estimate", "_flag", "_i64",
+                              "project", "column_of", "sum_check",
+                              "any_flag"},
 }
 
 #: modules the executor is built on: they import nothing from it
@@ -453,6 +457,13 @@ def render_metrics_doc(catalog: dict) -> str:
         "the lint (`unregistered-metric`), and a row with no remaining",
         "call site fails it too (`stale-metric`) — every metric rename is",
         "one reviewable catalog diff.",
+        "",
+        "The benchmark's per-layer readers (`benchmarks/layer_metrics/`)",
+        "read these names; root `PERF.md` §3 says which reads which.  The",
+        "newest, `expr_fused_pct` (PR 40, cell `tpch_q6_sf1_1994`), is the",
+        "growth of `engine.expr.fused` over that of `engine.expr.fused` +",
+        "`engine.expr.eager`, in %: the share of expression nodes that ran",
+        "compiled into a chunk program.",
         "",
         "| name | kind | call sites |",
         "|---|---|---|",
