@@ -1018,9 +1018,15 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
             # live-progress denominator from footer metadata (no page
             # decode)
             pqm.progress_total(reader.footer_chunk_estimate())
-        kept = len(reader.kept_groups())
+        kept = reader.kept_groups()
         sp.stat(groups=reader.file.num_row_groups,
-                pruned=reader.file.num_row_groups - kept)
+                pruned=reader.file.num_row_groups - len(kept))
+        # the key domain the footer shows over the kept groups: the chunk
+        # program's aggregate takes the dense form over it (None: sorted)
+        domain = None if seg is None else \
+            sg.agg_domain(seg, reader.file, kept, reader.columns)
+        dense_k, lo = (None, None) if domain is None else \
+            (domain[1], jnp.asarray(domain[0], jnp.int64))
 
     partials: list = []          # interpreted path: compacted Tables
     fused = sg.StreamedPartials()   # fused path: padded device partials
@@ -1114,11 +1120,11 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                         if kind == "dev":
                             ctx.recovery.charge(payload.comp_bytes)
                             fused_compiled = sg.SEGMENT_CACHE.get_decode(
-                                seg, payload.geom, build_tables)
+                                seg, payload.geom, build_tables, dense_k)
                             with op_scope("engine.fused_segment",
                                           **fused_compiled.span_stats()):
                                 fused.add(fused_compiled(
-                                    planes, payload.nrows, preps),
+                                    planes, payload.nrows, preps, lo),
                                     fused_compiled)
                             nvalid, padded = payload.nrows, 0
                             cb = payload.comp_bytes
@@ -1136,22 +1142,23 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                             padded = chunk.num_rows - nvalid
                             ctx.recovery.charge(cb)
                             fused_compiled = sg.SEGMENT_CACHE.get(
-                                seg, chunk, build_tables)
+                                seg, chunk, build_tables, dense_k)
                             with op_scope("engine.fused_segment",
                                           **fused_compiled.span_stats()):
                                 fused.add(fused_compiled(
-                                    chunk, nvalid, preps), fused_compiled)
+                                    chunk, nvalid, preps, lo),
+                                    fused_compiled)
                     else:
                         chunk, nvalid = item
                         cb = table_nbytes(chunk)
                         padded = chunk.num_rows - nvalid
                         ctx.recovery.charge(cb)
-                        fused_compiled = sg.SEGMENT_CACHE.get(seg, chunk,
-                                                              build_tables)
+                        fused_compiled = sg.SEGMENT_CACHE.get(
+                            seg, chunk, build_tables, dense_k)
                         with op_scope("engine.fused_segment",
                                       **fused_compiled.span_stats()):
-                            fused.add(fused_compiled(chunk, nvalid, preps),
-                                      fused_compiled)
+                            fused.add(fused_compiled(chunk, nvalid, preps,
+                                                     lo), fused_compiled)
                     if qm is not None:
                         # per-chunk latency is dispatch time — the fused
                         # loop never syncs per chunk, by design

@@ -388,24 +388,62 @@ def _probe_join_node(nd: Join, pb, table: Table, live, needed):
     return Table(cols, names), live
 
 
+def _passes_through(seg: Segment, name: str) -> bool:
+    """Does column ``name`` of the segment's input reach its aggregate
+    unchanged?  Filters and joins keep it (an inner join's payload of the
+    same name is renamed ``_r``); every Project must pass it as it is."""
+    return all(dict(nd.items).get(name) == ("col", name)
+               for nd in seg.chain if isinstance(nd, Project))
+
+
+def agg_domain(seg: Segment, file, groups, columns=None) -> Optional[tuple]:
+    """``(lo, slots)`` of the dense form (``ops.aggregate.groupby_dense``)
+    for the chunk program of ``seg`` streamed from row groups ``groups`` of
+    the Parquet ``file`` (reading ``columns``, None: all), or None where
+    the sort form stays.  The dense form needs ONE group key that is an
+    integer column of the file reaching the aggregate unchanged,
+    ``DENSE_OPS`` aggregations, and footer statistics of that column in
+    every group, their range spanning at most ``DENSE_MAX_GROUPS`` slots.
+    The statistics only choose the program: it checks the keys itself."""
+    from ..ops.aggregate import DENSE_KEY_TYPES, DENSE_OPS, dense_slots
+    agg = seg.agg
+    if agg is None or len(agg.keys) != 1 or not groups \
+            or any(op not in DENSE_OPS for _, op in agg.aggs):
+        return None
+    key = agg.keys[0]
+    if key not in file.names or (columns is not None and key not in columns) \
+            or not _passes_through(seg, key) \
+            or file.schema[file.names.index(key)].dtype.id \
+            not in DENSE_KEY_TYPES:
+        return None
+    stats = [file.group_stats(gi, key) for gi in groups]
+    if any(st is None for st in stats):
+        return None
+    lo, hi = min(st[0] for st in stats), max(st[1] for st in stats)
+    slots = dense_slots(lo, hi)
+    return None if slots is None else (lo, slots)
+
+
 def _build_fn(seg: Segment, compiled: "CompiledSegment"):
     """The single program a segment traces into.
 
-    ``fn(table, nvalid, prepared)``: rows >= nvalid are padding (chunk
+    ``fn(table, nvalid, prepared, lo)``: rows >= nvalid are padding (chunk
     buckets); ``prepared`` carries one ``PreparedBuild`` pytree per Join
-    in the chain (execution order).  Map segments return (table, live,
-    ovf); agg segments return padded partial aggregates + group-live mask
-    + ovf — all device-resident, zero host syncs.  ``ovf`` is the program's
-    overflow flag (``engine/expr.py``: an arithmetic node or a decimal sum
-    outgrew int64's checked bound), None where it checks nothing.
+    in the chain (execution order); ``lo`` is the key domain's low end
+    where the aggregate takes the dense form (``compiled.dense_k`` slots),
+    else None.  Map segments return (table, live, ovf); agg segments
+    return padded partial aggregates + group-live mask + ovf — all
+    device-resident, zero host syncs.  ``ovf`` is the program's overflow
+    flag (``engine/expr.py``: an arithmetic node or a decimal sum outgrew
+    int64's checked bound), None where it checks nothing.
     """
     from .expr import decimal_sums
     chain, agg = seg.chain, seg.agg
     needed = {i: _needed_after(seg, i + 1)
               for i, nd in enumerate(chain) if isinstance(nd, Join)}
 
-    def fn(table: Table, nvalid, prepared=()):
-        from ..ops.aggregate import groupby_padded
+    def fn(table: Table, nvalid, prepared=(), lo=None):
+        from ..ops.aggregate import groupby_dense, groupby_padded
         from .expr import any_flag, evaluate, project, sum_check
         compiled.traces += 1  # trace-time side effect: the no-recompile proof
         live = jnp.arange(table.num_rows, dtype=jnp.int32) < nvalid
@@ -430,9 +468,14 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
             return table, live, any_flag(ovf)
         for c in decimal_sums(agg.aggs, table):
             sum_check(table.column(c), live, ovf)
-        out_keys, out_aggs, ngroups = groupby_padded(
-            table, list(agg.keys), [(c, op) for c, op in agg.aggs],
-            row_mask=live)
+        aggs = [(c, op) for c, op in agg.aggs]
+        if compiled.dense_k:
+            out_keys, out_aggs, ngroups = groupby_dense(
+                table, list(agg.keys), aggs, lo, compiled.dense_k,
+                row_mask=live)
+        else:
+            out_keys, out_aggs, ngroups = groupby_padded(
+                table, list(agg.keys), aggs, row_mask=live)
         npad = out_aggs[0].data.shape[0] if out_aggs else live.shape[0]
         glive = jnp.arange(npad, dtype=jnp.int32) < ngroups
         # dtypes are static metadata (CompiledSegment.key_dtypes); only the
@@ -444,41 +487,57 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
     return fn
 
 
+def _agg_form(segment: Segment, dense_k: Optional[int]) -> Optional[str]:
+    """How a chunk program computes its keyed aggregate: ``dense/<slots>``
+    or ``sorted``; None where it has none (no aggregate, or no keys)."""
+    if segment.agg is None or not segment.agg.keys:
+        return None
+    return f"dense/{dense_k}" if dense_k else "sorted"
+
+
 class CompiledSegment:
     """One (segment, shape-class) entry: a jitted callable plus the trace
     counter tests use to prove chunks reuse one executable.  ``probes``
     is ``probe_methods`` of the chain's joins for this shape class: what
-    the ``engine.probe.*`` counters and the span's stat report."""
+    the ``engine.probe.*`` counters and the span's stat report.
+    ``dense_k``: the slots of the aggregate's dense form, None for the sort
+    form; ``agg_form`` what the ``engine.agg.*`` counters and the span's
+    stat report of it (None: no keyed aggregate, or not a chunk program)."""
 
     __slots__ = ("key", "segment", "key_dtypes", "jfn", "traces", "calls",
-                 "probes", "exprs")
+                 "probes", "exprs", "dense_k", "agg_form")
 
     #: prefix of this program's compile-vs-replay events (``_tick``)
     counters = "engine.segment"
 
     def __init__(self, key: tuple, segment: Segment, key_dtypes: tuple,
-                 probes: tuple = ()):
+                 probes: tuple = (), dense_k: Optional[int] = None):
         self.key = key
         self.segment = segment
         self.key_dtypes = key_dtypes
         self.probes = probes
         self.exprs = segment.exprs()
+        self.dense_k = dense_k
+        self.agg_form = _agg_form(segment, dense_k)
         self.traces = 0
         self.calls = 0
         self.jfn = jax.jit(_build_fn(segment, self))
 
     def span_stats(self) -> dict:
         """Stats of the ``engine.fused_segment`` span around a launch: the
-        expression nodes compiled into it, the joins' probe methods."""
+        expression nodes compiled into it, the joins' probe methods, the
+        keyed aggregate's form."""
         out = {"exprs": self.exprs}
         if self.probes:
             out["probe_compare"] = \
                 f"{self.probes.count('compare')}/{len(self.probes)}"
+        if self.agg_form:
+            out["agg"] = self.agg_form
         return out
 
-    def __call__(self, table: Table, nvalid=None, prepared=()):
+    def __call__(self, table: Table, nvalid=None, prepared=(), lo=None):
         nv = jnp.int32(table.num_rows if nvalid is None else nvalid)
-        return self._launch(table, nv, tuple(prepared))
+        return self._launch(table, nv, tuple(prepared), lo)
 
     def _launch(self, *args):
         self.calls += 1
@@ -489,6 +548,9 @@ class CompiledSegment:
             metrics.count("engine.probe.compare", compare)
         if len(self.probes) > compare:
             metrics.count("engine.probe.rank", len(self.probes) - compare)
+        if self.agg_form:
+            metrics.count("engine.agg.dense" if self.dense_k
+                          else "engine.agg.sorted")
         if not metrics.enabled() and not timeline.enabled():
             return self.jfn(*args)
         # compile-vs-replay tagging: ``traces`` ticks inside the traced fn,
@@ -526,8 +588,8 @@ def _build_decode_fn(seg: Segment, compiled: "CompiledSegment", geom):
     from ..ops.parquet_decode import decode_table
     inner = _build_fn(seg, compiled)
 
-    def fn(planes, nvalid, prepared=()):
-        return inner(decode_table(planes, geom), nvalid, prepared)
+    def fn(planes, nvalid, prepared=(), lo=None):
+        return inner(decode_table(planes, geom), nvalid, prepared, lo)
 
     return fn
 
@@ -542,12 +604,14 @@ class CompiledDecodeSegment(CompiledSegment):
     __slots__ = ("geom",)
 
     def __init__(self, key: tuple, segment: Segment, key_dtypes: tuple,
-                 geom, probes: tuple = ()):
+                 geom, probes: tuple = (), dense_k: Optional[int] = None):
         self.key = key
         self.segment = segment
         self.key_dtypes = key_dtypes
         self.probes = probes
         self.exprs = segment.exprs()
+        self.dense_k = dense_k
+        self.agg_form = _agg_form(segment, dense_k)
         self.traces = 0
         self.calls = 0
         self.geom = geom
@@ -657,6 +721,7 @@ class CompiledCombine(CompiledSegment):
         self.key_dtypes = key_dtypes
         self.probes = ()        # the merge probes nothing
         self.exprs = 0
+        self.dense_k = self.agg_form = None     # sort form, not counted
         self.traces = 0
         self.calls = 0
         self.jfn = jax.jit(_build_combine_fn(segment.agg, key_dtypes, cap,
@@ -672,6 +737,11 @@ class CompiledCombine(CompiledSegment):
         else:
             metrics.count("engine.combine.replay")
             metrics.observe("engine.combine.replay_dispatch_s", dt)
+
+
+def _dense_class(shape: tuple, dense_k: Optional[int]) -> tuple:
+    """A chunk program's shape class with its dense form's slot count."""
+    return shape if dense_k is None else shape + (("dense", dense_k),)
 
 
 def _resolve_dtype(name: str, table: Table, builds: tuple):
@@ -743,26 +813,31 @@ class SegmentCache:
                 metrics.count("engine.segment_cache.eviction")
             return compiled
 
-    def get(self, segment: Segment, table: Table,
-            builds: tuple = ()) -> CompiledSegment:
-        key = (segment.fingerprint(), shape_class(table),
+    def get(self, segment: Segment, table: Table, builds: tuple = (),
+            dense_k: Optional[int] = None) -> CompiledSegment:
+        """The chunk program of ``segment`` over ``table``'s shape class;
+        ``dense_k``: its aggregate's dense form over that many key slots
+        (``agg_domain``), a part of the shape class."""
+        key = (segment.fingerprint(), _dense_class(shape_class(table),
+                                                   dense_k),
                tuple(shape_class(b) for b in builds))
 
         def build():
             key_dtypes = () if segment.agg is None else tuple(
                 _resolve_dtype(k, table, builds) for k in segment.agg.keys)
             return CompiledSegment(key, segment, key_dtypes,
-                                   probe_methods(segment, builds))
+                                   probe_methods(segment, builds), dense_k)
 
         return self._lookup(key, build)
 
-    def get_decode(self, segment: Segment, geom,
-                   builds: tuple = ()) -> CompiledDecodeSegment:
+    def get_decode(self, segment: Segment, geom, builds: tuple = (),
+                   dense_k: Optional[int] = None) -> CompiledDecodeSegment:
         """The fused scan-decode variant of :meth:`get`: keyed by
         (fingerprint, page geometry, build shapes) — one executable per
         (plan segment, page-geometry bucket) class, shared by every chunk
         whose pages quantize to the same buckets."""
-        key = (segment.fingerprint(), ("device_decode", geom),
+        key = (segment.fingerprint(),
+               _dense_class(("device_decode", geom), dense_k),
                tuple(shape_class(b) for b in builds))
 
         def build():
@@ -771,7 +846,8 @@ class SegmentCache:
                 _resolve_dtype(k, probe_table(geom), builds)
                 for k in segment.agg.keys)
             return CompiledDecodeSegment(key, segment, key_dtypes, geom,
-                                         probe_methods(segment, builds))
+                                         probe_methods(segment, builds),
+                                         dense_k)
 
         return self._lookup(key, build)
 
@@ -1373,6 +1449,7 @@ class CompiledTail(CompiledSegment):
         self.tail = tail
         self.key_dtypes = key_dtypes
         self.probes = ()        # counted by the chunk programs only
+        self.dense_k = self.agg_form = None
         from .expr import count_nodes
         self.exprs = sum(count_nodes(nd.predicate) for nd in tail.nodes
                          if isinstance(nd, Filter))

@@ -946,6 +946,8 @@ class _TraceProbe:
 
     __slots__ = ("traces",)
 
+    dense_k = None      # the aggregate's sort form: no key domain is known
+
     def __init__(self):
         self.traces = 0
 

@@ -14,6 +14,8 @@ expressed as radix sort + scans, which map perfectly onto the VPU:
 ``groupby_padded`` is the fully jit-able core: output padded to n rows with a
 group-count scalar (static shapes for pjit pipelines — the distributed
 partial-aggregation path).  ``groupby`` compacts at the host boundary.
+``groupby_dense`` is its sort-free form for one integer key of a small,
+known domain: a masked reduction per key slot, guarded in the program.
 
 Null semantics match Spark: null keys form their own group (nulls equal in
 GROUP BY); null values are excluded from sum/min/max/mean/count(col), while
@@ -43,6 +45,21 @@ AGGS = ("sum", "min", "max", "mean", "count", "count_all", "var", "std",
 # ops the sort-carried fast path implements; first/last need positional
 # selection and collect_list is ragged (host-compacted in ``groupby``)
 _FAST_OPS = frozenset(AGGS) - {"first", "last", "collect_list"}
+
+#: the most key slots (a power of two) ``groupby_dense`` reduces over.  On a
+#: TPU v5e, over a 262,144-row chunk (``tools/agg_sweep.py``): 0.61-0.73 ms
+#: up to 256 slots, 1.18 ms (float64 sum) at 512, where the one-hot starts
+#: to be materialized, and no better than the sort form's 2.18 ms at 1,024
+DENSE_MAX_GROUPS = 512
+
+#: the aggregations the dense form computes: sums and counts of a slot
+DENSE_OPS = frozenset({"sum", "count", "count_all", "mean"})
+
+#: key types whose value order is their slots' order: the integers an int64
+#: holds
+DENSE_KEY_TYPES = frozenset({TypeId.INT8, TypeId.INT16, TypeId.INT32,
+                             TypeId.INT64, TypeId.UINT8, TypeId.UINT16,
+                             TypeId.UINT32})
 
 
 # ---------------------------------------------------------------------------
@@ -331,28 +348,15 @@ def _fast_groupby_padded(key_cols, agg_specs, row_mask):
             continue
         if kind in ("sum_psb", "mean_psb"):
             count_slot, cgrand, grand = extra
-            counts = psb_total(count_slot, cgrand)
-            s = psb_total(slot, grand)
-            has_any = counts > 0
-            if kind == "mean_psb":
-                m = s.astype(jnp.float64) / jnp.maximum(counts, 1).astype(
-                    jnp.float64)
-                if col.dtype.is_decimal:
-                    m = m * (10.0 ** col.dtype.scale)
-                out_aggs.append(Column.fixed(FLOAT64, m, validity=has_any))
-            else:
-                out_aggs.append(Column(out_dtype, data=s, validity=has_any))
+            out_aggs.append(_sum_result(
+                kind[:-4], col, psb_total(slot, grand),
+                psb_total(count_slot, cgrand), out_dtype, False))
             continue
         if kind in ("sum_scan", "mean_scan"):
             count_slot, cgrand = extra
-            counts = psb_total(count_slot, cgrand)
-            has_any = counts > 0
-            s = comp_e[slot]
-            if kind == "mean_scan":
-                m = s / jnp.maximum(counts, 1).astype(jnp.float64)
-                out_aggs.append(Column.fixed(FLOAT64, m, validity=has_any))
-            else:
-                out_aggs.append(Column.fixed(FLOAT64, s, validity=has_any))
+            out_aggs.append(_sum_result(
+                kind[:-5], col, comp_e[slot], psb_total(count_slot, cgrand),
+                out_dtype, True))
             continue
         if kind == "var_scan":
             s_slot, q_slot = slot
@@ -408,16 +412,8 @@ def _keyless_padded(agg_specs, row_mask, n: int):
             vals, out_dtype, is_float = _sum_dtype_and_vals(col, col.data,
                                                             valid)
             s = jnp.sum(jnp.where(valid, vals, jnp.zeros((), vals.dtype)))
-            if op == "mean":
-                m = s.astype(jnp.float64) / jnp.maximum(count, 1).astype(
-                    jnp.float64)
-                if col.dtype.is_decimal:
-                    m = m * (10.0 ** col.dtype.scale)
-                out.append(Column.fixed(FLOAT64, m[None], validity=has))
-            elif is_float:
-                out.append(Column.fixed(FLOAT64, s[None], validity=has))
-            else:
-                out.append(Column(out_dtype, data=s[None], validity=has))
+            out.append(_sum_result(op, col, s[None], count[None], out_dtype,
+                                   is_float))
         elif op in ("var", "std", "sumsq", "fsum"):
             vf = jnp.where(valid, _float64_vals(col, col.data), 0.0)
             if op in ("var", "std"):
@@ -470,6 +466,181 @@ def _keyless_padded(agg_specs, row_mask, n: int):
             raise ValueError(f"aggregation {op!r} over no group keys is "
                              "not supported")
     return [], out, jnp.int32(1)
+
+
+def _sum_result(op: str, col: Column, s, count, out_dtype,
+                is_float: bool) -> Column:
+    """A ``sum`` or ``mean`` Column from each group's total ``s`` and its
+    count of valid rows: null where the group had none (Spark)."""
+    has = count > 0
+    if op == "mean":
+        m = s.astype(jnp.float64) / jnp.maximum(count, 1).astype(jnp.float64)
+        if col.dtype.is_decimal:
+            m = m * (10.0 ** col.dtype.scale)
+        return Column.fixed(FLOAT64, m, validity=has)
+    if is_float:
+        return Column.fixed(FLOAT64, s, validity=has)
+    return Column(out_dtype, data=s, validity=has)
+
+
+# ---------------------------------------------------------------------------
+# dense form: one integer key of a small, known domain — no sort
+#
+# When every live key is known to lie in [lo, lo + slots), a group is a
+# slot, ``key - lo``, and each aggregation a masked reduction over the
+# one-hot ``slot == j`` (the shape of ``ops/join.py::select_build_rows``):
+# no sort, no scan, no gather or scatter.  The present slots are then
+# compacted to the front in key order, so the result is ``groupby_padded``'s
+# — same rows, dtypes, validity and group order.  Who knows the domain is
+# the caller (a streamed file's footer statistics: ``engine/segment.py``);
+# the answer never rests on it: ``groupby_dense`` checks inside the program
+# that every live key does lie in it, and a chunk where one does not takes
+# the sort form (``lax.cond``: no host sync).
+# ---------------------------------------------------------------------------
+
+def dense_slots(lo: int, hi: int):
+    """The dense form's slot count for keys known to lie in ``[lo, hi]``:
+    the power of two >= ``hi - lo + 1``, or None above
+    ``DENSE_MAX_GROUPS`` (a null key takes one slot more, in the program)."""
+    span = hi - lo + 1
+    if span < 1:
+        return None
+    slots = 1 << (span - 1).bit_length()
+    return slots if slots <= DENSE_MAX_GROUPS else None
+
+
+def groupby_dense(table: Table, key_names: list, aggs: list[tuple], lo,
+                  slots: int, row_mask=None):
+    """``groupby_padded(table, key_names, aggs, row_mask=row_mask)`` for
+    ONE key of a ``DENSE_KEY_TYPES`` type whose live values are expected in
+    ``[lo, lo + slots)`` — ``lo`` a traced int64 scalar, ``slots`` static —
+    and ``DENSE_OPS`` aggregations.  A null key has a slot of its own,
+    first (nulls sort first).  Where a live non-null key lies outside the
+    range, the sort form's result is taken instead."""
+    key = table.column(key_names[0])
+    resolved = [(None if op == "count_all" else
+                 c if isinstance(c, Column) else table.column(c), op)
+                for c, op in aggs]
+    n = key.data.shape[0]
+    live = jnp.ones((n,), jnp.bool_) if row_mask is None else row_mask
+    nullable = key.validity is not None
+    with jax.named_scope("groupby_dense"):
+        k64 = key.data.astype(jnp.int64)
+        d = k64 - lo                    # wraps only where k64 - lo >= 2**63
+        has_key = live & key.validity if nullable else live
+        inside = (k64 >= lo) & (d >= 0) & (d < slots)
+        fits = jnp.all(inside | ~has_key)
+        slot = jnp.where(has_key, d.astype(jnp.int32) + np.int32(nullable),
+                         jnp.where(live, np.int32(0), np.int32(-1)))
+        dense = _dense_groupby(key, resolved, live, slot, lo,
+                               slots + nullable)
+
+    def sort_form():
+        out_keys, out_aggs, ngroups = groupby_padded(table, key_names, aggs,
+                                                     row_mask=row_mask)
+        return out_keys[0][2], out_keys[0][3], out_aggs, ngroups
+
+    with jax.named_scope("groupby_dense"):
+        # the branch that keeps the dense result returns zeros, and a select
+        # takes it: a branch that returns its operands makes the TPU
+        # compiler abort (HloReplicationAnalysis, jax 0.9's libtpu)
+        sort = jax.lax.cond(fits,
+                            lambda: jax.tree.map(jnp.zeros_like, dense),
+                            sort_form)
+        kdat, kval, out_aggs, ngroups = jax.tree.map(
+            lambda a, b: jnp.where(fits, a, b), dense, sort)
+    return [("fixed", key.dtype, kdat, kval)], out_aggs, ngroups
+
+
+def _signed_zeros(s, col: Column, valid, slot, rows, count):
+    """``s``, each slot's float sum from a reduction that starts at +0.0,
+    with -0.0 where every row of the slot holds -0.0 — what IEEE addition,
+    and so the sort form's scan, gives them.  Only a chunk that holds a
+    -0.0 pays for the count of the slots' other rows (``lax.cond``)."""
+    if col.dtype.id == TypeId.FLOAT64:      # the stored bit pattern
+        negz = col.data.astype(jnp.uint64) == np.uint64(1 << 63)
+    else:
+        negz = jax.lax.bitcast_convert_type(
+            jnp.asarray(col.data, jnp.float32), jnp.uint32) \
+            == np.uint32(1 << 31)
+    negz = negz & valid
+    others = jax.lax.cond(jnp.any(negz & (slot >= 0)),
+                          lambda: count(~negz),
+                          lambda: jnp.ones(rows.shape, rows.dtype))
+    return jnp.where((others == 0) & (rows > 0), np.float64(-0.0), s)
+
+
+def _dense_groupby(key: Column, resolved: list, live, slot, lo, ks: int):
+    """The dense form proper: ``(key data, key validity, aggregate
+    Columns, ngroups)`` padded to the input's rows.  ``slot`` is each row's
+    slot in ``[0, ks)``, -1 for a dead row; slot 0 is the null key's when
+    ``key`` has validity."""
+    n = slot.shape[0]
+    nullable = key.validity is not None
+    j = jnp.arange(ks, dtype=jnp.int32)
+    onehot = slot[None, :] == j[:, None]    # fused into each reduction
+
+    def count(mask=None):
+        m = onehot if mask is None else onehot & mask[None, :]
+        return jnp.sum(m, axis=1, dtype=jnp.int32).astype(jnp.int64)
+
+    rows = count()                          # live rows of each slot
+    counts: dict = {}
+    per_slot = []                           # one Column of ks rows per agg
+    for col, op in resolved:
+        if op == "count_all":
+            per_slot.append(Column(INT64, data=rows))
+            continue
+        if id(col) not in counts:
+            counts[id(col)] = rows if col.validity is None \
+                else count(col.validity)
+        cnt = counts[id(col)]
+        if op == "count":
+            per_slot.append(Column(INT64, data=cnt))
+            continue
+        valid = col.valid_mask()
+        vals, out_dtype, is_float = _sum_dtype_and_vals(col, col.data, valid)
+        zero = jnp.zeros((), vals.dtype)
+        if is_float:
+            # a null row adds +0.0, as in the sort form's scan
+            contrib = jnp.where(valid, vals, zero)[None, :]
+            s = jnp.sum(jnp.where(onehot, contrib, zero), axis=1)
+            s = _signed_zeros(s, col, valid, slot, rows, count)
+        else:
+            s = jnp.sum(jnp.where(onehot & valid[None, :], vals[None, :],
+                                  zero), axis=1, dtype=vals.dtype)
+        per_slot.append(_sum_result(op, col, s, cnt, out_dtype, is_float))
+
+    kval = j >= np.int32(nullable)
+    kdat = (lo + (j - np.int32(nullable)).astype(jnp.int64)).astype(
+        key.data.dtype)
+    if nullable:
+        # the null group's key bytes: its first live row's, as the stable
+        # sort leaves them
+        idx = jnp.arange(n, dtype=jnp.int32)
+        first = jnp.min(jnp.where(live & ~key.validity, idx, np.int32(n)))
+        null_data = jnp.sum(jnp.where(idx == first, key.data,
+                                      jnp.zeros((), key.data.dtype)),
+                            dtype=key.data.dtype)
+        kdat = jnp.where(kval, kdat, null_data)
+
+    # compaction: the present slots, in key order, to the front
+    present = rows > 0
+    ngroups = jnp.sum(present.astype(jnp.int32))   # the sort form's dtype
+    pos = jnp.cumsum(present.astype(jnp.int32)) - 1
+    pick = present[None, :] & (pos[None, :] == j[:, None])   # (out, slot)
+
+    def compact(a):
+        if a.dtype == jnp.bool_:
+            return compact(a.astype(jnp.int32)) != 0
+        out = jnp.sum(jnp.where(pick, a[None, :], jnp.zeros((), a.dtype)),
+                      axis=1, dtype=a.dtype)    # one term at most: exact
+        return out[:n] if ks >= n else jnp.pad(out, (0, n - ks))
+
+    out_aggs = [Column(c.dtype, data=compact(c.data),
+                       validity=None if c.validity is None
+                       else compact(c.validity)) for c in per_slot]
+    return compact(kdat), compact(kval), out_aggs, ngroups
 
 
 def _seg_ids(keys: list[SortKey], row_mask=None):

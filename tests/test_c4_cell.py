@@ -642,7 +642,8 @@ def test_the_cell_is_the_year_cell_sent_by_four_clients():
     # the four accepted cells instead (PERF.md section 3)
     silent = {"bridge_overhead_ms", "post_stream_launches"}
     assert new <= mine and not silent & mine
-    assert "bridge_server_ms" in mine and len(mine) == 17 + 6    # PR 38's six
+    # the request path's six readers and the chunk aggregate's form
+    assert "bridge_server_ms" in mine and len(mine) == 17 + 6 + 1
     for other in ("q5lite_sf1_year", "q55lite_sf1_nov1999",
                   "q5lite_sf1_14day", "q5lite_sf1_mesh4"):
         theirs = {m["name"] for m in run.Cell(other).metrics("per_layer")}

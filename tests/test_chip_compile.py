@@ -106,9 +106,9 @@ def q5_calls(tmp_path_factory):
     calls = {"chunk": [], "merge": []}
     chunk, merge = seg.CompiledSegment.__call__, seg.CompiledCombine.__call__
 
-    def chunk_call(self, table, nvalid=None, prepared=()):
+    def chunk_call(self, table, nvalid=None, prepared=(), lo=None):
         calls["chunk"].append((self, table, tuple(prepared)))
-        return chunk(self, table, nvalid, prepared)
+        return chunk(self, table, nvalid, prepared, lo)
 
     def merge_call(self, partials, nreal):
         calls["merge"].append((self, partials))
@@ -133,10 +133,55 @@ def test_fused_q5_chunk_segment(q5_calls, one_chip, tpu_branches):
     assert q5_calls["chunk"], "the plan ran no fused segment"
     compiled, table, prepared = q5_calls["chunk"][0]
     assert compiled.segment.agg is not None and compiled.segment.joins
+    assert compiled.agg_form == "dense/16"     # 12 stores
     compile_for_chip(seg._build_fn(compiled.segment, compiled),
                      on(one_chip, table),
                      jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-                     on(one_chip, prepared))
+                     on(one_chip, prepared),
+                     jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip))
+
+
+def _dense_program(q5_calls, slots):
+    """The smoke's chunk program with its aggregate's dense form over
+    ``slots`` key slots: (program, its recorded table and builds)."""
+    from spark_rapids_jni_tpu.engine import segment as seg
+    compiled, table, prepared = q5_calls["chunk"][0]
+    dense = seg.CompiledSegment(compiled.key, compiled.segment,
+                                compiled.key_dtypes, compiled.probes, slots)
+    return seg._build_fn(dense.segment, dense), table, prepared
+
+
+@pytest.mark.parametrize("slots", [16, 128])
+def test_dense_chunk_program_with_its_guard(q5_calls, one_chip, tpu_branches,
+                                            slots):
+    """The chunk program whose aggregate takes the dense form — 16 slots
+    (SF1's 12 stores), 128 (SF10's 102) — with the guard's other branch,
+    the sort form: at the sort-carried programs' small row count."""
+    fn, table, prepared = _dense_program(q5_calls, slots)
+    rows = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (CHUNK_ROWS,) + a.shape[1:], a.dtype, sharding=one_chip), table)
+    text = compile_for_chip(
+        fn, rows, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        on(one_chip, prepared),
+        jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)).as_text()
+    assert " conditional(" in text and " sort(" in text
+
+
+@pytest.mark.parametrize("slots", [16, 128])
+def test_dense_chunk_program_real_chunk(q5_calls, one_chip, tpu_branches,
+                                        monkeypatch, slots):
+    """The dense form alone (the guard's sort branch left out) at the
+    benchmark's real chunk, 262,144 rows: sort-free, so it compiles at
+    full size in seconds, with no sort and no gather."""
+    fn, table, prepared = _dense_program(q5_calls, slots)
+    monkeypatch.setattr(jax.lax, "cond", lambda pred, dense, sort: dense())
+    rows = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (262_144,) + a.shape[1:], a.dtype, sharding=one_chip), table)
+    text = compile_for_chip(
+        fn, rows, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        on(one_chip, prepared),
+        jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)).as_text()
+    assert " sort(" not in text and " gather(" not in text
 
 
 def test_q5_merge_of_streamed_partials(q5_calls, one_chip, tpu_branches):
@@ -388,9 +433,9 @@ def test_q6_chunk_program_and_keyless_merge(tmp_path, one_chip,
     calls = {"chunk": [], "merge": []}
     chunk, merge = seg.CompiledSegment.__call__, seg.CompiledCombine.__call__
 
-    def chunk_call(self, table, nvalid=None, prepared=()):
+    def chunk_call(self, table, nvalid=None, prepared=(), lo=None):
         calls["chunk"].append((self, table))
-        return chunk(self, table, nvalid, prepared)
+        return chunk(self, table, nvalid, prepared, lo)
 
     def merge_call(self, partials, nreal):
         calls["merge"].append((self, partials))

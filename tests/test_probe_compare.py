@@ -291,8 +291,8 @@ def _json(*parts):
         return json.load(f)
 
 
-CELLS = {"q5lite": ("nds_q5lite_sf1", "year", 1),
-         "q55lite": ("nds_q55lite_sf1", "nov1999", 2)}
+CELLS = {"q5lite": ("nds_q5lite_sf1", "year", 1, "dense/16"),
+         "q55lite": ("nds_q55lite_sf1", "nov1999", 2, "sorted")}
 CHUNK_BYTES = 1 << 20       # two 20,000-row groups a chunk: several chunks
 
 
@@ -336,7 +336,7 @@ def _run_plan(plan):
 def rehearsal(request, tmp_path_factory):
     import pyarrow as pa
     import pyarrow.parquet as pq
-    config_name, traffic, joins = CELLS[request.param]
+    config_name, traffic, joins, agg = CELLS[request.param]
     config = _json("configs", config_name + ".json")
     params = _json("traffic", traffic + ".json")["params"]
     query = _load(os.path.join(BENCH, "queries", config["query"] + ".py"),
@@ -361,7 +361,7 @@ def rehearsal(request, tmp_path_factory):
     finally:
         mp.undo()
     return {"want": query.reference(frames, params), "joins": joins,
-            "compare": compare, "rank": rank}
+            "agg": agg, "compare": compare, "rank": rank}
 
 
 def _columns_bytes(t: Table) -> list:
@@ -381,7 +381,8 @@ def test_rehearsal_every_join_of_every_chunk_took_the_compare_path(rehearsal):
     assert compiled.probes == ("compare",) * rehearsal["joins"]
     assert compiled.span_stats() == {
         "probe_compare": f"{rehearsal['joins']}/{rehearsal['joins']}",
-        "exprs": compiled.segment.exprs()}     # PR 40's stat beside it
+        "exprs": compiled.segment.exprs(),     # the stats beside it
+        "agg": rehearsal["agg"]}
     # the forced merge-rank run counts the other way: compare + rank is
     # joins x chunks either way
     _, rstats, rgrew, _, _ = rehearsal["rank"]

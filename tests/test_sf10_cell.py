@@ -13,7 +13,8 @@ chunks lie before the window and hand in empty partials):
 - (a) for three seeds every result equals the plain pandas reference and the
   one-merge result (the same rows in 16 row groups) byte for byte;
 - (b) a stream of at most 16 chunks leaves the counts it always left
-  (``engine.host_sync`` 2, one merge, no fold); a longer one folds
+  (``engine.host_sync`` 2, one merge, no fold), every chunk program's
+  aggregate in the dense form (``engine.agg.dense``); a longer one folds
   ``ceil((chunks - 16) / 15)`` times, pays one ``combine-fold-sizing`` sync
   per fold, holds at most 16 padded partials, and launches ``folds + 1``
   merges;
@@ -242,6 +243,10 @@ def test_counts_of_a_stream(served, seed, chunks):
         assert held["max"] == min(chunks, ARITY) <= ARITY
         assert c["engine.segment.replay"] \
             + c.get("engine.segment.compile", 0) == chunks
+        # 102 stores: every chunk program's aggregate is the dense form
+        # over 128 key slots
+        assert c.get("engine.agg.dense", 0) == chunks
+        assert c.get("engine.agg.sorted", 0) == 0
         # one transfer buffer per staged blob, from the free list or new:
         # the chunks' and the dimension scans'
         assert c.get("io.scan.stage.reused", 0) \
@@ -292,6 +297,8 @@ def test_the_cells_own_traffic(served, seed):
     c = run["cold"]["counters"]
     assert run["cold"]["stats"]["chunks"] == GROUPS - PRUNED
     assert c["engine.combine.folds"] == 7 and c["engine.host_sync"] == 9
+    assert c["engine.agg.dense"] == GROUPS - PRUNED == 108
+    assert c.get("engine.agg.sorted", 0) == 0
     # 16 empty partials; a merged one with 15, at either capacity and with
     # the slots either leaves it (the last is the open window's own program)
     assert c.get("engine.combine.compile", 0) == (3 if seed == SEEDS[0] else 0)
@@ -678,8 +685,9 @@ def test_new_metrics_list_the_new_cell_alone():
     for m in BENCHMARK["per_layer"]:
         if m["name"] not in new and m.get("workloads") != accepted_with:
             assert CELL not in m.get("workloads", ()), m["name"]
+    # the request path's six, and the keyed chunk aggregate's form
     assert sum(m.get("workloads") == accepted_with
-               for m in BENCHMARK["per_layer"]) == 6
+               for m in BENCHMARK["per_layer"]) == 7
     e2e = [m["name"] for m in BENCHMARK["end_to_end"]
            if "workloads" not in m or CELL in m["workloads"]]
     assert e2e == ["fact_rows_per_s", "setup_s"]
