@@ -923,15 +923,25 @@ def _precompute_independent(root: PlanNode, scan: Scan, memo: dict,
 
 def _get_builds(joins: tuple, build_tables: tuple) -> tuple:
     """The per-chunk BUILD_CACHE access: one ``get`` per join per chunk —
-    the first chunk of a cold stream misses and pays the hash + sort,
+    the first chunk of a cold stream misses and pays the rank + sort,
     every later chunk hits (``hits == chunks - 1``)."""
-    from ..ops.join import prepare_build
     from .cache import BUILD_CACHE
     return tuple(
         BUILD_CACHE.get(j.fingerprint(), bt,
-                        lambda j=j, bt=bt: prepare_build(
-                            bt, list(j.right_keys)))
+                        lambda j=j, bt=bt: _prepare_build(j, bt))
         for j, bt in zip(joins, build_tables))
+
+
+def _prepare_build(j: Join, bt: Table):
+    """A cache miss's ``prepare_build``, under its timed span: the build's
+    rows, the probe method it will take and whether it is ranked on its
+    keys themselves (``ops.join.exact_keys``)."""
+    from ..ops.join import exact_keys, prepare_build, probe_method
+    keys = [bt.column(k) for k in j.right_keys]
+    with op_scope("engine.build.prepare", timed=True, rows=bt.num_rows,
+                  method=probe_method(bt.num_rows, keys),
+                  exact=int(exact_keys(keys))):
+        return prepare_build(bt, list(j.right_keys))
 
 
 def _exec_streamed(st: Stage, memo: dict, stats: dict, ctx: _ExecCtx,
@@ -952,20 +962,24 @@ def _exec_streamed(st: Stage, memo: dict, stats: dict, ctx: _ExecCtx,
       accumulate on device and merge with ONE combine groupby at the end.
     - **Fused probe joins** (a Join in the stage's segment): a Join on the
       path whose build side is scan-independent joins the segment instead of
-      breaking it — the build is hashed + sorted once per execution
+      breaking it — the build is ranked + sorted once per execution
       (``BUILD_CACHE``) and enters the chunk program as a pytree input.
-      Non-unique build hashes or ineligible schemas fall back to the
-      interpreted per-chunk loop, which still pipelines.
+      A non-unique build (a key held twice; for a build not keyed by one
+      integer column, two keys of one 32-bit hash) or an ineligible schema
+      falls back to the interpreted per-chunk loop, which still pipelines.
     """
     from ..ops.selection import concat_tables
 
     agg, scan = st.node, st.scan
+    # the joins an interpreted chunk is probed through in the chunk
+    # program's place (``engine.probe.interp``)
+    probes = 0 if st.segment is None else len(st.segment.joins())
     # the scan-independent subtrees are in ``memo`` already:
     # ``_exec_aggregate`` precomputed them (``engine.precompute``)
     with op_scope("engine.stream", timed=True):
         reader, partials, fused = _stream_chunks(
             agg, scan, None if force_interp else st.segment, memo, stats,
-            ctx)
+            ctx, probes)
     # what follows, to the end of ``execute``, is ``engine.post_stream``:
     # the final merge of the partials (one program) and every operator
     # above it; the stream's own waits (a long stream's folds) are behind
@@ -992,13 +1006,16 @@ def _exec_streamed(st: Stage, memo: dict, stats: dict, ctx: _ExecCtx,
 
 
 def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
-                   ctx: _ExecCtx) -> tuple:
+                   ctx: _ExecCtx, probes: int = 0) -> tuple:
     """The chunk loop of ``_exec_streamed``: reader open -> last chunk
     dispatched -> reader closed.  ``seg``: the stage's fused chunk
-    segment, None to interpret each chunk.  Returns ``(reader, partials,
-    fused)``; at most one of ``partials`` (interpreted path: compacted
-    Tables) and ``fused`` (fused path: the padded device partials, folded
-    as the stream ran — ``segment.StreamedPartials``) is filled."""
+    segment, None to interpret each chunk, whose ``probes`` joins count
+    ``engine.probe.interp``.  Returns ``(reader, partials, fused)``; at
+    most one of ``partials`` (interpreted path: compacted Tables) and
+    ``fused`` (fused path: the padded device partials, folded as the
+    stream ran — ``segment.StreamedPartials``, or summed slot by slot in
+    the aggregate's build-row form — ``segment.BuildRowPartials``) is
+    filled."""
     from ..io import ParquetChunkedReader
     from ..utils.config import config
     from . import segment as sg
@@ -1030,6 +1047,7 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
 
     partials: list = []          # interpreted path: compacted Tables
     fused = sg.StreamedPartials()   # fused path: padded device partials
+    build_row = None
     try:
         if seg is not None:
             joins = seg.joins()
@@ -1061,9 +1079,18 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                     # this access stands in for chunk 1's per-chunk get
                     first_preps = _get_builds(joins, build_tables)
                     if any(not p.unique for p in first_preps):
-                        # duplicate 32-bit build hashes: the <=1-candidate
-                        # probe shape doesn't hold; interpret instead
+                        # a probe row could have two candidates, so the
+                        # <=1-candidate shape doesn't hold: a build keyed by
+                        # one integer column holds a key twice; any other
+                        # build, two keys share a 32-bit hash.  Interpret
                         veto = True
+                    elif dense_k is None:
+                        # a group that is one build row adds into its slot
+                        build_row = sg.build_row_join(seg, probe,
+                                                      build_tables)
+                        if build_row is not None:
+                            fused = sg.BuildRowPartials(
+                                first_preps[build_row[0]], build_row[1])
             if veto:
                 from ..ops.selection import slice_table
                 seg = None
@@ -1075,7 +1102,7 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                     if nvalid < chunk.num_rows:
                         chunk = slice_table(chunk, 0, nvalid)
                     partials.extend(_stream_partial(agg, scan, chunk, memo,
-                                                    stats, ctx))
+                                                    stats, ctx, probes))
             else:
                 stats["nodes"] += len(seg.chain)  # agg counted by _exec
                 qm = metrics.current()
@@ -1120,7 +1147,8 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                         if kind == "dev":
                             ctx.recovery.charge(payload.comp_bytes)
                             fused_compiled = sg.SEGMENT_CACHE.get_decode(
-                                seg, payload.geom, build_tables, dense_k)
+                                seg, payload.geom, build_tables, dense_k,
+                                build_row)
                             with op_scope("engine.fused_segment",
                                           **fused_compiled.span_stats()):
                                 fused.add(fused_compiled(
@@ -1142,7 +1170,7 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                             padded = chunk.num_rows - nvalid
                             ctx.recovery.charge(cb)
                             fused_compiled = sg.SEGMENT_CACHE.get(
-                                seg, chunk, build_tables, dense_k)
+                                seg, chunk, build_tables, dense_k, build_row)
                             with op_scope("engine.fused_segment",
                                           **fused_compiled.span_stats()):
                                 fused.add(fused_compiled(
@@ -1154,7 +1182,7 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                         padded = chunk.num_rows - nvalid
                         ctx.recovery.charge(cb)
                         fused_compiled = sg.SEGMENT_CACHE.get(
-                            seg, chunk, build_tables, dense_k)
+                            seg, chunk, build_tables, dense_k, build_row)
                         with op_scope("engine.fused_segment",
                                       **fused_compiled.span_stats()):
                             fused.add(fused_compiled(chunk, nvalid, preps,
@@ -1183,7 +1211,7 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
             for chunk in reader:
                 ctx.recovery.checkpoint()
                 partials.extend(_stream_partial(agg, scan, chunk, memo,
-                                                stats, ctx))
+                                                stats, ctx, probes))
     finally:
         # the last chunk is dispatched (its `wait_reader` saw the end
         # mark): what is left is the producer's join
@@ -1256,10 +1284,14 @@ class _ChunkMemo(dict):
 
 
 def _stream_partial(agg: Aggregate, scan: Scan, chunk: Table, memo: dict,
-                    stats: dict, ctx: _ExecCtx) -> list:
+                    stats: dict, ctx: _ExecCtx, probes: int = 0) -> list:
     """Interpreted per-chunk partial: re-walk the scan-dependent subtree
-    with the chunk standing in for the scan, then a compacting groupby."""
+    with the chunk standing in for the scan, then a compacting groupby.
+    ``probes``: the joins of the stage's chunk segment, which the chunk
+    meets on the way, counted as ``engine.probe.interp``."""
     stats["chunks"] += 1
+    if probes:
+        metrics.count("engine.probe.interp", probes)
     ctx.recovery.charge(table_nbytes(chunk))
     qm = metrics.current()
     tc0 = time.perf_counter() if qm is not None else 0.0

@@ -25,9 +25,11 @@ plans.  The TPU translation:
   callable per chunk with zero per-chunk host syncs.  How a probe row
   finds its build row is ``ops.join.probe_method``'s choice, from the
   build's row count: a broadcast compare of the keys for a small build
-  (no hash, sort or gather in the program), the hash merge-rank above it;
+  (no hash, sort or gather in the program), the merge-rank above it (of
+  the keys themselves for one integer key, else of their hashes);
   ``engine.probe.compare`` / ``engine.probe.rank`` count the joins that
-  took each, per chunk launch.
+  took each, per chunk launch, and ``engine.probe.interp`` those of a
+  chunk the interpreter ran instead.
 - Compiled segments live in a process-wide LRU keyed by
   ``(segment fingerprint, input shape-class)`` with hit/miss/eviction
   counters in ``utils.tracing`` (``engine.segment_cache.*``).  The
@@ -56,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..columnar import Column, Table
+from ..dtypes import TypeId
 from ..utils import metrics, timeline
 from ..utils.config import config
 from ..utils.tracing import op_scope
@@ -357,8 +360,10 @@ def _probe_join_node(nd: Join, pb, table: Table, live, needed):
     verified match, and (inner only) select the needed build payload
     columns at the matched build rows — by the probe's own method: a
     one-hot masked reduce beside the compare probe, a gather beside the
-    merge-rank.  No expansion, no host sync — the prepared build
-    guarantees <= 1 candidate per probe row."""
+    rank probe.  No expansion, no host sync — the prepared build
+    guarantees <= 1 candidate per probe row.  Returns ``(table, live,
+    ri)``: ``ri`` each live row's build row (what the aggregate's
+    build-row form adds into)."""
     from ..ops.join import (probe_join_prepared, probe_method,
                             select_build_rows)
     from ..ops.selection import gather_column
@@ -368,7 +373,7 @@ def _probe_join_node(nd: Join, pb, table: Table, live, needed):
     ri, matched = probe_join_prepared(lk, pb, left_live=live)
     live = live & matched
     if nd.how == "semi":
-        return table, live
+        return table, live, ri
     lnames = list(table.names or [])
     cols, names = list(table.columns), list(lnames)
     n = table.num_rows
@@ -383,9 +388,10 @@ def _probe_join_node(nd: Join, pb, table: Table, live, needed):
         elif compare:   # payload is 1-D fixed-width: runtime eligibility
             cols.append(select_build_rows(c, ri))
         else:
-            cols.append(gather_column(c, ri))
+            with op_scope("probe_rank"):
+                cols.append(gather_column(c, ri))
         names.append(out_nm)
-    return Table(cols, names), live
+    return Table(cols, names), live, ri
 
 
 def _passes_through(seg: Segment, name: str) -> bool:
@@ -424,6 +430,88 @@ def agg_domain(seg: Segment, file, groups, columns=None) -> Optional[tuple]:
     return None if slots is None else (lo, slots)
 
 
+#: the aggregations the build-row form adds up: additive, so a chunk's
+#: totals and the stream's merge are sums of slots
+BUILD_ROW_OPS = frozenset({"sum", "count", "count_all"})
+
+
+def _is_float_expr(expr, floats: set) -> bool:
+    """Can ``expr`` be a float: it reads a float column or a float
+    literal (the arithmetic of integers and decimals is exact)."""
+    if not isinstance(expr, tuple):
+        return isinstance(expr, float)
+    if expr[0] == "col":
+        return expr[1] in floats
+    if expr[0] == "lit":
+        return isinstance(expr[1], float)
+    return any(_is_float_expr(e, floats) for e in expr[1:])
+
+
+def build_row_join(seg: Segment, table: Table, builds: tuple) \
+        -> Optional[tuple]:
+    """``(join index, key sources)`` where the chunk program's aggregate
+    takes the build-row form (``ops.aggregate.groupby_build_rows``), else
+    None.  The form needs a group that IS one build row: an inner join of
+    the chain whose build is ranked on ONE integer key (``exact_keys``,
+    above ``PROBE_COMPARE_MAX_BUILD``: the rank probe), a group key that is
+    the join's probe key (equal to the build's on every joined row, of its
+    dtype), every other group key a payload column of that build reaching
+    the aggregate unchanged, and ``BUILD_ROW_OPS`` over integer or decimal
+    inputs.  A group is then a build row: its totals add into that row's
+    slot, no sort.  ``key sources``: per group key, the payload column it
+    is, None for the join's key.  ``table``: the chunk (its names and
+    dtypes); ``builds``: the chain's build Tables."""
+    from ..ops.join import exact_keys, probe_method
+    agg = seg.agg
+    if agg is None or not agg.keys \
+            or any(op not in BUILD_ROW_OPS for _, op in agg.aggs):
+        return None
+    try:
+        origin = {nm: None for nm in (table.names or [])}
+        floats = {nm for nm in origin if table.column(nm).dtype.id
+                  in (TypeId.FLOAT32, TypeId.FLOAT64)}
+        dtypes = {nm: table.column(nm).dtype for nm in origin}
+        ji = 0
+        for nd in seg.chain:
+            if isinstance(nd, Project):
+                floats = {nm for nm, e in nd.items
+                          if _is_float_expr(e, floats)}
+                origin = {nm: origin.get(e[1]) if e[0] == "col" else None
+                          for nm, e in nd.items}
+                dtypes = {nm: dtypes.get(e[1]) if e[0] == "col" else None
+                          for nm, e in nd.items}
+            elif isinstance(nd, Join):
+                b = builds[ji]
+                if nd.how == "inner":
+                    lnames = set(origin)
+                    for nm in (b.names or []):
+                        if nm not in nd.right_keys:
+                            out = _join_out_name(nm, lnames)
+                            origin[out] = (ji, nm)
+                            dtypes[out] = b.column(nm).dtype
+                            if b.column(nm).dtype.id in (TypeId.FLOAT32,
+                                                         TypeId.FLOAT64):
+                                floats.add(out)
+                    if len(nd.left_keys) == 1:
+                        origin[nd.left_keys[0]] = (ji, None)
+                ji += 1
+        if any(c in floats for c, op in agg.aggs if op == "sum"):
+            return None
+        for ji, (j, b) in enumerate(zip(seg.joins(), builds)):
+            keys = [b.column(k) for k in j.right_keys]
+            if j.how != "inner" or not exact_keys(keys) \
+                    or probe_method(b.num_rows, keys) != "rank" \
+                    or dtypes.get(j.left_keys[0]) != keys[0].dtype:
+                continue
+            src = [origin.get(k) for k in agg.keys]
+            if (ji, None) in src and all(o is not None and o[0] == ji
+                                         for o in src):
+                return ji, tuple(o[1] for o in src)
+    except (KeyError, ValueError):
+        return None
+    return None
+
+
 def _build_fn(seg: Segment, compiled: "CompiledSegment"):
     """The single program a segment traces into.
 
@@ -432,10 +520,12 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
     in the chain (execution order); ``lo`` is the key domain's low end
     where the aggregate takes the dense form (``compiled.dense_k`` slots),
     else None.  Map segments return (table, live, ovf); agg segments
-    return padded partial aggregates + group-live mask + ovf — all
-    device-resident, zero host syncs.  ``ovf`` is the program's overflow
-    flag (``engine/expr.py``: an arithmetic node or a decimal sum outgrew
-    int64's checked bound), None where it checks nothing.
+    return padded partial aggregates + group-live mask + ovf — or, in the
+    build-row form (``compiled.build_row``), ``(rows, aggregate Columns,
+    ovf)`` over the build's rows — all device-resident, zero host syncs.
+    ``ovf`` is the program's overflow flag (``engine/expr.py``: an
+    arithmetic node or a decimal sum outgrew int64's checked bound), None
+    where it checks nothing.
     """
     from .expr import decimal_sums
     chain, agg = seg.chain, seg.agg
@@ -443,12 +533,14 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
               for i, nd in enumerate(chain) if isinstance(nd, Join)}
 
     def fn(table: Table, nvalid, prepared=(), lo=None):
-        from ..ops.aggregate import groupby_dense, groupby_padded
+        from ..ops.aggregate import (groupby_build_rows, groupby_dense,
+                                     groupby_padded)
         from .expr import any_flag, evaluate, project, sum_check
         compiled.traces += 1  # trace-time side effect: the no-recompile proof
         live = jnp.arange(table.num_rows, dtype=jnp.int32) < nvalid
         ovf: list = []
         ji = 0
+        rows_of = []        # each join's build row per probe row
         for i, nd in enumerate(chain):
             if isinstance(nd, Filter):
                 vals, valid, _ = evaluate(nd.predicate, table, ovf)
@@ -457,8 +549,9 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
                     m = m & valid  # SQL semantics: NULL comparison drops
                 live = live & m
             elif isinstance(nd, Join):
-                table, live = _probe_join_node(nd, prepared[ji], table,
-                                               live, needed[i])
+                table, live, ri = _probe_join_node(nd, prepared[ji], table,
+                                                   live, needed[i])
+                rows_of.append(ri)
                 ji += 1
             elif nd.computed:
                 table = project(table, nd.items, ovf)
@@ -469,6 +562,11 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
         for c in decimal_sums(agg.aggs, table):
             sum_check(table.column(c), live, ovf)
         aggs = [(c, op) for c, op in agg.aggs]
+        if compiled.build_row is not None:
+            bj = compiled.build_row[0]
+            rows, out_aggs = groupby_build_rows(table, aggs, live,
+                                                rows_of[bj], prepared[bj].nr)
+            return rows, tuple(out_aggs), any_flag(ovf)
         if compiled.dense_k:
             out_keys, out_aggs, ngroups = groupby_dense(
                 table, list(agg.keys), aggs, lo, compiled.dense_k,
@@ -487,11 +585,15 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
     return fn
 
 
-def _agg_form(segment: Segment, dense_k: Optional[int]) -> Optional[str]:
-    """How a chunk program computes its keyed aggregate: ``dense/<slots>``
-    or ``sorted``; None where it has none (no aggregate, or no keys)."""
+def _agg_form(segment: Segment, dense_k: Optional[int],
+              build_row: Optional[tuple] = None) -> Optional[str]:
+    """How a chunk program computes its keyed aggregate: ``dense/<slots>``,
+    ``build`` (``build_row_join``) or ``sorted``; None where it has none
+    (no aggregate, or no keys)."""
     if segment.agg is None or not segment.agg.keys:
         return None
+    if build_row is not None:
+        return "build"
     return f"dense/{dense_k}" if dense_k else "sorted"
 
 
@@ -501,24 +603,28 @@ class CompiledSegment:
     is ``probe_methods`` of the chain's joins for this shape class: what
     the ``engine.probe.*`` counters and the span's stat report.
     ``dense_k``: the slots of the aggregate's dense form, None for the sort
-    form; ``agg_form`` what the ``engine.agg.*`` counters and the span's
-    stat report of it (None: no keyed aggregate, or not a chunk program)."""
+    form; ``build_row``: ``build_row_join``'s answer where the aggregate
+    takes the build-row form; ``agg_form`` what the ``engine.agg.*``
+    counters and the span's stat report of it (None: no keyed aggregate,
+    or not a chunk program)."""
 
     __slots__ = ("key", "segment", "key_dtypes", "jfn", "traces", "calls",
-                 "probes", "exprs", "dense_k", "agg_form")
+                 "probes", "exprs", "dense_k", "build_row", "agg_form")
 
     #: prefix of this program's compile-vs-replay events (``_tick``)
     counters = "engine.segment"
 
     def __init__(self, key: tuple, segment: Segment, key_dtypes: tuple,
-                 probes: tuple = (), dense_k: Optional[int] = None):
+                 probes: tuple = (), dense_k: Optional[int] = None,
+                 build_row: Optional[tuple] = None):
         self.key = key
         self.segment = segment
         self.key_dtypes = key_dtypes
         self.probes = probes
         self.exprs = segment.exprs()
         self.dense_k = dense_k
-        self.agg_form = _agg_form(segment, dense_k)
+        self.build_row = build_row
+        self.agg_form = _agg_form(segment, dense_k, build_row)
         self.traces = 0
         self.calls = 0
         self.jfn = jax.jit(_build_fn(segment, self))
@@ -529,8 +635,9 @@ class CompiledSegment:
         keyed aggregate's form."""
         out = {"exprs": self.exprs}
         if self.probes:
-            out["probe_compare"] = \
-                f"{self.probes.count('compare')}/{len(self.probes)}"
+            compare = self.probes.count("compare")
+            out["probe"] = f"{compare}/{len(self.probes) - compare}" \
+                f"/{len(self.probes)}"
         if self.agg_form:
             out["agg"] = self.agg_form
         return out
@@ -550,6 +657,7 @@ class CompiledSegment:
             metrics.count("engine.probe.rank", len(self.probes) - compare)
         if self.agg_form:
             metrics.count("engine.agg.dense" if self.dense_k
+                          else "engine.agg.build" if self.build_row
                           else "engine.agg.sorted")
         if not metrics.enabled() and not timeline.enabled():
             return self.jfn(*args)
@@ -604,14 +712,16 @@ class CompiledDecodeSegment(CompiledSegment):
     __slots__ = ("geom",)
 
     def __init__(self, key: tuple, segment: Segment, key_dtypes: tuple,
-                 geom, probes: tuple = (), dense_k: Optional[int] = None):
+                 geom, probes: tuple = (), dense_k: Optional[int] = None,
+                 build_row: Optional[tuple] = None):
         self.key = key
         self.segment = segment
         self.key_dtypes = key_dtypes
         self.probes = probes
         self.exprs = segment.exprs()
         self.dense_k = dense_k
-        self.agg_form = _agg_form(segment, dense_k)
+        self.build_row = build_row
+        self.agg_form = _agg_form(segment, dense_k, build_row)
         self.traces = 0
         self.calls = 0
         self.geom = geom
@@ -721,7 +831,7 @@ class CompiledCombine(CompiledSegment):
         self.key_dtypes = key_dtypes
         self.probes = ()        # the merge probes nothing
         self.exprs = 0
-        self.dense_k = self.agg_form = None     # sort form, not counted
+        self.dense_k = self.build_row = self.agg_form = None  # not counted
         self.traces = 0
         self.calls = 0
         self.jfn = jax.jit(_build_combine_fn(segment.agg, key_dtypes, cap,
@@ -739,9 +849,13 @@ class CompiledCombine(CompiledSegment):
             metrics.observe("engine.combine.replay_dispatch_s", dt)
 
 
-def _dense_class(shape: tuple, dense_k: Optional[int]) -> tuple:
-    """A chunk program's shape class with its dense form's slot count."""
-    return shape if dense_k is None else shape + (("dense", dense_k),)
+def _dense_class(shape: tuple, dense_k: Optional[int],
+                 build_row: Optional[tuple] = None) -> tuple:
+    """A chunk program's shape class with its aggregate's form: the dense
+    form's slot count, or the build-row form's join and key sources."""
+    if dense_k is not None:
+        shape = shape + (("dense", dense_k),)
+    return shape if build_row is None else shape + (("build", build_row),)
 
 
 def _resolve_dtype(name: str, table: Table, builds: tuple):
@@ -814,30 +928,35 @@ class SegmentCache:
             return compiled
 
     def get(self, segment: Segment, table: Table, builds: tuple = (),
-            dense_k: Optional[int] = None) -> CompiledSegment:
+            dense_k: Optional[int] = None,
+            build_row: Optional[tuple] = None) -> CompiledSegment:
         """The chunk program of ``segment`` over ``table``'s shape class;
         ``dense_k``: its aggregate's dense form over that many key slots
-        (``agg_domain``), a part of the shape class."""
+        (``agg_domain``), ``build_row`` its build-row form
+        (``build_row_join``), each a part of the shape class."""
         key = (segment.fingerprint(), _dense_class(shape_class(table),
-                                                   dense_k),
+                                                   dense_k, build_row),
                tuple(shape_class(b) for b in builds))
 
         def build():
             key_dtypes = () if segment.agg is None else tuple(
                 _resolve_dtype(k, table, builds) for k in segment.agg.keys)
             return CompiledSegment(key, segment, key_dtypes,
-                                   probe_methods(segment, builds), dense_k)
+                                   probe_methods(segment, builds), dense_k,
+                                   build_row)
 
         return self._lookup(key, build)
 
     def get_decode(self, segment: Segment, geom, builds: tuple = (),
-                   dense_k: Optional[int] = None) -> CompiledDecodeSegment:
+                   dense_k: Optional[int] = None,
+                   build_row: Optional[tuple] = None) \
+            -> CompiledDecodeSegment:
         """The fused scan-decode variant of :meth:`get`: keyed by
         (fingerprint, page geometry, build shapes) — one executable per
         (plan segment, page-geometry bucket) class, shared by every chunk
         whose pages quantize to the same buckets."""
         key = (segment.fingerprint(),
-               _dense_class(("device_decode", geom), dense_k),
+               _dense_class(("device_decode", geom), dense_k, build_row),
                tuple(shape_class(b) for b in builds))
 
         def build():
@@ -847,7 +966,7 @@ class SegmentCache:
                 for k in segment.agg.keys)
             return CompiledDecodeSegment(key, segment, key_dtypes, geom,
                                          probe_methods(segment, builds),
-                                         dense_k)
+                                         dense_k, build_row)
 
         return self._lookup(key, build)
 
@@ -1109,6 +1228,124 @@ class StreamedPartials:
         return self.merge().compact()
 
 
+@functools.partial(jax.jit, static_argnums=(2,))
+def _add_build_rows(acc: tuple, part: tuple, checked: tuple) -> tuple:
+    """``acc + part``, two build-row partials ``(rows, aggregate Columns,
+    ovf)``: slot by slot, a sum's validity the OR of both (it has a value
+    where either had one).  ``checked``: the aggregates that are decimal
+    sums, whose totals ``sum_check`` guards as the merge of the sort form
+    guards its partial sums."""
+    from .expr import any_flag, sum_check
+    rows = acc[0] + part[0]
+    aggs = tuple(Column(a.dtype, data=a.data + b.data,
+                        validity=None if a.validity is None
+                        else a.validity | b.validity)
+                 for a, b in zip(acc[1], part[1]))
+    ovf = [f for f in (acc[2], part[2]) if f is not None]
+    for j in checked:
+        sum_check(aggs[j], rows > 0, ovf)
+    return rows, aggs, any_flag(ovf)
+
+
+@jax.jit
+def _count_build_rows(rows):
+    """The build-row form's group count: its sizing reduce."""
+    return jnp.sum((rows > 0).astype(jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _build_row_groups(acc: tuple, pb, sources: tuple, cap: int) -> tuple:
+    """The build-row form's merged partial as the sort form's merge leaves
+    one: ``(kdat, kval, aggregate Columns, ngroups)`` over ``cap`` slots,
+    the groups packed at the front in the build's key order (the order of
+    the sort form's groups, whose first key is the join's).  A group's keys
+    are its build row's: the payload column each of ``sources`` names, the
+    build key for None.  A compaction by prefix count and scatter: no
+    sort."""
+    rows, aggs = acc[0], acc[1]
+    present = jnp.take(rows > 0, pb.r_order)    # in the build's key order
+    ngroups = jnp.sum(present.astype(jnp.int32))
+    dest = jnp.where(present, jnp.cumsum(present.astype(jnp.int32)) - 1,
+                     np.int32(cap))
+    at = jnp.zeros((cap,), jnp.int32).at[dest].set(pb.r_order, mode="drop")
+    kdat, kval = [], []
+    for src in sources:
+        c = pb.rk.columns[0] if src is None else pb.payload.column(src)
+        kdat.append(jnp.take(c.data, at))
+        kval.append(jnp.take(c.valid_mask(), at))
+    out = tuple(Column(c.dtype, data=jnp.take(c.data, at),
+                       validity=None if c.validity is None
+                       else jnp.take(c.validity, at)) for c in aggs)
+    return tuple(kdat), tuple(kval), out, ngroups
+
+
+class BuildRowPartials:
+    """``StreamedPartials`` for a chunk program whose aggregate takes the
+    build-row form (``build_row_join``): each chunk's partial holds one
+    slot per build row, so the stream's merge is a slot-by-slot sum, one
+    small program per chunk (``_add_build_rows``), and nothing folds: the
+    device holds the running total and one chunk's partial.  ``merge``
+    then pays the sort form's sizing fetch (``combine-sizing``: how many
+    build rows were joined) and compacts them to the power-of-two bucket
+    of that count (``_build_row_groups``) — the padded partial a ``tail``
+    takes, or ``finish`` compacts."""
+
+    __slots__ = ("pb", "sources", "acc", "compiled", "chunks", "folds",
+                 "held", "checked")
+
+    def __init__(self, pb, sources: tuple):
+        self.pb = pb                # the prepared build a group is a row of
+        self.sources = sources      # build_row_join's key sources
+        self.acc = None             # (rows, aggregate Columns, ovf)
+        self.compiled = None
+        self.chunks = 0
+        self.folds = 0
+        self.held = 0
+        self.checked = ()
+
+    def __len__(self) -> int:
+        return self.chunks
+
+    def make_room(self) -> None:
+        """Nothing to fold: the running total takes every chunk."""
+
+    def add(self, partial: tuple, compiled: CompiledSegment) -> None:
+        if self.acc is None:
+            agg = compiled.segment.agg
+            self.checked = tuple(j for j, c in enumerate(partial[1])
+                                 if agg.aggs[j][1] == "sum"
+                                 and c.dtype.is_decimal)
+            self.acc = partial
+        else:
+            with op_scope("engine.combine", timed=True, partials=2):
+                self.acc = _add_build_rows(self.acc, partial, self.checked)
+        self.compiled = compiled
+        self.chunks += 1
+        self.held = 1
+
+    def merge(self) -> "PaddedPartial":
+        """The stream's groups, still padded: what a ``tail`` stage
+        takes."""
+        from ..ops.parquet_decode import bucket
+        metrics.observe("engine.stream.partials_held", self.held)
+        metrics.host_sync(label="combine-sizing")
+        with op_scope("engine.sync_wait", timed=True, label="combine-sizing"):
+            ng = int(_count_build_rows(self.acc[0]))
+        cap = bucket(ng, 64)
+        with op_scope("engine.combine", timed=True, partials=self.chunks,
+                      cap=cap, final=1):
+            kdat, kval, aggs, ngroups = _build_row_groups(
+                self.acc, self.pb, self.sources, cap)
+        agg = self.compiled.segment.agg
+        return PaddedPartial(self.compiled.key_dtypes, kdat, kval, aggs,
+                             ngroups, list(agg.keys) + list(agg.names),
+                             self.acc[2])
+
+    def finish(self) -> Table:
+        """The final merge and its compaction: the aggregate's Table."""
+        return self.merge().compact()
+
+
 class PaddedPartial:
     """A streamed aggregate's merged result as its merge program left it:
     key buffers, their validity and the aggregate Columns at the merge's
@@ -1319,6 +1556,33 @@ def _tail_join(nd: Join, cols: list, names: list, live, right: Table,
     return cols, names, live, spill
 
 
+#: a tail's top-k of at most this many rows selects them one by one
+#: (``_select_first``) instead of sorting every slot
+TOPK_SELECT_MAX = 16
+
+
+def _select_first(words: list, live, n: int) -> tuple:
+    """``(positions, found)`` of the first ``n`` live rows in the stable
+    order of ``words`` (``ops.order.encode_keys``: most significant first,
+    ascending): ``n`` rounds, each the lexicographic least row left — a
+    masked min per word, the lowest position among the ties — so the
+    order is the stable sort's, and no sort is compiled.  ``found`` is
+    False past the last live row."""
+    slots = live.shape[0]
+    idx = jnp.arange(slots, dtype=jnp.int32)
+    top = np.uint64(2**64 - 1)
+    left, at, found = live, [], []
+    for _ in range(n):
+        cand = left
+        for w in words:
+            cand = cand & (w == jnp.min(jnp.where(cand, w, top)))
+        p = jnp.min(jnp.where(cand, idx, np.int32(slots)))
+        found.append(p < slots)
+        at.append(jnp.minimum(p, np.int32(slots - 1)))
+        left = left & (idx != p)
+    return jnp.stack(at), jnp.stack(found)
+
+
 def _build_tail_fn(tail: Tail, compiled: "CompiledTail"):
     """The single program a tail traces into.
 
@@ -1408,6 +1672,14 @@ def _build_tail_fn(tail: Tail, compiled: "CompiledTail"):
                      for s in out_keys], out_aggs, live)
                 names = list(nd.keys) + list(nd.names)
                 packed = True
+            elif isinstance(nd, TopK) and 0 < nd.n <= TOPK_SELECT_MAX \
+                    and nd.n < live.shape[0]:
+                order, live = _select_first(encode_keys(
+                    [SortKey(cols[names.index(c)], ascending=a)
+                     for c, a in nd.keys]), live, nd.n)
+                cols = [_take(c, order) for c in cols]
+                packed = True
+                vk = [True] * len(cols)
             else:
                 if isinstance(nd, (Sort, TopK)):
                     order = sort_by(nd.keys)
@@ -1449,7 +1721,7 @@ class CompiledTail(CompiledSegment):
         self.tail = tail
         self.key_dtypes = key_dtypes
         self.probes = ()        # counted by the chunk programs only
-        self.dense_k = self.agg_form = None
+        self.dense_k = self.build_row = self.agg_form = None
         from .expr import count_nodes
         self.exprs = sum(count_nodes(nd.predicate) for nd in tail.nodes
                          if isinstance(nd, Filter))

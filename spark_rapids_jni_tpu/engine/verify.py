@@ -947,6 +947,7 @@ class _TraceProbe:
     __slots__ = ("traces",)
 
     dense_k = None      # the aggregate's sort form: no key domain is known
+    build_row = None
 
     def __init__(self):
         self.traces = 0

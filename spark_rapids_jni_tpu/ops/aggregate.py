@@ -552,6 +552,50 @@ def groupby_dense(table: Table, key_names: list, aggs: list[tuple], lo,
     return [("fixed", key.dtype, kdat, kval)], out_aggs, ngroups
 
 
+# ---------------------------------------------------------------------------
+# build-row form: a group that is one row of a unique join build — no sort
+#
+# Where every group key is a column of one build row (the join's key among
+# them: ``engine/segment.py::build_row_join``), a group is that row, and its
+# totals add into the row's slot: a scatter-add of each live row into
+# ``slot`` (the build row it joined), no sort.  The chunk's partial is one
+# slot per build row; partials merge by adding slot to slot.
+# ---------------------------------------------------------------------------
+
+def groupby_build_rows(table: Table, aggs: list[tuple], live, slot,
+                       nslots: int) -> tuple:
+    """``(rows, Columns)``: each of ``nslots`` slots' live row count and
+    its ``sum`` / ``count`` / ``count_all`` totals, row i adding into
+    ``slot[i]`` where ``live[i]``.  A sum's input is integral or decimal
+    (exact in any order); it is null where no valid row added to it."""
+    with jax.named_scope("groupby_build_row"):
+        dest = jnp.where(live, slot, np.int32(nslots))  # dead: a spare slot
+
+        def total(v):
+            return jax.ops.segment_sum(v, dest, nslots + 1)[:nslots]
+
+        rows = total(live.astype(jnp.int64))
+        out = []
+        for c, op in aggs:
+            if op == "count_all":
+                out.append(Column(INT64, data=rows))
+                continue
+            col = c if isinstance(c, Column) else table.column(c)
+            valid = live & col.valid_mask()
+            cnt = rows if col.validity is None else \
+                total(valid.astype(jnp.int64))
+            if op == "count":
+                out.append(Column(INT64, data=cnt))
+                continue
+            vals, out_dtype, is_float = _sum_dtype_and_vals(col, col.data,
+                                                            valid)
+            if is_float:
+                raise TypeError("the build-row form sums no float")
+            s = total(jnp.where(valid, vals, jnp.zeros((), vals.dtype)))
+            out.append(_sum_result(op, col, s, cnt, out_dtype, False))
+    return rows, out
+
+
 def _signed_zeros(s, col: Column, valid, slot, rows, count):
     """``s``, each slot's float sum from a reduction that starts at +0.0,
     with -0.0 where every row of the slot holds -0.0 — what IEEE addition,

@@ -22,7 +22,9 @@ A prepared build (``PreparedBuild``, the fused chunk segment's join) is
 probed by one of two methods, chosen by ``probe_method`` from the build's
 row count (a static shape) and the key dtypes: small builds by a broadcast
 compare of the keys themselves (``_probe_compare``: no hash, no sort, no
-gather), larger ones by the hash merge-rank of steps 1-3 and 5.
+gather), larger ones by rank (``_probe_rank``) — a ``searchsorted`` of the
+int64 keys themselves where the build is keyed by one integer column (no
+hash: its uniqueness is exact), else the hash merge-rank of steps 1-3 and 5.
 """
 
 from __future__ import annotations
@@ -49,8 +51,12 @@ def _key_table(table: Table, on) -> Table:
 
 
 def _pair_equal(lcol: Column, rcol: Column, li, ri, null_equal: bool):
-    """Per-pair true equality of key values at rows (li, ri)."""
-    lv = jnp.take(lcol.valid_mask(), li)
+    """Per-pair true equality of key values at rows (li, ri); ``li`` None:
+    every left row, in order (row i pairs with ``ri[i]``)."""
+    def at_l(a):
+        return a if li is None else jnp.take(a, li, axis=0)
+
+    lv = at_l(lcol.valid_mask())
     rv = jnp.take(rcol.valid_mask(), ri)
     if lcol.dtype.is_string:
         lmat, llen = to_padded_bytes(lcol)
@@ -58,23 +64,22 @@ def _pair_equal(lcol: Column, rcol: Column, li, ri, null_equal: bool):
         w = max(lmat.shape[1], rmat.shape[1])
         lmat = jnp.pad(lmat, ((0, 0), (0, w - lmat.shape[1])))
         rmat = jnp.pad(rmat, ((0, 0), (0, w - rmat.shape[1])))
-        eq = jnp.take(llen, li) == jnp.take(rlen, ri)
-        eq = eq & (jnp.take(lmat, li, axis=0)
-                   == jnp.take(rmat, ri, axis=0)).all(axis=1)
+        eq = at_l(llen) == jnp.take(rlen, ri)
+        eq = eq & (at_l(lmat) == jnp.take(rmat, ri, axis=0)).all(axis=1)
     elif lcol.dtype.id == TypeId.FLOAT64:
         # compare normalized bit patterns: -0.0 = 0.0, NaN matches NaN
         # (Spark join-key float normalization)
         ln = normalize_f64_bits(lcol.data.astype(jnp.uint64))
         rn = normalize_f64_bits(rcol.data.astype(jnp.uint64))
-        eq = jnp.take(ln, li) == jnp.take(rn, ri)
+        eq = at_l(ln) == jnp.take(rn, ri)
     elif lcol.dtype.id == TypeId.FLOAT32:
         ln = normalize_f32_bits(jax.lax.bitcast_convert_type(
             jnp.asarray(lcol.data, jnp.float32), jnp.uint32))
         rn = normalize_f32_bits(jax.lax.bitcast_convert_type(
             jnp.asarray(rcol.data, jnp.float32), jnp.uint32))
-        eq = jnp.take(ln, li) == jnp.take(rn, ri)
+        eq = at_l(ln) == jnp.take(rn, ri)
     else:
-        eq = jnp.take(lcol.data, li) == jnp.take(rcol.data, ri)
+        eq = at_l(lcol.data) == jnp.take(rcol.data, ri)
     if null_equal:
         eq = jnp.where(lv & rv, eq, lv == rv)
     else:
@@ -163,55 +168,133 @@ def _probe_ranges(lh, rh):
 class PreparedBuild:
     """Join build-side state reusable across probe chunks.
 
-    Captures everything ``_probe_ranges`` derives from the build side —
-    xxhash64 of the key columns (dead rows replaced by even sentinels), the
-    32-bit rank-domain cast, and the stable build sort (``rh_sorted`` /
-    ``r_order``) — plus the key and payload Tables the per-pair verify and
-    output assembly gather from.  Computed ONCE per join per execution
-    (cached in ``engine.cache.BUILD_CACHE`` across chunks/executions) where
-    the naive streamed loop re-hashed and re-sorted the build side on every
-    chunk.
+    Captures the build side's sorted rank domain (``rh_sorted``) and the
+    build row at each of its positions (``r_order``) — plus the key and
+    payload Tables the per-pair verify and output assembly gather from.
+    The rank domain is one of two (``exact``):
 
-    ``unique`` (host bool, the one sync ``prepare_build`` pays) says the
-    sorted 32-bit hashes are duplicate-free: every probe row then has at
-    most one candidate, which is what lets ``probe_join_prepared`` stay at
-    probe-row shape with no expansion and no per-chunk sync.  Registered as
-    a jax pytree so a prepared build crosses the jit boundary of a fused
-    chunk program as ordinary traced inputs.
+    - the keys themselves, as int64 (``exact_keys``: ONE integer key
+      column; a null key ranks as ``_NULL_KEY``): a probe key's rank finds
+      the one build row that can hold it, and ``unique`` is exact.  A build
+      the compare probe takes (``probe_method``) reads its keys as they
+      are, and has no sorted domain (``rh_sorted`` / ``r_order`` None);
+    - any other key (several columns, strings, floats): xxhash64 of the
+      key columns cast to 32 bits (``rh``; dead rows replaced by even
+      sentinels) — ``_probe_ranges``' domain, where two keys may share a
+      hash and ``unique`` says the HASHES are distinct.
+
+    Computed ONCE per join (cached in ``engine.cache.BUILD_CACHE`` across
+    chunks/executions) where the naive streamed loop re-hashed and
+    re-sorted the build side on every chunk.
+
+    ``unique`` (host bool, the one sync ``prepare_build`` pays): every
+    probe row has at most one candidate, which is what lets
+    ``probe_join_prepared`` stay at probe-row shape with no expansion and
+    no per-chunk sync.  Registered as a jax pytree so a prepared build
+    crosses the jit boundary of a fused chunk program as ordinary traced
+    inputs.
     """
 
     __slots__ = ("rk", "payload", "rh", "rh_sorted", "r_order",
-                 "right_live", "unique", "nr")
+                 "right_live", "unique", "nr", "exact")
 
     def __init__(self, rk, payload, rh, rh_sorted, r_order, right_live,
-                 unique, nr):
+                 unique, nr, exact=False):
         self.rk = rk                  # build key Table
         self.payload = payload        # build Table for output gathers
-        self.rh = rh                  # int32 sentinel-adjusted hashes
-        self.rh_sorted = rh_sorted
-        self.r_order = r_order
+        self.rh = rh                  # int32 hashes; None where exact
+        self.rh_sorted = rh_sorted    # the sorted rank domain (or None)
+        self.r_order = r_order        # build row at each sorted position
         self.right_live = right_live  # optional build row mask
-        self.unique = unique          # host bool: sorted hashes distinct
+        self.unique = unique          # host bool: <= 1 candidate a probe
         self.nr = nr
+        self.exact = exact            # ranked on the keys themselves
 
     def tree_flatten(self):
         return ((self.rk, self.payload, self.rh, self.rh_sorted,
-                 self.r_order, self.right_live), (self.unique, self.nr))
+                 self.r_order, self.right_live),
+                (self.unique, self.nr, self.exact))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children, *aux)
 
 
+#: a null key's place in the exact rank domain.  A live key of this value
+#: beside a null one counts as a duplicate (``_exact_build_sort``,
+#: ``_exact_unique``), so a
+#: unique build holds at most one row here and the verify tells them apart.
+_NULL_KEY = np.int64(np.iinfo(np.int64).min)
+
+
+def exact_keys(key_cols) -> bool:
+    """Is a build keyed by ``key_cols`` ranked on its keys themselves?  ONE
+    integer column whose values an int64 holds (``DENSE_KEY_TYPES``); every
+    other key is ranked by its 32-bit hash (``PreparedBuild``)."""
+    from .aggregate import DENSE_KEY_TYPES
+    return len(key_cols) == 1 and key_cols[0].dtype.id in DENSE_KEY_TYPES \
+        and key_cols[0].data is not None and key_cols[0].data.ndim == 1
+
+
+def _key_rank_domain(col: Column):
+    """One key column as the exact rank domain: int64 values, a null as
+    ``_NULL_KEY``."""
+    k = col.data.astype(jnp.int64)
+    return k if col.validity is None else jnp.where(col.validity, k,
+                                                    _NULL_KEY)
+
+
+@jax.jit
+def _exact_build_sort(key: Column, right_live):
+    """A large exact build's one program: ``(sorted keys, build row at each
+    position, unique)``.  Among equal keys live rows sort before dead ones,
+    so a key's first position holds a live row wherever one has it;
+    ``unique``: no key is held by two live rows."""
+    ks = _key_rank_domain(key)
+    row = jnp.arange(ks.shape[0], dtype=_I32)
+    if right_live is None:
+        ks, order = jax.lax.sort((ks, row), num_keys=1, is_stable=False)
+        return ks, order, ~jnp.any(ks[1:] == ks[:-1])
+    dead = (~right_live).astype(_I32)
+    ks, dead, order = jax.lax.sort((ks, dead, row), num_keys=2,
+                                   is_stable=False)
+    twice = (ks[1:] == ks[:-1]) & (dead[1:] == 0) & (dead[:-1] == 0)
+    return ks, order, ~jnp.any(twice)
+
+
+@jax.jit
+def _exact_unique(key: Column, right_live):
+    """A small exact build's ``unique``, from every pair of its rows: no
+    two live rows hold one key.  The compare probe reads the keys
+    themselves, so such a build needs no sorted domain."""
+    ks = _key_rank_domain(key)
+    row = jnp.arange(ks.shape[0], dtype=_I32)
+    pair = (ks[:, None] == ks[None, :]) & (row[:, None] < row[None, :])
+    if right_live is not None:
+        pair = pair & right_live[:, None] & right_live[None, :]
+    return ~jnp.any(pair)
+
+
 def prepare_build(right: Table, on_right, right_live=None,
                   payload: Table | None = None) -> PreparedBuild:
-    """Hash + sort the join build side once; see ``PreparedBuild``.
+    """Rank and sort the join build side once; see ``PreparedBuild``.
 
     ``payload`` defaults to ``right`` itself (inner-join output columns);
     pass a pruned Table to bound what fused programs carry.  One host sync
     (the ``unique`` scalar) per call — never per probe chunk.
     """
     rk = _key_table(right, on_right)
+    payload = right if payload is None else payload
+    if exact_keys(rk.columns):
+        key = rk.columns[0]
+        nr = int(key.data.shape[0])
+        if probe_method(nr, rk.columns) == "compare":
+            return PreparedBuild(rk, payload, None, None, None, right_live,
+                                 bool(_exact_unique(key, right_live)), nr,
+                                 exact=True)
+        ks, r_order, unique = _exact_build_sort(key, right_live)
+        return PreparedBuild(rk, payload, None, ks, r_order, right_live,
+                             bool(unique), nr, exact=True)
     rh = xxhash64(rk).data
     if right_live is not None:
         iota = jnp.arange(rh.shape[0], dtype=rh.dtype)
@@ -220,12 +303,12 @@ def prepare_build(right: Table, on_right, right_live=None,
     nr = int(rh32.shape[0])
     unique = True if nr <= 1 else \
         bool(jnp.all(rh_sorted[1:] != rh_sorted[:-1]))
-    return PreparedBuild(rk, right if payload is None else payload,
-                         rh32, rh_sorted, r_order, right_live, unique, nr)
+    return PreparedBuild(rk, payload, rh32, rh_sorted, r_order, right_live,
+                         unique, nr)
 
 
 #: A prepared build of at most this many rows is probed by comparing the
-#: keys themselves (``_probe_compare``); above it the hash merge-rank runs.
+#: keys themselves (``_probe_compare``); above it the rank probe runs.
 #: The compare's work grows with ``nl * nr``, the rank's does not grow with
 #: ``nr``; the chip sweep that places the crossover is in PERF.md section 6
 #: (PR 31).
@@ -411,16 +494,16 @@ def probe_join_prepared(left_keys: Table, pb: PreparedBuild,
                         left_live=None, null_equal: bool = False):
     """Probe a ``PreparedBuild``: masked gather map + match mask per row.
 
-    Requires ``pb.unique`` (every build hash appears at most once in the
-    32-bit rank domain), so each probe row has at most ONE candidate and
-    the result stays at probe-row shape — no expansion sort, fully
-    jit-able, zero host syncs.  Returns ``(ri, matched)``: the int32 build
-    row per probe row (arbitrary where unmatched — mask before trusting
-    it) and the bool match mask.  ``null_equal=True`` is null-safe
-    equality (``<=>``); default SQL semantics never match null keys.
+    Requires ``pb.unique`` (no two live build rows share a place in the
+    rank domain), so each probe row has at most ONE candidate and the
+    result stays at probe-row shape — no expansion sort, fully jit-able,
+    zero host syncs.  Returns ``(ri, matched)``: the int32 build row per
+    probe row (arbitrary where unmatched — mask before trusting it) and the
+    bool match mask.  ``null_equal=True`` is null-safe equality (``<=>``);
+    default SQL semantics never match null keys.
 
     Two methods, one meaning (``probe_method``): a small build is probed by
-    ``_probe_compare``, a larger one by the hash merge-rank below.
+    ``_probe_compare``, a larger one by rank (``_probe_rank``).
     """
     nl = left_keys.num_rows
     if pb.nr == 0:
@@ -428,19 +511,38 @@ def probe_join_prepared(left_keys: Table, pb: PreparedBuild,
     if probe_method(pb.nr, list(left_keys.columns)
                     + list(pb.rk.columns)) == "compare":
         return _probe_compare(left_keys, pb, left_live, null_equal)
-    lh = xxhash64(left_keys).data
-    if left_live is not None:
-        iota = jnp.arange(nl, dtype=lh.dtype)
-        lh = jnp.where(left_live, lh, iota * 2 + 1)  # odd sentinels
-    lh = lh.astype(_I32)
-    lo, hi = _rank_bounds(pb.rh, lh, ref_sorted=pb.rh_sorted)
-    matched = hi > lo
+    return _probe_rank(left_keys, pb, left_live, null_equal)
+
+
+@traced("probe_rank")
+def _probe_rank(left_keys: Table, pb: PreparedBuild, left_live,
+                null_equal: bool):
+    """``probe_join_prepared`` for a larger build: each probe key's rank in
+    the build's sorted rank domain names the one build row that can hold
+    it, and that row's key is verified against the probe's.  An exact
+    build (``pb.exact``) looks the int64 key itself up in its sorted keys
+    (``jnp.searchsorted``: no sort in the program): the row at the rank is
+    the first of the key's run, live where one is.  A hashed build
+    merge-ranks the 32-bit hashes (``_rank_bounds``; dead probe rows given
+    odd sentinels) and asks first that the run is not empty."""
+    nl = left_keys.num_rows
+    if pb.exact:
+        lo = jnp.searchsorted(pb.rh_sorted,
+                              _key_rank_domain(left_keys.columns[0]),
+                              side="left")
+        eq = True
+    else:
+        lh = xxhash64(left_keys).data
+        if left_live is not None:
+            iota = jnp.arange(nl, dtype=lh.dtype)
+            lh = jnp.where(left_live, lh, iota * 2 + 1)  # odd sentinels
+        lo, hi = _rank_bounds(pb.rh, lh.astype(_I32),
+                              ref_sorted=pb.rh_sorted)
+        eq = hi > lo
     ri = jnp.take(pb.r_order,
                   jnp.clip(lo, 0, pb.nr - 1).astype(_I32)).astype(_I32)
-    li = jnp.arange(nl, dtype=_I32)
-    eq = matched
     for lc, rc in zip(left_keys.columns, pb.rk.columns):
-        eq = eq & _pair_equal(lc, rc, li, ri, null_equal=null_equal)
+        eq = eq & _pair_equal(lc, rc, None, ri, null_equal=null_equal)
     if pb.right_live is not None:
         eq = eq & jnp.take(pb.right_live, ri)
     if left_live is not None:

@@ -396,8 +396,9 @@ def test_reader_agg_dense_pct(start, end, want):
 def test_reader_lists_the_keyed_cells():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]
-    assert entry["name"] == "agg_dense_pct"
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == "agg_dense_pct"]
+    # Q6 has no group key; Q3's keys come from its build, so its chunk
+    # program always takes the sorted form and the reader does not list it
     keyed = [w["name"] for w in bench["workloads"]
-             if w["config"] != "tpch_q6_sf1"]
+             if w["config"] not in ("tpch_q6_sf1", "tpch_q3_sf1")]
     assert entry["workloads"] == keyed

@@ -283,9 +283,11 @@ def test_groupby_padded_chunk(one_chip, tpu_branches):
 
 
 def test_probe_join_prepared_chunk(one_chip, tpu_branches):
-    """The merge-rank probe of a chunk against a build one row above
-    ``PROBE_COMPARE_MAX_BUILD`` (the method a large build keeps), and the
-    device half of the build (hash + sort)."""
+    """The rank probe of a chunk against a build one row above
+    ``PROBE_COMPARE_MAX_BUILD`` (the method a large build keeps) by its
+    exact int64 keys — a ``searchsorted``, no sort in the program — and the
+    device half of both builds: the exact one's sort, and the hashed one's
+    (hash + sort) that a build keyed otherwise takes."""
     from spark_rapids_jni_tpu.ops import join as J
     from spark_rapids_jni_tpu.ops.hash import xxhash64
     nr = J.PROBE_COMPARE_MAX_BUILD + 1
@@ -293,15 +295,17 @@ def test_probe_join_prepared_chunk(one_chip, tpu_branches):
                           data=jnp.arange(2_451_545, 2_451_545 + nr))],
                   ["d_date_sk"])
     pb = J.prepare_build(dates, ["d_date_sk"])
-    assert J.probe_method(pb.nr, pb.rk.columns) == "rank"
+    assert J.probe_method(pb.nr, pb.rk.columns) == "rank" and pb.exact
     keys = Table([Column(dt.INT64, data=np.zeros(CHUNK_ROWS, np.int64),
                          validity=np.ones(CHUNK_ROWS, np.bool_))],
                  ["ss_sold_date_sk"])
     probe = compile_for_chip(J.probe_join_prepared, on(one_chip, keys),
                              on(one_chip, pb))
-    assert " sort(" in probe.as_text()
+    assert " sort(" not in probe.as_text()
     compile_for_chip(lambda t: J._build_sort(xxhash64(t).data),
                      on(one_chip, dates))
+    compile_for_chip(lambda c: J._exact_build_sort(c, None),
+                     on(one_chip, dates.columns[0]))
 
 
 def test_probe_compare_real_chunk(one_chip, tpu_branches):
@@ -462,3 +466,107 @@ def test_q6_chunk_program_and_keyless_merge(tmp_path, one_chip,
     compile_for_chip(
         seg._build_combine_fn(m.segment.agg, m.key_dtypes, 1, m),
         on(one_chip, partials), count)
+
+
+def test_q3_chunk_program(tmp_path, one_chip, tpu_branches):
+    """TPC-H Q3's programs at the cell's SF1 shapes: the chunk program (the
+    rank probe of a build above ``PROBE_COMPARE_MAX_BUILD`` by its exact
+    int64 keys, the decimal product, the group-by in the build-row form:
+    a scatter-add into the build's slots), the running sum of the stream's
+    partials, the groups' compaction and the ``tail``'s top 10 by
+    selection — none of them sorts.  Found by running the cell's plan
+    small on the CPU, compiled with the chunk's rows at 262,144, the
+    build's at 145,761 and the groups' slots at 16,384; the temporaries
+    are noted against the chip's HBM."""
+    import importlib.util
+    import json
+
+    from spark_rapids_jni_tpu.engine import execute, lower, optimize
+    from spark_rapids_jni_tpu.engine import segment as seg
+    from spark_rapids_jni_tpu.engine.executor import lowering_flags
+    from spark_rapids_jni_tpu.ops import join as J
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def load(rel, name):
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(root, "benchmarks", rel))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    q3 = load("queries/tpch_q3.py", "chipc_q3")
+    run = load("run.py", "chipc_run3")
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "tpch_q3_sf1.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "benchmarks", "traffic",
+                           "q3_building.json")) as f:
+        params = json.load(f)["params"]
+    # 90,000 orders: a build of 8,746 rows, just above the compare's 8,192
+    frames = q3.tables(3, {"lineitem": 360_000, "orders": 90_000,
+                           "customer": 9_000})
+    paths = run.write_tables(frames, cfg, str(tmp_path))
+    calls: dict = {"chunk": [], "add": [], "groups": [], "tail": []}
+    chunk, tail = seg.CompiledSegment.__call__, seg.CompiledTail.__call__
+    add, groups = seg._add_build_rows, seg._build_row_groups
+
+    def chunk_call(self, table, nvalid=None, prepared=(), lo=None):
+        calls["chunk"].append((self, table, tuple(prepared)))
+        return chunk(self, table, nvalid, prepared, lo)
+
+    def tail_call(self, part, dims):
+        calls["tail"].append((self, part, dims))
+        return tail(self, part, dims)
+
+    def add_call(acc, part, checked):
+        calls["add"].append((acc, part, checked))
+        return add(acc, part, checked)
+
+    def groups_call(acc, pb, sources, cap):
+        calls["groups"].append((acc, pb, sources, cap))
+        return groups(acc, pb, sources, cap)
+
+    try:
+        seg.CompiledSegment.__call__ = chunk_call
+        seg.CompiledTail.__call__ = tail_call
+        seg._add_build_rows, seg._build_row_groups = add_call, groups_call
+        opt = optimize(q3.plan(paths, params, cfg["storage"]["chunk_bytes"]))
+        execute(lower(opt, **{**lowering_flags(), "ndev": 1}))
+    finally:
+        seg.CompiledSegment.__call__ = chunk
+        seg.CompiledTail.__call__ = tail
+        seg._add_build_rows, seg._build_row_groups = add, groups
+    compiled, table, prepared = calls["chunk"][0]
+    (pb,) = prepared
+    assert compiled.probes == ("rank",) and pb.exact
+    assert pb.nr == 8_746 > J.PROBE_COMPARE_MAX_BUILD
+    assert compiled.agg_form == "build" and len(compiled.key_dtypes) == 3
+    (acc, _, sources, cap), = calls["groups"]
+    # the cell's shapes: a 262,144-row chunk, 145,761 build rows (and the
+    # offsets of a string payload column), ~11,300 groups in 16,384 slots
+    size = {table.num_rows: 262_144, pb.nr: 145_761, pb.nr + 1: 145_762,
+            cap: 16_384}
+
+    def at_sf1(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            tuple(size.get(d, d) if i == 0 else d
+                  for i, d in enumerate(a.shape)), a.dtype,
+            sharding=one_chip), tree)
+
+    count = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    a, p, checked = calls["add"][0]
+    (tl, part, dims), = calls["tail"]
+    programs = [
+        compile_for_chip(seg._build_fn(compiled.segment, compiled),
+                         at_sf1(table), count, at_sf1(prepared)),
+        compile_for_chip(lambda x, y: add(x, y, checked), at_sf1(a),
+                         at_sf1(p)),
+        compile_for_chip(lambda x, y: groups(x, y, sources, 16_384),
+                         at_sf1(acc), at_sf1(pb)),
+        compile_for_chip(seg._build_tail_fn(tl.tail, tl), at_sf1(part),
+                         tuple((at_sf1(t), count) for t, _ in dims))]
+    for program in programs:
+        text = program.as_text()
+        assert " sort(" not in text and "tpu_custom_call" not in text
+        # tens of MB at most against 16 GB; PERF.md has the cell's count
+        assert program.memory_analysis().temp_size_in_bytes < HBM_BYTES // 64

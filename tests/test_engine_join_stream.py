@@ -125,8 +125,9 @@ def test_probe_methods_agree_and_count_per_chunk(warehouse, how,
                                                  metrics_isolation,
                                                  monkeypatch):
     """The 30-row build takes the compare path; with the constant moved
-    under it the same plan takes the merge-rank: same rows, and
-    ``compare + rank == joins x chunks`` either way."""
+    under it the same plan takes the rank probe: same rows, and
+    ``compare + rank == joins x chunks`` either way.  The build is prepared
+    anew for each: a compare build carries no sorted keys."""
     from spark_rapids_jni_tpu.engine import segment as sg
     from spark_rapids_jni_tpu.ops import join as J
     metrics_isolation("engine.probe")
@@ -137,6 +138,7 @@ def test_probe_methods_agree_and_count_per_chunk(warehouse, how,
         if method == "rank":
             monkeypatch.setattr(J, "PROBE_COMPARE_MAX_BUILD", 29)
         sg.SEGMENT_CACHE.clear()    # one plan shape, two programs
+        BUILD_CACHE.clear()         # and two prepares
         tracing.reset_counters("engine.probe")
         stats = new_stats()
         results[method] = as_rows(execute(optimize(plan), stats=stats,

@@ -380,7 +380,7 @@ def test_rehearsal_every_join_of_every_chunk_took_the_compare_path(rehearsal):
     compiled = calls[0][0]
     assert compiled.probes == ("compare",) * rehearsal["joins"]
     assert compiled.span_stats() == {
-        "probe_compare": f"{rehearsal['joins']}/{rehearsal['joins']}",
+        "probe": f"{rehearsal['joins']}/0/{rehearsal['joins']}",
         "exprs": compiled.segment.exprs(),     # the stats beside it
         "agg": rehearsal["agg"]}
     # the forced merge-rank run counts the other way: compare + rank is
@@ -421,9 +421,10 @@ def test_rehearsal_chunk_program_has_no_gather_or_sort_outside_groupby(
         rehearsal):
     """Structure, no chip: on the compare path the chunk program's only
     gathers and sorts are the group-by's; on the rank path the probe's own
-    are there (so the walker sees what it is asked to see)."""
+    gathers are there (so the walker sees what it is asked to see), and no
+    sort: each build is keyed by one integer column, whose keys the probe
+    looks up by ``searchsorted``."""
     assert rehearsal["compare"][4] == []
     outside = rehearsal["rank"][4]
     assert any(o.startswith("gather") for o in outside)
-    assert sum(o.startswith("sort") for o in outside) \
-        == 2 * rehearsal["joins"]
+    assert not any(o.startswith("sort") for o in outside)

@@ -460,10 +460,14 @@ def render_metrics_doc(catalog: dict) -> str:
         "",
         "The benchmark's per-layer readers (`benchmarks/layer_metrics/`)",
         "read these names; root `PERF.md` §3 says which reads which.  The",
-        "newest, `expr_fused_pct` (PR 40, cell `tpch_q6_sf1_1994`), is the",
-        "growth of `engine.expr.fused` over that of `engine.expr.fused` +",
-        "`engine.expr.eager`, in %: the share of expression nodes that ran",
-        "compiled into a chunk program.",
+        "newest two read the probe join of cell `tpch_q3_sf1_building`:",
+        "`probe_fused_pct` is the growth of `engine.probe.compare` +",
+        "`engine.probe.rank` over that of those two + `engine.probe.interp`,",
+        "in %: the share of streamed probe joins that ran inside a chunk",
+        "program.  `probe_rank_roofline` is the query module's",
+        "`probe_bytes_needed` times the growth of `engine.probe.rank` per",
+        "query, over the chip's HBM rate, over the device time per query of",
+        "the ops under `engine.fused_segment/probe_rank` in the trace.",
         "",
         "| name | kind | call sites |",
         "|---|---|---|",
