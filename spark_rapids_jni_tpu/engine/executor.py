@@ -1148,7 +1148,7 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                             ctx.recovery.charge(payload.comp_bytes)
                             fused_compiled = sg.SEGMENT_CACHE.get_decode(
                                 seg, payload.geom, build_tables, dense_k,
-                                build_row)
+                                build_row, preps)
                             with op_scope("engine.fused_segment",
                                           **fused_compiled.span_stats()):
                                 fused.add(fused_compiled(
@@ -1170,7 +1170,8 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                             padded = chunk.num_rows - nvalid
                             ctx.recovery.charge(cb)
                             fused_compiled = sg.SEGMENT_CACHE.get(
-                                seg, chunk, build_tables, dense_k, build_row)
+                                seg, chunk, build_tables, dense_k, build_row,
+                                preps)
                             with op_scope("engine.fused_segment",
                                           **fused_compiled.span_stats()):
                                 fused.add(fused_compiled(
@@ -1182,7 +1183,8 @@ def _stream_chunks(agg: Aggregate, scan: Scan, seg, memo: dict, stats: dict,
                         padded = chunk.num_rows - nvalid
                         ctx.recovery.charge(cb)
                         fused_compiled = sg.SEGMENT_CACHE.get(
-                            seg, chunk, build_tables, dense_k, build_row)
+                            seg, chunk, build_tables, dense_k, build_row,
+                            preps)
                         with op_scope("engine.fused_segment",
                                       **fused_compiled.span_stats()):
                             fused.add(fused_compiled(chunk, nvalid, preps,

@@ -25,11 +25,13 @@ plans.  The TPU translation:
   callable per chunk with zero per-chunk host syncs.  How a probe row
   finds its build row is ``ops.join.probe_method``'s choice, from the
   build's row count: a broadcast compare of the keys for a small build
-  (no hash, sort or gather in the program), the merge-rank above it (of
-  the keys themselves for one integer key, else of their hashes);
+  (no hash, sort or gather in the program), the rank probe above it (for
+  one integer key a gather from the build's direct-address table, or a
+  search of its sorted keys; else a merge-rank of their hashes);
   ``engine.probe.compare`` / ``engine.probe.rank`` count the joins that
-  took each, per chunk launch, and ``engine.probe.interp`` those of a
-  chunk the interpreter ran instead.
+  took each, per chunk launch (``engine.probe.direct`` the rank probes by
+  table), and ``engine.probe.interp`` those of a chunk the interpreter ran
+  instead.
 - Compiled segments live in a process-wide LRU keyed by
   ``(segment fingerprint, input shape-class)`` with hit/miss/eviction
   counters in ``utils.tracing`` (``engine.segment_cache.*``).  The
@@ -344,15 +346,21 @@ def shape_class(table: Table) -> tuple:
     )
 
 
-def probe_methods(seg: Segment, builds: tuple) -> tuple:
+def probe_methods(seg: Segment, builds: tuple, prepared: tuple = ()) \
+        -> tuple:
     """``ops.join.probe_method`` of every Join of the chain, execution
     order, from the build Tables the chunk program is compiled for: the
     build's row count and its key columns (the probe side's keys are
-    fixed-width by ``stream_runtime_eligible``)."""
+    fixed-width by ``stream_runtime_eligible``) — ``"direct"`` for a rank
+    probe whose prepared build (``prepared``) has a direct-address table
+    (``PreparedBuild.direct``)."""
     from ..ops.join import probe_method
-    return tuple(
-        probe_method(b.num_rows, [b.column(k) for k in j.right_keys])
-        for j, b in zip(seg.joins(), builds))
+    methods = [probe_method(b.num_rows, [b.column(k) for k in j.right_keys])
+               for j, b in zip(seg.joins(), builds)]
+    for i, pb in enumerate(prepared):
+        if methods[i] == "rank" and pb.direct is not None:
+            methods[i] = "direct"
+    return tuple(methods)
 
 
 def _probe_join_node(nd: Join, pb, table: Table, live, needed):
@@ -601,7 +609,8 @@ class CompiledSegment:
     """One (segment, shape-class) entry: a jitted callable plus the trace
     counter tests use to prove chunks reuse one executable.  ``probes``
     is ``probe_methods`` of the chain's joins for this shape class: what
-    the ``engine.probe.*`` counters and the span's stat report.
+    the ``engine.probe.*`` counters and the span's stat report (a
+    ``direct`` probe counts as ``rank`` and as ``direct``).
     ``dense_k``: the slots of the aggregate's dense form, None for the sort
     form; ``build_row``: ``build_row_join``'s answer where the aggregate
     takes the build-row form; ``agg_form`` what the ``engine.agg.*``
@@ -637,7 +646,7 @@ class CompiledSegment:
         if self.probes:
             compare = self.probes.count("compare")
             out["probe"] = f"{compare}/{len(self.probes) - compare}" \
-                f"/{len(self.probes)}"
+                f"/{len(self.probes)}/{self.probes.count('direct')}"
         if self.agg_form:
             out["agg"] = self.agg_form
         return out
@@ -655,6 +664,9 @@ class CompiledSegment:
             metrics.count("engine.probe.compare", compare)
         if len(self.probes) > compare:
             metrics.count("engine.probe.rank", len(self.probes) - compare)
+        direct = self.probes.count("direct")      # rank probes by table
+        if direct:
+            metrics.count("engine.probe.direct", direct)
         if self.agg_form:
             metrics.count("engine.agg.dense" if self.dense_k
                           else "engine.agg.build" if self.build_row
@@ -929,35 +941,38 @@ class SegmentCache:
 
     def get(self, segment: Segment, table: Table, builds: tuple = (),
             dense_k: Optional[int] = None,
-            build_row: Optional[tuple] = None) -> CompiledSegment:
+            build_row: Optional[tuple] = None,
+            prepared: tuple = ()) -> CompiledSegment:
         """The chunk program of ``segment`` over ``table``'s shape class;
         ``dense_k``: its aggregate's dense form over that many key slots
         (``agg_domain``), ``build_row`` its build-row form
-        (``build_row_join``), each a part of the shape class."""
+        (``build_row_join``), each a part of the shape class, as are the
+        probe methods of the builds ``prepared`` for it."""
+        probes = probe_methods(segment, builds, prepared)
         key = (segment.fingerprint(), _dense_class(shape_class(table),
                                                    dense_k, build_row),
-               tuple(shape_class(b) for b in builds))
+               (tuple(shape_class(b) for b in builds), probes))
 
         def build():
             key_dtypes = () if segment.agg is None else tuple(
                 _resolve_dtype(k, table, builds) for k in segment.agg.keys)
-            return CompiledSegment(key, segment, key_dtypes,
-                                   probe_methods(segment, builds), dense_k,
+            return CompiledSegment(key, segment, key_dtypes, probes, dense_k,
                                    build_row)
 
         return self._lookup(key, build)
 
     def get_decode(self, segment: Segment, geom, builds: tuple = (),
                    dense_k: Optional[int] = None,
-                   build_row: Optional[tuple] = None) \
-            -> CompiledDecodeSegment:
+                   build_row: Optional[tuple] = None,
+                   prepared: tuple = ()) -> CompiledDecodeSegment:
         """The fused scan-decode variant of :meth:`get`: keyed by
-        (fingerprint, page geometry, build shapes) — one executable per
-        (plan segment, page-geometry bucket) class, shared by every chunk
-        whose pages quantize to the same buckets."""
+        (fingerprint, page geometry, build shapes and probe methods) — one
+        executable per (plan segment, page-geometry bucket) class, shared by
+        every chunk whose pages quantize to the same buckets."""
+        probes = probe_methods(segment, builds, prepared)
         key = (segment.fingerprint(),
                _dense_class(("device_decode", geom), dense_k, build_row),
-               tuple(shape_class(b) for b in builds))
+               (tuple(shape_class(b) for b in builds), probes))
 
         def build():
             from ..ops.parquet_decode import probe_table
@@ -965,8 +980,7 @@ class SegmentCache:
                 _resolve_dtype(k, probe_table(geom), builds)
                 for k in segment.agg.keys)
             return CompiledDecodeSegment(key, segment, key_dtypes, geom,
-                                         probe_methods(segment, builds),
-                                         dense_k, build_row)
+                                         probes, dense_k, build_row)
 
         return self._lookup(key, build)
 

@@ -22,9 +22,11 @@ A prepared build (``PreparedBuild``, the fused chunk segment's join) is
 probed by one of two methods, chosen by ``probe_method`` from the build's
 row count (a static shape) and the key dtypes: small builds by a broadcast
 compare of the keys themselves (``_probe_compare``: no hash, no sort, no
-gather), larger ones by rank (``_probe_rank``) — a ``searchsorted`` of the
-int64 keys themselves where the build is keyed by one integer column (no
-hash: its uniqueness is exact), else the hash merge-rank of steps 1-3 and 5.
+gather), larger ones by rank (``_probe_rank``) — where the build is keyed by
+one integer column (no hash: its uniqueness is exact) one gather from a
+direct-address table of its key span (``DIRECT_MAX_SLOTS``), else a
+``searchsorted`` of the int64 keys themselves; any other build by the hash
+merge-rank of steps 1-3 and 5.
 """
 
 from __future__ import annotations
@@ -193,13 +195,19 @@ class PreparedBuild:
     no per-chunk sync.  Registered as a jax pytree so a prepared build
     crosses the jit boundary of a fused chunk program as ordinary traced
     inputs.
+
+    ``direct`` (a unique exact build whose live keys span at most
+    ``DIRECT_MAX_SLOTS``, else None): the int32 build row of each key
+    ``kmin + i`` at slot ``i``, ``_NO_ROW`` where no live row holds it —
+    ``kmin`` the smallest live key, a device scalar, so another build of
+    the same slot count compiles nothing.
     """
 
     __slots__ = ("rk", "payload", "rh", "rh_sorted", "r_order",
-                 "right_live", "unique", "nr", "exact")
+                 "right_live", "unique", "nr", "exact", "direct", "kmin")
 
     def __init__(self, rk, payload, rh, rh_sorted, r_order, right_live,
-                 unique, nr, exact=False):
+                 unique, nr, exact=False, direct=None, kmin=None):
         self.rk = rk                  # build key Table
         self.payload = payload        # build Table for output gathers
         self.rh = rh                  # int32 hashes; None where exact
@@ -209,15 +217,18 @@ class PreparedBuild:
         self.unique = unique          # host bool: <= 1 candidate a probe
         self.nr = nr
         self.exact = exact            # ranked on the keys themselves
+        self.direct = direct          # direct-address table (or None)
+        self.kmin = kmin              # int64 key at its slot 0
 
     def tree_flatten(self):
         return ((self.rk, self.payload, self.rh, self.rh_sorted,
-                 self.r_order, self.right_live),
+                 self.r_order, self.right_live, self.direct, self.kmin),
                 (self.unique, self.nr, self.exact))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, *aux)
+        *arrays, direct, kmin = children
+        return cls(*arrays, *aux, direct=direct, kmin=kmin)
 
 
 #: a null key's place in the exact rank domain.  A live key of this value
@@ -244,22 +255,63 @@ def _key_rank_domain(col: Column):
                                                     _NULL_KEY)
 
 
+def _live_keys(key: Column, right_live):
+    """``(int64 keys, mask)``: the build rows a probe may match — live,
+    with a non-null key."""
+    ok = key.valid_mask()
+    return key.data.astype(jnp.int64), \
+        ok if right_live is None else ok & right_live
+
+
 @jax.jit
 def _exact_build_sort(key: Column, right_live):
     """A large exact build's one program: ``(sorted keys, build row at each
-    position, unique)``.  Among equal keys live rows sort before dead ones,
-    so a key's first position holds a live row wherever one has it;
-    ``unique``: no key is held by two live rows."""
+    position, unique, kmin, kmax)``.  Among equal keys live rows sort
+    before dead ones, so a key's first position holds a live row wherever
+    one has it; ``unique``: no key is held by two live rows; ``kmin`` /
+    ``kmax``: the smallest and largest live non-null key (``kmin > kmax``
+    where there is none)."""
+    k, ok = _live_keys(key, right_live)
+    kmin = jnp.min(jnp.where(ok, k, np.iinfo(np.int64).max))
+    kmax = jnp.max(jnp.where(ok, k, np.iinfo(np.int64).min))
     ks = _key_rank_domain(key)
     row = jnp.arange(ks.shape[0], dtype=_I32)
     if right_live is None:
         ks, order = jax.lax.sort((ks, row), num_keys=1, is_stable=False)
-        return ks, order, ~jnp.any(ks[1:] == ks[:-1])
+        return ks, order, ~jnp.any(ks[1:] == ks[:-1]), kmin, kmax
     dead = (~right_live).astype(_I32)
     ks, dead, order = jax.lax.sort((ks, dead, row), num_keys=2,
                                    is_stable=False)
     twice = (ks[1:] == ks[:-1]) & (dead[1:] == 0) & (dead[:-1] == 0)
-    return ks, order, ~jnp.any(twice)
+    return ks, order, ~jnp.any(twice), kmin, kmax
+
+
+#: The most slots a direct-address table (``PreparedBuild.direct``) may
+#: hold: 2^26 int32 slots are 256 MiB of HBM.  A unique exact build whose
+#: live keys span more is probed by ``searchsorted``.
+DIRECT_MAX_SLOTS = 1 << 26
+
+
+def _direct_slots(kmin: int, kmax: int):
+    """The slots of the direct-address table for live keys ``kmin`` ..
+    ``kmax``: their span rounded up to a power of two (so builds of one
+    size class share a program), or None where there is no key or the
+    span passes ``DIRECT_MAX_SLOTS``."""
+    span = kmax - kmin + 1
+    if span < 1:
+        return None
+    slots = 1 << (span - 1).bit_length()
+    return slots if slots <= DIRECT_MAX_SLOTS else None
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _direct_table(key: Column, right_live, slots: int, kmin):
+    """The direct-address table of a unique exact build: slot ``key -
+    kmin`` holds the key's live row, every other slot ``_NO_ROW``."""
+    k, ok = _live_keys(key, right_live)
+    at = jnp.where(ok, k - kmin, slots).astype(_I32)   # dropped where not ok
+    return jnp.full((slots,), _NO_ROW, _I32).at[at].set(
+        jnp.arange(k.shape[0], dtype=_I32), mode="drop")
 
 
 @jax.jit
@@ -281,7 +333,8 @@ def prepare_build(right: Table, on_right, right_live=None,
 
     ``payload`` defaults to ``right`` itself (inner-join output columns);
     pass a pruned Table to bound what fused programs carry.  One host sync
-    (the ``unique`` scalar) per call — never per probe chunk.
+    (the ``unique`` scalar, with the key range of an exact build) per call
+    — never per probe chunk.
     """
     rk = _key_table(right, on_right)
     payload = right if payload is None else payload
@@ -292,9 +345,15 @@ def prepare_build(right: Table, on_right, right_live=None,
             return PreparedBuild(rk, payload, None, None, None, right_live,
                                  bool(_exact_unique(key, right_live)), nr,
                                  exact=True)
-        ks, r_order, unique = _exact_build_sort(key, right_live)
+        ks, r_order, unique, kmin, kmax = _exact_build_sort(key, right_live)
+        unique, lo, hi = (x.item() for x in jax.device_get((unique, kmin,
+                                                            kmax)))
+        slots = _direct_slots(lo, hi) if unique else None
+        direct = None if slots is None else \
+            _direct_table(key, right_live, slots, kmin)
         return PreparedBuild(rk, payload, None, ks, r_order, right_live,
-                             bool(unique), nr, exact=True)
+                             bool(unique), nr, exact=True, direct=direct,
+                             kmin=None if direct is None else kmin)
     rh = xxhash64(rk).data
     if right_live is not None:
         iota = jnp.arange(rh.shape[0], dtype=rh.dtype)
@@ -517,16 +576,27 @@ def probe_join_prepared(left_keys: Table, pb: PreparedBuild,
 @traced("probe_rank")
 def _probe_rank(left_keys: Table, pb: PreparedBuild, left_live,
                 null_equal: bool):
-    """``probe_join_prepared`` for a larger build: each probe key's rank in
-    the build's sorted rank domain names the one build row that can hold
-    it, and that row's key is verified against the probe's.  An exact
-    build (``pb.exact``) looks the int64 key itself up in its sorted keys
-    (``jnp.searchsorted``: no sort in the program): the row at the rank is
-    the first of the key's run, live where one is.  A hashed build
+    """``probe_join_prepared`` for a larger build: each probe key names the
+    one build row that can hold it, and that row's key is verified against
+    the probe's.  An exact build with a direct-address table
+    (``pb.direct``) reads the row at the key's offset from ``pb.kmin``:
+    one gather (a null key, under ``null_equal``, ranks instead).  Another
+    exact build (``pb.exact``) looks the int64 key itself up in its sorted
+    keys (``jnp.searchsorted``: no sort in the program): the row at the
+    rank is the first of the key's run, live where one is.  A hashed build
     merge-ranks the 32-bit hashes (``_rank_bounds``; dead probe rows given
     odd sentinels) and asks first that the run is not empty."""
     nl = left_keys.num_rows
-    if pb.exact:
+    direct = pb.direct is not None and not null_equal
+    if direct:
+        lc = left_keys.columns[0]
+        slots = pb.direct.shape[0]
+        off = lc.data.astype(jnp.int64) - pb.kmin
+        eq = lc.valid_mask() & (off >= 0) & (off < slots)
+        ri = jnp.take(pb.direct, jnp.clip(off, 0, slots - 1).astype(_I32))
+        eq = eq & (ri != _NO_ROW)
+        ri = jnp.where(eq, ri, 0)
+    elif pb.exact:
         lo = jnp.searchsorted(pb.rh_sorted,
                               _key_rank_domain(left_keys.columns[0]),
                               side="left")
@@ -539,8 +609,9 @@ def _probe_rank(left_keys: Table, pb: PreparedBuild, left_live,
         lo, hi = _rank_bounds(pb.rh, lh.astype(_I32),
                               ref_sorted=pb.rh_sorted)
         eq = hi > lo
-    ri = jnp.take(pb.r_order,
-                  jnp.clip(lo, 0, pb.nr - 1).astype(_I32)).astype(_I32)
+    if not direct:
+        ri = jnp.take(pb.r_order,
+                      jnp.clip(lo, 0, pb.nr - 1).astype(_I32)).astype(_I32)
     for lc, rc in zip(left_keys.columns, pb.rk.columns):
         eq = eq & _pair_equal(lc, rc, None, ri, null_equal=null_equal)
     if pb.right_live is not None:

@@ -282,30 +282,40 @@ def test_groupby_padded_chunk(one_chip, tpu_branches):
     compile_for_chip(step, on(one_chip, sales_chunk(CHUNK_ROWS)))
 
 
-def test_probe_join_prepared_chunk(one_chip, tpu_branches):
+def test_probe_join_prepared_chunk(one_chip, tpu_branches, monkeypatch):
     """The rank probe of a chunk against a build one row above
     ``PROBE_COMPARE_MAX_BUILD`` (the method a large build keeps) by its
-    exact int64 keys — a ``searchsorted``, no sort in the program — and the
-    device half of both builds: the exact one's sort, and the hashed one's
-    (hash + sort) that a build keyed otherwise takes."""
+    exact int64 keys — one gather from the direct-address table, or with
+    the table forced off a ``searchsorted``; no sort in the program either
+    way — and the device half of the builds: the exact one's sort and its
+    table, and the hashed one's (hash + sort) that a build keyed otherwise
+    takes."""
     from spark_rapids_jni_tpu.ops import join as J
     from spark_rapids_jni_tpu.ops.hash import xxhash64
     nr = J.PROBE_COMPARE_MAX_BUILD + 1
     dates = Table([Column(dt.INT64,
                           data=jnp.arange(2_451_545, 2_451_545 + nr))],
                   ["d_date_sk"])
-    pb = J.prepare_build(dates, ["d_date_sk"])
-    assert J.probe_method(pb.nr, pb.rk.columns) == "rank" and pb.exact
     keys = Table([Column(dt.INT64, data=np.zeros(CHUNK_ROWS, np.int64),
                          validity=np.ones(CHUNK_ROWS, np.bool_))],
                  ["ss_sold_date_sk"])
-    probe = compile_for_chip(J.probe_join_prepared, on(one_chip, keys),
-                             on(one_chip, pb))
-    assert " sort(" not in probe.as_text()
+    for table in (True, False):
+        if not table:
+            monkeypatch.setattr(J, "DIRECT_MAX_SLOTS", 0)
+        pb = J.prepare_build(dates, ["d_date_sk"])
+        assert J.probe_method(pb.nr, pb.rk.columns) == "rank" and pb.exact
+        assert (pb.direct is not None) == table
+        probe = compile_for_chip(J.probe_join_prepared, on(one_chip, keys),
+                                 on(one_chip, pb))
+        text = probe.as_text()
+        assert " sort(" not in text and (" while(" in text) != table
     compile_for_chip(lambda t: J._build_sort(xxhash64(t).data),
                      on(one_chip, dates))
     compile_for_chip(lambda c: J._exact_build_sort(c, None),
                      on(one_chip, dates.columns[0]))
+    compile_for_chip(lambda c, k: J._direct_table(c, None, 1 << 14, k),
+                     on(one_chip, dates.columns[0]),
+                     jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip))
 
 
 def test_probe_compare_real_chunk(one_chip, tpu_branches):
@@ -471,13 +481,14 @@ def test_q6_chunk_program_and_keyless_merge(tmp_path, one_chip,
 def test_q3_chunk_program(tmp_path, one_chip, tpu_branches):
     """TPC-H Q3's programs at the cell's SF1 shapes: the chunk program (the
     rank probe of a build above ``PROBE_COMPARE_MAX_BUILD`` by its exact
-    int64 keys, the decimal product, the group-by in the build-row form:
-    a scatter-add into the build's slots), the running sum of the stream's
-    partials, the groups' compaction and the ``tail``'s top 10 by
-    selection — none of them sorts.  Found by running the cell's plan
-    small on the CPU, compiled with the chunk's rows at 262,144, the
-    build's at 145,761 and the groups' slots at 16,384; the temporaries
-    are noted against the chip's HBM."""
+    int64 keys, read from its direct-address table, the decimal product,
+    the group-by in the build-row form: a scatter-add into the build's
+    slots), the running sum of the stream's partials, the groups'
+    compaction and the ``tail``'s top 10 by selection — none of them
+    sorts — and the table's own program.  Found by running the plan small
+    on the CPU, compiled with the chunk's rows at 262,144, the build's at
+    145,761, the groups' slots at 16,384 and the table's at 2**23; the
+    temporaries are noted against the chip's HBM."""
     import importlib.util
     import json
 
@@ -538,14 +549,15 @@ def test_q3_chunk_program(tmp_path, one_chip, tpu_branches):
         seg._add_build_rows, seg._build_row_groups = add, groups
     compiled, table, prepared = calls["chunk"][0]
     (pb,) = prepared
-    assert compiled.probes == ("rank",) and pb.exact
+    assert compiled.probes == ("direct",) and pb.exact
     assert pb.nr == 8_746 > J.PROBE_COMPARE_MAX_BUILD
     assert compiled.agg_form == "build" and len(compiled.key_dtypes) == 3
     (acc, _, sources, cap), = calls["groups"]
     # the cell's shapes: a 262,144-row chunk, 145,761 build rows (and the
-    # offsets of a string payload column), ~11,300 groups in 16,384 slots
+    # offsets of a string payload column), ~11,300 groups in 16,384 slots,
+    # the direct-address table of orders keys up to 6,000,000 in 2**23
     size = {table.num_rows: 262_144, pb.nr: 145_761, pb.nr + 1: 145_762,
-            cap: 16_384}
+            cap: 16_384, pb.direct.shape[0]: 1 << 23}
 
     def at_sf1(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -570,3 +582,9 @@ def test_q3_chunk_program(tmp_path, one_chip, tpu_branches):
         assert " sort(" not in text and "tpu_custom_call" not in text
         # tens of MB at most against 16 GB; PERF.md has the cell's count
         assert program.memory_analysis().temp_size_in_bytes < HBM_BYTES // 64
+    # the table's scatter sorts its 145,761 indices on the chip: a prepare
+    # program, run once per process and build, beside the build's own sort
+    table = compile_for_chip(
+        lambda c, k: J._direct_table(c, None, 1 << 23, k),
+        at_sf1(pb.rk.columns[0]), at_sf1(pb.kmin))
+    assert table.memory_analysis().output_size_in_bytes == 4 << 23

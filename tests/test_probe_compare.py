@@ -99,8 +99,12 @@ def _seed(*parts) -> int:
     return zlib.crc32(repr(parts).encode())     # the same in every worker
 
 
-def _force_rank(monkeypatch):
+def _force_rank(monkeypatch, table=True):
+    """Every build to the rank probe; ``table=False`` also keeps an exact
+    build from its direct-address table (the ``searchsorted`` form)."""
     monkeypatch.setattr(J, "PROBE_COMPARE_MAX_BUILD", -1)
+    if not table:
+        monkeypatch.setattr(J, "DIRECT_MAX_SLOTS", 0)
 
 
 def _keys(kind, rng, nl=96, nr=24):
@@ -131,11 +135,7 @@ def _masks(nulls, rng, nl, nr):
     return lval, rval
 
 
-@pytest.mark.parametrize("null_equal", [False, True], ids=["sql", "nullsafe"])
-@pytest.mark.parametrize("nulls", ["none", "left", "right", "both"])
-@pytest.mark.parametrize("kind", ["int32", "int64", "float64"])
-def test_one_key_compare_equals_rank_and_reference(kind, nulls, null_equal,
-                                                   monkeypatch):
+def _one_key_case(kind, nulls, null_equal, table, monkeypatch):
     rng = np.random.default_rng(_seed(kind, nulls))
     lk, bk = _keys(kind, rng)
     lval, rval = _masks(nulls, rng, len(lk), len(bk))
@@ -145,11 +145,30 @@ def test_one_key_compare_equals_rank_and_reference(kind, nulls, null_equal,
     assert pb.unique and J.probe_method(pb.nr, pb.rk.columns) == "compare"
     np.testing.assert_array_equal(matched, want)
     np.testing.assert_array_equal(ri, want_ri)
-    _force_rank(monkeypatch)
+    _force_rank(monkeypatch, table)
     assert J.probe_method(pb.nr, pb.rk.columns) == "rank"
-    _, ri_rank, matched_rank = _probe(*args)
+    pb, ri_rank, matched_rank = _probe(*args)
+    # an int32 build spans few keys; the int64 one holds 2**40
+    assert (pb.direct is not None) == (table and kind == "int32")
     np.testing.assert_array_equal(matched_rank, matched)
     np.testing.assert_array_equal(ri_rank, ri)
+
+
+@pytest.mark.parametrize("null_equal", [False, True], ids=["sql", "nullsafe"])
+@pytest.mark.parametrize("nulls", ["none", "left", "right", "both"])
+@pytest.mark.parametrize("kind", ["int32", "int64", "float64"])
+def test_one_key_compare_equals_rank_and_reference(kind, nulls, null_equal,
+                                                   monkeypatch):
+    _one_key_case(kind, nulls, null_equal, True, monkeypatch)
+
+
+@pytest.mark.parametrize("null_equal", [False, True], ids=["sql", "nullsafe"])
+@pytest.mark.parametrize("nulls", ["none", "left", "right", "both"])
+@pytest.mark.parametrize("kind", ["int32", "int64", "float64"])
+def test_one_key_compare_equals_searchsorted(kind, nulls, null_equal,
+                                             monkeypatch):
+    """The same, the direct-address table forced off."""
+    _one_key_case(kind, nulls, null_equal, False, monkeypatch)
 
 
 @pytest.mark.parametrize("null_equal", [False, True], ids=["sql", "nullsafe"])
@@ -182,12 +201,28 @@ def test_two_keys_and_dead_rows(kinds, live, null_equal, monkeypatch):
     np.testing.assert_array_equal(ri_rank, ri)
 
 
-@pytest.mark.parametrize("nr", [0, 1, J.PROBE_COMPARE_MAX_BUILD,
-                                J.PROBE_COMPARE_MAX_BUILD + 1],
-                         ids=["empty", "one", "at_constant", "above_constant"])
+_SWITCH = pytest.mark.parametrize(
+    "nr", [0, 1, J.PROBE_COMPARE_MAX_BUILD, J.PROBE_COMPARE_MAX_BUILD + 1],
+    ids=["empty", "one", "at_constant", "above_constant"])
+
+
+@_SWITCH
 def test_both_sides_of_the_switch(nr):
     """The choice reads the build's row count: at the constant the compare
-    path runs, one row above it the merge-rank — same answer."""
+    path runs, one row above it the rank probe by its direct-address
+    table — same answer."""
+    _switch_case(nr, True)
+
+
+@_SWITCH
+def test_both_sides_of_the_switch_searchsorted(nr, monkeypatch):
+    """The same, the table forced off: one row above the constant the rank
+    probe is ``searchsorted``."""
+    monkeypatch.setattr(J, "DIRECT_MAX_SLOTS", 0)
+    _switch_case(nr, False)
+
+
+def _switch_case(nr, table):
     rng = np.random.default_rng(nr)
     bk = rng.permutation(2 * nr + 2)[:nr].astype(np.int64)
     lk = rng.integers(0, 2 * nr + 2, 64).astype(np.int64)
@@ -195,13 +230,107 @@ def test_both_sides_of_the_switch(nr):
     pb, ri, matched = _probe(*args)
     want = "compare" if nr <= J.PROBE_COMPARE_MAX_BUILD else "rank"
     assert J.probe_method(pb.nr, pb.rk.columns) == want
+    assert (pb.direct is not None) == (want == "rank" and table)
     text = str(jax.make_jaxpr(J.probe_join_prepared)(
         Table([_column(lk)], ["k0"]), pb))
-    assert ("sort" in text) == (want == "rank" and nr > 0)
+    # the rank probe gathers; only ``searchsorted`` loops
+    assert ("gather" in text) == (want == "rank" and nr > 0)
+    assert ("searchsorted" in text) == (want == "rank" and not table
+                                        and nr > 0)
     assert pb.unique    # else the engine's veto, not the probe, answers
     want_ri, want_matched = reference_probe(*args)
     np.testing.assert_array_equal(matched, want_matched)
     np.testing.assert_array_equal(ri, want_ri)
+
+
+# -- the direct-address table of an exact build ----------------------------------
+
+def _pandas_pairs(lk, lval, bk, right_live) -> set:
+    """(probe row, build row) of every SQL match, by ``DataFrame.merge``."""
+    left = pd.DataFrame({"k": lk, "l": np.arange(len(lk))})
+    right = pd.DataFrame({"k": bk, "r": np.arange(len(bk))})
+    if lval is not None:
+        left = left[lval]
+    if right_live is not None:
+        right = right[right_live]
+    j = left.merge(right, on="k")
+    return set(zip(j.l.tolist(), j.r.tolist()))
+
+
+@pytest.mark.parametrize("table", [True, False], ids=["table", "no_table"])
+@pytest.mark.parametrize("nulls", ["none", "left", "right", "both"])
+@pytest.mark.parametrize("dead", [False, True], ids=["live", "dead_build"])
+@pytest.mark.parametrize("kind", ["int32", "int64"])
+def test_direct_table_equals_searchsorted_and_pandas(kind, dead, nulls,
+                                                     table, monkeypatch):
+    """The rank probe of an exact build, the table forced on and off:
+    negative keys (int64 ones far from zero, so the offset from ``kmin``
+    is 64-bit arithmetic), probe keys below the smallest build key and
+    above the largest (the dtype's extremes among them), nulls on either
+    side, and dead build rows — one of them holding a live row's key — give
+    pandas' pairs and the reference's rows either way."""
+    _force_rank(monkeypatch, table)
+    rng = np.random.default_rng(_seed("direct", kind, dead, nulls))
+    nl, nr = 2_000, 300
+    base = -(1 << 36) if kind == "int64" else 0
+    bk = (rng.permutation(1_000)[:nr] - 500 + base).astype(kind)
+    lk = (rng.integers(-700, 700, nl) + base).astype(kind)
+    info = np.iinfo(kind)
+    lk[:2] = [info.min, info.max]
+    lval, rval = _masks(nulls, rng, nl, nr)
+    right_live = None
+    if dead:
+        right_live = rng.random(nr) < 0.8
+        bk[-1], right_live[-1], right_live[0] = bk[0], False, True
+    args = ([(lk, lval)], [(bk, rval)], None, right_live, False)
+    pb, ri, matched = _probe(*args)
+    assert pb.unique and (pb.direct is not None) == table
+    want_ri, want = reference_probe(*args)
+    np.testing.assert_array_equal(matched, want)
+    np.testing.assert_array_equal(ri, want_ri)
+    keep = right_live if rval is None else \
+        rval if right_live is None else rval & right_live
+    assert want.any() and set(zip(np.flatnonzero(matched).tolist(),
+                                  ri[matched].tolist())) \
+        == _pandas_pairs(lk, lval, bk, keep)
+
+
+@pytest.mark.parametrize("span, table", [(64, True), (65, False)],
+                         ids=["at_cap", "above_cap"])
+def test_a_span_at_the_cap_takes_the_table(span, table, monkeypatch):
+    """A build whose live keys span ``DIRECT_MAX_SLOTS`` gets a table of
+    that many slots; one key further, ``searchsorted`` — same answer."""
+    _force_rank(monkeypatch)
+    monkeypatch.setattr(J, "DIRECT_MAX_SLOTS", 64)
+    bk = np.concatenate([[7, 7 + span - 1], np.arange(8, 40)])
+    lk = np.arange(0, 80, dtype=np.int64)
+    args = ([(lk, None)], [(bk.astype(np.int64), None)], None, None, False)
+    pb, ri, matched = _probe(*args)
+    assert pb.unique
+    assert (None if pb.direct is None else pb.direct.shape) \
+        == ((64,) if table else None)
+    want_ri, want = reference_probe(*args)
+    np.testing.assert_array_equal(matched, want)
+    np.testing.assert_array_equal(ri, want_ri)
+
+
+@pytest.mark.parametrize("twin_live", [True, False],
+                         ids=["duplicate", "duplicate_dead"])
+def test_a_duplicated_live_key_builds_no_table(twin_live, monkeypatch):
+    """Two live rows of one key: not unique, so no table (the engine's veto
+    answers); the same key on a dead row leaves the build unique and the
+    table the live row's."""
+    _force_rank(monkeypatch)
+    bk = np.arange(50, dtype=np.int64)
+    bk[-1] = bk[3]
+    live = np.ones(50, bool)
+    live[-1] = twin_live
+    pb = J.prepare_build(Table([_column(bk)], ["k0"]), ["k0"],
+                         right_live=jnp.asarray(live))
+    assert pb.unique != twin_live
+    assert (pb.direct is None) == twin_live
+    if not twin_live:
+        assert int(np.asarray(pb.direct)[3]) == 3
 
 
 def test_only_fixed_width_keys_take_the_compare_path():
@@ -380,7 +509,7 @@ def test_rehearsal_every_join_of_every_chunk_took_the_compare_path(rehearsal):
     compiled = calls[0][0]
     assert compiled.probes == ("compare",) * rehearsal["joins"]
     assert compiled.span_stats() == {
-        "probe": f"{rehearsal['joins']}/0/{rehearsal['joins']}",
+        "probe": f"{rehearsal['joins']}/0/{rehearsal['joins']}/0",
         "exprs": compiled.segment.exprs(),     # the stats beside it
         "agg": rehearsal["agg"]}
     # the forced merge-rank run counts the other way: compare + rank is
@@ -423,7 +552,7 @@ def test_rehearsal_chunk_program_has_no_gather_or_sort_outside_groupby(
     gathers and sorts are the group-by's; on the rank path the probe's own
     gathers are there (so the walker sees what it is asked to see), and no
     sort: each build is keyed by one integer column, whose keys the probe
-    looks up by ``searchsorted``."""
+    looks up in its direct-address table."""
     assert rehearsal["compare"][4] == []
     outside = rehearsal["rank"][4]
     assert any(o.startswith("gather") for o in outside)

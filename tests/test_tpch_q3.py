@@ -7,8 +7,10 @@ CPU, against the cell's own plain reference
   8,746-row build) for two seeds, fused, and on a warehouse of 6 chunks
   fused and interpreted; per query ``engine.probe.compare + .rank + .interp
   == joins x chunks``, with ``interp`` + 0 where the chunk program ran;
-- the build's row count picks the probe: 8,746 rows ``rank``, 8,163
-  ``compare``;
+- the build's row count picks the probe: 8,746 rows ``rank`` (by its
+  direct-address table: ``engine.probe.direct == rank``, and the chunk
+  program holds no loop; with the table forced off, ``searchsorted``'s),
+  8,163 ``compare``;
 - a build whose keys are unique but whose 32-bit hashes collide streams
   fused and answers exactly; a build that holds a key twice still vetoes
   the chunk program, by either method, and answers exactly;
@@ -122,11 +124,13 @@ def _against_reference(out, frames):
 
 @pytest.mark.parametrize("where, seed, fused", [
     ("rehearsal", SEEDS[0], True), ("rehearsal", SEEDS[1], True),
-    ("small", 5, True), ("small", 5, False), ("small", 5, "vetoed")],
+    ("small", 5, True), ("small", 5, False), ("small", 5, "vetoed"),
+    ("rehearsal", SEEDS[0], "no_table")],
     ids=["rehearsal-fused", "rehearsal-fused-seed2", "small-fused",
-         "small-interpreted", "small-vetoed"])
+         "small-interpreted", "small-vetoed", "rehearsal-fused-no-table"])
 def test_q3_equals_the_reference(rehearsal, small, monkeypatch, where, seed,
                                  fused):
+    from spark_rapids_jni_tpu.engine import BUILD_CACHE
     from spark_rapids_jni_tpu.engine import segment as sg
     frames, paths = rehearsal[seed] if where == "rehearsal" \
         else small[90_000]
@@ -134,15 +138,25 @@ def test_q3_equals_the_reference(rehearsal, small, monkeypatch, where, seed,
     if fused == "vetoed":
         monkeypatch.setattr(sg, "stream_runtime_eligible",
                             lambda *a, **k: False)
-    out, stats, c = _run(optimize(Q3.plan(paths, PARAMS, CHUNK_BYTES)),
-                         bool(fused))
+    if fused == "no_table":     # the build ranked by ``searchsorted``
+        monkeypatch.setattr(J, "DIRECT_MAX_SLOTS", 0)
+        BUILD_CACHE.clear()
+    try:
+        out, stats, c = _run(optimize(Q3.plan(paths, PARAMS, CHUNK_BYTES)),
+                             bool(fused))
+    finally:
+        if fused == "no_table":
+            BUILD_CACHE.clear()
     _against_reference(out, frames)
     assert stats["streamed"] and stats["chunks"] == chunks
-    if fused is True:
+    if fused in (True, "no_table"):
         assert stats["fused_segments"] == 1
         # one streamed probe join a chunk, counted once by the form that
-        # ran it
+        # ran it; a rank probe by the build's direct-address table counts
+        # as ``direct`` too
         assert _probes(c) == (0, chunks, 0)
+        assert c.get("engine.probe.direct", 0) == \
+            (chunks if fused is True else 0)
         # a group is one build row: no chunk sorts
         assert c.get("engine.agg.build", 0) == chunks
         assert c.get("engine.agg.sorted", 0) == 0
@@ -178,10 +192,61 @@ def test_the_build_size_picks_the_probe(small, monkeypatch, orders, method,
     _against_reference(out, frames)
     assert len(seen) == SMALL_GROUPS and len(set(seen)) == 1
     probes, stat, nr, exact = seen[0]
-    assert probes == (method,) and nr == (build_rows,) and exact == (True,)
-    assert stat == ("1/0/1" if method == "compare" else "0/1/1")
+    # the rank probe of this exact build reads its direct-address table
+    form = "direct" if method == "rank" else method
+    assert probes == (form,) and nr == (build_rows,) and exact == (True,)
+    assert stat == ("1/0/1/0" if method == "compare" else "0/1/1/1")
     assert _probes(c) == ((SMALL_GROUPS, 0, 0) if method == "compare"
                           else (0, SMALL_GROUPS, 0))
+    assert c.get("engine.probe.direct", 0) == \
+        (SMALL_GROUPS if method == "rank" else 0)
+
+
+def _primitives(jaxpr) -> set:
+    """Names of every primitive of ``jaxpr``, its sub-jaxprs' included."""
+    found = set()
+    for eqn in jaxpr.eqns:
+        found.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found |= _primitives(sub)
+    return found
+
+
+@pytest.mark.parametrize("table", [True, False], ids=["table", "no_table"])
+def test_the_chunk_program_holds_no_loop(small, monkeypatch, table):
+    """Q3's chunk program, traced again as it was launched: with the
+    build's direct-address table its probe is one gather and the program
+    holds no loop; with the table forced off ``searchsorted``'s loop is
+    there (so the walk sees what it is asked to see)."""
+    import jax
+    from spark_rapids_jni_tpu.engine import BUILD_CACHE
+    from spark_rapids_jni_tpu.engine import segment as sg
+    frames, paths = small[90_000]
+    if not table:
+        monkeypatch.setattr(J, "DIRECT_MAX_SLOTS", 0)
+    calls = []
+    launch = sg.CompiledSegment.__call__
+
+    def record(self, chunk, nvalid=None, prepared=(), lo=None):
+        calls.append((self, (chunk, jnp.int32(nvalid), tuple(prepared), lo)))
+        return launch(self, chunk, nvalid, prepared, lo)
+
+    monkeypatch.setattr(sg.CompiledSegment, "__call__", record)
+    BUILD_CACHE.clear()
+    try:
+        out, _, _ = _run(optimize(Q3.plan(paths, PARAMS, CHUNK_BYTES)))
+    finally:
+        BUILD_CACHE.clear()
+    _against_reference(out, frames)
+    compiled, args = calls[0]
+    assert compiled.probes == (("direct",) if table else ("rank",))
+    prims = _primitives(jax.make_jaxpr(
+        sg._build_fn(compiled.segment, compiled))(*args).jaxpr)
+    assert "gather" in prims
+    assert bool(prims & {"while", "scan"}) == (not table), sorted(prims)
 
 
 @pytest.mark.parametrize("orders, method, build_rows", [

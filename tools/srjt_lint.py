@@ -460,7 +460,7 @@ def render_metrics_doc(catalog: dict) -> str:
         "",
         "The benchmark's per-layer readers (`benchmarks/layer_metrics/`)",
         "read these names; root `PERF.md` §3 says which reads which.  The",
-        "newest two read the probe join of cell `tpch_q3_sf1_building`:",
+        "newest three read the probe join of cell `tpch_q3_sf1_building`:",
         "`probe_fused_pct` is the growth of `engine.probe.compare` +",
         "`engine.probe.rank` over that of those two + `engine.probe.interp`,",
         "in %: the share of streamed probe joins that ran inside a chunk",
@@ -468,6 +468,9 @@ def render_metrics_doc(catalog: dict) -> str:
         "`probe_bytes_needed` times the growth of `engine.probe.rank` per",
         "query, over the chip's HBM rate, over the device time per query of",
         "the ops under `engine.fused_segment/probe_rank` in the trace.",
+        "`probe_direct_pct` is the growth of `engine.probe.direct` over that",
+        "of `engine.probe.rank`, in %: the share of rank probes that read",
+        "the build's direct-address table.",
         "",
         "| name | kind | call sites |",
         "|---|---|---|",
