@@ -530,7 +530,9 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
     else None.  Map segments return (table, live, ovf); agg segments
     return padded partial aggregates + group-live mask + ovf — or, in the
     build-row form (``compiled.build_row``), ``(rows, aggregate Columns,
-    ovf)`` over the build's rows — all device-resident, zero host syncs.
+    ovf, sparse)`` over the build's rows, ``sparse`` 1 where the chunk's
+    live rows were compacted before the scatter-add — all
+    device-resident, zero host syncs.
     ``ovf`` is the program's overflow flag (``engine/expr.py``: an
     arithmetic node or a decimal sum outgrew int64's checked bound), None
     where it checks nothing.
@@ -572,9 +574,9 @@ def _build_fn(seg: Segment, compiled: "CompiledSegment"):
         aggs = [(c, op) for c, op in agg.aggs]
         if compiled.build_row is not None:
             bj = compiled.build_row[0]
-            rows, out_aggs = groupby_build_rows(table, aggs, live,
-                                                rows_of[bj], prepared[bj].nr)
-            return rows, tuple(out_aggs), any_flag(ovf)
+            rows, out_aggs, sparse = groupby_build_rows(
+                table, aggs, live, rows_of[bj], prepared[bj].nr)
+            return rows, tuple(out_aggs), any_flag(ovf), sparse
         if compiled.dense_k:
             out_keys, out_aggs, ngroups = groupby_dense(
                 table, list(agg.keys), aggs, lo, compiled.dense_k,
@@ -1245,8 +1247,9 @@ class StreamedPartials:
 @functools.partial(jax.jit, static_argnums=(2,))
 def _add_build_rows(acc: tuple, part: tuple, checked: tuple) -> tuple:
     """``acc + part``, two build-row partials ``(rows, aggregate Columns,
-    ovf)``: slot by slot, a sum's validity the OR of both (it has a value
-    where either had one).  ``checked``: the aggregates that are decimal
+    ovf, sparse)``: slot by slot, a sum's validity the OR of both (it has a
+    value where either had one), ``sparse`` the count of chunks whose live
+    rows were compacted.  ``checked``: the aggregates that are decimal
     sums, whose totals ``sum_check`` guards as the merge of the sort form
     guards its partial sums."""
     from .expr import any_flag, sum_check
@@ -1258,13 +1261,14 @@ def _add_build_rows(acc: tuple, part: tuple, checked: tuple) -> tuple:
     ovf = [f for f in (acc[2], part[2]) if f is not None]
     for j in checked:
         sum_check(aggs[j], rows > 0, ovf)
-    return rows, aggs, any_flag(ovf)
+    return rows, aggs, any_flag(ovf), acc[3] + part[3]
 
 
 @jax.jit
-def _count_build_rows(rows):
-    """The build-row form's group count: its sizing reduce."""
-    return jnp.sum((rows > 0).astype(jnp.int32))
+def _count_build_rows(rows, sparse):
+    """The build-row form's sizing reduce: ``[group count, compacted
+    chunks]``, one fetch."""
+    return jnp.stack([jnp.sum((rows > 0).astype(jnp.int32)), sparse])
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
@@ -1300,9 +1304,11 @@ class BuildRowPartials:
     small program per chunk (``_add_build_rows``), and nothing folds: the
     device holds the running total and one chunk's partial.  ``merge``
     then pays the sort form's sizing fetch (``combine-sizing``: how many
-    build rows were joined) and compacts them to the power-of-two bucket
-    of that count (``_build_row_groups``) — the padded partial a ``tail``
-    takes, or ``finish`` compacts."""
+    build rows were joined, and in how many chunks the live rows were
+    compacted before the scatter-add — ``engine.agg.build_sparse``, the
+    others ``engine.agg.build_full``) and compacts them to the power-of-two
+    bucket of that count (``_build_row_groups``) — the padded partial a
+    ``tail`` takes, or ``finish`` compacts."""
 
     __slots__ = ("pb", "sources", "acc", "compiled", "chunks", "folds",
                  "held", "checked")
@@ -1310,7 +1316,7 @@ class BuildRowPartials:
     def __init__(self, pb, sources: tuple):
         self.pb = pb                # the prepared build a group is a row of
         self.sources = sources      # build_row_join's key sources
-        self.acc = None             # (rows, aggregate Columns, ovf)
+        self.acc = None             # (rows, aggregate Columns, ovf, sparse)
         self.compiled = None
         self.chunks = 0
         self.folds = 0
@@ -1344,7 +1350,12 @@ class BuildRowPartials:
         metrics.observe("engine.stream.partials_held", self.held)
         metrics.host_sync(label="combine-sizing")
         with op_scope("engine.sync_wait", timed=True, label="combine-sizing"):
-            ng = int(_count_build_rows(self.acc[0]))
+            ng, sparse = (int(v) for v in np.asarray(
+                _count_build_rows(self.acc[0], self.acc[3])))
+        # per chunk one of the two: its live rows compacted, or all
+        # scattered
+        metrics.count("engine.agg.build_sparse", sparse)
+        metrics.count("engine.agg.build_full", self.chunks - sparse)
         cap = bucket(ng, 64)
         with op_scope("engine.combine", timed=True, partials=self.chunks,
                       cap=cap, final=1):

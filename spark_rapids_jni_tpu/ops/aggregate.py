@@ -52,6 +52,14 @@ _FAST_OPS = frozenset(AGGS) - {"first", "last", "collect_list"}
 #: to be materialized, and no better than the sort form's 2.18 ms at 1,024
 DENSE_MAX_GROUPS = 512
 
+#: the most live rows of a chunk ``groupby_build_rows`` compacts before its
+#: scatter-add; a chunk with more scatters every row.  On a TPU v5e, 262,144
+#: rows into 145,761 slots (``tools/agg_sweep.py --build-row``): every row
+#: scattered 36-64 ms, the compacted form 0.8 / 1.4 / 2.6 / 5.0 ms at 2,048 /
+#: 4,096 / 8,192 / 16,384 entries; 4,096 holds three times TPC-H Q3's
+#: ~1,300 live rows a chunk
+BUILD_SPARSE_MAX_ROWS = 4096
+
 #: the aggregations the dense form computes: sums and counts of a slot
 DENSE_OPS = frozenset({"sum", "count", "count_all", "mean"})
 
@@ -560,39 +568,99 @@ def groupby_dense(table: Table, key_names: list, aggs: list[tuple], lo,
 # totals add into the row's slot: a scatter-add of each live row into
 # ``slot`` (the build row it joined), no sort.  The chunk's partial is one
 # slot per build row; partials merge by adding slot to slot.
+#
+# On a TPU a scatter is a serialized loop over its updates, whether they
+# add anything or not (70-120 ns an int64 update on a v5e), so a chunk whose
+# joins keep few rows first compacts its live rows into a bucket of
+# ``BUILD_SPARSE_MAX_ROWS`` — by a binary search of the live mask's prefix
+# count, no scatter and no sort — and scatters that bucket.  A chunk with
+# more live rows scatters every row (``lax.cond``: no host sync).  Both add
+# the same int64 units into the same slots: the answer is the same.
 # ---------------------------------------------------------------------------
 
 def groupby_build_rows(table: Table, aggs: list[tuple], live, slot,
                        nslots: int) -> tuple:
-    """``(rows, Columns)``: each of ``nslots`` slots' live row count and
-    its ``sum`` / ``count`` / ``count_all`` totals, row i adding into
-    ``slot[i]`` where ``live[i]``.  A sum's input is integral or decimal
-    (exact in any order); it is null where no valid row added to it."""
+    """``(rows, Columns, sparse)``: each of ``nslots`` slots' live row
+    count and its ``sum`` / ``count`` / ``count_all`` totals, row i adding
+    into ``slot[i]`` where ``live[i]``.  A sum's input is integral or
+    decimal (exact in any order); it is null where no valid row added to
+    it.  ``sparse``: an int32 scalar, 1 where the chunk's live rows were
+    compacted before the scatter, 0 where every row was scattered."""
+    resolved = [(c if op == "count_all" or isinstance(c, Column)
+                 else table.column(c), op) for c, op in aggs]
+    n, k = live.shape[0], BUILD_SPARSE_MAX_ROWS
     with jax.named_scope("groupby_build_row"):
-        dest = jnp.where(live, slot, np.int32(nslots))  # dead: a spare slot
+        if n <= k:                  # a compaction would not shrink it
+            return (*_build_row_totals(resolved, live, slot, nslots),
+                    jnp.int32(0))
+        counted = jnp.cumsum(live, dtype=jnp.int32)
+        nlive = counted[n - 1]
+        at = jnp.minimum(_first_reaching(counted, k), np.int32(n - 1))
+        packed = [(c if op == "count_all" else Column(
+                       c.dtype, data=c.data[at],
+                       validity=None if c.validity is None
+                       else c.validity[at]), op) for c, op in resolved]
+        sparse = _build_row_totals(
+            packed, jnp.arange(k, dtype=jnp.int32) < nlive, slot[at],
+            nslots)
+        fits = nlive <= k
 
-        def total(v):
-            return jax.ops.segment_sum(v, dest, nslots + 1)[:nslots]
+        def full_form():
+            return _build_row_totals(resolved, live, slot, nslots)
 
-        rows = total(live.astype(jnp.int64))
-        out = []
-        for c, op in aggs:
-            if op == "count_all":
-                out.append(Column(INT64, data=rows))
-                continue
-            col = c if isinstance(c, Column) else table.column(c)
-            valid = live & col.valid_mask()
-            cnt = rows if col.validity is None else \
-                total(valid.astype(jnp.int64))
-            if op == "count":
-                out.append(Column(INT64, data=cnt))
-                continue
-            vals, out_dtype, is_float = _sum_dtype_and_vals(col, col.data,
-                                                            valid)
-            if is_float:
-                raise TypeError("the build-row form sums no float")
-            s = total(jnp.where(valid, vals, jnp.zeros((), vals.dtype)))
-            out.append(_sum_result(op, col, s, cnt, out_dtype, False))
+        # the branch that keeps the compacted totals returns zeros, and a
+        # select takes them: a branch that returns its operands makes the
+        # TPU compiler abort (as in ``groupby_dense``)
+        full = jax.lax.cond(
+            fits, lambda: jax.tree.map(jnp.zeros_like, sparse), full_form)
+        rows, out = jax.tree.map(lambda a, b: jnp.where(fits, a, b),
+                                 sparse, full)
+    return rows, out, fits.astype(jnp.int32)
+
+
+def _first_reaching(counted, k: int):
+    """For j < ``k``, the first row whose prefix count ``counted`` reaches
+    j + 1 — the j-th live row's position, ``n`` where there is none: a
+    binary search of each j at once, unrolled (one ``k``-element gather a
+    round, log2(n) + 1 rounds; no loop, no scatter, no sort)."""
+    n = counted.shape[0]
+    want = jnp.arange(1, k + 1, dtype=jnp.int32)
+    pos = jnp.zeros((k,), jnp.int32)    # rows known to count short of want
+    step = 1 << (n.bit_length() - 1)
+    while step:
+        nxt = pos + np.int32(step)
+        short = counted[jnp.minimum(nxt, np.int32(n)) - 1] < want
+        pos = jnp.where((nxt <= n) & short, nxt, pos)
+        step >>= 1
+    return pos
+
+
+def _build_row_totals(resolved: list, live, slot, nslots: int) -> tuple:
+    """``(rows, Columns)`` of ``groupby_build_rows`` by one scatter-add of
+    every row a total takes: row i into ``slot[i]`` where ``live[i]``, a
+    dead row into a spare slot.  ``resolved``: ``(Column or None, op)``."""
+    dest = jnp.where(live, slot, np.int32(nslots))
+
+    def total(v):
+        return jax.ops.segment_sum(v, dest, nslots + 1)[:nslots]
+
+    rows = total(live.astype(jnp.int64))
+    out = []
+    for col, op in resolved:
+        if op == "count_all":
+            out.append(Column(INT64, data=rows))
+            continue
+        valid = live & col.valid_mask()
+        cnt = rows if col.validity is None else \
+            total(valid.astype(jnp.int64))
+        if op == "count":
+            out.append(Column(INT64, data=cnt))
+            continue
+        vals, out_dtype, is_float = _sum_dtype_and_vals(col, col.data, valid)
+        if is_float:
+            raise TypeError("the build-row form sums no float")
+        s = total(jnp.where(valid, vals, jnp.zeros((), vals.dtype)))
+        out.append(_sum_result(op, col, s, cnt, out_dtype, False))
     return rows, out
 
 

@@ -7,6 +7,10 @@ CPU, against the cell's own plain reference
   8,746-row build) for two seeds, fused, and on a warehouse of 6 chunks
   fused and interpreted; per query ``engine.probe.compare + .rank + .interp
   == joins x chunks``, with ``interp`` + 0 where the chunk program ran;
+  per query ``engine.agg.build_sparse + .build_full == engine.agg.build``
+  and two host syncs where it ran: every chunk's live rows compacted
+  before the scatter-add, or, with the compaction's bucket forced to one
+  row, every row scattered, the answer the same;
 - the build's row count picks the probe: 8,746 rows ``rank`` (by its
   direct-address table: ``engine.probe.direct == rank``, and the chunk
   program holds no loop; with the table forced off, ``searchsorted``'s),
@@ -125,13 +129,15 @@ def _against_reference(out, frames):
 @pytest.mark.parametrize("where, seed, fused", [
     ("rehearsal", SEEDS[0], True), ("rehearsal", SEEDS[1], True),
     ("small", 5, True), ("small", 5, False), ("small", 5, "vetoed"),
-    ("rehearsal", SEEDS[0], "no_table")],
+    ("rehearsal", SEEDS[0], "no_table"), ("rehearsal", SEEDS[0], "full")],
     ids=["rehearsal-fused", "rehearsal-fused-seed2", "small-fused",
-         "small-interpreted", "small-vetoed", "rehearsal-fused-no-table"])
+         "small-interpreted", "small-vetoed", "rehearsal-fused-no-table",
+         "rehearsal-fused-full-scatter"])
 def test_q3_equals_the_reference(rehearsal, small, monkeypatch, where, seed,
                                  fused):
-    from spark_rapids_jni_tpu.engine import BUILD_CACHE
+    from spark_rapids_jni_tpu.engine import BUILD_CACHE, SEGMENT_CACHE
     from spark_rapids_jni_tpu.engine import segment as sg
+    from spark_rapids_jni_tpu.ops import aggregate as A
     frames, paths = rehearsal[seed] if where == "rehearsal" \
         else small[90_000]
     chunks = 24 if where == "rehearsal" else SMALL_GROUPS
@@ -141,22 +147,36 @@ def test_q3_equals_the_reference(rehearsal, small, monkeypatch, where, seed,
     if fused == "no_table":     # the build ranked by ``searchsorted``
         monkeypatch.setattr(J, "DIRECT_MAX_SLOTS", 0)
         BUILD_CACHE.clear()
+    if fused == "full":         # every chunk past the compaction's bucket
+        monkeypatch.setattr(A, "BUILD_SPARSE_MAX_ROWS", 1)
+        SEGMENT_CACHE.clear()
     try:
         out, stats, c = _run(optimize(Q3.plan(paths, PARAMS, CHUNK_BYTES)),
                              bool(fused))
     finally:
         if fused == "no_table":
             BUILD_CACHE.clear()
+        if fused == "full":
+            SEGMENT_CACHE.clear()
     _against_reference(out, frames)
     assert stats["streamed"] and stats["chunks"] == chunks
-    if fused in (True, "no_table"):
+    # per build-row chunk one of the two: its live rows compacted before
+    # the scatter-add, or every row scattered
+    assert c.get("engine.agg.build_sparse", 0) \
+        + c.get("engine.agg.build_full", 0) == c.get("engine.agg.build", 0)
+    if fused in (True, "no_table", "full"):
+        # the stream's one sizing fetch and the tail's: the branch taken
+        # rides the first
+        assert c.get("engine.host_sync", 0) == 2
+        assert c.get("engine.agg.build_full", 0) == \
+            (chunks if fused == "full" else 0)
         assert stats["fused_segments"] == 1
         # one streamed probe join a chunk, counted once by the form that
         # ran it; a rank probe by the build's direct-address table counts
         # as ``direct`` too
         assert _probes(c) == (0, chunks, 0)
         assert c.get("engine.probe.direct", 0) == \
-            (chunks if fused is True else 0)
+            (0 if fused == "no_table" else chunks)
         # a group is one build row: no chunk sorts
         assert c.get("engine.agg.build", 0) == chunks
         assert c.get("engine.agg.sorted", 0) == 0
