@@ -130,16 +130,18 @@ def main(argv=None) -> int:
 
     import numpy as np
 
-    import bench
     from spark_rapids_jni_tpu.engine import execute, optimize
+    from spark_rapids_jni_tpu.engine.fuzz import (pipeline_plans,
+                                                  pipeline_warehouse,
+                                                  serving_plans)
     from spark_rapids_jni_tpu.utils import blackbox, errors, faults, tracing
     from spark_rapids_jni_tpu.utils.config import refresh
 
     refresh()
     rng = np.random.default_rng(7)
     root = tempfile.mkdtemp(prefix="srjt-chaos-")
-    bench._pipeline_warehouse(root, args.rows, rng)
-    q5, chunked = bench._pipeline_plans(root, chunk_bytes=256_000)
+    pipeline_warehouse(root, args.rows, rng)
+    q5, chunked = pipeline_plans(root, chunk_bytes=256_000)
     plans = [("q5", optimize(q5), "s_mgr"),
              ("chunked", optimize(chunked), "ss_store_sk")]
 
@@ -284,7 +286,7 @@ def main(argv=None) -> int:
     #    server must cut EXACTLY one trace-joined bundle per typed error.
     from spark_rapids_jni_tpu.bridge import BridgeClient, spawn_server
     n_clients = 4
-    conc_plans = bench._serving_plans(root, 64_000, n_clients)
+    conc_plans = serving_plans(root, 64_000, n_clients)
     conc_oracle = [execute(optimize(p)) for p in conc_plans]
 
     def _concurrent_pass(tag, fault_spec, bb):

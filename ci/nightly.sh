@@ -1,7 +1,7 @@
 #!/bin/bash
 #
-# Nightly build (analog of ci/nightly-build.sh): premerge + benchmarks +
-# wheel packaging with baked provenance.
+# Nightly build (analog of ci/nightly-build.sh): premerge + the wider
+# lint, fuzz and chaos sweeps + wheel packaging with baked provenance.
 
 set -ex
 cd "$(dirname "$0")/.."
@@ -9,12 +9,12 @@ cd "$(dirname "$0")/.."
 ci/premerge.sh
 
 # nightly lint: premerge covers the smoke plans; --full extends the jaxpr
-# sync-lint over the bench join + top-k plan shapes
+# sync-lint over a chunked join + top-k plan pair
 JAX_PLATFORMS=cpu python tools/srjt_lint.py --segments --full \
     --baseline ci/lint-baseline.json
 
 # nightly fuzz sweep: a bigger corpus on a fresh seed over the EXTENDED
-# variant matrix (adds dist-nofuse + interp-notopk).  The shrunk repro
+# variant matrix (adds dist-nofuse + dist-fused-aqe).  The shrunk repro
 # artifact lands in target/fuzz-repro.json on failure — re-run with the
 # printed seed to reproduce deterministically.
 JAX_PLATFORMS=cpu python tools/srjt_fuzz.py \
@@ -25,13 +25,6 @@ JAX_PLATFORMS=cpu python tools/srjt_fuzz.py \
 # (docs/ROBUSTNESS.md).  `timeout` is the outermost hang detector — a soak
 # that can't finish inside 15 minutes IS a robustness failure.
 JAX_PLATFORMS=cpu timeout 900 python ci/chaos_soak.py --devices 2
-
-# benchmarks (runs on whatever backend jax selects; TPU when present)
-python bench.py | tee target/bench-nightly.json
-
-# regression gate over the full artifact — report-only until the _gate
-# tolerances have soaked; flip to --enforce to make regressions fail
-python ci/bench_gate.py --artifact target/bench-nightly.json --report-only
 
 # wheel with provenance baked in (build/build-info ran in premerge)
 python -m pip wheel --no-deps --no-build-isolation -w target/dist . \

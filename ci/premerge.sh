@@ -36,220 +36,6 @@ JAX_PLATFORMS=cpu python tools/srjt_fuzz.py --smoke
 # full suite on the virtual 8-device CPU mesh (includes bridge round trip)
 python -m pytest tests/ -q
 
-# engine perf-path smoke: tiny shapes through the fused-segment and
-# double-buffered streaming paths end-to-end (correctness cross-checks,
-# no timing assertions) — keeps the bench's perf paths runnable without
-# paying full bench time in the gate.  Runs with tracing, the metrics
-# layer, AND the timeline forced on so the instrumented paths (spans,
-# histograms, Perfetto annotations, trace events) are exercised in-gate;
-# the snapshot line must carry the per-query summary block and the
-# timeline line must point at a loadable Chrome trace-event JSON
-# (docs/OBSERVABILITY.md).
-mkdir -p target
-rm -rf target/smoke-profiles
-SMOKE_OUT=$(JAX_PLATFORMS=cpu SRJT_TRACE=1 SRJT_METRICS=1 \
-    SRJT_TIMELINE=1 SRJT_TIMELINE_OUT=target/smoke-timeline.json \
-    SRJT_PROFILE_DIR=target/smoke-profiles \
-    python bench.py --smoke)
-echo "$SMOKE_OUT"
-echo "$SMOKE_OUT" > target/smoke-artifact.json
-echo "$SMOKE_OUT" | python -c '
-import json, sys
-snaps = [json.loads(l) for l in sys.stdin if l.strip()]
-snap = [s for s in snaps if s.get("metric") == "metrics_snapshot"]
-assert snap, "bench.py --smoke emitted no metrics_snapshot line"
-assert snap[0].get("queries"), "metrics snapshot missing per-query summaries"
-assert snap[0]["ok"], "metrics snapshot not ok"
-print("metrics snapshot: %d per-query summaries" % len(snap[0]["queries"]))
-tl = [s for s in snaps if s.get("metric") == "timeline"]
-assert tl, "bench.py --smoke emitted no timeline line"
-assert tl[0]["enabled"] and tl[0]["ok"], "timeline line not ok: %r" % tl[0]
-trace = json.load(open(tl[0]["path"]))
-evs = trace["traceEvents"]
-assert evs and all("ph" in e and "name" in e for e in evs), \
-    "timeline dump is not Chrome trace-event JSON"
-assert any(e["ph"] == "X" for e in evs), "timeline has no complete spans"
-print("timeline: %d trace events at %s" % (tl[0]["events"], tl[0]["path"]))
-dist = [s for s in snaps if s.get("metric") == "engine_dist_smoke"]
-assert dist, "bench.py --smoke emitted no engine_dist_smoke line"
-assert dist[0]["ok"], "engine_dist_smoke not ok: %r" % dist[0]
-ex = dist[0]["exchanges"]
-# the static exchange census (verify.plan_exchanges) must equal what the
-# executor actually ran, and co-partitioned plans must carry none
-assert ex["broadcast_static"] == ex["broadcast_executed"], ex
-assert ex["exchange_static"] == ex["exchange_executed"], ex
-assert ex["copartitioned_static"] == ex["copartitioned_executed"] == 0, ex
-print("engine dist: exchanges static==executed (%d broadcast-plan, %d "
-      "exchange-plan), co-partitioned 0" % (ex["broadcast_executed"],
-                                            ex["exchange_executed"]))
-# per-device exchange attribution (docs/OBSERVABILITY.md): the per-(src,
-# dest) wire matrix must sum to engine.exchange.wire_bytes, and the dist
-# smoke plan must render skew in EXPLAIN ANALYZE
-da = dist[0].get("device_attrib") or {}
-assert da.get("matrix_matches") is True, \
-    "exchange wire matrix != wire_bytes counter: %r" % da
-assert da.get("explain_skew_rendered") is True, da
-assert da.get("skew") is not None and da["skew"] >= 1.0, da
-print("device attrib: %d exchange nodes, matrix sum %d == counter, "
-      "skew %.2f" % (da["exchange_nodes"], da["wire_matrix_sum"],
-                     da["skew"]))
-prof = [s for s in snaps if s.get("metric") == "profile_store"]
-assert prof, "bench.py --smoke emitted no profile_store line"
-assert prof[0]["enabled"] and prof[0]["ok"], \
-    "profile_store line not ok: %r" % prof[0]
-assert prof[0]["profiles"] > 0, prof[0]
-assert prof[0]["top_exchange_skew"] is not None, \
-    "no exchange skew reached the profile store"
-print("profile store: %d profiles at %s, top skew %s" %
-      (prof[0]["profiles"], prof[0]["dir"], prof[0]["top_exchange_skew"]))
-# AQE evidence plane (docs/OBSERVABILITY.md): the dist smoke report must
-# carry the cardinality columns on every node line and a decision footer
-# whose structural entry count equals the static census
-ev = da.get("evidence") or {}
-assert ev.get("node_lines_annotated") is True, \
-    "EXPLAIN node lines missing est_rows/q_error: %r" % ev
-assert ev.get("footer_rendered") is True and ev.get("decisions", 0) > 0, ev
-assert ev.get("census_matches") is True, \
-    "decision ledger count != static census: %r" % ev
-print("evidence: %d decisions (%d pathed == census %d)" %
-      (ev["decisions"], ev["decisions_pathed"], ev["census"]))
-# the profile store carries the scored ledger + per-node q_error: some
-# stored profile (the dist subprocess queries ran distributed plans)
-# must have a decisions block, and some node must carry a q_error score
-import glob
-profs = [json.load(open(p))
-         for p in glob.glob(prof[0]["dir"] + "/profile-*.json")]
-assert any(p.get("decisions") for p in profs), \
-    "no stored profile carries a decision ledger"
-assert any(n.get("q_error") is not None
-           for p in profs for n in p.get("nodes", ())), \
-    "no stored profile node carries q_error"
-ndec = sum(len(p.get("decisions") or ()) for p in profs)
-print("profile evidence: %d decision entries across %d profiles" %
-      (ndec, len(profs)))
-mo = [s for s in snaps if s.get("metric") == "metrics_overhead"]
-assert mo and mo[0]["ok"], "metrics_overhead line missing or not ok"
-print("metrics overhead: on/off ratio %s (report-only gate key)" %
-      mo[0]["ratios"]["on_vs_off"])
-bo = [s for s in snaps if s.get("metric") == "blackbox_overhead"]
-assert bo and bo[0]["ok"], "blackbox_overhead line missing or not ok"
-print("blackbox overhead: on/off ratio %s (report-only gate key)" %
-      bo[0]["ratios"]["on_vs_off"])
-# adaptive execution (docs/ENGINE.md "Adaptive execution"): the skewed
-# smoke run must have APPLIED at least one verified skew split, the
-# post-split engine.exchange.skew gauge must sit under the trigger
-# threshold (the re-deal provably flattened the hot device), and the
-# repeat query must have planned run 2 from run 1s measured actuals
-# (adaptive:history_warmed -> broadcast) and beaten the cold run — all
-# with bit-parity against the AQE-off plans.  The wall-clock ratios
-# (aqe.skew_ratio / aqe.rerun_vs_first) stay report-only in the gate
-# below; this block asserts the structure.
-aqe = [s for s in snaps if s.get("metric") == "aqe"]
-assert aqe, "bench.py --smoke emitted no aqe line"
-assert aqe[0]["ok"], "aqe line not ok: %r" % aqe[0]
-sk, wm = aqe[0]["skew"], aqe[0]["warm"]
-assert sk["splits_applied"] >= 1, "no adaptive:skew_split applied: %r" % sk
-assert sk["gauge_skew"] is not None \
-    and sk["gauge_skew"] < sk["threshold"], \
-    "post-split skew gauge not under threshold: %r" % sk
-assert sk["parity"] and wm["parity"], "AQE parity failed: %r" % aqe[0]
-assert wm["warmed_entries"] >= 1 and wm["run2_broadcast_planned"], \
-    "history warming did not replan run 2: %r" % wm
-print("aqe: %d skew split(s) applied, skew %.2f -> gauge %.2f "
-      "(threshold %.1f); warmed rerun planned broadcast, "
-      "rerun_vs_first %s" % (sk["splits_applied"], sk["pre_skew"],
-                             sk["gauge_skew"], sk["threshold"],
-                             aqe[0]["rerun_vs_first"]))
-# whole-stage fusion (docs/ENGINE.md): the fused run must pay exactly
-# its static sync budget — and stay far under the host-orchestrated
-# sync count — with bit-exact parity and a matching exchange census.
-# The wall-clock ratio (fused_stage.vs_host_exchange) is report-only in
-# the gate below while it soaks; this block asserts the structure.
-fs = [s for s in snaps if s.get("metric") == "fused_stage"]
-assert fs, "bench.py --smoke emitted no fused_stage line"
-assert fs[0]["ok"], "fused_stage line not ok: %r" % fs[0]
-fsy = fs[0]["host_syncs"]
-assert fsy["fused"] == fsy["fused_budget"], \
-    "fused syncs != static budget: %r" % fsy
-assert fsy["fused"] < 5, \
-    "fused stage paying host-path-order sync counts: %r" % fsy
-assert fs[0]["results_match"], "fused vs host parity failed: %r" % fs[0]
-print("fused_stage: %d sync(s) (== static budget, host path pays %d), "
-      "%d dispatch(es), vs_host_exchange %s, bit-exact"
-      % (fsy["fused"], fsy["host"], fs[0]["dispatches"],
-         fs[0]["vs_host_exchange"]))
-# row-conversion roofline: the smoke line must pass its numpy-oracle
-# wire check; roofline_frac is the report-only gate key
-rc = [s for s in snaps if s.get("metric") == "row_conversion"]
-assert rc, "bench.py --smoke emitted no row_conversion line"
-assert rc[0]["ok"], "row_conversion wire check failed: %r" % rc[0]
-print("row_conversion: %.2f GB/s of %.2f ceiling (roofline_frac %s)"
-      % (rc[0]["GBps"], rc[0]["ceiling_GBps"], rc[0]["roofline_frac"]))
-# multi-tenant serving (docs/SERVING.md): the concurrent pass must be
-# bit-exact per trace vs the serial pass, the forced-low-SLO scenario
-# must shed at least once with the typed admission error carrying
-# trace id + bundle pointer, and the repeat plan must serve from the
-# result cache far under its cold wall.  The wall-clock keys
-# (serving.p99_ms / serving.throughput / serving.shed_count) are
-# ENFORCED in the gate below (promoted r7 after the r6 report-only
-# soak); this block asserts the structure.
-srv = [s for s in snaps if s.get("metric") == "serving"]
-assert srv, "bench.py --smoke emitted no serving line"
-assert srv[0]["ok"], "serving line not ok: %r" % srv[0]
-shed = srv[0]["shed"]
-assert shed and shed["kind"] == "resource" and shed["retryable"] is False, \
-    "shed not the typed admission error: %r" % shed
-assert shed["trace_id"] and shed["bundle"], \
-    "shed error missing trace/bundle join: %r" % shed
-assert srv[0]["shed_count"] >= 1, "no shed counted: %r" % srv[0]
-assert srv[0]["result_cache_speedup"] > 10, \
-    "result-cache repeat not well under cold wall: %r" % srv[0]
-print("serving: %d clients bit-exact, p99 %.0fms, %d shed (typed, "
-      "trace-joined), result-cache speedup %.0fx"
-      % (srv[0]["clients"], srv[0]["p99_ms"], srv[0]["shed_count"],
-         srv[0]["result_cache_speedup"]))
-'
-
-# Prometheus exposition: one local scrape through tools/srjt_export.py,
-# parsed line-by-line as text exposition format (every line a comment or
-# a srjt_-prefixed sample; histogram buckets cumulative)
-JAX_PLATFORMS=cpu python tools/srjt_export.py --warm \
-    > target/smoke-scrape.prom
-python -c '
-lines = [l.rstrip("\n") for l in open("target/smoke-scrape.prom") if l.strip()]
-assert lines, "empty Prometheus scrape"
-samples = 0
-for l in lines:
-    if l.startswith("# TYPE "):
-        parts = l.split()
-        assert len(parts) == 4 and parts[3] in ("counter", "gauge",
-                                                "histogram"), l
-        continue
-    assert l.startswith("srjt_"), "non-exposition line: %r" % l
-    name_labels, value = l.rsplit(" ", 1)
-    float(value)  # every sample value parses as a number
-    samples += 1
-assert samples > 0
-assert any("_bucket{le=" in l for l in lines), "no histogram buckets"
-print("prometheus scrape: %d samples parse as text exposition" % samples)
-'
-
-# bench regression gate: ENFORCED for the smoke-line ratio keys that have
-# soaked since PR 5, the serving keys promoted r7 after their r6
-# report-only soak, and fused_stage.vs_host_exchange promoted r8 after
-# its r7 soak (--enforce-keys allowlist — a regression or a silently
-# dropped key among them fails premerge); every other enrolled key,
-# including the PR-8 dist ratios, the profile-derived keys,
-# row_conversion.roofline_frac, and the new r8 device-decode keys
-# (parquet.device_vs_host, parquet.link_ratio — backend-dependent, see
-# BENCH_BASELINES.json), stays report-only in the same run.  --profiles
-# folds the query-profile store into the artifact
-# (profile.exchange.skew, profile.chunk_latency.p99).
-python ci/bench_gate.py --artifact target/smoke-artifact.json \
-    --profiles target/smoke-profiles \
-    --enforce \
-    --enforce-keys engine_pipeline_smoke.ratios.fused_vs_interp,engine_join_smoke.ratios.cached_vs_per_chunk,serving.p99_ms,serving.throughput,serving.shed_count,fused_stage.vs_host_exchange
-
 # end-to-end trace join (docs/OBSERVABILITY.md): a clean query's
 # client-minted trace id must reach the server's OP_METRICS summary and
 # the stored profile with zero bundles cut; a fault-injected failing

@@ -567,18 +567,15 @@ class BridgeServer:
                             compiled = self._plan_cache.get(plan)
                             prepare.stat(hit=int(compiled.executions > 0))
                     if out is None:
-                        session = None
-                        if _cfg.sched:
-                            from ..engine.scheduler import SCHEDULER
-                            session = SCHEDULER.admit(
-                                fingerprint=fp, trace_id=scope.trace_id)
+                        from ..engine.scheduler import SCHEDULER
+                        session = SCHEDULER.admit(
+                            fingerprint=fp, trace_id=scope.trace_id)
                         try:
                             with op_scope("engine.execute", timed=True):
                                 out = compiled.execute(
                                     stats=stats, cancel=tok, session=session)
                         finally:
-                            if session is not None:
-                                session.release()
+                            session.release()
                         if RESULT_CACHE.enabled and version is not None:
                             RESULT_CACHE.put(fp, version, out)
                     if qm is not None:
@@ -665,7 +662,7 @@ class BridgeServer:
         import json
         # optional payload = UTF-8 name prefix: narrows the counter /
         # histogram / gauge blocks so pollers that chart one family
-        # (bench's exchange scrape, an exporter's engine.stream.* panel)
+        # (an exchange scrape, an exporter's engine.stream.* panel)
         # don't ship the whole registry.  Empty payload = everything,
         # byte-compatible with pre-prefix clients.
         prefix = payload.decode("utf-8") if payload else ""

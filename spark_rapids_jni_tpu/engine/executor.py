@@ -1457,7 +1457,6 @@ def lowering_flags(fused: Optional[bool] = None) -> dict:
     device count."""
     from ..utils.config import config
     return {"fuse": config.fuse if fused is None else bool(fused),
-            "fuse_join": config.fuse_join, "topk": config.topk,
             "fuse_exchange": config.fuse_exchange,
             "ndev": len(jax.devices())}
 
@@ -1482,7 +1481,7 @@ def execute(plan: PlanNode | PhysicalPlan, stats: Optional[dict] = None,
     (ladder steps taken, engine/recovery.py).
 
     ``fused``/``prefetch`` override the ``SRJT_FUSE``/``SRJT_PREFETCH``
-    config defaults for this execution (the bench harness compares the
+    config defaults for this execution (the tests compare the
     node-by-node interpreter against the fused pipeline this way).
 
     ``cancel`` (utils.errors.CancelToken) makes the execution cooperatively

@@ -352,7 +352,7 @@ def explain_analyze(plan: PlanNode, stats: Optional[dict] = None,
             # the decision-ledger footer: one line per optimizer decision,
             # scored against the actual rows the decision's node saw.
             # verify.decision_census(opt) counts the same structural
-            # entries statically — bench/CI assert the counts match.
+            # entries statically — the tests assert the counts match.
             from .plan import node_paths
             actuals = {p: spans[i].get("rows_out")
                        for i, p in node_paths(opt).items() if i in spans}

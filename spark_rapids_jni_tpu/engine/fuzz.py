@@ -1,10 +1,10 @@
 """Seeded plan-space fuzzer + differential rewrite-soundness harness.
 
 The generative half of the plan-algebra soundness analyzer
-(docs/ANALYSIS.md): before AQE starts rewriting plans mid-query
-(ROADMAP item 1), every optimizer rule gets adversarial coverage over
-random valid plans instead of the handful of shapes the tests and
-benches happen to build.  Four pieces:
+(docs/ANALYSIS.md): every optimizer rule, the AQE rules that rewrite
+plans mid-query among them, gets adversarial coverage over
+random valid plans instead of the handful of shapes the tests happen
+to build.  Four pieces:
 
 1. **Warehouse generator** — a tiny seeded parquet star schema
    (``gen_warehouse``): one fact table with integer keys of differing
@@ -13,7 +13,10 @@ benches happen to build.  Four pieces:
    executor parity can be asserted bit-for-bit regardless of reduction
    order), a DECIMAL(9,2) measure (its frame holds int64 units), plus
    dimension tables keyed by each family.  The dataframes are kept in
-   memory as the oracle's base relations.
+   memory as the oracle's base relations.  Beside it, a fixed q5-lite
+   warehouse and its plans (``pipeline_warehouse``, ``pipeline_plans``,
+   ``serving_plans``), shared by the segment lint, the chaos soak and
+   the verifier's tests.
 
 2. **Plan generator** — ``gen_plan`` synthesizes a random valid plan
    over all 9 ``plan._NODE_TYPES``: scans with column subsets,
@@ -30,7 +33,7 @@ benches happen to build.  Four pieces:
 3. **Differential harness** — ``run_case`` sweeps one plan across the
    flag matrix (interpreted / fused / distributed-shuffle /
    distributed-broadcast / distributed-AQE via ``SRJT_FUSE``/
-   ``SRJT_DIST``/``SRJT_TOPK``/``SRJT_BROADCAST_ROWS``/``SRJT_AQE``),
+   ``SRJT_DIST``/``SRJT_BROADCAST_ROWS``/``SRJT_AQE``),
    asserting after every variant: ``verify()`` passes on the optimized
    plan, the stamped decision ledger equals ``verify.decision_census``
    (for plans without hand-placed structure), the static sync budget
@@ -147,6 +150,86 @@ def gen_warehouse(root, rng) -> dict:
         pq.write_table(table, path, row_group_size=max(8, len(df) // 4))
         cat[name] = {"path": path, "df": df}
     return cat
+
+
+def pipeline_warehouse(root, n, rng):
+    """q5-lite warehouse: ``store_sales`` (``n`` rows, 8 row groups),
+    ``date_dim`` and ``store``, written under ``root``."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    pq.write_table(pa.table({
+        "ss_sold_date_sk": pa.array(
+            np.sort(rng.integers(0, 400, n)).astype(np.int64)),
+        "ss_store_sk": pa.array(rng.integers(1, 13, n).astype(np.int64)),
+        "ss_ext_sales_price": pa.array(rng.uniform(0.5, 300.0, n)),
+        "ss_net_profit": pa.array(rng.uniform(-50.0, 120.0, n)),
+    }), os.path.join(root, "store_sales.parquet"),
+        row_group_size=max(1, n // 8))
+    pq.write_table(pa.table({
+        "d_date_sk": pa.array(np.arange(100, 300, dtype=np.int64)),
+    }), os.path.join(root, "date_dim.parquet"))
+    pq.write_table(pa.table({
+        "s_store_sk": pa.array(np.arange(1, 13, dtype=np.int64)),
+        "s_mgr": pa.array(np.arange(1, 13, dtype=np.int64) % 4),
+    }), os.path.join(root, "store.parquet"))
+
+
+def pipeline_plans(root, chunk_bytes):
+    """(q5-lite plan, chunked-scan aggregate plan) over the warehouse.
+
+    The q5 filters survive optimization as real Filter nodes (the scan
+    predicate only prunes row groups), so the fused executor has chains to
+    compile; the chunked aggregate feeds the scan straight into a fused
+    partial-groupby segment — the double-buffered streaming shape.
+    """
+    dates_f = Filter(Scan(os.path.join(root, "date_dim.parquet")),
+                     ("&", (">=", col("d_date_sk"), lit(100)),
+                      ("<", col("d_date_sk"), lit(300))))
+    sales = Scan(os.path.join(root, "store_sales.parquet"))
+    kept = Filter(Join(sales, dates_f, ["ss_sold_date_sk"], ["d_date_sk"],
+                       how="semi"),
+                  ("&", (">", col("ss_net_profit"), lit(0.0)),
+                   (">=", col("ss_sold_date_sk"), lit(100))))
+    totals = Aggregate(kept, ["ss_store_sk"],
+                       [("ss_ext_sales_price", "sum"),
+                        ("ss_net_profit", "sum"),
+                        ("ss_ext_sales_price", "count")],
+                       names=["sales", "profit", "n"])
+    joined = Join(totals, Scan(os.path.join(root, "store.parquet")),
+                  ["ss_store_sk"], ["s_store_sk"], how="inner")
+    q5 = Sort(Aggregate(joined, ["s_mgr"],
+                        [("sales", "sum"), ("profit", "sum"), ("n", "sum")],
+                        names=["sales", "profit", "n"]),
+              (("s_mgr", True),))
+
+    chunked = Aggregate(
+        Filter(Scan(os.path.join(root, "store_sales.parquet"),
+                    chunk_bytes=chunk_bytes),
+               (">", col("ss_ext_sales_price"), lit(1.0))),
+        ["ss_store_sk"],
+        [("ss_ext_sales_price", "sum"), ("ss_net_profit", "sum"),
+         ("ss_net_profit", "min"), ("ss_net_profit", "max"),
+         ("ss_ext_sales_price", "count")],
+        names=["sales", "profit", "lo", "hi", "n"])
+    return q5, chunked
+
+
+def serving_plans(root, chunk_bytes, k, base=1.0):
+    """k distinct-fingerprint chunked aggregates over the warehouse.
+
+    Same shape (filter + partial groupby, the fused streaming segment),
+    different filter literal per plan — so every plan is its own plan-cache
+    / result-cache entry and its own scheduler fingerprint, like k tenants
+    running k different queries of the same family.
+    """
+    sales = os.path.join(root, "store_sales.parquet")
+    return [Aggregate(
+        Filter(Scan(sales, chunk_bytes=chunk_bytes),
+               (">", col("ss_ext_sales_price"), lit(base + 0.25 * i))),
+        ["ss_store_sk"],
+        [("ss_ext_sales_price", "sum"), ("ss_net_profit", "sum"),
+         ("ss_ext_sales_price", "count")],
+        names=["sales", "profit", "n"]) for i in range(k)]
 
 
 # -- plan generation ---------------------------------------------------------
@@ -660,8 +743,6 @@ VARIANTS = (
 FULL_VARIANTS = VARIANTS + (
     {"name": "dist-nofuse", "fuse": False, "distribute": True,
      "broadcast_rows": 0},
-    {"name": "interp-notopk", "fuse": False, "distribute": False,
-     "topk": False},
     # fusion composed with the AQE adversary: the counts probe routes hot
     # stages to the host path where the skew split still fires, cold ones
     # into the fused program — parity and the adaptive-ledger invariant

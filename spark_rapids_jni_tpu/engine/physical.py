@@ -174,14 +174,13 @@ def _stream_scan_of(agg: Aggregate) -> Optional[Scan]:
     return _single_chunked_scan(agg.child)
 
 
-def lower(plan: PlanNode, *, fuse: bool, fuse_join: bool, topk: bool,
-          fuse_exchange: bool, ndev: int,
+def lower(plan: PlanNode, *, fuse: bool, fuse_exchange: bool, ndev: int,
           resolver: Optional[Callable] = None) -> PhysicalPlan:
     """Choose the stage form of every node of an optimized ``plan``.
 
-    ``fuse`` / ``fuse_join`` / ``topk`` / ``fuse_exchange`` are the
-    ``Config`` fields of those names (or ``execute(fused=...)``'s
-    override), ``ndev`` the mesh size (a ``tail`` is a one-device form).  ``resolver`` (``node -> {name:
+    ``fuse`` / ``fuse_exchange`` are the ``Config`` fields of those names
+    (or ``execute(fused=...)``'s override), ``ndev`` the mesh size (a
+    ``tail`` is a one-device form).  ``resolver`` (``node -> {name:
     DType} | None``, the verifier's schema inference) changes no form: it
     lets the static shadow of the run-time schema vetoes mark
     ``Stage.vetoed``; without it the run alone decides."""
@@ -212,15 +211,13 @@ def lower(plan: PlanNode, *, fuse: bool, fuse_join: bool, topk: bool,
             # placement over one device is the identity
             return mk("exchange-hash" if ndev > 1 else "exchange-identity")
         if isinstance(node, TopK):
-            scan = _single_chunked_scan(node.child) \
-                if topk and node.n else None
+            scan = _single_chunked_scan(node.child) if node.n else None
             return mk("interp") if scan is None \
                 else mk("stream-topk", scan=scan)
         if isinstance(node, Aggregate):
             scan = _stream_scan_of(node)
             if scan is not None:
-                seg = sg.build_stream_segment(
-                    node, scan, nparents, fuse_join=fuse_join) \
+                seg = sg.build_stream_segment(node, scan, nparents) \
                     if fuse else None
                 if seg is not None and seg.input is scan \
                         and sg.worthwhile(seg, streaming=True):

@@ -437,7 +437,8 @@ class TopK(PlanNode):
     ORDER BY ... LIMIT form the optimizer rewrites ``Limit(Sort(x), n)``
     into.  Semantically identical to sort-then-slice; the executor may run
     it as a streaming per-chunk partial top-k (a capacity-``n`` device
-    buffer instead of a full materialized sort) when ``SRJT_TOPK`` is on.
+    buffer instead of a full materialized sort) when ``n`` > 0 and the
+    child streams one chunked scan.
     ``keys`` = ((column, ascending), ...), like ``Sort``."""
     child: PlanNode
     keys: Tuple[tuple, ...]

@@ -4,8 +4,8 @@ interleaving + per-session memory budgets.
 Everything below the bridge was already concurrency-ready — fingerprints
 are session-agnostic, the caches are lock-audited LRUs, trace ids join a
 query's spans/profiles/bundles across connections (PRs 11-15).  This
-module adds the missing policy layer for ROADMAP item 1 (the
-interactive-concurrency regime "Accelerating Presto with GPUs" targets):
+module adds the missing policy layer for the interactive-concurrency
+regime ("Accelerating Presto with GPUs" targets it):
 WHO gets on the device, WHEN their chunks run, and HOW MUCH memory each
 tenant may pin.
 

@@ -196,8 +196,7 @@ def build_segment(top: PlanNode, nparents: dict) -> Optional[Segment]:
 
 
 def build_stream_segment(agg: Aggregate, scan: PlanNode,
-                         nparents: dict,
-                         fuse_join: bool = True) -> Optional[Segment]:
+                         nparents: dict) -> Optional[Segment]:
     """The streamed-path segment under ``agg``: like ``build_segment``, but
     an inner/semi Join whose build (right) side is scan-independent is
     absorbed instead of breaking — the chain continues down the probe
@@ -213,7 +212,7 @@ def build_stream_segment(agg: Aggregate, scan: PlanNode,
         if isinstance(cur, _FUSABLE) and nparents.get(id(cur), 1) == 1:
             chain.append(cur)
             cur = cur.child
-        elif (fuse_join and isinstance(cur, Join)
+        elif (isinstance(cur, Join)
               and nparents.get(id(cur), 1) == 1
               and cur.how in _FUSABLE_JOINS
               and depends_on(cur.left, scan, dep)
@@ -1945,6 +1944,10 @@ def fused_runtime_eligible(stage: FusedStage, table: Table) -> bool:
     return True
 
 
+#: the fused stage's static per-shard live-group budget (a power of two)
+FUSE_GROUPS = 4096
+
+
 def fused_prefix(n_local: int) -> int:
     """Static per-shard live-group budget of the fused stage.
 
@@ -1955,17 +1958,16 @@ def fused_prefix(n_local: int) -> int:
     plausibly hold, not the shard's full row count.  Sizing that prefix
     from rows (the obvious static bound) makes the combine sort
     ``ndev * capacity`` mostly-dead slots and triples the stage's wall
-    time on a 30k-row shard with 2k live groups, so the budget comes from
-    ``SRJT_FUSE_GROUPS`` instead (bucketed for compile-cache stability,
-    clamped by the row bound).  A shard that aggregates MORE live groups
-    than the budget trips the same device-side psum'd overflow counter as
-    a full exchange bucket, and the executor re-plans on the
-    host-orchestrated path — a runtime fallback, never an error.
+    time on a 30k-row shard with 2k live groups, so the budget is
+    ``FUSE_GROUPS`` instead (clamped by the row bound).  A shard that
+    aggregates MORE live groups than the budget trips the same device-side
+    psum'd overflow counter as a full exchange bucket, and the executor
+    re-plans on the host-orchestrated path — a runtime fallback, never an
+    error.
     """
-    from ..parallel.shuffle import cap_bucket
     if n_local <= 0:
         return 1
-    return min(n_local, cap_bucket(max(1, int(config.fuse_groups))))
+    return min(n_local, FUSE_GROUPS)
 
 
 def fused_capacity(prefix: int, ndev: int) -> int:
@@ -2156,8 +2158,7 @@ class FusedStageCache:
             axis: str) -> CompiledFusedStage:
         from ..parallel.mesh import axis_size
         ndev = axis_size(mesh, axis)
-        # fused_prefix in the key: an SRJT_FUSE_GROUPS change must compile
-        # a fresh program, not replay one sized for the old budget
+        # fused_prefix in the key: a program is sized for its group budget
         key = (stage.fingerprint(), shape_class(padded), ndev, axis,
                fused_prefix(padded.num_rows // ndev))
         with self._lock:

@@ -779,8 +779,8 @@ def decision_census(plan: PlanNode, dist: bool | None = None) -> list:
     distributed Aggregate still carrying order-sensitive ops.  So for a
     planner-optimized plan (no hand-placed exchanges) this census equals,
     kind for kind, the structural entries of the plan's ``_decisions``
-    ledger — ci/premerge.sh and the bench dist script assert exactly
-    that against the EXPLAIN footer.  Elimination/fold decisions remove
+    ledger — ``tests/test_evidence_plane.py`` asserts exactly that
+    against the EXPLAIN footer.  Elimination/fold decisions remove
     structure and are deliberately absent here.
 
     ``dist`` gates the order-sensitive-revert entries (the revert only
@@ -880,8 +880,7 @@ def _lowered(plan: PlanNode, resolver: "SchemaResolver", cfg,
     if ndev is None:
         import jax
         ndev = len(jax.devices())
-    physical = lower(plan, fuse=cfg.fuse, fuse_join=cfg.fuse_join,
-                     topk=cfg.topk, fuse_exchange=cfg.fuse_exchange,
+    physical = lower(plan, fuse=cfg.fuse, fuse_exchange=cfg.fuse_exchange,
                      ndev=ndev, resolver=lambda node: verify(node, resolver))
     entries: list = []
     for st in physical.run_stages():

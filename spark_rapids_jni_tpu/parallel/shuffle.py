@@ -426,7 +426,7 @@ def shuffle_table_padded(table: Table, mesh: Mesh, keys: list,
                       split)
     # exchange observability: every slot of the padded all_to_all crosses
     # the interconnect whether live or not, so slots x row_size IS the
-    # wire traffic (the padding_efficiency ratio bench.py reports)
+    # wire traffic
     metrics.count("parallel.shuffle.exchanges")
     metrics.count("parallel.shuffle.exchange_bytes",
                   ndev * ndev * capacity * layout.row_size)

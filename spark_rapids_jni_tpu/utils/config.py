@@ -14,8 +14,6 @@ environment flags read once at import:
 | ``SRJT_PREFETCH``     | ``1``   | chunked-scan pipeline depth (0 = serial) |
 | ``SRJT_PLAN_CACHE``   | ``128`` | plan-cache capacity (spark.sql plan-cache size) |
 | ``SRJT_SEGMENT_CACHE``| ``256`` | compiled-segment cache capacity |
-| ``SRJT_FUSE_JOIN``    | ``1``   | fuse scan-independent-build joins into streamed chunk programs |
-| ``SRJT_TOPK``         | ``1``   | streaming top-k for ORDER BY ... LIMIT (TopK plans) |
 | ``SRJT_BUILD_CACHE``  | ``32``  | prepared-join-build cache capacity (entries) |
 | ``SRJT_METRICS``      | ``1``   | query-scoped metrics collection (spans/histograms/gauges, utils/metrics.py) |
 | ``SRJT_TIMELINE``     | ``0``   | in-process trace-event timeline (utils/timeline.py, Perfetto-loadable JSON) |
@@ -26,7 +24,6 @@ environment flags read once at import:
 | ``SRJT_BROADCAST_ROWS`` | ``100000`` | broadcast-join threshold: estimated build rows at or under this replicate instead of shuffling |
 | ``SRJT_AQE``          | ``0``   | adaptive query execution (engine/adaptive.py): runtime broadcast flip, hot-key skew split, profile-warmed planning |
 | ``SRJT_FUSE_EXCHANGE`` | ``0``  | whole-stage exchange fusion: lower the partial-agg -> hash Exchange -> final-agg sandwich into ONE pjit/shard_map program (engine/segment.py fused stage) |
-| ``SRJT_FUSE_GROUPS`` | ``4096`` | fused stage's static per-shard live-group budget: sizes the in-program exchange (prefix + per-dest capacity); a shard aggregating more groups trips the device-side overflow counter and the stage re-plans on the host path |
 | ``SRJT_AQE_SKEW``     | ``4.0`` | skew (max/mean device load) above which a hash exchange splits its hot keys round-robin |
 | ``SRJT_AQE_BROADCAST_ROWS`` | ``-1`` | measured-rows threshold for the runtime broadcast flip (``-1`` = follow ``SRJT_BROADCAST_ROWS``) |
 | ``SRJT_PROFILE_DIR``  | *(unset)* | persist one compact query profile JSON per query into this dir (utils/profile.py; empty = off) |
@@ -41,8 +38,7 @@ environment flags read once at import:
 | ``SRJT_BLACKBOX_DIR`` | *(unset)* | post-mortem bundle directory (empty = ring only, no disk writes) |
 | ``SRJT_BLACKBOX_CAP`` | ``512`` | flight-recorder ring capacity (events; oldest dropped) |
 | ``SRJT_SLO_MS``       | *(unset)* | latency objectives: ``default_ms[,fp12=ms,...]`` per source fingerprint, evaluated from the profile store (utils/blackbox.py slo_report) |
-| ``SRJT_TRACE_ID``     | *(unset)* | inherited trace context for helper processes (bench dist subprocess); minted per client/query when empty |
-| ``SRJT_SCHED``        | ``1``   | multi-tenant scheduler (engine/scheduler.py): SLO-aware admission + fair-share chunk interleaving on the bridge PLAN_EXECUTE path |
+| ``SRJT_TRACE_ID``     | *(unset)* | inherited trace context for helper processes; minted per client/query when empty |
 | ``SRJT_MAX_SESSIONS`` | ``8``   | concurrent admitted PLAN_EXECUTE sessions; arrivals past this queue at admission |
 | ``SRJT_ADMISSION_QUEUE_S`` | ``5.0`` | max seconds a query waits in the admission queue before it is shed (AdmissionRejectedError) |
 | ``SRJT_ADMISSION_BURN`` | ``0.9`` | SLO burn rate (breaches/runs from the profile store) at or above which a saturated server sheds the fingerprint immediately instead of queueing |
@@ -99,8 +95,6 @@ class Config:
     prefetch: int = 1            # chunked-scan pipeline depth (0 = serial)
     plan_cache: int = 128        # PlanCache capacity (entries)
     segment_cache: int = 256     # compiled-segment cache capacity (entries)
-    fuse_join: bool = True       # probe-join fusion on the streamed path
-    topk: bool = True            # streaming top-k execution of TopK plans
     build_cache: int = 32        # prepared-build cache capacity (entries)
     metrics: bool = True         # query-scoped metrics (utils/metrics.py)
     timeline: bool = False       # trace-event timeline (utils/timeline.py)
@@ -111,7 +105,6 @@ class Config:
     broadcast_rows: int = 100_000  # broadcast-join build-size threshold (rows)
     aqe: bool = False            # adaptive execution (engine/adaptive.py)
     fuse_exchange: bool = False  # in-program exchange (fused dist stage)
-    fuse_groups: int = 4096      # fused stage's static per-shard group cap
     aqe_skew: float = 4.0        # skew threshold for the hot-key split
     aqe_broadcast_rows: int = -1  # runtime flip threshold (-1 = follow
     #                               broadcast_rows)
@@ -128,7 +121,6 @@ class Config:
     blackbox_cap: int = 512      # flight-recorder ring capacity (events)
     slo_ms: str = ""             # latency objectives spec (default[,fp=ms])
     trace_id: str = ""           # inherited trace context (subprocesses)
-    sched: bool = True           # multi-tenant scheduler (engine/scheduler)
     max_sessions: int = 8        # concurrent admitted PLAN_EXECUTE sessions
     admission_queue_s: float = 5.0  # admission-queue wait bound (seconds)
     admission_burn: float = 0.9  # burn rate that sheds when saturated
@@ -146,8 +138,6 @@ class Config:
             prefetch=_int_flag("SRJT_PREFETCH", 1),
             plan_cache=_int_flag("SRJT_PLAN_CACHE", 128, minimum=1),
             segment_cache=_int_flag("SRJT_SEGMENT_CACHE", 256, minimum=1),
-            fuse_join=_bool_flag("SRJT_FUSE_JOIN", True),
-            topk=_bool_flag("SRJT_TOPK", True),
             build_cache=_int_flag("SRJT_BUILD_CACHE", 32, minimum=1),
             metrics=_bool_flag("SRJT_METRICS", True),
             timeline=_bool_flag("SRJT_TIMELINE", False),
@@ -159,7 +149,6 @@ class Config:
             broadcast_rows=_int_flag("SRJT_BROADCAST_ROWS", 100_000),
             aqe=_bool_flag("SRJT_AQE", False),
             fuse_exchange=_bool_flag("SRJT_FUSE_EXCHANGE", False),
-            fuse_groups=_int_flag("SRJT_FUSE_GROUPS", 4096, minimum=1),
             aqe_skew=_float_flag("SRJT_AQE_SKEW", 4.0, minimum=1.0),
             aqe_broadcast_rows=_int_flag("SRJT_AQE_BROADCAST_ROWS", -1,
                                          minimum=-1),
@@ -176,7 +165,6 @@ class Config:
             blackbox_cap=_int_flag("SRJT_BLACKBOX_CAP", 512, minimum=16),
             slo_ms=os.environ.get("SRJT_SLO_MS", "").strip(),
             trace_id=os.environ.get("SRJT_TRACE_ID", "").strip(),
-            sched=_bool_flag("SRJT_SCHED", True),
             max_sessions=_int_flag("SRJT_MAX_SESSIONS", 8, minimum=1),
             admission_queue_s=_float_flag("SRJT_ADMISSION_QUEUE_S", 5.0),
             admission_burn=_float_flag("SRJT_ADMISSION_BURN", 0.9),
@@ -216,8 +204,8 @@ def enable_compile_cache() -> str:
     """Point jax's persistent compilation cache at a stable directory;
     returns the directory in use.
 
-    Called by the entry points that compile (``bridge.server.main``,
-    ``bench.main``), never at package import.  Where
+    Called by the entry point that compiles (``bridge.server.main``),
+    never at package import.  Where
     ``JAX_COMPILATION_CACHE_DIR`` is set jax already honours it and nothing
     is set in code.  Otherwise the cache lives at ONE fixed path inside the
     checkout — the path is part of every cache key's lookup, so a temp

@@ -1,6 +1,6 @@
 """Error taxonomy, typed failures, and cooperative cancellation.
 
-The serving regime (ROADMAP item 3) needs every failure classified before
+The serving regime needs every failure classified before
 anything can decide what to do with it: retry, degrade, or surface.  This
 module is the dependency-free bottom layer both sides of the bridge share —
 ``engine/recovery.py`` builds retry/degradation policy on top, and the

@@ -8,14 +8,12 @@ SQLMetrics: a ``QueryMetrics`` context that collects per-plan-node spans
 count), per-query counter attribution, and lock-protected histograms and
 gauges keyed by dotted name so concurrent queries never collide.
 
-Three consumers sit on top (docs/OBSERVABILITY.md):
+Two consumers sit on top (docs/OBSERVABILITY.md):
 
 - ``engine.explain_analyze(plan)`` renders the optimized DAG annotated
   with the spans recorded here (the EXPLAIN ANALYZE analog).
 - The bridge's ``OP_METRICS`` reply embeds ``snapshot()`` so JNI-side
   callers can poll counters + histograms + per-query summaries.
-- ``bench.py`` embeds ``snapshot()`` into its emitted JSON so BENCH_*.json
-  carries attribution, not just totals.
 
 Collection is gated by ``SRJT_METRICS`` (default on): every entry point is
 cheap dict/``perf_counter`` work — no device syncs — and with the flag off
@@ -64,7 +62,7 @@ _gauges: dict[str, float] = {}
 #: runs.  Writes ride the per-query lock; no device work anywhere.
 _progress: dict[int, "QueryMetrics"] = {}
 
-#: completed-query summaries, newest last (the bridge/bench export window)
+#: completed-query summaries, newest last (the bridge's export window)
 _RECENT_LIMIT = 32
 _recent: "deque[dict]" = deque(maxlen=_RECENT_LIMIT)
 

@@ -20,8 +20,6 @@ Consumers:
   per-node deltas between two runs of the same fingerprint and flags
   regression attribution (node slowed, cache stopped hitting, exchange
   skewed, latency tail grew).
-- ``ci/bench_gate.py --profiles DIR`` — gates on profile-derived keys
-  (``profile.exchange.skew``, ``profile.chunk_latency.p99``).
 - The bridge's ``OP_METRICS`` reply embeds ``store_summary()``.
 
 All writes are best-effort (the metrics layer swallows profile IO errors);
@@ -242,9 +240,9 @@ def history(source_fingerprint: str | None,
 
 
 def store_summary(dir_path: str | None = None) -> dict:
-    """Aggregate view of the store — the bench smoke line / OP_METRICS
-    block: profile count, worst exchange skew seen, and the latest
-    chunk-latency p99 across stored profiles."""
+    """Aggregate view of the store — the OP_METRICS block: profile
+    count, worst exchange skew seen, and the latest chunk-latency p99
+    across stored profiles."""
     paths = list_profiles(dir_path)
     top_skew = None
     p99 = None

@@ -2,7 +2,7 @@
 
 ``SRJT_TRACE`` gives Perfetto spans *through* ``jax.profiler`` — heavyweight,
 platform-dependent, and unavailable in plenty of deployment shells.  This
-module is the always-available fallback the bridge and bench can ship: a
+module is the always-available fallback the bridge can ship: a
 bounded ring buffer of events recorded with nothing but ``perf_counter`` and
 a deque append, exported as Chrome trace-event JSON that loads directly in
 Perfetto (ui.perfetto.dev) or ``chrome://tracing``.

@@ -248,6 +248,39 @@ def test_aqe_rules_fire_with_parity(warehouse, monkeypatch):
         cfg.refresh()
 
 
+def test_skew_split_brings_skew_gauge_under_threshold(warehouse,
+                                                     monkeypatch):
+    """A non-decomposable mean sends the whole hot-key fact through the
+    exchange: the split is applied, the exchange's post-placement skew
+    gauge lands under the trigger threshold, and the answer is the
+    AQE-off single-device one."""
+    if not metrics.enabled():
+        pytest.skip("SRJT_METRICS off")
+
+    def meanplan():
+        return Aggregate(Scan(warehouse / "fact.parquet"), ("k",),
+                         (("v", "mean"),), ("m",))
+
+    base = execute(optimize(meanplan()), new_stats())
+    try:
+        _aqe_env(monkeypatch, SRJT_AQE=1, SRJT_AQE_SKEW=2.0)
+        opt = optimize(meanplan(), distribute=True)
+        stats = new_stats()
+        out = execute(opt, stats)
+        gauge = metrics.gauges_snapshot("engine.exchange.skew")[
+            "engine.exchange.skew"]
+        splits = [d for d in adaptive.runtime_entries(opt)
+                  if d["kind"] == "adaptive:skew_split" and d["triggered"]]
+        assert splits and stats["aqe_splits"] == len(splits)
+        assert splits[0]["measured_skew"] > 2.0
+        assert gauge < 2.0
+        pd.testing.assert_frame_equal(_as_df(out), _as_df(base))
+    finally:
+        for k in ("SRJT_AQE", "SRJT_AQE_SKEW"):
+            monkeypatch.delenv(k)
+        cfg.refresh()
+
+
 def test_aqe_declines_are_recorded_not_applied(warehouse, monkeypatch):
     """Thresholds that nothing crosses: the rules are consulted and
     recorded (triggered=no) but the planned strategies execute."""

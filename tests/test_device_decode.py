@@ -152,6 +152,26 @@ class TestGoldenParity:
         for gi, (chunk, table) in enumerate(_decode_file(path)):
             _assert_group_parity(chunk, table, ref.read_row_group(gi))
 
+    def test_snappy_back_references_ship_fewer_bytes(self, tmp_path):
+        # repeating values make snappy emit copies, not only literals: the
+        # copy resolution decodes bit-exact, and the compressed pages the
+        # device path ships are fewer bytes than the decoded columns
+        import jax
+        n = 2_400
+        path = str(tmp_path / "t.parquet")
+        pq.write_table(pa.table({
+            "a": pa.array((np.arange(n) % 97).astype(np.int64)),
+            "b": pa.array(np.repeat(np.arange(n // 100), 100)
+                          .astype(np.int64)),
+        }), path, compression="snappy", use_dictionary=False)
+        chunk, reason = pqio.plan_device_group(pqio.ParquetFile(path), 0,
+                                               None, 1 << 30)
+        assert chunk is not None, reason
+        table = jax.jit(pqd.decode_table, static_argnums=1)(
+            chunk.to_device(), chunk.geom)
+        _assert_group_parity(chunk, table, pq.read_table(path))
+        assert chunk.comp_bytes < chunk.unc_bytes
+
     def test_multi_column_multi_page(self, tmp_path):
         # small data_page_size forces several pages per column chunk, so
         # the on-device row -> (page, slot) derivation sees npages > 1
@@ -426,8 +446,7 @@ class TestEngineE2E:
         from spark_rapids_jni_tpu.engine.verify import lint_decode_segment
         path = self._warehouse(tmp_path)
         opt = optimize(self._plan(path), distribute=False)
-        st = lower(opt, fuse=True, fuse_join=True, topk=True,
-                   fuse_exchange=False, ndev=1).stage_at(opt)
+        st = lower(opt, fuse=True, fuse_exchange=False, ndev=1).stage_at(opt)
         assert st.kind == "stream-agg"
         seg = st.segment
         chunk, reason = pqio.plan_device_group(

@@ -203,6 +203,26 @@ def test_shuffle_elimination_on_co_partitioned_input(warehouse):
     check_partitioning(opt)
 
 
+def test_co_partitioned_plan_executes_no_exchange(warehouse):
+    """What the static census says of the co-partitioned plan is what
+    runs: zero exchanges executed, and the single-device answer."""
+    root, _, _ = warehouse
+
+    def plan(**part):
+        j = Join(Scan(root / "fact.parquet", **part.get("f", {})),
+                 Scan(root / "dim.parquet", **part.get("d", {})),
+                 ("k",), ("dk",), "inner")
+        return Aggregate(j, ("k",), (("v", "sum"),), ("total",))
+
+    opt = optimize(plan(f={"partitioned_by": ("k",)},
+                        d={"partitioned_by": ("dk",)}), distribute=True)
+    stats = new_stats()
+    out = _as_df(execute(opt, stats))
+    assert stats["exchanges"] == len(plan_exchanges(opt)) == 0
+    base = _as_df(execute(optimize(plan()), new_stats()))
+    pd.testing.assert_frame_equal(out, base, atol=1e-6)
+
+
 def test_order_sensitive_agg_stays_single_stream(warehouse):
     """first/last/collect_list results depend on input row order, which
     the hash exchange does not preserve — the planner must leave their

@@ -245,25 +245,6 @@ def test_duplicate_build_hashes_fall_back(warehouse):
     assert as_rows(fused) == as_rows(whole)
 
 
-def test_fuse_join_flag_disables_fusion(warehouse):
-    os.environ["SRJT_FUSE_JOIN"] = "0"
-    config.refresh()
-    try:
-        stats = new_stats()
-        off = execute(optimize(join_agg_plan(warehouse / "fact.parquet",
-                                             warehouse / "dim.parquet",
-                                             24 * 1_024)),
-                      stats=stats, fused=True)
-        assert stats["streamed"] and stats["fused_segments"] == 0
-    finally:
-        del os.environ["SRJT_FUSE_JOIN"]
-        config.refresh()
-    on = execute(optimize(join_agg_plan(warehouse / "fact.parquet",
-                                        warehouse / "dim.parquet",
-                                        24 * 1_024)), fused=True)
-    assert as_rows(off) == as_rows(on)
-
-
 # -- prepared-build ops-level edge cases ------------------------------------
 
 def _null_key_table(n):

@@ -9,8 +9,6 @@ order, on every chunk geometry (1-row chunks, unaligned, row-group-aligned,
 whole-table), with nulls, descending keys, and degenerate k.
 """
 
-import os
-
 import numpy as np
 import pyarrow as pa
 import pyarrow.parquet as pq
@@ -19,7 +17,6 @@ import pytest
 from spark_rapids_jni_tpu.engine import (
     Filter, Limit, Scan, Sort, TopK, col, execute, lit, new_stats, optimize,
 )
-from spark_rapids_jni_tpu.utils import config
 
 N = 3_000
 
@@ -136,17 +133,16 @@ def test_topk_k_zero_and_oversize(warehouse):
 
 
 def test_topk_flag_disables_streaming(warehouse):
-    os.environ["SRJT_TOPK"] = "0"
-    config.refresh()
-    try:
-        stats = new_stats()
-        off = execute(optimize(topk_plan(warehouse / "fact.parquet",
-                                         [("w", True)], 12, 24 * 1_024)),
-                      stats=stats)
-        assert not stats["topk"]
-    finally:
-        del os.environ["SRJT_TOPK"]
-        config.refresh()
-    on = execute(optimize(topk_plan(warehouse / "fact.parquet",
-                                    [("w", True)], 12, 24 * 1_024)))
-    assert ordered_rows(off) == ordered_rows(on)
+    """Whether a TopK streams is the plan's call alone: over a chunked scan
+    it streams, over the same scan unchunked it sorts the whole table, and
+    both give the same rows in the same order."""
+    stats = new_stats()
+    whole = execute(optimize(topk_plan(warehouse / "fact.parquet",
+                                       [("w", True)], 12)), stats=stats)
+    assert not stats["topk"]
+    stats = new_stats()
+    streamed = execute(optimize(topk_plan(warehouse / "fact.parquet",
+                                          [("w", True)], 12, 24 * 1_024)),
+                       stats=stats)
+    assert stats["topk"]
+    assert ordered_rows(whole) == ordered_rows(streamed)

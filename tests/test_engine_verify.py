@@ -61,12 +61,13 @@ def files(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def warehouse(tmp_path_factory):
-    """The bench smoke warehouse + plans, at test size."""
-    import bench
+    """The q5-lite pipeline warehouse + plans, at test size."""
+    from spark_rapids_jni_tpu.engine.fuzz import (pipeline_plans,
+                                                  pipeline_warehouse)
     root = str(tmp_path_factory.mktemp("wh"))
     rng = np.random.default_rng(7)
-    bench._pipeline_warehouse(root, 2000, rng)
-    q5, chunked = bench._pipeline_plans(root, 24_000)
+    pipeline_warehouse(root, 2000, rng)
+    q5, chunked = pipeline_plans(root, 24_000)
     return {"q5": q5, "chunked": chunked}
 
 
@@ -316,6 +317,32 @@ def test_sync_budget_of_a_plan_with_a_tail_is_two(warehouse):
     with metrics.query("verify-tail-crosscheck") as qm:
         executor.execute(physical)
     assert qm.summary()["counters"]["engine.host_sync"] == 2
+
+
+def test_smoke_plans_fused_and_prefetched_match_interpreter(warehouse):
+    """Both smoke plans give one answer fused or interpreted, serial or
+    double-buffered; the chunked plan streams its chunks through a fused
+    segment."""
+    def by_key(t):   # both plans' first column is a unique group key
+        cols = np.stack([np.asarray(c.to_numpy(), np.float64)
+                         for c in t.columns])
+        return cols[:, np.argsort(cols[0], kind="stable")]
+
+    for name, plan in warehouse.items():
+        opt = optimize(plan)
+        want = None
+        for fused in (True, False):
+            for prefetch in (0, 2):
+                stats = executor.new_stats()
+                got = by_key(executor.execute(opt, stats, fused=fused,
+                                              prefetch=prefetch))
+                want = got if want is None else want
+                np.testing.assert_allclose(got, want, rtol=1e-9,
+                                           err_msg=f"{name} {fused} "
+                                                   f"{prefetch}")
+                if name == "chunked":
+                    assert stats["chunks"] > 1
+                    assert bool(stats["fused_segments"]) == fused
 
 
 def test_artifact_lint_clean_on_smoke_plans(warehouse):

@@ -178,6 +178,34 @@ def test_profile_persists_and_scores_decisions(warehouse):
     assert bd["misestimate"] is False
 
 
+def test_profile_store_keeps_dist_run_evidence(warehouse, tmp_path,
+                                               monkeypatch):
+    """A distributed run with hash exchanges, persisted by the store: the
+    store's summary carries the exchanges' skew, and the stored profile
+    its decision ledger and per-node q_error."""
+    monkeypatch.setenv("SRJT_PROFILE_DIR", str(tmp_path))
+    monkeypatch.setenv("SRJT_BROADCAST_ROWS", "0")
+    cfg.refresh()
+    try:
+        opt = optimize(_join_agg(warehouse), distribute=True)
+        with metrics.query("evidence-store") as qm:
+            execute(opt)
+        if qm is None:
+            pytest.skip("SRJT_METRICS off")
+    finally:
+        monkeypatch.delenv("SRJT_PROFILE_DIR")
+        monkeypatch.delenv("SRJT_BROADCAST_ROWS")
+        cfg.refresh()
+    summ = profile.store_summary(str(tmp_path))
+    assert summ["profiles"] == 1
+    assert summ["top_exchange_skew"] is not None
+    (path,) = profile.list_profiles(str(tmp_path))
+    stored = profile.read(path)
+    assert stored["decisions"]
+    assert any(d["kind"] == "shuffle" for d in stored["decisions"])
+    assert any(n.get("q_error") is not None for n in stored["nodes"])
+
+
 def _mk_summary(est_rows, actual_rows):
     """Minimal summary: one broadcast decision over one join-side node."""
     return {"qid": 1, "name": "seed", "wall_s": 0.01,
@@ -418,3 +446,27 @@ def test_srjt_export_cli_warm(capsys):
     assert "# TYPE srjt_engine_stream_chunk_latency_s histogram" in out
     for ln in out.splitlines():
         assert ln.startswith(("#", "srjt_")), ln
+
+
+def test_srjt_export_cli_warm_full_scrape(capsys):
+    """The whole local scrape, no prefix: every line is a ``# TYPE``
+    declaration of a known type or an ``srjt_`` sample whose value parses,
+    and histograms expose their buckets."""
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "tools"))
+    import srjt_export
+    assert srjt_export.main(["--warm"]) == 0
+    lines = [ln for ln in capsys.readouterr().out.splitlines() if ln.strip()]
+    samples = 0
+    for ln in lines:
+        if ln.startswith("# TYPE "):
+            parts = ln.split()
+            assert len(parts) == 4, ln
+            assert parts[3] in ("counter", "gauge", "histogram"), ln
+            continue
+        assert ln.startswith("srjt_"), ln
+        float(ln.rsplit(" ", 1)[1])
+        samples += 1
+    assert samples > 0
+    assert any("_bucket{le=" in ln for ln in lines)

@@ -117,6 +117,33 @@ def test_static_budget_equals_runtime_sync_counter(warehouse):
         assert _host_syncs() - before == sum(e["count"] for e in budget) == 1
 
 
+def test_chunked_sandwich_pays_its_budget(warehouse):
+    """Over a chunked scan too: the fused stage dispatches once and pays
+    its one-sync budget where the host path streams the chunks and pays
+    its own; both tick the exchange census and agree bit for bit."""
+    plan = Aggregate(Scan(warehouse / "fact.parquet", chunk_bytes=64_000),
+                     ("k",), (("v", "sum"), ("v", "count")), ("total", "n"))
+    out, paid = {}, {}
+    for fuse_x in (True, False):
+        with _flags(fuse_exchange=fuse_x):
+            opt = optimize(plan, distribute=True)
+            budget = sum(e["count"]
+                         for e in sync_budget(opt, cfg=config, ndev=NDEV))
+            stats = new_stats()
+            syncs = _host_syncs()
+            before = _counter("engine.fused_stage.dispatches")
+            out[fuse_x] = execute(opt, stats)
+            paid[fuse_x] = _host_syncs() - syncs
+            assert paid[fuse_x] == budget
+            assert stats["exchanges"] == len(plan_exchanges(opt)) == 1
+            assert _counter("engine.fused_stage.dispatches") - before \
+                == int(fuse_x)
+            assert fuse_x or stats["chunks"] > 1
+    assert paid[True] == 1 < paid[False]
+    pd.testing.assert_frame_equal(_df(out[True]), _df(out[False]),
+                                  check_exact=True)
+
+
 def test_empty_input_budget_still_exact(warehouse):
     """The PR 8 review discrepancy, closed: an EMPTY input pays exactly
     the statically-charged syncs on both the fused path (dead-row
@@ -147,8 +174,7 @@ def test_empty_input_budget_still_exact(warehouse):
 def test_lowering_reports_fused_stage(warehouse):
     with _flags(fuse_exchange=True):
         opt = optimize(_sandwich(warehouse), distribute=True)
-        flags = dict(fuse=True, fuse_join=True, topk=True,
-                     fuse_exchange=True)
+        flags = dict(fuse=True, fuse_exchange=True)
         stages = lower(opt, ndev=NDEV, **flags).stages
         assert "fused-stage" in [s.kind for s in stages]
         st = next(s for s in stages if s.kind == "fused-stage")

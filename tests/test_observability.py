@@ -242,6 +242,20 @@ def test_explain_analyze_totals_match_interpreter(metrics_warehouse):
         assert f"chunks={root_span['chunks']}" in rep.text
 
 
+def test_snapshot_carries_query_summaries(metrics_warehouse):
+    """The export body (``metrics.snapshot()``, what OP_METRICS embeds)
+    holds the summary of a query just run, and serializes as JSON."""
+    from spark_rapids_jni_tpu.engine import execute, optimize
+    from spark_rapids_jni_tpu.utils import metrics
+    if not metrics.enabled():
+        pytest.skip("SRJT_METRICS off")
+    with metrics.query("snapshot-summary"):
+        execute(optimize(_agg_plan(metrics_warehouse)))
+    snap = json.loads(json.dumps(metrics.snapshot()))
+    mine = [q for q in snap["queries"] if q["name"] == "snapshot-summary"]
+    assert mine and mine[-1]["nodes"] and mine[-1]["wall_s"] > 0
+
+
 def test_build_cache_hit_attributed_to_owning_query(metrics_warehouse,
                                                     metrics_isolation):
     """Two queries over the same streamed join: the first owns the one
@@ -479,138 +493,6 @@ def test_profile_enters_and_exits_jax_trace(monkeypatch, tmp_path):
     with tracing.profile(str(tmp_path / "d")):
         calls.append(("body",))
     assert [c[0] for c in calls] == ["init", "enter", "body", "exit"]
-
-
-# -- ci/bench_gate.py --------------------------------------------------------
-
-def _load_bench_gate():
-    import importlib.util
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "ci", "bench_gate.py")
-    spec = importlib.util.spec_from_file_location("bench_gate", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_gate_classification(tmp_path):
-    """Flattening, direction handling, and the four statuses."""
-    bg = _load_bench_gate()
-    baselines = tmp_path / "pins.json"
-    baselines.write_text(json.dumps({"_gate": {
-        "tolerance_default": 0.2,
-        "metrics": {
-            "m.value": {"reference": 100.0, "direction": "higher"},
-            "m.extras.sub.value": {"reference": 10.0, "direction": "higher",
-                                   "tolerance": 0.5},
-            "lat.latency_ms.p50": {"reference": 50.0, "direction": "lower"},
-            "gone.value": {"reference": 1.0, "direction": "higher"},
-        }}}))
-    artifact = "\n".join([
-        "non-json chatter is skipped",
-        json.dumps({"metric": "m", "value": 90.0, "ok": True,
-                    "extras": {"sub": {"value": 30.0}}}),
-        json.dumps({"metric": "lat", "latency_ms": {"p50": 70.0}}),
-    ])
-    s = bg.run_gate(artifact, str(baselines))
-    rows = s["rows"]
-    assert rows["m.value"]["status"] == "ok"          # within 20%
-    assert rows["m.extras.sub.value"]["status"] == "improved"
-    assert rows["lat.latency_ms.p50"]["status"] == "regression"  # lower-is-better
-    assert rows["gone.value"]["status"] == "missing"
-    assert (s["checked"], s["ok"], s["improved"],
-            s["regressions"], s["missing"]) == (4, 1, 1, 1, 1)
-    text = bg.render(s)
-    assert "regression" in text and "gone.value" in text
-
-
-def test_bench_gate_exit_codes(tmp_path, capsys):
-    """Report-only always exits 0; --enforce fails on regressions."""
-    bg = _load_bench_gate()
-    baselines = tmp_path / "pins.json"
-    baselines.write_text(json.dumps({"_gate": {
-        "tolerance_default": 0.25,
-        "metrics": {"m.value": {"reference": 100.0,
-                                "direction": "higher"}}}}))
-    art = tmp_path / "bench.json"
-    art.write_text(json.dumps({"metric": "m", "value": 10.0}))
-    assert bg.main(["--artifact", str(art),
-                    "--baselines", str(baselines)]) == 0
-    assert bg.main(["--artifact", str(art), "--baselines", str(baselines),
-                    "--enforce"]) == 1
-    art.write_text(json.dumps({"metric": "m", "value": 99.0}))
-    assert bg.main(["--artifact", str(art), "--baselines", str(baselines),
-                    "--enforce"]) == 0
-    out = capsys.readouterr().out
-    assert '"metric": "bench_gate"' in out
-
-
-def test_bench_gate_repo_artifacts_parse():
-    """The real BENCH_BASELINES.json _gate section loads, and every gated
-    full-bench key matches the artifact shape bench.py main() emits."""
-    bg = _load_bench_gate()
-    specs, tol = bg.load_gate(bg.DEFAULT_BASELINES)
-    assert specs and 0 < tol < 1
-    for key, spec in specs.items():
-        assert spec["direction"] in ("higher", "lower")
-        assert float(spec["reference"]) > 0
-
-
-def test_bench_gate_enforce_keys_allowlist(tmp_path, capsys):
-    """--enforce-keys narrows the flip: only allowlisted regressions (or
-    allowlisted keys the artifact silently dropped) fail the gate; every
-    other key keeps reporting without gating."""
-    bg = _load_bench_gate()
-    baselines = tmp_path / "pins.json"
-    baselines.write_text(json.dumps({"_gate": {
-        "tolerance_default": 0.2,
-        "metrics": {
-            "soaked.value": {"reference": 100.0, "direction": "higher"},
-            "fresh.value": {"reference": 100.0, "direction": "higher"},
-        }}}))
-    art = tmp_path / "bench.json"
-    # fresh regresses hard, soaked is within tolerance
-    art.write_text("\n".join([
-        json.dumps({"metric": "soaked", "value": 99.0}),
-        json.dumps({"metric": "fresh", "value": 10.0})]))
-    common = ["--artifact", str(art), "--baselines", str(baselines),
-              "--enforce"]
-    assert bg.main(common + ["--enforce-keys", "soaked.value"]) == 0
-    assert bg.main(common + ["--enforce-keys", "fresh.value"]) == 1
-    assert bg.main(common) == 1        # no allowlist: every key enforces
-    # a DROPPED allowlisted key fails too (missing == regression)
-    art.write_text(json.dumps({"metric": "fresh", "value": 200.0}))
-    assert bg.main(common + ["--enforce-keys", "soaked.value"]) == 1
-    out = capsys.readouterr().out
-    assert '"enforced_failures": ["soaked.value"]' in out
-
-
-def test_bench_gate_profiles_fold(tmp_path):
-    """--profiles DIR folds the query-profile store into gateable keys:
-    worst-case max across profiles, torn files and strangers skipped."""
-    bg = _load_bench_gate()
-    pdir = tmp_path / "store"
-    pdir.mkdir()
-    (pdir / "profile-001-aaa.json").write_text(json.dumps({
-        "exchanges": [{"skew": 1.2, "straggler_share": 0.1}],
-        "histograms": {"engine.stream.chunk_latency_s": {"p99": 0.01}}}))
-    (pdir / "profile-002-bbb.json").write_text(json.dumps({
-        "exchanges": [{"skew": 3.5, "straggler_share": 0.7}],
-        "histograms": {"engine.stream.chunk_latency_s": {"p99": 0.002}}}))
-    (pdir / "profile-003-ccc.json").write_text("{torn")   # skipped
-    (pdir / "notes.txt").write_text("not a profile")      # ignored
-    assert bg.profile_keys(str(pdir)) == {
-        "profile.exchange.skew": 3.5,
-        "profile.exchange.straggler_share": 0.7,
-        "profile.chunk_latency.p99": 0.01}
-    assert bg.profile_keys(str(tmp_path / "missing")) == {}
-    baselines = tmp_path / "pins.json"
-    baselines.write_text(json.dumps({"_gate": {"metrics": {
-        "profile.exchange.skew": {"reference": 1.3, "direction": "lower",
-                                  "tolerance": 1.0}}}}))
-    s = bg.run_gate("", str(baselines), profiles_dir=str(pdir))
-    # 3.5 > 1.3 * (1 + 1.0): the skewed run trips the lower-is-better key
-    assert s["rows"]["profile.exchange.skew"]["status"] == "regression"
 
 
 def test_histogram_percentiles_in_snapshot(metrics_isolation):

@@ -89,6 +89,50 @@ def test_offsets_are_row_size_stride():
         np.asarray(blob.offsets), np.arange(6, dtype=np.int32) * lay.row_size)
 
 
+def numpy_pack(cols, layout):
+    """Host oracle for the fixed-width wire (independent of the device
+    kernel): each column's bytes at its offset, one validity bit per
+    column after them."""
+    n = len(cols[0][0])
+    out = np.zeros((n, layout.row_size), np.uint8)
+    for (data, valid), off in zip(cols, layout.offsets):
+        if data.dtype == np.bool_:
+            data = data.astype(np.uint8)
+        out[:, off:off + data.dtype.itemsize] = \
+            data.view(np.uint8).reshape(n, data.dtype.itemsize)
+    for i, (data, valid) in enumerate(cols):
+        bit = np.uint8(1 << (i % 8))
+        out[:, layout.validity_offset + i // 8] |= \
+            bit if valid is None else np.where(valid, bit, np.uint8(0))
+    return out
+
+
+def test_fixed_wire_bytes_match_oracle():
+    """Eight fixed-width types with nulls in two columns, on random rows:
+    the device's row bytes equal the host oracle's, byte for byte."""
+    rng = np.random.default_rng(0)
+    n = 1_000
+    cols = [
+        (rng.integers(-2**62, 2**62, n).astype(np.int64), None),
+        (rng.standard_normal(n), rng.random(n) > 0.1),
+        (rng.integers(-2**31, 2**31 - 1, n).astype(np.int32), None),
+        (rng.standard_normal(n).astype(np.float32), None),
+        (rng.integers(-2**15, 2**15 - 1, n).astype(np.int16),
+         rng.random(n) > 0.5),
+        (rng.integers(-128, 128, n).astype(np.int8), None),
+        (rng.random(n) > 0.5, None),
+        (rng.integers(-10**15, 10**15, n).astype(np.int64), None),
+    ]
+    schema = [dt.INT64, dt.FLOAT64, dt.INT32, dt.FLOAT32, dt.INT16, dt.INT8,
+              dt.BOOL8, dt.decimal64(-4)]
+    table = Table([Column.from_numpy(d, validity=v, dtype=t)
+                   for (d, v), t in zip(cols, schema)])
+    [blob] = convert_to_rows(table)
+    got = np.asarray(blob.children[0].data).view(np.uint8)
+    want = numpy_pack(cols, fixed_width_layout(schema)).reshape(-1)
+    np.testing.assert_array_equal(got, want)
+
+
 # -- round trips ------------------------------------------------------------
 
 def test_reference_roundtrip():

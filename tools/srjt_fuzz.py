@@ -3,7 +3,7 @@
 
 Drives ``engine/fuzz.py``: synthesize random valid plans over a seeded
 parquet warehouse, sweep each through the executor flag matrix
-(``SRJT_FUSE``/``SRJT_DIST``/``SRJT_TOPK``/``SRJT_BROADCAST_ROWS``),
+(``SRJT_FUSE``/``SRJT_DIST``/``SRJT_BROADCAST_ROWS``/``SRJT_AQE``),
 and assert the rewrite-soundness invariants (verify-after-rewrite,
 ledger==census, exchange census==executed counter, sync whitelist,
 bit-exact executor parity, pandas-oracle parity).  Any failure is
@@ -51,7 +51,7 @@ def main(argv=None) -> int:
     ap.add_argument("--count", type=int, default=SMOKE_COUNT)
     ap.add_argument("--full", action="store_true",
                     help="sweep the extended variant matrix "
-                         "(adds dist-nofuse and interp-notopk)")
+                         "(adds dist-nofuse and dist-fused-aqe)")
     ap.add_argument("--out", default=None,
                     help="write the failure report (seed + shrunk "
                          "minimal plan JSON) to this path on failure")

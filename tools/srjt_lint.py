@@ -54,11 +54,12 @@ Plus two import-time passes:
   ``verify._INFER``, ``verify._NULLS`` (nullability lattice), and
   ``fuzz._ORACLE`` (pandas differential oracle) — a new plan node can't
   silently miss a layer.
-- **``--segments``** — build the bench smoke warehouse in a tempdir, lower
+- **``--segments``** — build the q5-lite pipeline warehouse
+  (``engine/fuzz.py::pipeline_warehouse``) in a tempdir, lower
   the optimized q5-lite + chunked plans' fused segments to jaxprs
   (``verify.lint_plan_artifacts``, nothing executes) and assert the static
   sync budget is EXACTLY the three whitelisted host syncs.  ``--full``
-  extends the plan set with the bench join + top-k shapes (nightly).
+  extends the plan set with a chunked join + top-k shape (nightly).
 
 Usage::
 
@@ -588,7 +589,7 @@ def _fused_plan(tmp: str):
 
 
 def _full_plans(tmp: str):
-    """The nightly extension: bench-shaped join + top-k plans."""
+    """The nightly extension: a chunked join + top-k plan pair."""
     import numpy as np
     import pyarrow as pa
     import pyarrow.parquet as pq
@@ -622,15 +623,16 @@ def segments_pass(full: bool = False) -> list:
     import numpy as np
 
     sys.path.insert(0, REPO)
-    import bench
     from spark_rapids_jni_tpu.engine import optimize
+    from spark_rapids_jni_tpu.engine.fuzz import (pipeline_plans,
+                                                  pipeline_warehouse)
     from spark_rapids_jni_tpu.engine.verify import (check_sync_budget,
                                                     lint_plan_artifacts)
     out: list = []
     with tempfile.TemporaryDirectory() as tmp:
         rng = np.random.default_rng(7)
-        bench._pipeline_warehouse(tmp, 4000, rng)
-        q5, chunked = bench._pipeline_plans(tmp, 48_000)
+        pipeline_warehouse(tmp, 4000, rng)
+        q5, chunked = pipeline_plans(tmp, 48_000)
         plans = {"q5": optimize(q5), "chunked": optimize(chunked)}
         entries, bad = check_sync_budget(list(plans.values()))
         smoke_syncs = sum(e["count"] for e in entries)
@@ -706,8 +708,8 @@ def segments_pass(full: bool = False) -> list:
         from spark_rapids_jni_tpu.io.parquet import (ParquetFile,
                                                      plan_device_group)
         copt = plans["chunked"]
-        seg = lower(copt, fuse=True, fuse_join=True, topk=True,
-                    fuse_exchange=False, ndev=1).stage_at(copt).segment
+        seg = lower(copt, fuse=True, fuse_exchange=False,
+                    ndev=1).stage_at(copt).segment
         chunk, reason = plan_device_group(
             ParquetFile(os.path.join(tmp, "store_sales.parquet")), 0,
             None, 1 << 30)
@@ -740,8 +742,8 @@ def main(argv=None) -> int:
     ap.add_argument("--segments", action="store_true",
                     help="also jaxpr-lint the smoke plans' fused segments")
     ap.add_argument("--full", action="store_true",
-                    help="with --segments: extend to the bench join/top-k "
-                         "plan shapes")
+                    help="with --segments: extend to a chunked join/top-k "
+                         "plan pair")
     args = ap.parse_args(argv)
 
     # import-time passes need the engine importable without a device
